@@ -32,7 +32,7 @@ test:
 # 2->1 topology restore. Writes MULTICHIP_LOCAL_r07.json.
 multihost-sim:
 	$(PY) -m deeplearning4j_tpu.parallel.multihost_sim \
-		--outdir /tmp/dl4j_tpu_multihost_sim \
+		--outdir .scratch/multihost_sim \
 		--artifact MULTICHIP_LOCAL_r07.json
 
 # the tier-1 smoke slice of the same harness: spawn the 2-process pod,
@@ -98,7 +98,7 @@ print(json.dumps(bench.bench_disaggregated_serving(), indent=1))"
 # gate via tests/test_disagg.py::test_disagg_two_process_sim)
 disagg-sim:
 	$(PY) -m deeplearning4j_tpu.parallel.multihost_sim --disagg \
-		--outdir /tmp/dl4j_tpu_disagg_sim
+		--outdir .scratch/disagg_sim
 
 # ISSUE 16: the fused-epilogue kernel-library metric standalone — the
 # fused master-cast+updater step vs the unfused updater-then-cast-sweep

@@ -29,6 +29,10 @@ import jax
 from jax import lax
 
 
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
 class Environment:
     _instance = None
 
@@ -49,20 +53,13 @@ class Environment:
             raise ValueError(
                 f"DL4J_TPU_F32_MATMUL_PRECISION={self.f32_matmul_precision!r} "
                 "— expected one of: auto, highest, high, default")
-        # Persistent XLA compile cache: a given (program, shape) compiles
-        # once per machine, not once per process. "" or "0" disables; any
-        # failure to create the dir just disables caching (never blocks
-        # package import).
-        cache_dir = os.environ.get(
-            "DL4J_TPU_COMPILE_CACHE",
-            os.path.expanduser("~/.cache/deeplearning4j_tpu/xla"))
-        if cache_dir not in ("", "0"):
-            try:
-                os.makedirs(cache_dir, exist_ok=True)
-                jax.config.update("jax_compilation_cache_dir", cache_dir)
-                jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-            except OSError:
-                pass
+        # Persistent XLA compile cache. JAX_COMPILATION_CACHE_DIR, when set,
+        # is JAX's own setting and nothing is set here; otherwise one fixed
+        # directory inside the checkout (the path is part of the cache key,
+        # so it never moves).
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
         # NaN/Inf panic mode (ProfilerConfig.checkForNAN/INF equivalent):
         # routes to jax debug_nans/debug_infs.
         if os.environ.get("DL4J_TPU_CHECK_NAN", "0") == "1":

@@ -137,7 +137,9 @@ class LSTM(_RecurrentLayer):
     def _cell(self, grad_path: bool):
         if not grad_path and self.use_pallas_cell:
             from ...ops import pallas_kernels as pk
-            return pk.lstm_cell_fused if pk.available() else nnops.lstm_cell
+            # a Mosaic kernel cannot ride a GSPMD-partitioned program
+            return pk.lstm_cell_fused if pk.available() \
+                and pk.partitioned() is None else nnops.lstm_cell
         return nnops.lstm_cell
 
     def scan_with_state(self, params, x, carry, mask=None, grad_path=True):

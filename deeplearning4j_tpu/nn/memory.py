@@ -173,7 +173,7 @@ _MA_SUPPORTED = None
 
 def memory_analysis_supported() -> bool:
     """Whether this PJRT build exposes ``Compiled.memory_analysis()``
-    (probed once on a trivial program; some plugin versions lack the API
+    (probed once on a trivial program; a backend may lack the API
     or return None — tests skip-guard on this)."""
     global _MA_SUPPORTED
     if _MA_SUPPORTED is None:

@@ -60,7 +60,8 @@ from jax.sharding import PartitionSpec as P
 
 from . import register
 from ..environment import precision_for
-from .pallas_kernels import _VMEM_BUDGET, available as _tpu_available
+from .pallas_kernels import (_VMEM_BUDGET, available as _tpu_available,
+                             partitioned as _partitioned)
 
 _LANES = 128          # TPU lane count: running max/sum ride replicated lanes
 _NEG = float(np.finfo(np.float32).min)
@@ -107,7 +108,7 @@ def _scores(q_ref, k_ref, bias_ref, scale):
         q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     if bias_ref is not None:
-        s = s + bias_ref[...].astype(jnp.float32)  # [1, bk] broadcasts rows
+        s = s + bias_ref[0].astype(jnp.float32)  # [1, bk] broadcasts rows
     return s
 
 
@@ -217,14 +218,15 @@ def _bwd_dkv_kernel(*refs, scale, nq, has_bias):
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _mq_decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref,
-                      m_scr, l_scr, acc_scr, *, scale, nk, bk):
+def _mq_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
+                      m_scr, l_scr, acc_scr, *, scale, nk, bk, heads):
     """Multi-query decode forward (speculative verify, ISSUE 12): the
     whole Tq=k query window rides one grid row, streaming the cache in
     ``bk`` tiles. The mask is computed INSIDE the kernel from the per-row
     valid length: query i (global position ``l + i``) may attend cache
     columns ``< l + 1 + i`` — a per-(query, key) causal window that is
-    not key-reducible, so it cannot ride the fwd kernel's [B, Tk] bias."""
+    not key-reducible, so it cannot ride the fwd kernel's key bias. The
+    lengths arrive by scalar prefetch ([B] int32 in SMEM)."""
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -236,7 +238,7 @@ def _mq_decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref,
     s = jax.lax.dot_general(
         q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale          # [bq, bk] f32
-    ln = len_ref[0, 0]                                       # int32 scalar
+    ln = len_ref[pl.program_id(0) // heads]                  # int32 scalar
     col = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     s = jnp.where(col < ln + 1 + row, s, _NEG)
@@ -272,11 +274,8 @@ def _load_pallas():
 
 
 def _compiler_params(pltpu):
-    try:
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:  # older/newer spelling: let the compiler default
-        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 # --------------------------------------------------------------------------
@@ -297,7 +296,7 @@ def _fwd_impl(q3, k3, v3, kb, scale, heads, bq, bk, interpret):
     args = [q3, k3, v3]
     if has_bias:
         in_specs.append(
-            pl.BlockSpec((1, bk), lambda b, i, j: (b // heads, j)))
+            pl.BlockSpec((1, 1, bk), lambda b, i, j: (b // heads, 0, j)))
         args.append(kb)
     kernel = functools.partial(_fwd_kernel, scale=scale, nk=nk,
                                has_bias=has_bias)
@@ -332,7 +331,8 @@ def _bwd_impl(q3, k3, v3, kb, m, l, di, do, scale, heads, bq, bk, interpret):
         pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),   # k by j
         pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),   # v by j
     ]
-    bias_spec = [pl.BlockSpec((1, bk), lambda b, i, j: (b // heads, j))] \
+    bias_spec = [pl.BlockSpec((1, 1, bk),
+                              lambda b, i, j: (b // heads, 0, j))] \
         if has_bias else []
     row_specs = [
         pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0)),  # m
@@ -360,7 +360,8 @@ def _bwd_impl(q3, k3, v3, kb, m, l, di, do, scale, heads, bq, bk, interpret):
         pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),   # k by outer i
         pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),   # v by outer i
     ]
-    dkv_bias_spec = [pl.BlockSpec((1, bk), lambda b, i, j: (b // heads, i))] \
+    dkv_bias_spec = [pl.BlockSpec((1, 1, bk),
+                                  lambda b, i, j: (b // heads, 0, i))] \
         if has_bias else []
     dkv_row_specs = [
         pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, j, 0)),  # m
@@ -385,33 +386,36 @@ def _bwd_impl(q3, k3, v3, kb, m, l, di, do, scale, heads, bq, bk, interpret):
     return dq, dk, dv
 
 
-def _mq_impl(q3, k3, v3, lens2, scale, heads, bk, interpret):
+def _mq_impl(q3, k3, v3, lens, scale, heads, bk, interpret):
     """pallas_call wrapper for the Tq=k multi-query decode kernel: the
     whole query window is one block (bq = Tq), the cache streams in
-    ``bk`` tiles, ``lens2`` is the lane-replicated [B, LANES] int32
-    valid-length array (forward only — verify never trains)."""
+    ``bk`` tiles, ``lens`` is the [B] int32 valid-length array, scalar-
+    prefetched (forward only — verify never trains)."""
     pl, pltpu = _load_pallas()
     G, Tq, d = q3.shape
     Tk = k3.shape[1]
     nk = Tk // bk
-    kernel = functools.partial(_mq_decode_kernel, scale=scale, nk=nk, bk=bk)
+    kernel = functools.partial(_mq_decode_kernel, scale=scale, nk=nk, bk=bk,
+                               heads=heads)
     return pl.pallas_call(
         kernel,
-        grid=(G, 1, nk),
-        in_specs=[
-            pl.BlockSpec((1, Tq, d), lambda b, i, j: (b, 0, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, _LANES), lambda b, i, j: (b // heads, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(G, 1, nk),
+            in_specs=[
+                pl.BlockSpec((1, Tq, d), lambda b, i, j, lens: (b, 0, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j, lens: (b, j, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j, lens: (b, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, Tq, d),
+                                   lambda b, i, j, lens: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((Tq, _LANES), jnp.float32),
+                            pltpu.VMEM((Tq, _LANES), jnp.float32),
+                            pltpu.VMEM((Tq, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((G, Tq, d), q3.dtype),
-        out_specs=pl.BlockSpec((1, Tq, d), lambda b, i, j: (b, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((Tq, _LANES), jnp.float32),
-                        pltpu.VMEM((Tq, _LANES), jnp.float32),
-                        pltpu.VMEM((Tq, d), jnp.float32)],
         compiler_params=_compiler_params(pltpu),
         interpret=interpret,
-    )(q3, k3, v3, lens2)
+    )(lens, q3, k3, v3)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -460,34 +464,58 @@ def pick_block(t: int, target: int = 128) -> Optional[int]:
     return None
 
 
+def kv_block_ok(bk: int, tk: int, has_bias: bool) -> bool:
+    """The key bias rides as ``[B, 1, Tk]`` in ``(1, 1, bk)`` blocks, and
+    the TPU lowering wants a block's lane dim a multiple of 128 or the
+    whole row."""
+    return not has_bias or bk % _LANES == 0 or bk == tk
+
+
+def pick_kv_block(tk: int, target: int = 128,
+                  has_bias: bool = False) -> Optional[int]:
+    """:func:`pick_block` for the kv axis, held to :func:`kv_block_ok`."""
+    bk = pick_block(tk, target)
+    if bk is None or kv_block_ok(bk, tk, has_bias):
+        return bk
+    b = int(target) - int(target) % _LANES
+    while b >= _LANES:
+        if tk % b == 0:
+            return b
+        b -= _LANES
+    return None
+
+
 def fits_vmem_attention(bq: int, bk: int, d: int, itemsize: int = 4) -> bool:
     """Per-grid-cell VMEM estimate over the WORST of the three kernels —
     dispatching commits the backward too, and the dkv kernel holds the
     largest set (q/k/v/do blocks, four f32 score-sized tiles, dk/dv
     scratch AND outputs). x2 for pipelining double-buffers."""
+    bias = 8 * bk * 4           # (1, 1, bk) f32 key bias, 8-sublane padded
     fwd = ((bq * d + 2 * bk * d) * itemsize           # q, k, v blocks
            + 2 * bq * bk * 4                          # scores + p (f32)
            + (2 * bq * _LANES + bq * d) * 4           # m/l/acc scratch
-           + (bq * d + 2 * bq * _LANES) * 4)          # o + m/l out blocks
+           + (bq * d + 2 * bq * _LANES) * 4           # o + m/l out blocks
+           + bias)
     dkv = ((2 * bq * d + 2 * bk * d) * itemsize       # q, do, k, v blocks
            + 4 * bq * bk * 4                          # s/p/dp/ds (f32)
            + 3 * bq * _LANES * 4                      # m/l/di row blocks
            + 2 * bk * d * 4                           # dk/dv scratch
-           + 2 * bk * d * itemsize)                   # dk/dv out blocks
+           + 2 * bk * d * itemsize                    # dk/dv out blocks
+           + bias)
     return 2 * max(fwd, dkv) < _VMEM_BUDGET
 
 
 def _key_bias(bias, batch, tk):
     """Reduce an additive bias broadcastable to [B,H,Tq,Tk] down to the
-    per-(batch, key) form [B, Tk] the kernel streams, or None if the bias
-    genuinely varies over heads/queries."""
+    per-(batch, key) form [B, 1, Tk] the kernel streams, or None if the
+    bias genuinely varies over heads/queries."""
     if bias is None:
         return None
     if bias.ndim != 4 or bias.shape[1] != 1 or bias.shape[2] != 1:
         return None
     if bias.shape[0] not in (1, batch) or bias.shape[3] != tk:
         return None
-    kb = jnp.broadcast_to(bias[:, 0, 0, :], (batch, tk))
+    kb = jnp.broadcast_to(bias[:, 0, :, :], (batch, 1, tk))
     return jnp.maximum(kb.astype(jnp.float32), _NEG)
 
 
@@ -526,11 +554,13 @@ def flash_attention(q, k, v, bias=None, scale: Optional[float] = None, *,
         # belt over the autotuner's own validation: blocks that do not
         # tile would silently truncate the grid (Tq // bq); a poisoned
         # entry falls back to the target-128 defaults, never garbage
-        if bq is not None and (Tq % bq or Tk % bk):
-            bq, bk = pick_block(Tq), pick_block(Tk)
+        if bq is not None and (Tq % bq or Tk % bk or not kv_block_ok(
+                bk, Tk, bias is not None)):
+            bq, bk = pick_block(Tq), pick_kv_block(
+                Tk, has_bias=bias is not None)
     else:
         bq = pick_block(Tq, block_q or 128)
-        bk = pick_block(Tk, block_k or 128)
+        bk = pick_kv_block(Tk, block_k or 128, bias is not None)
     if bq is None or bk is None:
         raise ValueError(f"sequence lengths ({Tq}, {Tk}) do not tile into "
                          f"({block_q or 128}, {block_k or 128}) blocks")
@@ -554,10 +584,10 @@ def flash_attention(q, k, v, bias=None, scale: Optional[float] = None, *,
 # --------------------------------------------------------------------------
 
 def length_bias(lengths, cache_len: int):
-    """Per-row valid-length mask in the kernel's key-bias form: ``[B, C]``
-    f32, zero where ``position < length`` and finfo.min elsewhere — exactly
-    the ``kb`` the forward kernel streams, so ragged cache occupancy stays
-    exact without materializing a [B,H,1,C] mask."""
+    """Per-row valid-length mask ``[B, C]`` f32, zero where ``position <
+    length`` and finfo.min elsewhere — with a unit middle axis, the key
+    bias the forward kernel streams, so ragged cache occupancy stays exact
+    without materializing a [B,H,1,C] mask."""
     lengths = jnp.asarray(lengths)
     pos = jax.lax.broadcasted_iota(jnp.int32, (1, cache_len), 1)
     return jnp.where(pos < lengths[:, None].astype(jnp.int32),
@@ -600,10 +630,11 @@ def decode_attention(q, k, v, lengths, scale=None, *,
             1, C, d, q.dtype, True, decode=True, page=page,
             concrete=not isinstance(q, jax.core.Tracer))
         bk = tuned[1] if tuned is not None else None
-        if bk is not None and C % bk:
-            bk = pick_block(C)  # belt: a poisoned entry must not truncate
+        if bk is not None and (C % bk or not kv_block_ok(bk, C, True)):
+            # belt: a poisoned entry must not truncate
+            bk = pick_kv_block(C, has_bias=True)
     else:
-        bk = pick_block(C, block_k)
+        bk = pick_kv_block(C, block_k, True)
     if bk is None:
         raise ValueError(f"cache length {C} does not tile into decode "
                          "blocks; bucket the cache to a power of two")
@@ -612,7 +643,7 @@ def decode_attention(q, k, v, lengths, scale=None, *,
                          f"(bk={bk}, d={d})")
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
-    kb = length_bias(lengths, C)
+    kb = length_bias(lengths, C)[:, None, :]
     o, _, _ = _fwd_impl(q.reshape(B * H, 1, d), k.reshape(B * H, C, d),
                         v.reshape(B * H, C, d), kb, float(scale), H, 1, bk,
                         bool(interpret))
@@ -795,10 +826,9 @@ def decode_multiquery_attention(q, k, v, lengths, scale=None, *,
                          f"budget (Tq={Tq}, bk={bk}, d={d})")
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
-    lens2 = jnp.broadcast_to(
-        jnp.asarray(lengths).astype(jnp.int32)[:, None], (B, _LANES))
     o = _mq_impl(q.reshape(B * H, Tq, d), k.reshape(B * H, C, d),
-                 v.reshape(B * H, C, d), lens2, float(scale), H, bk,
+                 v.reshape(B * H, C, d),
+                 jnp.asarray(lengths).astype(jnp.int32), float(scale), H, bk,
                  bool(interpret))
     return o.reshape(B, H, Tq, d)
 
@@ -823,15 +853,16 @@ _COUNTER_KEYS = ("fused", "fallback_mode", "fallback_platform",
                  # (decode_multiquery_fallback)
                  "decode_fallback_multiquery", "decode_multiquery",
                  "decode_multiquery_fallback",
-                 # ISSUE 17: tensor-parallel serving decisions. Armed by
-                 # tp_shard_context during engine lowering: heads divide
-                 # the model axis -> per-shard dispatch under shard_map;
-                 # otherwise the GSPMD-partitioned einsum path. Both
-                 # counted — zero silent fallbacks extends to TP.
-                 "decode_tp_shard_map", "decode_fallback_tp_gspmd",
-                 "decode_multiquery_tp_shard_map",
-                 "decode_multiquery_fallback_tp_gspmd",
-                 "fallback_tp_gspmd")
+                 # a trace that GSPMD partitions over a mesh
+                 # (pallas_kernels.gspmd_trace: ParallelWrapper's step, a
+                 # serving engine's lowering; ISSUE 17). GSPMD cannot split
+                 # a Mosaic kernel, so the decode dispatchers open a
+                 # shard_map over the model axis when the heads divide it,
+                 # and everything else takes the reference einsum path,
+                 # which GSPMD partitions itself. Both counted.
+                 "decode_tp_shard_map", "decode_multiquery_tp_shard_map",
+                 "fallback_gspmd", "decode_fallback_gspmd",
+                 "decode_multiquery_fallback_gspmd")
 # dispatch decisions live in the process-wide MetricsRegistry (ISSUE 6):
 # one counter, labeled by decision, so `GET /metrics` exposes the
 # fused-vs-fallback mix; counters()/reset_counters() below are the
@@ -841,46 +872,8 @@ from ..runtime import telemetry as _tel  # noqa: E402  (stdlib-only import)
 _DISPATCH = _tel.counter(
     "flash_attention.dispatch",
     "attention dispatch decisions at trace time (fused vs fallback_*)")
-_state = {"mode": os.environ.get("DL4J_TPU_FLASH_ATTENTION", "auto"),
-          "tp_mesh": None, "tp_axis": None}
+_state = {"mode": os.environ.get("DL4J_TPU_FLASH_ATTENTION", "auto")}
 _FUSABLE_DTYPES = (jnp.float32, jnp.bfloat16, jnp.float16)
-
-
-class tp_shard_context:
-    """Arm tensor-parallel dispatch for the duration of a trace (ISSUE
-    17). The serving engines enter this around ``jit(...).lower(...)``
-    when params/KV are model-axis sharded; while armed,
-    :func:`decode_dispatch` / :func:`decode_multiquery_dispatch` route
-    per-shard under ``shard_map`` when the head axis divides the model
-    axis, and :func:`attention` + indivisible decode shapes take the
-    GSPMD-partitioned einsum path — every decision counted. Consulted at
-    TRACE time only (same contract as :func:`set_mode`): warmed
-    executables keep whichever path was traced into them. Re-entrant;
-    not thread-safe (lowering happens under the engine lock)."""
-
-    def __init__(self, mesh, axis: str):
-        self.mesh = mesh
-        self.axis = axis
-        self._prev = (None, None)
-
-    def __enter__(self):
-        self._prev = (_state["tp_mesh"], _state["tp_axis"])
-        _state["tp_mesh"] = self.mesh
-        _state["tp_axis"] = self.axis
-        return self
-
-    def __exit__(self, *exc):
-        _state["tp_mesh"], _state["tp_axis"] = self._prev
-        return False
-
-
-def _tp_armed():
-    """(mesh, axis, k) while a tp_shard_context is live, else None."""
-    mesh, axis = _state["tp_mesh"], _state["tp_axis"]
-    if mesh is None or axis is None or axis not in mesh.shape:
-        return None
-    k = int(mesh.shape[axis])
-    return (mesh, axis, k) if k > 1 else None
 
 
 def mode() -> str:
@@ -907,6 +900,13 @@ def set_mode(m: str) -> str:
     return old
 
 
+def _interpret() -> bool:
+    """Interpret mode is asked for, never fallen into: ``force`` off-TPU
+    (how the CPU tests reach the kernel code). On ``auto`` the off-TPU
+    route is the counted reference path, and on TPU the kernel compiles."""
+    return _state["mode"] == "force" and not _tpu_available()
+
+
 def counters() -> dict:
     """Dispatch-decision counts. Decisions happen at TRACE time (shapes are
     static), so under jit each compiled call-site counts once, not once per
@@ -930,12 +930,12 @@ def _route(q, k, v, bias) -> Optional[str]:
         return "fallback_shape"
     if q.dtype not in _FUSABLE_DTYPES:
         return "fallback_dtype"
-    bq = pick_block(q.shape[2])
-    bk = pick_block(k.shape[2])
-    if bq is None or bk is None:
-        return "fallback_shape"
     if bias is not None and _key_bias(bias, q.shape[0], k.shape[2]) is None:
         return "fallback_bias"
+    bq = pick_block(q.shape[2])
+    bk = pick_kv_block(k.shape[2], has_bias=bias is not None)
+    if bq is None or bk is None:
+        return "fallback_shape"
     if not fits_vmem_attention(bq, bk, q.shape[-1],
                                np.dtype(q.dtype).itemsize):
         return "fallback_vmem"
@@ -947,18 +947,18 @@ def attention(q, k, v, bias=None, scale: Optional[float] = None):
     the f32-softmax reference path otherwise. Layers and the SameDiff
     ``attention.fused_sdpa`` op both enter here.
 
-    Under an armed :class:`tp_shard_context` (TP prefill lowering) the
+    In a trace that GSPMD partitions (``pallas_kernels.gspmd_trace``) the
     reference einsum path is taken unconditionally: GSPMD partitions the
-    head-sharded contractions itself and the decision is counted under
-    ``fallback_tp_gspmd`` (not silent)."""
-    if _tp_armed() is not None:
-        _DISPATCH.inc(decision="fallback_tp_gspmd")
+    batch- or head-sharded contractions itself, which it cannot do to the
+    kernel, and the decision is counted ``fallback_gspmd`` (not silent)."""
+    if _partitioned() is not None:
+        _DISPATCH.inc(decision="fallback_gspmd")
         return reference_attention(q, k, v, bias, scale)
     reason = _route(q, k, v, bias)
     if reason is None:
         _DISPATCH.inc(decision="fused")
         return flash_attention(q, k, v, bias, scale,
-                               interpret=not _tpu_available())
+                               interpret=_interpret())
     _DISPATCH.inc(decision=reason)
     return reference_attention(q, k, v, bias, scale)
 
@@ -976,7 +976,7 @@ def _route_decode(q, k, v) -> Optional[str]:
         return "decode_fallback_shape"
     if q.dtype not in _FUSABLE_DTYPES:
         return "decode_fallback_dtype"
-    bk = pick_block(k.shape[2])
+    bk = pick_kv_block(k.shape[2], has_bias=True)
     if bk is None:
         return "decode_fallback_shape"
     if not fits_vmem_attention(1, bk, q.shape[-1],
@@ -988,9 +988,9 @@ def _route_decode(q, k, v) -> Optional[str]:
 def _decode_dispatch_local(q, k, v, lengths, scale=None, page: int = 0):
     """The per-device decode dispatch body: single-query flash kernel
     when the route is clear, f32-softmax reference otherwise. Called
-    directly (bypassing TP routing) from inside the shard_map inner —
-    the TP context is still armed during that trace and re-entering
-    :func:`decode_dispatch` would recurse."""
+    directly from inside the shard_map inner, where the kernel sees one
+    device's block — the trace is still a partitioned one there and
+    re-entering :func:`decode_dispatch` would recurse."""
     if q.ndim == 4 and q.shape[2] == 1:
         reason = _route_decode(q, k, v)
     elif q.ndim == 4 and q.shape[2] > 1:
@@ -1000,28 +1000,36 @@ def _decode_dispatch_local(q, k, v, lengths, scale=None, page: int = 0):
     if reason is None:
         _DISPATCH.inc(decision="decode_fused")
         return decode_attention(q, k, v, lengths, scale, page=page,
-                                interpret=not _tpu_available())
+                                interpret=_interpret())
     _DISPATCH.inc(decision=reason)
     C = k.shape[2]
     bias = length_bias(lengths, C)[:, None, None, :]
     return reference_attention(q, k, v, bias=bias, scale=scale)
 
 
-def _tp_head_shard(local_fn, armed, q, k, v, lengths, scale, page):
+def _head_shards(q) -> Optional[tuple]:
+    """In a partitioned trace whose mesh has a model axis that divides
+    the heads of ``q`` [B, H, *, d]: ``(mesh, axis)``, else None."""
+    mesh, axis = _partitioned()
+    if axis is None or q.shape[1] % int(mesh.shape[axis]):
+        return None
+    return mesh, axis
+
+
+def _tp_head_shard(local_fn, shards, q, k, v, lengths, scale, page):
     """Run a per-device dispatch body under shard_map with heads (axis 1
     of the [B, H, *, d] operands) split over the model axis. ``lengths``
     stays replicated; softmax is per-head so no cross-shard collective
-    is needed (check_rep=False: the head axis is genuinely sharded)."""
-    from jax.experimental.shard_map import shard_map
-    mesh, axis, _ = armed
+    is needed (check_vma=False: the head axis is genuinely sharded)."""
+    mesh, axis = shards
     spec4 = P(None, axis, None, None)
 
     def inner(q_, k_, v_, lengths_):
         return local_fn(q_, k_, v_, lengths_, scale=scale, page=page)
 
-    return shard_map(inner, mesh=mesh,
-                     in_specs=(spec4, spec4, spec4, P()),
-                     out_specs=spec4, check_rep=False)(q, k, v, lengths)
+    return jax.shard_map(inner, mesh=mesh,
+                         in_specs=(spec4, spec4, spec4, P()),
+                         out_specs=spec4, check_vma=False)(q, k, v, lengths)
 
 
 def decode_dispatch(q, k, v, lengths, scale=None, page: int = 0):
@@ -1034,17 +1042,18 @@ def decode_dispatch(q, k, v, lengths, scale=None, page: int = 0):
     ``decode_fallback_multiquery`` slug (ISSUE 12 satellite) so it never
     blends with genuine shape failures or the verify path's decisions.
 
-    Under an armed :class:`tp_shard_context` (ISSUE 17): heads divisible
-    by the model-axis size run the per-shard body under ``shard_map``
-    (``decode_tp_shard_map``); otherwise the GSPMD-partitioned reference
-    einsum (``decode_fallback_tp_gspmd``). Both counted."""
-    armed = _tp_armed()
-    if armed is not None and q.ndim == 4:
-        if q.shape[1] % armed[2] == 0:
+    In a partitioned trace (``pallas_kernels.gspmd_trace``, ISSUE 17):
+    heads divisible by the model-axis size run the per-shard body under
+    ``shard_map`` (``decode_tp_shard_map``); otherwise the reference
+    einsum, which GSPMD partitions (``decode_fallback_gspmd``). Both
+    counted."""
+    if _partitioned() is not None and q.ndim == 4:
+        shards = _head_shards(q)
+        if shards is not None:
             _DISPATCH.inc(decision="decode_tp_shard_map")
-            return _tp_head_shard(_decode_dispatch_local, armed,
+            return _tp_head_shard(_decode_dispatch_local, shards,
                                   q, k, v, lengths, scale, page)
-        _DISPATCH.inc(decision="decode_fallback_tp_gspmd")
+        _DISPATCH.inc(decision="decode_fallback_gspmd")
         C = k.shape[2]
         bias = length_bias(lengths, C)[:, None, None, :]
         return reference_attention(q, k, v, bias=bias, scale=scale)
@@ -1083,7 +1092,7 @@ def _decode_multiquery_local(q, k, v, lengths, scale=None, page: int = 0):
         _DISPATCH.inc(decision="decode_multiquery")
         return decode_multiquery_attention(q, k, v, lengths, scale,
                                            page=page,
-                                           interpret=not _tpu_available())
+                                           interpret=_interpret())
     _DISPATCH.inc(decision=reason)
     return reference_decode_multiquery(q, k, v, lengths, scale=scale)
 
@@ -1097,16 +1106,16 @@ def decode_multiquery_dispatch(q, k, v, lengths, scale=None, page: int = 0):
     ``decode_multiquery_fallback``) — the tier-1 dispatch asserts and
     ``/metrics`` both see a verify that lost its fused path.
 
-    TP routing under an armed :class:`tp_shard_context` mirrors
-    :func:`decode_dispatch` (``decode_multiquery_tp_shard_map`` /
-    ``decode_multiquery_fallback_tp_gspmd``)."""
-    armed = _tp_armed()
-    if armed is not None and q.ndim == 4:
-        if q.shape[1] % armed[2] == 0:
+    Routing in a partitioned trace mirrors :func:`decode_dispatch`
+    (``decode_multiquery_tp_shard_map`` /
+    ``decode_multiquery_fallback_gspmd``)."""
+    if _partitioned() is not None and q.ndim == 4:
+        shards = _head_shards(q)
+        if shards is not None:
             _DISPATCH.inc(decision="decode_multiquery_tp_shard_map")
-            return _tp_head_shard(_decode_multiquery_local, armed,
+            return _tp_head_shard(_decode_multiquery_local, shards,
                                   q, k, v, lengths, scale, page)
-        _DISPATCH.inc(decision="decode_multiquery_fallback_tp_gspmd")
+        _DISPATCH.inc(decision="decode_multiquery_fallback_gspmd")
         return reference_decode_multiquery(q, k, v, lengths, scale=scale)
     return _decode_multiquery_local(q, k, v, lengths, scale=scale,
                                     page=page)

@@ -55,7 +55,8 @@ import numpy as np
 from . import register
 from . import nnops
 from . import activations as _activations
-from .pallas_kernels import _VMEM_BUDGET, available as _tpu_available
+from .pallas_kernels import (_VMEM_BUDGET, available as _tpu_available,
+                             partitioned as _partitioned)
 
 _LANES = 128
 
@@ -259,10 +260,7 @@ def _fused_epilogue_ln_bwd(x_ref, g_ref, b_ref, mu_ref, rs_ref, dy_ref,
 
 
 def _compiler_params_rows(pltpu):
-    try:
-        return pltpu.TPUCompilerParams(dimension_semantics=("arbitrary",))
-    except Exception:  # older/newer spelling: let the compiler default
-        return None
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 # --------------------------------------------------------------------------
@@ -462,7 +460,7 @@ def fits_vmem_epilogue(br: int, cols: int, itemsize: int = 4,
 
 _COUNTER_KEYS = ("fused", "fallback_mode", "fallback_platform",
                  "fallback_act", "fallback_dtype", "fallback_shape",
-                 "fallback_vmem",
+                 "fallback_vmem", "fallback_gspmd",
                  # master-cast+updater decisions ride the same registry
                  # counter so the whole library's mix is one metric family
                  "fused_updater", "fallback_updater_mode",
@@ -496,6 +494,12 @@ def set_mode(m: str) -> str:
     return old
 
 
+def _interpret() -> bool:
+    """Interpret mode is asked for, never fallen into: ``force`` off-TPU
+    (how the CPU tests reach the kernel code)."""
+    return _state["mode"] == "force" and not _tpu_available()
+
+
 def counters() -> dict:
     """Dispatch-decision counts (trace-time units, like flash attention:
     one count per compiled call-site, not per execution)."""
@@ -518,6 +522,8 @@ def route_elementwise(shape, dtype, axis=-1, act="identity", alpha=None,
         return "fallback_act"
     if _state["mode"] != "force" and not _tpu_available():
         return "fallback_platform"
+    if _partitioned() is not None:
+        return "fallback_gspmd"  # see pallas_kernels.gspmd_trace
     if jnp.dtype(dtype) not in [jnp.dtype(d) for d in _FUSABLE_DTYPES]:
         return "fallback_dtype"
     ndim = len(shape)
@@ -579,7 +585,7 @@ def bn_act(x, gamma, beta, mean, var, eps=1e-5, axis=-1, act="identity",
             shift = beta.astype(jnp.float32) + shift
         br = _tuned_row_block("affine", rows, cols, x2)
         y = _affine_act(x2, scale.reshape(1, cols), shift.reshape(1, cols),
-                        act_c, br, not _tpu_available())
+                        act_c, br, _interpret())
         return y.reshape(x.shape)
     _DISPATCH.inc(decision=reason)
     y = nnops.batch_norm(x, gamma, beta, mean, var, eps, axis)
@@ -603,7 +609,7 @@ def bias_act(x, b=None, act="identity", axis=-1, alpha=None):
         bb = jnp.zeros((cols,), x.dtype) if b is None else b
         br = _tuned_row_block("affine", rows, cols, x2)
         y = _affine_act(x2, None, bb.reshape(1, cols), act_c, br,
-                        not _tpu_available())
+                        _interpret())
         return y.reshape(x.shape)
     _DISPATCH.inc(decision=reason)
     if b is not None:
@@ -627,7 +633,7 @@ def layer_norm_act(x, gamma, beta, eps=1e-5, act="identity"):
         x2, rows, cols = _collapse(x)
         br = _tuned_row_block("ln", rows, cols, x2)
         y = _ln_act(x2, gamma.reshape(1, cols), beta.reshape(1, cols),
-                    float(eps), act_c, br, not _tpu_available())
+                    float(eps), act_c, br, _interpret())
         return y.reshape(x.shape)
     _DISPATCH.inc(decision=reason)
     y = nnops.layer_norm(x, gamma, beta, eps, axis=-1)

@@ -26,7 +26,9 @@ hand kernels here; the remaining bwd HBM traffic is structural.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -37,10 +39,38 @@ _VMEM_BUDGET = 8 * 1024 * 1024  # conservative half of ~16MB VMEM
 
 def available() -> bool:
     """Pallas TPU lowering available on the default backend?"""
+    return jax.default_backend() == "tpu"
+
+
+_trace = threading.local()
+
+
+@contextlib.contextmanager
+def gspmd_trace(mesh, model_axis=None):
+    """Held around the TRACE of a program that GSPMD partitions over
+    ``mesh`` (``ParallelWrapper``'s step, a serving engine's lowering on a
+    mesh). The TPU compiler refuses such a program when it holds a Mosaic
+    kernel ("Mosaic kernels cannot be automatically partitioned. Please
+    wrap the call in a shard_map"), so while this is held the dispatchers
+    take their reference path, counted ``*fallback_gspmd``, except where
+    they open a ``shard_map`` themselves: the decode kernels do, over
+    ``model_axis`` when the heads divide it. A mesh of one device
+    partitions nothing and arms nothing. Trace-time state like the
+    dispatch modes, and per thread: a trace runs on one thread, and an
+    engine that another thread traces meanwhile keeps its kernels."""
+    was = partitioned()
+    _trace.held = None if mesh is None or mesh.devices.size < 2 \
+        else (mesh, model_axis)
     try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+        yield
+    finally:
+        _trace.held = was
+
+
+def partitioned():
+    """``(mesh, model_axis)`` while this thread is inside
+    :func:`gspmd_trace` over more than one device, else None."""
+    return getattr(_trace, "held", None)
 
 
 def fits_vmem(batch: int, n_in: int, units: int, bytes_per: int = 4) -> bool:

@@ -68,7 +68,9 @@ class PerformanceListener(TrainingListener):
         self.frequency = max(1, frequency)
         self.batch_size = batch_size
         self.flops_per_example = flops_per_example
-        self.peak_flops = peak_flops or _detect_peak_flops()
+        # the peak is only needed where an MFU is computed from it
+        self.peak_flops = peak_flops or (
+            _detect_peak_flops() if flops_per_example else None)
         self.collect_memory = collect_memory
         self.collect_resilience = collect_resilience
         self.collect_phases = collect_phases
@@ -153,41 +155,13 @@ class PerformanceListener(TrainingListener):
         self._it0 = iteration
 
 
-def _detect_peak_flops() -> Optional[float]:
-    """Peak BF16 FLOPs of device 0, for MFU. (v5e's widely-quoted 394
-    TOPS figure is INT8; bf16 peak is 197 TFLOPs — using 394 halves every
-    reported MFU.)
-
-    ``DL4J_TPU_PEAK_FLOPS`` (ISSUE 6 satellite) overrides the detection —
-    unknown devices (CI CPUs, new TPU generations before the table grows
-    a row) used to silently yield ``MFU=None``; with the override set,
-    MFU telemetry keeps flowing everywhere PerformanceListener runs."""
-    env = os.environ.get("DL4J_TPU_PEAK_FLOPS")
-    if env:
-        try:
-            v = float(env)
-            if v > 0:
-                return v
-            log.warning("DL4J_TPU_PEAK_FLOPS=%r is not positive; ignored",
-                        env)
-        except ValueError:
-            log.warning("DL4J_TPU_PEAK_FLOPS=%r is not a number; ignored",
-                        env)
-    try:
-        import jax
-        d = jax.devices()[0]
-        kind = getattr(d, "device_kind", "").lower()
-        if "v5 lite" in kind or "v5e" in kind:
-            return 197e12
-        if "v4" in kind:
-            return 275e12
-        if "v5p" in kind or "v5" in kind:
-            return 459e12
-        if "v6" in kind:
-            return 918e12
-    except Exception:
-        pass
-    return None
+def _detect_peak_flops() -> float:
+    """Peak bf16 FLOP/s of device 0, for MFU: ``DL4J_TPU_PEAK_FLOPS`` when
+    set, else the published table by exact ``device_kind``
+    (``runtime.attribution.DEVICE_PEAKS``). An unknown device raises."""
+    from ..runtime.attribution import _env_peak, published_peaks
+    env = _env_peak("DL4J_TPU_PEAK_FLOPS")
+    return env if env is not None else published_peaks()["flops_per_s"]
 
 
 class EvaluativeListener(TrainingListener):

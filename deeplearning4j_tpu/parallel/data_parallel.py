@@ -22,6 +22,7 @@ oblivious to host count — the mesh spans whatever ``jax.devices()`` reports.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence
 
 import jax
@@ -32,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import environment as _envmod
 from ..data.dataset import DataSetIterator, MultiDataSet
 from ..nn.model import MultiLayerNetwork, _as_iterator
+from ..ops import pallas_kernels as _pk
 
 
 def make_mesh(devices: Optional[Sequence] = None, axis: str = "data") -> Mesh:
@@ -375,8 +377,15 @@ class ParallelWrapper:
             model=getattr(self.model, "telemetry_label",
                           type(self.model).__name__),
             **_tel.host_labels()).set(n_buckets)
-        pure = self.model._build_train_step(
+        step = self.model._build_train_step(
             self.accum_steps, grad_transform=grad_transform).__wrapped__
+
+        @functools.wraps(step)
+        def pure(*args):
+            # GSPMD partitions this program; the kernel dispatchers must
+            # know while it is traced (ops/pallas_kernels.gspmd_trace)
+            with _pk.gspmd_trace(mesh):
+                return step(*args)
         from jax.tree_util import tree_structure
         from ..runtime import sentinel as _sent
         _, _, p_sh, upd_sh, opt_sh, bn_sh, p_struct = self._sharding_trees()

@@ -148,7 +148,7 @@ def initialize(coordinator_address: Optional[str] = None,
         pass
     from jax._src import xla_bridge as _xb
     if _xb.backends_are_initialized():
-        # a backend client predates us (e.g. an eager sitecustomize import);
+        # a backend client predates us (something already touched jax);
         # distributed init must come first, so tear the client down. Any
         # jax.Array created before this point is invalidated — call
         # initialize() at program start, before building models.

@@ -642,7 +642,7 @@ def run_serving(outdir: str, timeout: float = 420.0,
         pool_ratio = pv[0]["pool_bytes_per_device"] / pv[0]["pool_bytes"]
         assert abs(pool_ratio - 1.0 / k) < 0.05, \
             f"{variant}: per-device pool ratio {pool_ratio} != 1/{k}"
-        assert any(key.endswith("tp_shard_map") or key.endswith("tp_gspmd")
+        assert any(key.endswith(("tp_shard_map", "fallback_gspmd"))
                    for key in pv[0]["dispatch"]), \
             f"{variant}: no TP dispatch decision counted (silent route?)"
         checks[variant] = {

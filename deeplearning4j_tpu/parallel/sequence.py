@@ -109,18 +109,15 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
         m, l, o = carry[0], carry[1], carry[2]
         return o / jnp.maximum(l, 1e-30)[..., None]
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # older jax spelling
-        from jax.experimental.shard_map import shard_map  # type: ignore
-
     if key_mask is None:
-        fn = shard_map(lambda a, b, c: local_fn(a, b, c, None), mesh=mesh,
-                       in_specs=(spec_qkv, spec_qkv, spec_qkv),
-                       out_specs=spec_qkv)
+        fn = jax.shard_map(lambda a, b, c: local_fn(a, b, c, None),
+                           mesh=mesh,
+                           in_specs=(spec_qkv, spec_qkv, spec_qkv),
+                           out_specs=spec_qkv)
         return fn(q, k, v)
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(spec_qkv, spec_qkv, spec_qkv, spec_mask),
-                   out_specs=spec_qkv)
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(spec_qkv, spec_qkv, spec_qkv, spec_mask),
+                       out_specs=spec_qkv)
     return fn(q, k, v, key_mask)
 
 

@@ -9,9 +9,9 @@ module produces exactly that, for every warmed XLA program in the stack:
 
 - **cost model**: the AOT executable's own ``cost_analysis()`` (flops and
   bytes accessed — XLA's HloCostAnalysis, available on CPU and TPU);
-- **roofline**: device peaks (TPU table / env overrides / a one-shot CPU
-  calibration) turn flops and bytes into ideal compute and memory
-  seconds;
+- **roofline**: device peaks (explicit / env overrides / the published
+  table by exact ``device_kind``; an unknown device is an error) turn
+  flops and bytes into ideal compute and memory seconds;
 - **measurement**: the r11/r12 phase histograms (``serving.phase.*``) or
   a synced self-measurement of the compiled program.
 
@@ -38,6 +38,7 @@ sibling, both engines via ``nn/caches.py``), the serving engines'
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -51,96 +52,56 @@ __all__ = ["device_peaks", "cost_analysis", "attribute",
            "cached_report", "report_keys", "model_fingerprint",
            "train_step_key"]
 
-#: HBM bandwidth table (bytes/s) by device-kind substring — the roofline
-#: denominator ``_detect_peak_flops`` (optimize/listeners.py) does not
-#: cover. Sources: public TPU spec sheets.
-_TPU_BW = (
-    ("v5 lite", 819e9), ("v5e", 819e9),
-    ("v5p", 2765e9), ("v6", 1640e9),
-    ("v4", 1228e9), ("v5", 2765e9),
-)
-
-_calibrated: Optional[dict] = None
-_calib_lock = threading.Lock()
+#: Published per-chip peaks keyed by the exact ``device_kind`` JAX reports.
+#: "TPU v5 lite" is one v5e chip: 197 TFLOP/s bf16 (394 TOP/s is its int8
+#: figure), 16 GB HBM at 819 GB/s (Google Cloud documentation, "TPU v5e").
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops_per_s": 197e12, "bytes_per_s": 819e9},
+}
 
 
-def _calibrate() -> dict:
-    """One-shot peak estimate for devices outside the table (CI CPUs):
-    the best achieved rate of a cache-busting matmul stands in for peak
-    flops, a large device-array copy for peak bandwidth. Achieved-not-
-    theoretical is the honest choice here — the decomposition clamps, so
-    an optimistic peak only shrinks the compute fraction, never breaks
-    the sum-to-1 partition."""
-    global _calibrated
-    with _calib_lock:
-        if _calibrated is not None:
-            return _calibrated
-        import jax
-        import jax.numpy as jnp
-        n = 384
-        a = jnp.ones((n, n), jnp.float32)
-        mm = jax.jit(lambda x, y: x @ y)
-        mm(a, a).block_until_ready()
-        dt = min(_timed(lambda: mm(a, a).block_until_ready())
-                 for _ in range(5))
-        flops = 2.0 * n ** 3 / max(dt, 1e-9)
-        big = jnp.ones((1 << 22,), jnp.float32)          # 16 MiB
-        cp = jax.jit(lambda x: x + 0.0)
-        cp(big).block_until_ready()
-        dt = min(_timed(lambda: cp(big).block_until_ready())
-                 for _ in range(5))
-        bw = 2.0 * big.size * 4 / max(dt, 1e-9)          # read + write
-        _calibrated = {"flops_per_s": flops, "bytes_per_s": bw,
-                       "source": "calibrated"}
-        return _calibrated
+def _env_peak(name: str) -> Optional[float]:
+    raw = os.environ.get(name)
+    if not raw:
+        return None
+    v = float(raw)
+    if not v > 0:
+        raise ValueError(f"{name}={raw!r} is not a positive number")
+    return v
 
 
-def _timed(fn) -> float:
-    t0 = time.perf_counter()
-    fn()
-    return time.perf_counter() - t0
+def published_peaks() -> dict:
+    """The :data:`DEVICE_PEAKS` row of device 0. A device that is not in
+    the table is an error: a utilisation against a guessed peak is not a
+    utilisation."""
+    import jax
+    kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_PEAKS:
+        raise LookupError(
+            f"no published peaks for device_kind {kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}); pass explicit peaks")
+    return DEVICE_PEAKS[kind]
 
 
 def device_peaks(peaks: Optional[dict] = None) -> dict:
     """``{"flops_per_s", "bytes_per_s", "source"}`` for device 0.
     Resolution order: an explicit ``peaks`` dict, the
-    ``DL4J_TPU_PEAK_FLOPS`` / ``DL4J_TPU_PEAK_BW`` env overrides, the TPU
-    spec tables, then the one-shot calibration (unknown devices — CI
-    CPUs — keep attribution flowing instead of yielding None)."""
-    import os
+    ``DL4J_TPU_PEAK_FLOPS`` / ``DL4J_TPU_PEAK_BW`` env overrides, then
+    :data:`DEVICE_PEAKS` by exact ``device_kind`` (an unknown device
+    raises, see :func:`published_peaks`)."""
     if peaks is not None and peaks.get("flops_per_s") \
             and peaks.get("bytes_per_s"):
         return {"flops_per_s": float(peaks["flops_per_s"]),
                 "bytes_per_s": float(peaks["bytes_per_s"]),
                 "source": peaks.get("source", "explicit")}
-    from ..optimize.listeners import _detect_peak_flops
-    flops = _detect_peak_flops()          # env override + TPU table
-    bw = None
-    env_bw = os.environ.get("DL4J_TPU_PEAK_BW")
-    if env_bw:
-        try:
-            v = float(env_bw)
-            bw = v if v > 0 else None
-        except ValueError:
-            bw = None
-    if bw is None:
-        try:
-            import jax
-            kind = getattr(jax.devices()[0], "device_kind", "").lower()
-            for sub, v in _TPU_BW:
-                if sub in kind:
-                    bw = v
-                    break
-        except Exception:
-            pass
+    flops = _env_peak("DL4J_TPU_PEAK_FLOPS")
+    bw = _env_peak("DL4J_TPU_PEAK_BW")
     if flops is not None and bw is not None:
-        return {"flops_per_s": float(flops), "bytes_per_s": float(bw),
-                "source": "table"}
-    cal = _calibrate()
-    return {"flops_per_s": float(flops) if flops else cal["flops_per_s"],
-            "bytes_per_s": float(bw) if bw else cal["bytes_per_s"],
-            "source": cal["source"] if flops is None or bw is None
-            else "table"}
+        return {"flops_per_s": flops, "bytes_per_s": bw, "source": "env"}
+    row = published_peaks()
+    return {"flops_per_s": flops or row["flops_per_s"],
+            "bytes_per_s": bw or row["bytes_per_s"],
+            "source": "table" if flops is None and bw is None else "env"}
 
 
 def cost_analysis(compiled) -> Optional[dict]:
@@ -203,8 +164,7 @@ def attribute(flops: float, bytes_accessed: float,
         "compute_s": compute_s, "memory_s": memory_s,
         "host_s": host_s, "other_s": other_s,
         "fractions": fr,
-        # MFU == the compute fraction by construction (clamped at 1.0
-        # when the measurement beats the calibrated "peak")
+        # MFU == the compute fraction by construction (clamped at 1.0)
         "mfu": fr["compute"],
         "mfu_gap": {"total": 1.0 - fr["compute"],
                     "memory": fr["memory"], "host": fr["host"],

@@ -29,8 +29,8 @@ the WHOLE compiled train step:
   brute-force product order. With a ``max_candidates`` budget the
   ordering decides what gets measured at all.
 - **Measurement**: surviving candidates run as REAL compiled steps on
-  synthetic zero batches with a forced host readback, min over repeats,
-  rounds interleaved across candidates so multi-tenant drift hits every
+  synthetic zero batches timed to ``block_until_ready``, min over repeats,
+  rounds interleaved across candidates so drift hits every
   candidate alike — the ``ops/autotune.py`` timing discipline. Every
   probe lower+compile is reported to the retrace tracker as
   ``record_compile(..., cause="schedule_tune")`` so warm steady state
@@ -541,7 +541,7 @@ class ScheduleTuner:
     # ------------------------------------------------------------ timing
     def _runner(self, cfg: dict):
         """A zero-arg callable running ONE real step of this candidate
-        with a forced host readback. Fresh donated argument copies are
+        to ``block_until_ready``. Fresh donated argument copies are
         built per call OUTSIDE the timed region (the step donates
         params/opt/state)."""
         import jax
@@ -576,8 +576,7 @@ class ScheduleTuner:
                     self.seq_len, counter["i"])
 
         def run(args):
-            out = compiled(*args)
-            return float(jax.block_until_ready(out[-1]))
+            jax.block_until_ready(compiled(*args))
         return make_args, run
 
     def time_candidates(self, cands: List[dict]) -> List[dict]:

@@ -44,6 +44,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import dtypes as _dt
 from ..ops import flash_attention as _fa
+from ..ops import pallas_kernels as _pk
 from ..ops import quantize as _q
 from ..ops import sampling as _smp
 from ..parallel import placement as _pl
@@ -341,14 +342,15 @@ class InferenceEngine(_QuantizedParamsMixin):
                                 tuple(xs_avals), tuple(masks_avals))
 
     def _tp_trace(self):
-        """Arm ``flash_attention``'s tensor-parallel dispatch for the
-        duration of one trace/lower: attention sites route per-shard
-        ``shard_map`` (decode) or the counted GSPMD-partitioned einsum
-        path instead of tracing a Pallas kernel over sharded operands."""
+        """Held for the duration of one trace/lower: on a mesh GSPMD
+        partitions the program, so the kernel dispatchers route per-shard
+        ``shard_map`` (decode, over the model axis) or the counted
+        reference path, which GSPMD partitions, instead of tracing a
+        Pallas kernel over sharded operands."""
         pl = self._placement_layer
-        if pl is not None and pl.model_axis is not None:
-            return _fa.tp_shard_context(pl.mesh, pl.model_axis)
-        return contextlib.nullcontext()
+        if pl is None:
+            return contextlib.nullcontext()
+        return _pk.gspmd_trace(pl.mesh, pl.model_axis)
 
     @staticmethod
     def _bucket_label(key: Tuple) -> str:
@@ -1102,13 +1104,13 @@ class GenerativeEngine(_QuantizedParamsMixin):
             src=(self.model.params, self.model.state))
 
     def _tp_trace(self):
-        """Arm ``flash_attention``'s tensor-parallel dispatch while one
-        decode-family executable traces (per-shard ``shard_map`` or the
-        counted GSPMD einsum fallback — zero silent fallbacks)."""
+        """Held while one decode-family executable traces on a mesh
+        (per-shard ``shard_map`` over the model axis, or the counted
+        reference path that GSPMD partitions — zero silent fallbacks)."""
         pl = self._placement_layer
-        if pl is not None and pl.model_axis is not None:
-            return _fa.tp_shard_context(pl.mesh, pl.model_axis)
-        return contextlib.nullcontext()
+        if pl is None:
+            return contextlib.nullcontext()
+        return _pk.gspmd_trace(pl.mesh, pl.model_axis)
 
     def _tp_shardings(self, cache_avals):
         """(params, state, caches, replicated) sharding trees for one

@@ -17,7 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64 as _enable_x64
 
 
 def check_gradients(fn, params, eps=1e-5, max_rel_error=1e-5, min_abs_error=1e-8,
@@ -30,9 +29,7 @@ def check_gradients(fn, params, eps=1e-5, max_rel_error=1e-5, min_abs_error=1e-8
     """
     cpu = jax.devices("cpu")[0]
     with jax.default_device(cpu):
-        # jax.enable_x64 (deprecated alias) was removed in jax 0.4.37; the
-        # supported spelling is the jax.experimental context manager
-        with _enable_x64(True):
+        with jax.enable_x64(True):
             p64 = jax.tree.map(lambda a: jnp.asarray(np.asarray(a), dtype=jnp.float64), params)
             analytic = jax.grad(fn)(p64)
             leaves, treedef = jax.tree.flatten(p64)

@@ -87,20 +87,31 @@ def test_device_peaks_env_override(monkeypatch):
     pk = attr.device_peaks()
     assert pk["flops_per_s"] == 2e12
     assert pk["bytes_per_s"] == 3e11
-    assert pk["source"] == "table"
+    assert pk["source"] == "env"
 
 
-def test_device_peaks_calibrates_on_unknown_devices(monkeypatch):
+def test_device_peaks_unknown_device_is_an_error(monkeypatch):
     monkeypatch.delenv("DL4J_TPU_PEAK_FLOPS", raising=False)
     monkeypatch.delenv("DL4J_TPU_PEAK_BW", raising=False)
-    pk = attr.device_peaks()     # CPU CI: no table row -> calibration
-    assert pk["flops_per_s"] > 0 and pk["bytes_per_s"] > 0
+    # CPU CI has no table row: no calibration, no guess
+    with pytest.raises(LookupError, match="device_kind 'cpu'"):
+        attr.device_peaks()
+    with pytest.raises(LookupError, match="device_kind 'cpu'"):
+        attr.attribute(1e9, 1e9, measured_s=0.01)
+
+
+def test_device_peaks_table_is_keyed_by_exact_kind():
+    # what one v5e chip reports; published bf16 peak and HBM bandwidth
+    assert attr.DEVICE_PEAKS["TPU v5 lite"] == {
+        "flops_per_s": 197e12, "bytes_per_s": 819e9}
+    assert "v5" not in attr.DEVICE_PEAKS and "tpu v5 lite" not in \
+        attr.DEVICE_PEAKS
 
 
 # ---------------------------------------------------------- train step
 def test_model_attribution_report_partitions_and_caches():
     net = _net()
-    rep = net.attribution_report(8, steps=2)
+    rep = net.attribution_report(8, steps=2, peaks=PEAKS)
     assert rep["kind"] == "train_step" and rep["batch_size"] == 8
     assert rep["cost_available"] is True
     assert rep["measured_s"] > 0
@@ -147,7 +158,7 @@ def test_engine_attribution_after_traffic():
         eng.output(x)
     compiles = eng.compiles
     ev0 = int(tel.registry.get("compile.events").total())
-    rep = eng.attribution_report(8)
+    rep = eng.attribution_report(8, peaks=PEAKS)
     # the warmed bucket's executable is REUSED: no probe compile, no
     # serving-counter movement (the tuner calls this repeatedly)
     assert eng.compiles == compiles

@@ -103,21 +103,30 @@ def test_candidate_enumeration_properties():
     assert len(at.axis_blocks(2048)) <= at.AXIS_CANDIDATES
 
 
-def test_every_candidate_block_shape_parity(rng):
+@pytest.mark.parametrize("has_bias", [False, True])
+def test_every_candidate_block_shape_parity(rng, has_bias):
     """Interpret-mode numerical parity for EVERY candidate block shape the
     autotuner may pick for a representative key (ISSUE 7 satellite):
-    forward and gradient, against the einsum reference."""
+    forward and gradient, against the einsum reference. With a key bias
+    the candidates are held to the kv blocks the TPU lowering accepts."""
     B, H, T, d = 2, 2, 64, 16
     q, k, v = _qkv(rng, B=B, H=H, Tq=T, Tk=T, d=d)
-    mask = np.ones((B, T), np.float32)
-    mask[0, T // 2:] = 0.0
-    bias = jnp.where(jnp.asarray(mask)[:, None, None, :] > 0, 0.0,
-                     jnp.asarray(np.finfo(np.float32).min))
+    bias = None
+    if has_bias:
+        mask = np.ones((B, T), np.float32)
+        mask[0, T // 2:] = 0.0
+        bias = jnp.where(jnp.asarray(mask)[:, None, None, :] > 0, 0.0,
+                         jnp.asarray(np.finfo(np.float32).min))
     ref = fa.reference_attention(q, k, v, bias)
     g_ref = jax.grad(lambda x: jnp.sum(
         fa.reference_attention(x, k, v, bias)))(q)
-    cands = at.candidates(T, T, d)
+    cands = at.candidates(T, T, d, has_bias=has_bias)
     assert len(cands) >= 4  # a real sweep space, not a degenerate one
+    assert all(fa.kv_block_ok(bk, T, has_bias) for _, bk in cands)
+    if has_bias:
+        assert {bk for _, bk in cands} == {T}
+        with pytest.raises(ValueError, match="do not tile"):
+            fa.flash_attention(q, k, v, bias, block_k=32, interpret=True)
     for bq, bk in cands:
         out = fa.flash_attention(q, k, v, bias, block_q=bq, block_k=bk,
                                  interpret=True)

@@ -444,3 +444,62 @@ def test_fusion_minibert_graphdef_import():
     assert rep.matched == 2, (rep.matched, rep.reasons)
     after = sd.output({iname: ids}, [oname])[oname]
     np.testing.assert_allclose(after, before, atol=1e-5)
+
+
+@pytest.mark.parametrize("route,reference_key,sharded_key", [
+    ("one_shot", "fallback_gspmd", "fallback_gspmd"),
+    ("decode", "decode_fallback_gspmd", "decode_tp_shard_map"),
+    ("multiquery", "decode_multiquery_fallback_gspmd",
+     "decode_multiquery_tp_shard_map"),
+])
+def test_partitioned_trace_routes_around_the_kernel(
+        rng, route, reference_key, sharded_key):
+    """In a trace that GSPMD partitions (``pallas_kernels.gspmd_trace``)
+    no dispatcher hands GSPMD a kernel, which the TPU compiler cannot
+    partition: the reference path, counted, or for the decode kernels a
+    shard_map over the model axis when the heads divide it. One flag, held
+    per thread; a one-device mesh arms nothing."""
+    import threading
+    from jax.sharding import Mesh
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+    tq = {"one_shot": 128, "decode": 1, "multiquery": 4}[route]
+    q = jnp.asarray(rng.standard_normal((2, 4, tq, 64)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((2, 4, 128, 64)), jnp.float32)
+            for _ in range(2))
+    lengths = jnp.asarray([100, 37], jnp.int32)
+    call = {"one_shot": lambda: fa.attention(q, k, v),
+            "decode": lambda: fa.decode_dispatch(q, k, v, lengths),
+            "multiquery": lambda: fa.decode_multiquery_dispatch(
+                q, k, v, lengths)}[route]
+    fused_key = {"one_shot": "fused", "decode": "decode_fused",
+                 "multiquery": "decode_multiquery"}[route]
+    devs = np.array(jax.devices()[:2])
+    old = fa.set_mode("force")
+    try:
+        fa.reset_counters()
+        want = call()
+        assert fa.counters()[fused_key] == 1
+        with pk.gspmd_trace(Mesh(devs[:1], ("data",))):
+            assert pk.partitioned() is None
+        with pk.gspmd_trace(Mesh(devs, ("data",))):
+            seen = []
+            t = threading.Thread(target=lambda: seen.append(pk.partitioned()))
+            t.start()
+            t.join()
+            assert seen == [None] and pk.partitioned() is not None
+            got = call()
+        c = fa.counters()
+        assert (c[reference_key], c[fused_key]) == (1, 1)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        fa.reset_counters()
+        with pk.gspmd_trace(Mesh(devs.reshape(1, 2), ("data", "model")),
+                            "model"):
+            got = call()
+        assert pk.partitioned() is None
+        c = fa.counters()
+        assert c[sharded_key] == 1
+        # inside the shard_map the kernel sees one device's heads
+        assert c[fused_key] == (0 if route == "one_shot" else 1)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+    finally:
+        fa.set_mode(old)

@@ -14,9 +14,11 @@ The acceptance drill invariants, asserted throughout:
   background loads, warmups, flips and rollbacks.
 """
 
+import itertools
 import json
 import threading
 import time
+import types
 import urllib.error
 import urllib.request
 
@@ -31,6 +33,7 @@ from deeplearning4j_tpu.parallel.checkpoint import TrainingCheckpointer
 from deeplearning4j_tpu.runtime import faults
 from deeplearning4j_tpu.runtime import telemetry as tel
 from deeplearning4j_tpu.runtime.faults import QueueFull
+from deeplearning4j_tpu.serving import fleet as fleet_mod
 from deeplearning4j_tpu.serving import (CanaryGate, CheckpointWatcher,
                                         FleetError, HealthState,
                                         JsonModelServer, ModelRegistry,
@@ -435,16 +438,36 @@ def _drive(reg, name="m", n=30, seed=0):
     time.sleep(0.15)  # done-callbacks record latency/outcomes async
 
 
-def test_canary_promotes_on_all_gates_green():
+def test_canary_promotes_on_all_gates_green(monkeypatch):
+    # The p99 gate reads wall time, and a p99 over ~15 requests an arm is
+    # the worst wake-up the host gave that arm: two identical versions on a
+    # shared host differ by more than the gate's 1.25. Here the fleet's
+    # clock ticks one millisecond a reading and each request's done-callback
+    # has run before the next is submitted, so both arms measure the same
+    # latency and the default gate is held to it.
+    ticks = itertools.count()
+    monkeypatch.setattr(fleet_mod, "time", types.SimpleNamespace(
+        perf_counter=lambda: next(ticks) * 1e-3,
+        monotonic=time.monotonic, sleep=time.sleep))
+
+    def drive(n=30):
+        x = _x()
+        for _ in range(n):
+            recorded = threading.Event()
+            # callbacks run in the order added: the fleet's own came first
+            reg.submit("m", x).add_done_callback(lambda f: recorded.set())
+            assert recorded.wait(30)
+
     reg = _registry_with_live(seed=0)
     try:
         reg.add_version("m", 2, _mlp(0), front_kwargs=dict(FK))
         reg.start_canary("m", 2, CanaryGate(
             fraction=0.5, window_s=30, min_samples=8, promote_after=2))
-        _drive(reg)
+        drive()
         r1 = reg.evaluate_canary("m")
         assert r1["decision"] == "green", r1
-        _drive(reg)
+        assert r1["gates"]["p99_ratio"] is True, r1
+        drive()
         r2 = reg.evaluate_canary("m")
         assert r2["decision"] == "promoted", r2
         assert reg.stats()["models"]["m"]["live_version"] == 2
@@ -452,6 +475,28 @@ def test_canary_promotes_on_all_gates_green():
         can = tel.registry.get("serving.fleet.canary_events")
         events = {dict(k).get("event") for k in can.series()}
         assert {"started", "green", "promoted"} <= events
+    finally:
+        reg.shutdown()
+
+
+def test_canary_slow_candidate_rolls_back_on_p99():
+    """The same network behind a front that holds every request 80 ms
+    (against 1 ms) is a latency regression many times the default gate:
+    the p99 gate alone is red and the candidate is rolled back."""
+    # its own model name: flight dumps are rate-limited per reason, and
+    # the next test reads the dump of a rollback of "m"
+    reg = _registry_with_live(name="slow", seed=0)
+    try:
+        reg.add_version("slow", 2, _mlp(0), front_kwargs={
+            "max_batch_size": 4, "max_wait_ms": 80.0})
+        reg.start_canary("slow", 2, CanaryGate(
+            fraction=0.5, window_s=30, min_samples=8))
+        _drive(reg, name="slow")
+        rep = reg.evaluate_canary("slow")
+        assert rep["decision"] == "rolled_back", rep
+        assert rep["gates"] == {"error_delta": True, "p99_ratio": False}
+        assert reg.stats()["models"]["slow"]["live_version"] == 1
+        assert reg.version("slow", 2).state == ModelVersion.ROLLED_BACK
     finally:
         reg.shutdown()
 

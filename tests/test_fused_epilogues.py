@@ -790,3 +790,30 @@ def test_rederive_phase_split_unit():
         4.0 / 3.5, abs=2e-3)
     # no measured cast -> no re-derivation (field absent, not garbage)
     assert bench._rederive_phase_split(10.0, 4.0, 6.0, 2.0, None) == {}
+
+
+def test_partitioned_trace_routes_to_the_reference():
+    """A Mosaic kernel cannot be partitioned by GSPMD: while a program
+    partitioned over a mesh is traced (``pallas_kernels.gspmd_trace``) the
+    epilogue kernels give way to the reference, counted; a one-device
+    mesh partitions nothing."""
+    from jax.sharding import Mesh
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+    shape, dt = (64, 128), jnp.bfloat16
+    devs = np.array(jax.devices()[:2])
+    old = fe.set_mode("force")
+    try:
+        assert fe.route_elementwise(shape, dt) is None
+        with pk.gspmd_trace(Mesh(devs[:1], ("data",))):
+            assert fe.route_elementwise(shape, dt) is None
+        with pk.gspmd_trace(Mesh(devs, ("data",))):
+            assert fe.route_elementwise(shape, dt) == "fallback_gspmd"
+            fe.reset_counters()
+            x = jnp.ones(shape, dt)
+            y = fe.bias_act(x, jnp.ones((128,), dt), act="relu")
+            assert fe.counters()["fallback_gspmd"] == 1
+            assert float(y[0, 0]) == 2.0
+        assert pk.partitioned() is None
+        assert fe.route_elementwise(shape, dt) is None
+    finally:
+        fe.set_mode(old)

@@ -275,7 +275,7 @@ def test_paged_tp_greedy_parity(rng, kv):
     assert eng.pool_bytes(per_device=True) * 2 == eng.pool_bytes()
     assert eng.stats()["pool_bytes_per_device"] * 2 == eng.pool_bytes()
     counters = {k: v for k, v in fa.counters().items() if v}
-    assert any(k.endswith(("tp_shard_map", "tp_gspmd")) for k in counters)
+    assert any(k.endswith(("tp_shard_map", "fallback_gspmd")) for k in counters)
 
 
 def test_int8_weights_compose_with_tp(rng):
@@ -297,10 +297,12 @@ def test_attribution_key_has_mesh_suffix():
     fingerprint-key rule) so fractions never blend across topologies."""
     net = _mlp()
     eng = InferenceEngine(net, mesh=_mesh()).warmup([4])
-    rep = eng.attribution_report(4, measured_s=1e-3)
+    peaks = {"flops_per_s": 1e12, "bytes_per_s": 1e11, "source": "test"}
+    rep = eng.attribution_report(4, measured_s=1e-3, peaks=peaks)
     assert "mesh=1x2:tp2" in rep["key"]
     plain = InferenceEngine(net).warmup([4])
-    assert "mesh=" not in plain.attribution_report(4, measured_s=1e-3)["key"]
+    assert "mesh=" not in plain.attribution_report(
+        4, measured_s=1e-3, peaks=peaks)["key"]
 
 
 def test_tp_shards_gauge_labeled_with_mesh():

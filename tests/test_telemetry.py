@@ -438,15 +438,23 @@ def test_performance_listener_reports_phases_and_env_peak_flops(
     monkeypatch.setenv("DL4J_TPU_PEAK_FLOPS", "2.5e12")
     assert _detect_peak_flops() == 2.5e12
     monkeypatch.setenv("DL4J_TPU_PEAK_FLOPS", "bogus")
-    # a bad override is ignored, not fatal (CPU: detection returns None)
-    assert _detect_peak_flops() is None or _detect_peak_flops() > 0
+    with pytest.raises(ValueError):  # a bad override is not a peak
+        _detect_peak_flops()
+    monkeypatch.delenv("DL4J_TPU_PEAK_FLOPS")
+    # no override on an unlisted device (the CPU): an error, not None —
+    # but only where the peak would become an MFU
+    with pytest.raises(LookupError, match="device_kind 'cpu'"):
+        _detect_peak_flops()
+    with pytest.raises(LookupError):
+        PerformanceListener(batch_size=8, flops_per_example=1e6)
+    assert PerformanceListener(batch_size=8).peak_flops is None
 
     monkeypatch.setenv("DL4J_TPU_PEAK_FLOPS", "1e12")
     msgs = []
     pl = PerformanceListener(frequency=2, batch_size=8,
                              flops_per_example=1e6,
                              printer=msgs.append)
-    assert pl.peak_flops == 1e12  # MFU telemetry works on CI CPUs now
+    assert pl.peak_flops == 1e12
     net = _net()
     net.add_listener(pl)
     it = NumpyDataSetIterator(_data(n=48).features, _data(n=48).labels,

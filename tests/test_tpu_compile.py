@@ -1,0 +1,360 @@
+"""The Pallas kernels of the main path, compiled for a described TPU v5e
+at real widths. Interpret mode (every other kernel test) never runs the
+TPU lowering's block-shape, layout and VMEM checks; these compiles do, at
+no chip time. Nothing executes: a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture (one process at
+a time may load the TPU's library, and xdist workers all import this
+file), compiles run in this process, and the persistent compile cache is
+off around them (such entries cannot be read back without a chip).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.ops import flash_attention as fa
+from deeplearning4j_tpu.ops import fused_epilogues as fe
+from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *avals):
+    """Compile ``fn`` for the described chip on ``(shape, dtype)`` avals and
+    return the compiled text; the kernel must be in it."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in avals]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+BF16 = jnp.bfloat16
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("has_bias", [False, True], ids=["nobias", "keybias"])
+@pytest.mark.parametrize("shape", [(32, 12, 128, 64), (8, 12, 512, 64)],
+                         ids=["bert_b32_s128", "b8_s512"])
+def test_flash_attention_compiles(one_chip, shape, has_bias, grad):
+    B, H, T, d = shape
+    bq, bk = fa.pick_block(T), fa.pick_kv_block(T, has_bias=has_bias)
+    assert fa.fits_vmem_attention(bq, bk, d, 2)
+
+    def fwd(q, k, v, *bias):
+        return fa.flash_attention(q, k, v, bias[0] if bias else None,
+                                  block_q=bq, block_k=bk)
+
+    def loss(q, k, v, *bias):
+        return jnp.sum(fwd(q, k, v, *bias).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    avals = [(shape, BF16)] * 3
+    if has_bias:
+        avals.append(((B, 1, 1, T), jnp.float32))
+    text = _compile(fn, one_chip, *avals)
+    # forward, and in the backward the dq and dk/dv kernels beside it
+    assert text.count("tpu_custom_call") >= (3 if grad else 1)
+
+
+@pytest.mark.parametrize("page", [0, 16], ids=["contiguous", "page16"])
+def test_decode_attention_compiles(one_chip, page):
+    B, H, C, d = 8, 12, 1024, 64
+
+    def fn(q, k, v, lengths):
+        return fa.decode_attention(q, k, v, lengths, block_k=128, page=page)
+
+    _compile(fn, one_chip, ((B, H, 1, d), BF16), ((B, H, C, d), BF16),
+             ((B, H, C, d), BF16), ((B,), jnp.int32))
+
+
+def test_paged_decode_step_compiles(one_chip):
+    """The paged decode step as serving runs it: insert through the page
+    table, gather, then the decode kernel."""
+    B, H, d, P, MP = 8, 12, 64, 16, 64
+    rows = (B * MP + 1) * P
+
+    def fn(q, kn, vn, kp, vp, table, lengths):
+        kp2 = fa.paged_insert(kp, kn, lengths, table, P)
+        vp2 = fa.paged_insert(vp, vn, lengths, table, P)
+        kf = fa.paged_gather(kp2, table, P)
+        vf = fa.paged_gather(vp2, table, P)
+        return fa.decode_attention(q, kf, vf, lengths + 1, block_k=128,
+                                   page=P)
+
+    tok = ((B, H, 1, d), BF16)
+    pool = ((rows, H, d), BF16)
+    _compile(fn, one_chip, tok, tok, tok, pool, pool,
+             ((B, MP), jnp.int32), ((B,), jnp.int32))
+
+
+def test_decode_multiquery_compiles(one_chip):
+    B, H, C, d, Tq = 8, 12, 1024, 64, 4
+
+    def fn(q, k, v, lengths):
+        return fa.decode_multiquery_attention(q, k, v, lengths, block_k=128)
+
+    _compile(fn, one_chip, ((B, H, Tq, d), BF16), ((B, H, C, d), BF16),
+             ((B, H, C, d), BF16), ((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_layer_norm_act_compiles(one_chip, grad):
+    R, C = 4096, 768
+    br = fe.row_block(R, fe._row_mult(BF16))
+    assert fe.fits_vmem_epilogue(br, C, 2, "ln")
+
+    def fwd(x, g, b):
+        return fe._ln_act(x, g, b, 1e-12, "gelu", br, False)
+
+    def loss(x, g, b):
+        return jnp.sum(fwd(x, g, b).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    _compile(fn, one_chip, ((R, C), BF16), ((1, C), BF16), ((1, C), BF16))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_affine_act_compiles(one_chip, grad):
+    R, C = 128 * 56 * 56, 256          # ResNet-50 stage-1 activations, b128
+    br = fe.row_block(R, fe._row_mult(BF16))
+    assert fe.fits_vmem_epilogue(br, C, 2, "affine")
+
+    def fwd(x, s, b):
+        return fe._affine_act(x, s, b, "relu", br, False)
+
+    def loss(x, s, b):
+        return jnp.sum(fwd(x, s, b).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    _compile(fn, one_chip, ((R, C), BF16), ((1, C), jnp.float32),
+             ((1, C), jnp.float32))
+
+
+def test_lstm_cell_compiles(one_chip):
+    B, U = 64, 256
+    assert pk.fits_vmem(B, U, U)
+    f32 = jnp.float32
+    _compile(pk.lstm_cell_fused, one_chip, ((B, U), f32), ((B, U), f32),
+             ((B, U), f32), ((U, 4 * U), f32), ((U, 4 * U), f32),
+             ((4 * U,), f32))
+
+
+def test_data_parallel_step_compiles_for_the_mesh(topo, one_chip,
+                                                  monkeypatch):
+    """A Mosaic kernel cannot be partitioned by GSPMD, so the step that
+    ``ParallelWrapper`` traces routes the epilogue kernels to their
+    reference path, counted; the same network's one-device step keeps
+    them. The dispatchers ask the backend, which is the CPU here: the test
+    steers them onto their TPU branch."""
+    from jax.sharding import Mesh
+    from deeplearning4j_tpu.nn import memory
+    from deeplearning4j_tpu.nn.config import (InputType,
+                                              NeuralNetConfiguration)
+    from deeplearning4j_tpu.nn.layers.conv import (BatchNormalization,
+                                                   ConvolutionLayer)
+    from deeplearning4j_tpu.nn.layers.core import (ActivationLayer,
+                                                   OutputLayer)
+    from deeplearning4j_tpu.nn.model import MultiLayerNetwork
+    from deeplearning4j_tpu.nn.updaters import Nesterovs
+    from deeplearning4j_tpu.parallel.data_parallel import ParallelWrapper
+    from deeplearning4j_tpu.runtime import sentinel
+
+    _on_the_chip(monkeypatch)
+    conf = (NeuralNetConfiguration.builder().seed(0).data_type("BFLOAT16")
+            .updater(Nesterovs(learning_rate=0.01, momentum=0.9))
+            .input_type(InputType.convolutional(3, 16, 16,
+                                                data_format="NHWC"))
+            .list(ConvolutionLayer(n_out=128, kernel=(3, 3), mode="same",
+                                   activation="identity",
+                                   data_format="NHWC"),
+                  BatchNormalization(data_format="NHWC"),
+                  ActivationLayer(activation="relu"),
+                  OutputLayer(n_out=8))
+            .build())
+    net = MultiLayerNetwork(conf).init()
+
+    fe.reset_counters()
+    pw = ParallelWrapper(net, mesh=Mesh(np.array(topo.devices), ("data",)),
+                         shard_update=True)
+    text = pw._lower_step(64).as_text()
+    assert "tpu_custom_call" not in text and "all-reduce" in text
+    assert fe.counters()["fallback_gspmd"] > 0
+
+    fe.reset_counters()
+    x, y = memory._batch_avals(net, 64)
+    args = (jax.eval_shape(lambda: net.params),
+            jax.eval_shape(lambda: net.updater_state),
+            jax.eval_shape(lambda: net.state),
+            jax.ShapeDtypeStruct((), np.int32),
+            jax.eval_shape(lambda: jax.random.PRNGKey(0)), x, y, None, None,
+            sentinel.counter_avals())
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), args)
+    text = net._build_train_step(1).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    c = fe.counters()
+    assert c["fused"] > 0 and c["fallback_gspmd"] == 0
+
+
+def _on_the_chip(monkeypatch):
+    """The dispatchers ask the backend, which is the CPU here: steer them
+    onto their TPU branch for a lowering meant for the described chip."""
+    monkeypatch.setattr(fa, "_tpu_available", lambda: True)
+    monkeypatch.setattr(fe, "_tpu_available", lambda: True)
+    fa.reset_counters()
+    fe.reset_counters()
+
+
+def _attention_lm(width=768, heads=12):
+    from deeplearning4j_tpu.nn.config import (InputType,
+                                              NeuralNetConfiguration)
+    from deeplearning4j_tpu.nn.layers.attention import SelfAttentionLayer
+    from deeplearning4j_tpu.nn.layers.core import DenseLayer, OutputLayer
+    from deeplearning4j_tpu.nn.model import MultiLayerNetwork
+    conf = (NeuralNetConfiguration.builder().seed(0)
+            .input_type(InputType.recurrent(width, 128))
+            .list(SelfAttentionLayer(n_out=width, n_heads=heads),
+                  DenseLayer(n_out=width, activation="relu"),
+                  OutputLayer(n_out=width, activation="softmax"))
+            .build())
+    return MultiLayerNetwork(conf).init()
+
+
+@pytest.mark.parametrize("axes,model", [((1, 4), 4), ((4, 1), 1)],
+                         ids=["tensor_parallel", "data_only"])
+def test_generative_serving_compiles_for_the_mesh(topo, monkeypatch, axes,
+                                                  model):
+    """Prefill and decode of a serving engine on the described 2x2 mesh,
+    12 heads x 64 over a 1024-long cache. GSPMD partitions both programs,
+    so no kernel is handed to it: prefill takes the reference path; decode
+    runs its kernel per shard inside a shard_map when a model axis divides
+    the heads, and the reference path on a mesh without one. Counted."""
+    from jax.sharding import Mesh
+    from deeplearning4j_tpu.serving.engine import GenerativeEngine
+    _on_the_chip(monkeypatch)
+    mesh = Mesh(np.array(topo.devices).reshape(axes), ("data", "model"))
+    eng = GenerativeEngine(_attention_lm(), slots=8, mesh=mesh)
+    assert eng.stats()["tp_shards"] == model
+    prefill = eng._prefill_exe(128, 1024).as_text()
+    decode = eng._decode_exe(1024).as_text()
+    c = {k: v for k, v in fa.counters().items() if v}
+    assert "tpu_custom_call" not in prefill
+    if model > 1:
+        assert "tpu_custom_call" in decode and "all-reduce" in decode
+        assert c == {"fallback_gspmd": 1, "decode_tp_shard_map": 1,
+                     "decode_fused": 1}
+    else:
+        assert "tpu_custom_call" not in decode
+        assert c == {"fallback_gspmd": 1, "decode_fallback_gspmd": 1}
+    assert not fe.counters()["fused"]
+
+
+def test_paged_tensor_parallel_decode_compiles_for_the_mesh(topo,
+                                                            monkeypatch):
+    """The paged engine's decode step and speculative verify (Tq=4) on a
+    4-way model axis: ``page=16`` kernels, three heads a shard, inside the
+    dispatcher's shard_map."""
+    from jax.sharding import Mesh
+    from deeplearning4j_tpu.serving.engine import PagedGenerativeEngine
+    _on_the_chip(monkeypatch)
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    eng = PagedGenerativeEngine(_attention_lm(), slots=8, pages=512,
+                                page_size=16, max_cache_len=1024, mesh=mesh)
+    for tq in (1, 4):
+        assert "tpu_custom_call" in eng._pdecode_exe(tq, 64).as_text()
+    c = {k: v for k, v in fa.counters().items() if v}
+    assert c == {"decode_tp_shard_map": 1, "decode_fused": 1,
+                 "decode_multiquery_tp_shard_map": 1,
+                 "decode_multiquery": 1}
+
+
+def test_one_shot_serving_compiles_for_a_data_mesh(topo, monkeypatch):
+    """``InferenceEngine(mesh=...)`` on a data-only mesh shards the batch
+    and replicates the parameters: a partitioned program all the same, so
+    the conv epilogues take the reference path. The engine's own lowering,
+    fed sharding trees in place of placed arrays (nothing can be placed on
+    a described device)."""
+    from jax.sharding import Mesh
+    from deeplearning4j_tpu.nn.config import (InputType,
+                                              NeuralNetConfiguration)
+    from deeplearning4j_tpu.nn.layers.conv import (BatchNormalization,
+                                                   ConvolutionLayer)
+    from deeplearning4j_tpu.nn.layers.core import (ActivationLayer,
+                                                   OutputLayer)
+    from deeplearning4j_tpu.nn.model import MultiLayerNetwork
+    from deeplearning4j_tpu.serving.engine import InferenceEngine
+    _on_the_chip(monkeypatch)
+    conf = (NeuralNetConfiguration.builder().seed(0).data_type("BFLOAT16")
+            .input_type(InputType.convolutional(3, 56, 56,
+                                                data_format="NHWC"))
+            .list(ConvolutionLayer(n_out=256, kernel=(3, 3), mode="same",
+                                   activation="identity",
+                                   data_format="NHWC"),
+                  BatchNormalization(data_format="NHWC"),
+                  ActivationLayer(activation="relu"),
+                  OutputLayer(n_out=1000))
+            .build())
+    net = MultiLayerNetwork(conf).init()
+    eng = InferenceEngine(net, mesh=Mesh(np.array(topo.devices), ("data",)))
+    pl = eng._placement_layer
+    monkeypatch.setattr(eng, "_params_placement", lambda: (
+        "described", pl.param_shardings(net.params),
+        pl.state_shardings(net.state)))
+    text = eng._lower_bucket(*eng._bucket_avals(128, None)).compile() \
+        .as_text()
+    assert "tpu_custom_call" not in text
+    assert fe.counters()["fallback_gspmd"] > 0 and not fe.counters()["fused"]
+
+
+def test_compiler_params_declare_the_grid():
+    """The grid's parallel axes reach the compiler (they were once dropped
+    in silence behind a renamed class and an ``except``)."""
+    from jax.experimental.pallas import tpu as pltpu
+    assert fa._compiler_params(pltpu).dimension_semantics == (
+        "parallel", "parallel", "arbitrary")
+    assert fe._compiler_params_rows(pltpu).dimension_semantics == (
+        "arbitrary",)
+
+
+def test_key_bias_blocks_are_legal_for_the_lowering():
+    """A key bias rides ``(1, 1, bk)`` blocks of ``[B, 1, Tk]``: bk is a
+    multiple of 128 lanes or the whole row, in the dispatcher's pick, the
+    autotuner's candidates and the decode route alike."""
+    from deeplearning4j_tpu.ops import autotune as at
+    assert fa.pick_kv_block(1024, has_bias=True) == 128
+    assert fa.pick_kv_block(64, has_bias=True) == 64      # whole row
+    assert fa.pick_kv_block(192, has_bias=True) is None   # 96 is neither
+    assert fa.pick_kv_block(192) == 96
+    for tk in (128, 512, 1024):
+        cands = at.candidates(128, tk, 64, 2, has_bias=True)
+        assert cands and all(bk % 128 == 0 or bk == tk for _, bk in cands)
+    assert not at._valid_blocks([1, 64], 1, 1024, 64, np.float32,
+                                decode=True, has_bias=True)
+    assert at._valid_blocks([1, 128], 1, 1024, 64, np.float32,
+                            decode=True, has_bias=True)
