@@ -680,29 +680,32 @@ class SameDiff(_SentinelCounterMixin):
         policy = _memory.resolve_policy(getattr(self, "workspace_mode", None))
 
         def loss_split(tv_pen, tv, other_vals, feeds):
-            vals, fd = {**other_vals, **tv}, feeds
-            if mixed:
-                # fp32 masters -> compute-dtype working copies; grads
-                # flow back through the cast into fp32 (engine parity).
-                # Identity (zero eqns) for pre-cast fused-carry leaves.
-                vals = _dt.cast_floating(vals, cdt)
-                fd = _dt.cast_floating(fd, cdt)
-            if policy.remat:
-                from . import remat as _remat
-                env = _remat.compute_with_remat(self, vals, fd,
-                                                (loss_name,), policy)
-            else:
-                env = self._compute(vals, fd)
-            total = env[loss_name]
-            if mixed:  # regularization/score accumulate in fp32
-                total = jnp.asarray(total, jnp.float32)
-            if tc.get("l1"):
-                total = total + tc["l1"] * sum(
-                    jnp.sum(jnp.abs(v)) for v in tv_pen.values())
-            if tc.get("l2"):
-                total = total + 0.5 * tc["l2"] * sum(
-                    jnp.sum(jnp.square(v)) for v in tv_pen.values())
-            return total
+            # the scope names the forward's operations in a device trace;
+            # its transpose shows as transpose(jvp(forward))
+            with jax.named_scope("forward"):
+                vals, fd = {**other_vals, **tv}, feeds
+                if mixed:
+                    # fp32 masters -> compute-dtype working copies; grads
+                    # flow back through the cast into fp32 (engine parity).
+                    # Identity (zero eqns) for pre-cast fused-carry leaves.
+                    vals = _dt.cast_floating(vals, cdt)
+                    fd = _dt.cast_floating(fd, cdt)
+                if policy.remat:
+                    from . import remat as _remat
+                    env = _remat.compute_with_remat(self, vals, fd,
+                                                    (loss_name,), policy)
+                else:
+                    env = self._compute(vals, fd)
+                total = env[loss_name]
+                if mixed:  # regularization/score accumulate in fp32
+                    total = jnp.asarray(total, jnp.float32)
+                if tc.get("l1"):
+                    total = total + tc["l1"] * sum(
+                        jnp.sum(jnp.abs(v)) for v in tv_pen.values())
+                if tc.get("l2"):
+                    total = total + 0.5 * tc["l2"] * sum(
+                        jnp.sum(jnp.square(v)) for v in tv_pen.values())
+                return total
 
         if split_penalty:
             return loss_split
@@ -737,16 +740,18 @@ class SameDiff(_SentinelCounterMixin):
             # the shared engine clip pipeline; per-VARIABLE grouping means
             # each leaf is wrapped as its own "layer" for the mode step
             # (value/L2 clip are tree-shape agnostic, so the wrap is safe)
-            wrapped = {k: {"g": g} for k, g in grads.items()}
-            wrapped, clip_events = _gn.clip_with_events(
-                tc.get("grad_norm"), tc.get("grad_norm_threshold", 1.0),
-                tc.get("clip_value"), tc.get("clip_l2"), wrapped)
-            grads = {k: v["g"] for k, v in wrapped.items()}
+            with jax.named_scope("clip"):
+                wrapped = {k: {"g": g} for k, g in grads.items()}
+                wrapped, clip_events = _gn.clip_with_events(
+                    tc.get("grad_norm"), tc.get("grad_norm_threshold", 1.0),
+                    tc.get("clip_value"), tc.get("clip_l2"), wrapped)
+                grads = {k: v["g"] for k, v in wrapped.items()}
             # DIVERGENCE SENTINEL — engine-parity contract (see
             # MultiLayerNetwork._build_train_step): non-finite loss or
             # global grad norm skips the weight update inside lax.cond and
             # bumps the on-device counters; zero host syncs/retraces.
-            ok = _sent.finite_ok(loss, grads)
+            with jax.named_scope("sentinel"):
+                ok = _sent.finite_ok(loss, grads)
             return grads, ok, clip_events
 
         if fused_cast:
@@ -786,8 +791,9 @@ class SameDiff(_SentinelCounterMixin):
                         updater, grads, opt_state, p, step_i, cdt)
                     return (new_p, new_pc), new_opt
 
-                new_carry, new_opt = _sent.guarded_apply(
-                    ok, _apply, (tv, tv_c), opt_state)
+                with jax.named_scope("updater"):
+                    new_carry, new_opt = _sent.guarded_apply(
+                        ok, _apply, (tv, tv_c), opt_state)
                 if sentinel is None:  # pre-sentinel call signature
                     return new_carry, new_opt, loss
                 return (new_carry, new_opt,
@@ -807,8 +813,9 @@ class SameDiff(_SentinelCounterMixin):
                                          delta),
                             new_opt)
 
-                new_vals, new_opt = _sent.guarded_apply(
-                    ok, _apply, train_vals, opt_state)
+                with jax.named_scope("updater"):
+                    new_vals, new_opt = _sent.guarded_apply(
+                        ok, _apply, train_vals, opt_state)
                 if sentinel is None:  # pre-sentinel call signature
                     return new_vals, new_opt, loss
                 return (new_vals, new_opt,
@@ -908,67 +915,94 @@ class SameDiff(_SentinelCounterMixin):
         Checkpoint listeners (score(), iteration, epoch, save())."""
         if self.loss_name is None or self.updater is None:
             raise ValueError("set_loss(...) and set_updater(...) first")
-        feeds_list = [feeds_iter] if isinstance(feeds_iter, dict) else list(feeds_iter)
-        train_names = [n for n, v in self._vars.items() if v.kind == VARIABLE]
-        updater = self.updater
-        step = self._fit_step_cached()
-        # fused master-cast carry (ISSUE 16): under a 16-bit policy the
-        # step carries (masters, compute_copies) — built ONCE here, then
-        # the fused updater re-emits the copies every step on-device
-        carry = self._fit_carry({n: self._values[n] for n in train_names})
-        train_vals = self._carry_masters(carry)
-        # cast hoist (ISSUE 14 satellite): constants/frozen values go to
-        # the compute dtype ONCE here, not once per compiled step —
-        # self._values keeps the f32 originals (masters discipline)
-        other_vals = self._cast_other_vals(
-            {n: v for n, v in self._values.items()
-             if n not in train_names})
-        opt_state = updater.init_state(train_vals)
-        cbs = list(self._listeners) + list(listeners or [])
-        history = History()
-        i = self.iteration
+        from ..nn.caches import _TimedDispatch
         from ..runtime import faults as _faults
-        for _ in range(epochs):
-            epoch_losses = []
-            for feeds in feeds_list:
-                feeds = {k: jnp.asarray(v) for k, v in feeds.items()}
-                if _faults.enabled():
-                    _faults.trip("train.step")  # crash/preemption site
-                    # float check FIRST: all-int feeds must not consume
-                    # the injection's fire budget without poisoning anything
-                    if any(jnp.issubdtype(v.dtype, jnp.floating)
-                           for v in feeds.values()) and \
-                            _faults.trip("train.nonfinite") is not None:
-                        feeds = {k: jnp.full_like(v, jnp.nan)
-                                 if jnp.issubdtype(v.dtype, jnp.floating)
-                                 else v for k, v in feeds.items()}
-                carry, opt_state, self._sentinel, loss = step(
-                    carry, opt_state, other_vals,
-                    jnp.asarray(i, jnp.int32), feeds,
-                    self._ensure_sentinel())
+        from ..runtime import telemetry as _tel
+        # the train.phase.* spans of the engines' fit loops (nn/caches.py,
+        # "phase tracing"): one call_s, and per feed stage_s, prepare_s,
+        # step_s and readback_s (+ listeners_s where one is attached)
+        span_labels = self._phase_labels()
+        with _tel.span("train.phase.call_s", span_labels,
+                       entry="SameDiff.fit"):
+            feeds_list = [feeds_iter] if isinstance(feeds_iter, dict) \
+                else list(feeds_iter)
+            with _tel.span("train.phase.prepare_s", span_labels):
+                train_names = [n for n, v in self._vars.items()
+                               if v.kind == VARIABLE]
+                updater = self.updater
+                step = self._fit_step_cached()
+                # fused master-cast carry (ISSUE 16): under a 16-bit policy
+                # the step carries (masters, compute_copies) — built ONCE
+                # here, then the fused updater re-emits the copies every
+                # step on-device
+                carry = self._fit_carry(
+                    {n: self._values[n] for n in train_names})
                 train_vals = self._carry_masters(carry)
-                loss = float(loss)
-                history.losses.append(loss)
-                epoch_losses.append(loss)
-                self._score = loss
-                i += 1
-                self.iteration = i
+                # cast hoist (ISSUE 14 satellite): constants/frozen values
+                # go to the compute dtype ONCE here, not once per compiled
+                # step — self._values keeps the f32 originals (masters
+                # discipline)
+                other_vals = self._cast_other_vals(
+                    {n: v for n, v in self._values.items()
+                     if n not in train_names})
+                opt_state = updater.init_state(train_vals)
+            cbs = list(self._listeners) + list(listeners or [])
+            history = History()
+            i = self.iteration
+            for _ in range(epochs):
+                epoch_losses = []
+                for feeds in feeds_list:
+                    with _tel.span("train.phase.stage_s", span_labels):
+                        feeds = {k: jnp.asarray(v) for k, v in feeds.items()}
+                    with _tel.span("train.phase.prepare_s", span_labels):
+                        if _faults.enabled():
+                            _faults.trip("train.step")  # crash/preemption site
+                            # float check FIRST: all-int feeds must not
+                            # consume the injection's fire budget without
+                            # poisoning anything
+                            if any(jnp.issubdtype(v.dtype, jnp.floating)
+                                   for v in feeds.values()) and \
+                                    _faults.trip("train.nonfinite") \
+                                    is not None:
+                                feeds = {
+                                    k: jnp.full_like(v, jnp.nan)
+                                    if jnp.issubdtype(v.dtype, jnp.floating)
+                                    else v for k, v in feeds.items()}
+                        step_i = jnp.asarray(i, jnp.int32)
+                        sentinel = self._ensure_sentinel()
+                    with _TimedDispatch(span_labels, i):
+                        carry, opt_state, self._sentinel, loss = step(
+                            carry, opt_state, other_vals, step_i, feeds,
+                            sentinel)
+                    train_vals = self._carry_masters(carry)
+                    with _tel.span("train.phase.readback_s", span_labels):
+                        loss = float(loss)
+                    history.losses.append(loss)
+                    epoch_losses.append(loss)
+                    self._score = loss
+                    i += 1
+                    self.iteration = i
+                    if cbs:
+                        with _tel.span("train.phase.listeners_s",
+                                       span_labels):
+                            # listeners may save/inspect: publish updated
+                            # weights
+                            self._values.update(train_vals)
+                            for cb in cbs:
+                                cb.iteration_done(self, i, self.epoch)
+                self.epoch += 1
+                history.epoch_losses.append(
+                    sum(epoch_losses) / max(1, len(epoch_losses)))
                 if cbs:
-                    # listeners may save/inspect: publish updated weights
-                    self._values.update(train_vals)
-                for cb in cbs:
-                    cb.iteration_done(self, i, self.epoch)
-            self.epoch += 1
-            history.epoch_losses.append(
-                sum(epoch_losses) / max(1, len(epoch_losses)))
-            if cbs:
-                self._values.update(train_vals)
-            for cb in cbs:
-                cb.on_epoch_end(self)
-        self._values.update(train_vals)
-        # no cache clear: sessions/steps take values as ARGUMENTS, so the
-        # updated weights flow through; only graph mutation (call()) clears
-        return history
+                    with _tel.span("train.phase.listeners_s", span_labels):
+                        self._values.update(train_vals)
+                        for cb in cbs:
+                            cb.on_epoch_end(self)
+            self._values.update(train_vals)
+            # no cache clear: sessions/steps take values as ARGUMENTS, so
+            # the updated weights flow through; only graph mutation (call())
+            # clears
+            return history
 
     def evaluate(self, data_iter, output_name: str,
                  evaluation=None):

@@ -9,37 +9,36 @@ site or mutation point gets fixed in exactly one place.
 
 from __future__ import annotations
 
-import time
-
 from .. import dtypes as _dt
 from ..runtime import telemetry as _tel
 from ..runtime.sentinel import SentinelCounterMixin
 
 
+_DONE = object()      # the end of an iterator in ``_timed_batches``
+
+
 class _TimedDispatch:
-    """Times one async step dispatch into a bound histogram and wraps it
-    in the ``StepTraceAnnotation`` (device traces carry step numbers).
-    Tiny hand-rolled context manager: this runs every fit-loop step."""
+    """The ``train.phase.step_s`` span of one dispatch (the call of the
+    jitted step or epoch function until it returns: the enqueue, since the
+    step is async; a growing value means the host loop, not the device, is
+    the bottleneck) with the ``StepTraceAnnotation`` inside it, so device
+    traces carry step numbers. Tiny hand-rolled context manager: this runs
+    every fit-loop step."""
 
-    __slots__ = ("h", "tel", "ann", "t1")
+    __slots__ = ("span", "ann")
 
-    def __init__(self, h_step, tel: bool, iteration: int):
-        self.h = h_step
-        self.tel = tel
+    def __init__(self, labels: dict, iteration: int):
+        self.span = _tel.span("train.phase.step_s", labels, step=iteration)
         self.ann = _tel.step_annotation(iteration)
 
     def __enter__(self):
-        self.t1 = time.perf_counter() if self.tel else 0.0
+        self.span.__enter__()
         self.ann.__enter__()
         return self
 
     def __exit__(self, *exc):
         r = self.ann.__exit__(*exc)
-        if self.tel:
-            # dispatch time (the step is async): a growing value here
-            # means the host loop, not the device, is the bottleneck —
-            # the complementary signal to data_wait
-            self.h.observe(time.perf_counter() - self.t1)
+        self.span.__exit__(*exc)
         return r
 
 
@@ -251,41 +250,43 @@ class CompiledCacheMixin(SentinelCounterMixin):
         return self._inference_engine
 
     # ---------------------------------------------------- phase tracing
-    # step-phase tracing (ISSUE 6), shared by both engines' fit loops so
-    # the timing semantics cannot drift between MLN and CG: data-wait vs
-    # step-dispatch durations per iteration, plus a StepTraceAnnotation
-    # so device traces (ui/profiler.py) line up with step numbers. One
-    # enabled() read per batch; disabled telemetry skips every clock.
-
-    def _phase_clocks(self):
-        """(data_wait, step) bound histograms labeled ``model=<id>`` —
-        plus ``host=<process_index>`` on a multi-host run, so a pod-level
-        scrape/merge never blends the hosts' step-time distributions
-        (ISSUE 10 satellite; single-process cells stay unlabeled)."""
-        host = _tel.host_labels()
-        return (_tel.histogram("train.phase.data_wait_s")
-                .labeled(model=self.telemetry_label, **host),
-                _tel.histogram("train.phase.step_s")
-                .labeled(model=self.telemetry_label, **host))
+    # The fit loops' phases are telemetry spans in the ``train.phase.*``
+    # family, shared by both engines, ``ParallelWrapper`` and ``SameDiff``
+    # so the semantics cannot drift: one ``call_s`` per public call (the
+    # root of its trace id), and under it ``data_wait_s`` (each ``next()``
+    # of the iterator), ``stage_s`` (host cast, reshape and placement of
+    # the data), ``prepare_s`` (the rest of the host's work before a
+    # dispatch), ``step_s`` (the enqueue), ``readback_s`` (the host waits
+    # for a device value) and ``listeners_s``. A span observes the
+    # histogram of its own name and leaves an event with both ends on the
+    # wall clock in ``telemetry.flight``; disabled telemetry skips every
+    # clock. No span sits inside a jitted function. Their labels are the
+    # mixin's ``_phase_labels()``.
 
     @staticmethod
-    def _timed_batches(it, h_wait):
-        """Yield ``(batch, tel)`` from ``it``, recording the data-wait of
-        each ``next()`` into ``h_wait``; ``tel`` is the enabled() flag
-        sampled for that batch (reuse it for the step clock)."""
+    def _timed_batches(it, labels):
+        """Yield the batches of ``it``, each ``next()`` inside a
+        ``train.phase.data_wait_s`` span."""
         src = iter(it)
         while True:
-            tel = _tel.enabled()
-            t0 = time.perf_counter() if tel else 0.0
-            try:
-                ds = next(src)
-            except StopIteration:
-                return
-            if tel:
-                h_wait.observe(time.perf_counter() - t0)
-            yield ds, tel
+            wait = _tel.span("train.phase.data_wait_s", labels)
+            with wait:
+                ds = next(src, _DONE)
+                if ds is _DONE:
+                    wait.cancel()
+                    return
+            yield ds
 
-    def _timed_dispatch(self, tel, h_step):
-        """Context manager for ONE train-step dispatch: step annotation +
-        dispatch-time histogram (see ``_TimedDispatch``)."""
-        return _TimedDispatch(h_step, tel, self.iteration)
+    def _notify_listeners(self, labels, event: str, *args):
+        """Call ``event`` (``iteration_done`` / ``on_epoch_end``) on every
+        attached listener inside one ``train.phase.listeners_s`` span; no
+        span where none is attached."""
+        if self._listeners:
+            with _tel.span("train.phase.listeners_s", labels):
+                for cb in self._listeners:
+                    getattr(cb, event)(self, *args)
+
+    def _timed_dispatch(self, labels):
+        """Context manager for ONE train-step dispatch: the ``step_s``
+        span + step annotation (see ``_TimedDispatch``)."""
+        return _TimedDispatch(labels, self.iteration)

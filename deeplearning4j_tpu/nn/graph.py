@@ -48,6 +48,7 @@ from . import caches as _caches
 from ..data.dataset import (DataSet, DataSetIterator, MultiDataSet,
                             MultiDataSetIterator, NumpyMultiDataSetIterator)
 from ..ops import losses as _loss
+from ..runtime import telemetry as _tel
 from . import constraints as _constraints
 from . import updaters as _upd
 from .layers.base import Layer
@@ -548,38 +549,41 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             getattr(self.conf, "workspace_mode", None))
 
         def loss_fn(p, bn_state, key, xs, ys, fms, lms):
-            inputs = dict(zip(self.conf.inputs, xs))
-            masks = {n: m for n, m in zip(self.conf.inputs, fms)
-                     if m is not None}
-            acts, new_bn, mks = self._forward(
-                p, inputs, bn_state, train=True, rng=key, masks=masks,
-                remat_policy=policy)
-            total = 0.0
-            for o, y, lm in zip(outputs, ys, lms):
-                layer = out_layers[o]
-                # intersect explicit label mask with the propagated mask
-                m = _loss.combine_masks(lm, mks.get(o))
-                if hasattr(layer, "update_centers"):
-                    # CenterLossOutputLayer: pull the stashed features
-                    # out of the aux state channel (must not persist),
-                    # EMA-update centers outside the gradient
-                    st = dict(new_bn[o])
-                    feats = st.pop("__features__")
-                    centers = bn_state[o]["centers"]
-                    st["centers"] = jax.lax.stop_gradient(
-                        layer.update_centers(
-                            centers, jax.lax.stop_gradient(feats), y))
-                    new_bn = {**new_bn, o: st}
-                    total = total + layer.loss_value(
-                        acts[o], y, mask=m,
-                        weights=getattr(layer, "loss_weights", None),
-                        features=feats,
-                        centers=jax.lax.stop_gradient(centers))
-                else:
-                    total = total + layer.loss_value(
-                        acts[o], y, mask=m,
-                        weights=getattr(layer, "loss_weights", None))
-            return total + self._regularization(p), new_bn
+            # the scope names the forward's operations in a device trace;
+            # its transpose shows as transpose(jvp(forward))
+            with jax.named_scope("forward"):
+                inputs = dict(zip(self.conf.inputs, xs))
+                masks = {n: m for n, m in zip(self.conf.inputs, fms)
+                         if m is not None}
+                acts, new_bn, mks = self._forward(
+                    p, inputs, bn_state, train=True, rng=key, masks=masks,
+                    remat_policy=policy)
+                total = 0.0
+                for o, y, lm in zip(outputs, ys, lms):
+                    layer = out_layers[o]
+                    # intersect explicit label mask with the propagated mask
+                    m = _loss.combine_masks(lm, mks.get(o))
+                    if hasattr(layer, "update_centers"):
+                        # CenterLossOutputLayer: pull the stashed features
+                        # out of the aux state channel (must not persist),
+                        # EMA-update centers outside the gradient
+                        st = dict(new_bn[o])
+                        feats = st.pop("__features__")
+                        centers = bn_state[o]["centers"]
+                        st["centers"] = jax.lax.stop_gradient(
+                            layer.update_centers(
+                                centers, jax.lax.stop_gradient(feats), y))
+                        new_bn = {**new_bn, o: st}
+                        total = total + layer.loss_value(
+                            acts[o], y, mask=m,
+                            weights=getattr(layer, "loss_weights", None),
+                            features=feats,
+                            centers=jax.lax.stop_gradient(centers))
+                    else:
+                        total = total + layer.loss_value(
+                            acts[o], y, mask=m,
+                            weights=getattr(layer, "loss_weights", None))
+                return total + self._regularization(p), new_bn
 
         return loss_fn
 
@@ -638,7 +642,8 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                 grads = _dt.cast_floating(grads, pdt)
                 if grad_transform is not None:
                     grads = grad_transform(grads)
-                grads, clip_events = self._clip(grads)
+                with jax.named_scope("clip"):
+                    grads, clip_events = self._clip(grads)
 
                 def _apply(pair, opt_state):
                     p, _ = pair
@@ -658,9 +663,11 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                     return (new_p, new_pc, new_opt, new_bn,
                             _sent.update_counters(sentinel, jnp.bool_(True),
                                                   clip_events), loss)
-                ok = _sent.finite_ok(loss, grads)
-                (new_p, new_pc), new_opt = _sent.guarded_apply(
-                    ok, _apply, (params, params_c), opt_state)
+                with jax.named_scope("sentinel"):
+                    ok = _sent.finite_ok(loss, grads)
+                with jax.named_scope("updater"):
+                    (new_p, new_pc), new_opt = _sent.guarded_apply(
+                        ok, _apply, (params, params_c), opt_state)
                 out_bn = jax.tree.map(
                     lambda new, old: jnp.where(ok, new, old),
                     new_bn, bn_state) if bn_state else new_bn
@@ -689,7 +696,8 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                     grads = _dt.cast_floating(grads, pdt)
             if grad_transform is not None:
                 grads = grad_transform(grads)
-            grads, clip_events = self._clip(grads)
+            with jax.named_scope("clip"):
+                grads, clip_events = self._clip(grads)
 
             def _apply(params, opt_state):
                 # leaf-wise updater application. The flat-buffer variant
@@ -717,9 +725,11 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             # build_train_step: non-finite loss/grad-norm lax.cond-skips the
             # updater application and BN commit, bumps on-device counters;
             # zero host syncs, zero retraces in steady state.
-            ok = _sent.finite_ok(loss, grads)
-            new_params, new_opt = _sent.guarded_apply(
-                ok, _apply, params, opt_state)
+            with jax.named_scope("sentinel"):
+                ok = _sent.finite_ok(loss, grads)
+            with jax.named_scope("updater"):
+                new_params, new_opt = _sent.guarded_apply(
+                    ok, _apply, params, opt_state)
             out_bn = jax.tree.map(
                 lambda new, old: jnp.where(ok, new, old),
                 new_bn, bn_state) if bn_state else new_bn
@@ -808,58 +818,67 @@ class ComputationGraph(_caches.CompiledCacheMixin):
         Returns the loss history ``[epochs * n_batches]``. Masked datasets
         must use ``fit()``.
         """
-        if not self.params and not self.state:
-            self.init()
-        feats = [np.asarray(f) for f in
-                 (features if isinstance(features, (list, tuple)) else [features])]
-        labs = [np.asarray(l) for l in
-                (labels if isinstance(labels, (list, tuple)) else [labels])]
-        n = feats[0].shape[0]
-        b = batch_size or n
-        nb = n // b
-        if nb == 0:
-            raise ValueError(f"batch_size {b} exceeds dataset size {n}")
-        if n % b and not drop_remainder:
-            raise ValueError(
-                f"dataset size {n} is not divisible by batch_size {b}: the "
-                f"on-device scan would drop {n % b} examples. Pass "
-                "drop_remainder=True to accept that, or use fit() which "
-                "pads and masks the tail")
-        dt = _dt.resolve(self.conf.dtype)
-        def stack(a, cast):
-            a = a[:nb * b].reshape((nb, b) + a.shape[1:])
-            # features get the net-dtype cast fit() applies in _forward;
-            # labels stay in their original precision (the loss computes in
-            # fp32 under the mixed-precision policy — pre-rounding regression
-            # targets to bf16 would diverge from fit())
-            if cast and np.issubdtype(a.dtype, np.floating) and \
-                    jnp.issubdtype(dt, jnp.floating):
-                a = a.astype(dt)
-            return jax.device_put(jnp.asarray(a))
-        xs = tuple(stack(f, True) for f in feats)
-        ys = tuple(stack(l, False) for l in labs)
-        if self._epoch_fn is None:
-            self._epoch_fn = self._build_epoch_fn()
-            self._record_build("train.epoch_fn", cache_attr="_epoch_fn")
-        history = []
-        for _ in range(epochs):
-            self._key, sub = jax.random.split(self._key)
-            (self.params, self.updater_state, self.state, self._sentinel,
-             losses) = \
-                self._epoch_fn(self.params, self.updater_state, self.state,
-                               self._ensure_sentinel(),
-                               jnp.int32(self.iteration), sub, xs, ys)
-            self.iteration += nb
-            self.epoch += 1
-            # lazy device scalar — listeners calling score() get this
-            # epoch's final loss without forcing a mid-chain host sync
-            self._score = losses[-1]
-            history.append(losses)
-            for cb in self._listeners:
-                cb.on_epoch_end(self)
-        out = np.concatenate([np.asarray(h) for h in history])
-        self._score = float(out[-1])
-        return out
+        span_labels = self._phase_labels()
+        with _tel.span("train.phase.call_s", span_labels,
+                       entry="ComputationGraph.fit_on_device"):
+            if not self.params and not self.state:
+                self.init()
+            feats = [np.asarray(f) for f in
+                     (features if isinstance(features, (list, tuple)) else [features])]
+            labs = [np.asarray(l) for l in
+                    (labels if isinstance(labels, (list, tuple)) else [labels])]
+            n = feats[0].shape[0]
+            b = batch_size or n
+            nb = n // b
+            if nb == 0:
+                raise ValueError(f"batch_size {b} exceeds dataset size {n}")
+            if n % b and not drop_remainder:
+                raise ValueError(
+                    f"dataset size {n} is not divisible by batch_size {b}: "
+                    f"the on-device scan would drop {n % b} examples. Pass "
+                    "drop_remainder=True to accept that, or use fit() which "
+                    "pads and masks the tail")
+            dt = _dt.resolve(self.conf.dtype)
+
+            def stack(a, cast):
+                # features get the net-dtype cast fit() applies in _forward;
+                # labels stay in their original precision (the loss computes
+                # in fp32 under the mixed-precision policy — pre-rounding
+                # regression targets to bf16 would diverge from fit())
+                with _tel.span("train.phase.stage_s", span_labels):
+                    a = a[:nb * b].reshape((nb, b) + a.shape[1:])
+                    if cast and np.issubdtype(a.dtype, np.floating) and \
+                            jnp.issubdtype(dt, jnp.floating):
+                        a = a.astype(dt)
+                    return jax.device_put(jnp.asarray(a))
+            xs = tuple(stack(f, True) for f in feats)
+            ys = tuple(stack(l, False) for l in labs)
+            if self._epoch_fn is None:
+                self._epoch_fn = self._build_epoch_fn()
+                self._record_build("train.epoch_fn", cache_attr="_epoch_fn")
+            history = []
+            for _ in range(epochs):
+                with _tel.span("train.phase.prepare_s", span_labels):
+                    self._key, sub = jax.random.split(self._key)
+                    sentinel = self._ensure_sentinel()
+                    start = jnp.int32(self.iteration)
+                with self._timed_dispatch(span_labels):
+                    (self.params, self.updater_state, self.state,
+                     self._sentinel, losses) = \
+                        self._epoch_fn(self.params, self.updater_state,
+                                       self.state, sentinel, start, sub, xs,
+                                       ys)
+                self.iteration += nb
+                self.epoch += 1
+                # lazy device scalar — listeners calling score() get this
+                # epoch's final loss without forcing a mid-chain host sync
+                self._score = losses[-1]
+                history.append(losses)
+                self._notify_listeners(span_labels, "on_epoch_end")
+            with _tel.span("train.phase.readback_s", span_labels):
+                out = np.concatenate([np.asarray(h) for h in history])
+            self._score = float(out[-1])
+            return out
 
     def fit(self, data, labels=None, epochs: int = 1,
             resilience=None) -> "ComputationGraph":
@@ -890,55 +909,60 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             self.params, _dt.resolve(self.conf.dtype)) if fused else None
         from ..runtime import faults as _faults
         it = _as_multi_iterator(data, labels)
-        # step-phase tracing (ISSUE 6): shared scaffold on
-        # CompiledCacheMixin — see caches.py _phase_clocks/_timed_batches
-        _h_wait, _h_step = self._phase_clocks()
-
-        for _ in range(epochs):
-            for mds, tel in self._timed_batches(it, _h_wait):
-                self._key, sub = jax.random.split(self._key)
-                xs = tuple(jnp.asarray(f) for f in mds.features)
-                ys = tuple(jnp.asarray(l) for l in mds.labels)
-                if _faults.enabled():
-                    _faults.trip("train.step")  # crash/preemption site
-                    # float check FIRST: all-int inputs must not consume
-                    # the injection's fire budget without poisoning anything
-                    if any(jnp.issubdtype(x.dtype, jnp.floating)
-                           for x in xs) and \
-                            _faults.trip("train.nonfinite") is not None:
-                        xs = tuple(
-                            jnp.full_like(x, jnp.nan)
-                            if jnp.issubdtype(x.dtype, jnp.floating) else x
-                            for x in xs)  # sentinel site
-                fms = tuple(None if m is None else jnp.asarray(m)
-                            for m in mds.features_masks)
-                lms = tuple(None if m is None else jnp.asarray(m)
-                            for m in mds.labels_masks)
-                step = jnp.asarray(self.iteration, dtype=jnp.int32)
-                self._last_batch = xs  # StatsListener activation sampling
-                with self._timed_dispatch(tel, _h_step):
-                    if fused:
-                        (self.params, params_c, self.updater_state,
-                         self.state, self._sentinel, loss) = \
-                            self._train_step(self.params, params_c,
-                                             self.updater_state, self.state,
-                                             step, sub, xs, ys, fms, lms,
-                                             self._ensure_sentinel())
-                    else:
-                        (self.params, self.updater_state, self.state,
-                         self._sentinel, loss) = \
-                            self._train_step(self.params, self.updater_state,
-                                             self.state, step, sub, xs, ys,
-                                             fms, lms,
-                                             self._ensure_sentinel())
-                self._score = loss
-                self.iteration += 1
-                for cb in self._listeners:
-                    cb.iteration_done(self, self.iteration, self.epoch)
-            self.epoch += 1
-            for cb in self._listeners:
-                cb.on_epoch_end(self)
-            it = _as_multi_iterator(data, labels)
+        # the train.phase.* spans: shared scaffold on CompiledCacheMixin
+        # (see caches.py, "phase tracing")
+        span_labels = self._phase_labels()
+        with _tel.span("train.phase.call_s", span_labels,
+                       entry="ComputationGraph.fit"):
+            for _ in range(epochs):
+                for mds in self._timed_batches(it, span_labels):
+                    with _tel.span("train.phase.stage_s", span_labels):
+                        xs = tuple(jnp.asarray(f) for f in mds.features)
+                        ys = tuple(jnp.asarray(l) for l in mds.labels)
+                        fms = tuple(None if m is None else jnp.asarray(m)
+                                    for m in mds.features_masks)
+                        lms = tuple(None if m is None else jnp.asarray(m)
+                                    for m in mds.labels_masks)
+                    with _tel.span("train.phase.prepare_s", span_labels):
+                        self._key, sub = jax.random.split(self._key)
+                        if _faults.enabled():
+                            _faults.trip("train.step")  # crash/preemption site
+                            # float check FIRST: all-int inputs must not
+                            # consume the injection's fire budget without
+                            # poisoning anything
+                            if any(jnp.issubdtype(x.dtype, jnp.floating)
+                                   for x in xs) and \
+                                    _faults.trip("train.nonfinite") \
+                                    is not None:
+                                xs = tuple(
+                                    jnp.full_like(x, jnp.nan)
+                                    if jnp.issubdtype(x.dtype, jnp.floating)
+                                    else x for x in xs)  # sentinel site
+                        step = jnp.asarray(self.iteration, dtype=jnp.int32)
+                        sentinel = self._ensure_sentinel()
+                    self._last_batch = xs  # StatsListener activation sampling
+                    with self._timed_dispatch(span_labels):
+                        if fused:
+                            (self.params, params_c, self.updater_state,
+                             self.state, self._sentinel, loss) = \
+                                self._train_step(self.params, params_c,
+                                                 self.updater_state,
+                                                 self.state, step, sub, xs,
+                                                 ys, fms, lms, sentinel)
+                        else:
+                            (self.params, self.updater_state, self.state,
+                             self._sentinel, loss) = \
+                                self._train_step(self.params,
+                                                 self.updater_state,
+                                                 self.state, step, sub, xs,
+                                                 ys, fms, lms, sentinel)
+                    self._score = loss
+                    self.iteration += 1
+                    self._notify_listeners(span_labels, "iteration_done",
+                                           self.iteration, self.epoch)
+                self.epoch += 1
+                self._notify_listeners(span_labels, "on_epoch_end")
+                it = _as_multi_iterator(data, labels)
         return self
 
     # ------------------------------------------------------------- inference

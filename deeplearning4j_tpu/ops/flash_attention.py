@@ -315,6 +315,7 @@ def _fwd_impl(q3, k3, v3, kb, scale, heads, bq, bk, interpret):
                         pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=_compiler_params(pltpu),
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     return o, m, l
 
@@ -352,6 +353,7 @@ def _bwd_impl(q3, k3, v3, kb, m, l, di, do, scale, heads, bq, bk, interpret):
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=_compiler_params(pltpu),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*args)
 
     # dk/dv grid: kv-blocks outer, q-blocks inner (the reduction axis)
@@ -382,6 +384,7 @@ def _bwd_impl(q3, k3, v3, kb, m, l, di, do, scale, heads, bq, bk, interpret):
                         pltpu.VMEM((bk, d), jnp.float32)],
         compiler_params=_compiler_params(pltpu),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*args)
     return dq, dk, dv
 
@@ -415,6 +418,7 @@ def _mq_impl(q3, k3, v3, lens, scale, heads, bk, interpret):
         out_shape=jax.ShapeDtypeStruct((G, Tq, d), q3.dtype),
         compiler_params=_compiler_params(pltpu),
         interpret=interpret,
+        name="flash_decode_mq",
     )(lens, q3, k3, v3)
 
 

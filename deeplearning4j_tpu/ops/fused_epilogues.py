@@ -290,6 +290,7 @@ def _affine_fwd_impl(x2, s2, b2, act, br, interpret):
         out_specs=_pl.BlockSpec((br, C), lambda i: (i, 0)),
         compiler_params=_compiler_params_rows(pltpu),
         interpret=interpret,
+        name="affine_act_fwd",
     )(*args)
 
 
@@ -323,6 +324,7 @@ def _affine_bwd_impl(x2, s2, b2, dy, act, br, interpret):
         scratch_shapes=scratch,
         compiler_params=_compiler_params_rows(pltpu),
         interpret=interpret,
+        name="affine_act_bwd",
     )(*args)
     if has_scale:
         dx, ds, db = outs
@@ -349,6 +351,7 @@ def _ln_fwd_impl(x2, g2, b2, eps, act, br, interpret):
         out_specs=(row, stat, stat),
         compiler_params=_compiler_params_rows(pltpu),
         interpret=interpret,
+        name="layer_norm_act_fwd",
     )(x2, g2, b2)
 
 
@@ -372,6 +375,7 @@ def _ln_bwd_impl(x2, g2, b2, mu, rstd, dy, eps, act, br, interpret):
                         pltpu.VMEM((1, C), jnp.float32)],
         compiler_params=_compiler_params_rows(pltpu),
         interpret=interpret,
+        name="layer_norm_act_bwd",
     )(x2, g2, b2, mu, rstd, dy)
 
 

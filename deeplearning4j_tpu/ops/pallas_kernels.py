@@ -125,5 +125,6 @@ def lstm_cell_fused(x, h, c, w_ih, w_hh, b, forget_bias: float = 0.0,
         in_specs=[spec] * 6,
         out_specs=(spec, spec),
         interpret=interpret,
+        name="lstm_cell",
     )(x, h, c, w_ih, w_hh, b)
     return h_new, c_new

@@ -657,12 +657,12 @@ class ParallelWrapper:
             return run_resilient_fit(self, data, epochs=epochs,
                                      policy=resilience)
         from ..runtime import faults as _faults
+        from ..runtime import telemetry as _tel
         m = self.model
         if not m.params:
             m.init()
         if self._step is None:
             self._step = self._build()
-            from ..runtime import telemetry as _tel
             cause = self._pending_step_cause or (
                 m._consume_retrace_cause()
                 if hasattr(m, "_consume_retrace_cause") else "first_build")
@@ -671,44 +671,55 @@ class ParallelWrapper:
                                 shard_update=self.shard_update,
                                 overlap=self.overlap_grads)
         step_fn, shard_args = self._step
-        # step-phase tracing (shared CompiledCacheMixin scaffold, ISSUE 6):
-        # pod fits get the same train.phase.data_wait_s/step_s cells as the
-        # engine fit loops — labeled model= AND host= (ISSUE 10), so a
-        # pod-wide scrape shows every host's step-time distribution apart
-        h_wait, h_step = m._phase_clocks()
-        for _ in range(epochs):
-            for batch, tel in m._timed_batches(self._batches(data), h_wait):
-                x, y, fm, lm = batch
-                if _faults.enabled():
-                    _faults.trip("train.step")  # crash/preemption site
-                    # whole-host-loss site (ISSUE 10): deterministic
-                    # injections fire on every process at the same step
-                    # (SPMD), raising HostLoss — run_resilient_fit routes
-                    # it through launcher.reinitialize() + restore
-                    _faults.trip("parallel.host_loss")
-                    # float check FIRST: all-int inputs must not consume
-                    # the injection's fire budget without poisoning anything
-                    if any(np.issubdtype(np.asarray(a).dtype, np.floating)
-                           for a in jax.tree.leaves(x)) and \
-                            _faults.trip("train.nonfinite") is not None:
-                        x = jax.tree.map(
-                            lambda a: np.full_like(a, np.nan)
-                            if np.issubdtype(np.asarray(a).dtype, np.floating)
-                            else a, x)  # sentinel site
-                m._key, sub = jax.random.split(m._key)
-                args = shard_args(
-                    m.params, m.updater_state, m.state, m._ensure_sentinel(),
-                    jnp.asarray(m.iteration, jnp.int32), sub, x, y, fm, lm)
-                with m._timed_dispatch(tel, h_step):
-                    m.params, m.updater_state, m.state, m._sentinel, loss = \
-                        step_fn(*args)
-                m._score = loss
-                m.iteration += 1
-                for cb in m._listeners:
-                    cb.iteration_done(m, m.iteration, m.epoch)
-            m.epoch += 1
-            for cb in m._listeners:
-                cb.on_epoch_end(m)
+        # the engines' train.phase.* spans (nn/caches.py, "phase tracing"):
+        # pod fits get the same cells as the engine fit loops — labeled
+        # model= AND host= (ISSUE 10), so a pod-wide scrape shows every
+        # host's step-time distribution apart
+        span_labels = m._phase_labels()
+        with _tel.span("train.phase.call_s", span_labels,
+                       entry="ParallelWrapper.fit"):
+            for _ in range(epochs):
+                for batch in m._timed_batches(self._batches(data),
+                                              span_labels):
+                    x, y, fm, lm = batch
+                    with _tel.span("train.phase.prepare_s", span_labels):
+                        if _faults.enabled():
+                            _faults.trip("train.step")  # crash/preemption site
+                            # whole-host-loss site (ISSUE 10): deterministic
+                            # injections fire on every process at the same
+                            # step (SPMD), raising HostLoss —
+                            # run_resilient_fit routes it through
+                            # launcher.reinitialize() + restore
+                            _faults.trip("parallel.host_loss")
+                            # float check FIRST: all-int inputs must not
+                            # consume the injection's fire budget without
+                            # poisoning anything
+                            if any(np.issubdtype(np.asarray(a).dtype,
+                                                 np.floating)
+                                   for a in jax.tree.leaves(x)) and \
+                                    _faults.trip("train.nonfinite") \
+                                    is not None:
+                                x = jax.tree.map(
+                                    lambda a: np.full_like(a, np.nan)
+                                    if np.issubdtype(np.asarray(a).dtype,
+                                                     np.floating)
+                                    else a, x)  # sentinel site
+                        m._key, sub = jax.random.split(m._key)
+                        step = jnp.asarray(m.iteration, jnp.int32)
+                        sentinel = m._ensure_sentinel()
+                    with _tel.span("train.phase.stage_s", span_labels):
+                        args = shard_args(
+                            m.params, m.updater_state, m.state, sentinel,
+                            step, sub, x, y, fm, lm)
+                    with m._timed_dispatch(span_labels):
+                        (m.params, m.updater_state, m.state, m._sentinel,
+                         loss) = step_fn(*args)
+                    m._score = loss
+                    m.iteration += 1
+                    m._notify_listeners(span_labels, "iteration_done",
+                                        m.iteration, m.epoch)
+                m.epoch += 1
+                m._notify_listeners(span_labels, "on_epoch_end")
         return m
 
     def _pad_granularity(self) -> int:

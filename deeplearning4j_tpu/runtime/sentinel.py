@@ -129,6 +129,13 @@ class SentinelCounterMixin:
                              model=self._tel_label)
         return self._tel_label
 
+    def _phase_labels(self) -> dict:
+        """The labels of this model's ``train.phase.*`` spans and their
+        histogram cells: ``model=<id>`` — plus ``host=<process_index>`` on
+        a multi-host run, so a pod-level scrape/merge never blends the
+        hosts' step-time distributions (ISSUE 10 satellite)."""
+        return {"model": self.telemetry_label, **_tel.host_labels()}
+
     def _ensure_sentinel(self):
         if self._sentinel is None:
             self._sentinel = init_counters()

@@ -24,7 +24,12 @@ Four pieces:
 - **Span API** — ``with telemetry.span("serving.dispatch"):`` records a
   duration histogram under the span name and emits a structured event
   carrying trace/span/parent correlation ids (contextvar-propagated, so
-  nested spans across threads correlate when the context flows).
+  nested spans across threads correlate when the context flows) and both
+  ends on the wall clock (``t0_ns``/``t1_ns``). The event lands in the
+  in-memory ``flight`` ring, where :func:`spans` finds it: that is how a
+  device trace is laid over the training loops' ``train.phase.*`` spans
+  (``nn/caches.py``, "phase tracing"). An open span holds a
+  ``jax.profiler.TraceAnnotation`` of its name.
 - **Retrace tracker** — :func:`record_compile` is called by every
   lower+compile site (engine train-step builds, the serving engine's AOT
   bucket cache, the SameDiff fit-step spec cache) with its *cause*
@@ -68,7 +73,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 __all__ = [
     "MetricsRegistry", "registry", "counter", "gauge", "histogram",
-    "enabled", "set_enabled", "span", "current_span", "event_log",
+    "enabled", "set_enabled", "span", "spans", "current_span", "event_log",
     "emit_event", "record_compile", "compile_events",
     "reset_compile_events", "step_annotation", "prometheus_text",
     "snapshot", "coverage_report",
@@ -612,10 +617,14 @@ def coverage_report() -> dict:
 # ---------------------------------------------------------------- span API
 class Span:
     """One timed region. ``trace_id`` groups a whole request/step tree;
-    ``parent_id`` is the enclosing span (None at the root)."""
+    ``parent_id`` is the enclosing span (None at the root). ``t0_ns`` is
+    the start on the wall clock (``time.time_ns``), which is what lets a
+    profiler trace be laid over the spans; the duration comes from the
+    monotonic clock, and the event's ``t1_ns`` is ``t0_ns`` plus it, so a
+    stepped wall clock cannot turn a span inside out."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "labels", "t0", "duration_s")
+                 "labels", "t0", "t0_ns", "duration_s")
 
     def __init__(self, name, trace_id, span_id, parent_id, attrs,
                  labels=None):
@@ -625,6 +634,7 @@ class Span:
         self.parent_id = parent_id
         self.attrs = attrs
         self.labels = labels
+        self.t0_ns = time.time_ns()
         self.t0 = time.perf_counter()
         self.duration_s: Optional[float] = None
 
@@ -638,27 +648,62 @@ def current_span() -> Optional[Span]:
     return _current_span.get()
 
 
+_profiler = None  # jax.profiler, resolved on first use; False = unavailable
+
+
+def _jax_profiler():
+    """``jax.profiler`` or False. The lookup resolves once — spans and step
+    annotations run on every fit-loop step — and lazily, so this module
+    stays stdlib-only at import time."""
+    global _profiler
+    if _profiler is None:
+        try:
+            import jax
+            _profiler = jax.profiler
+        except Exception:
+            _profiler = False
+    return _profiler
+
+
 class _SpanCtx:
-    __slots__ = ("span", "_token")
+    __slots__ = ("span", "_token", "_ann", "_cancelled")
 
     def __init__(self, span: Span):
         self.span = span
         self._token = None
+        self._ann = None
+        self._cancelled = False
 
     def __enter__(self) -> Span:
         self._token = _current_span.set(self.span)
+        # an operator's own trace (``ui.ProfilingListener``, default host
+        # tracer) shows the program's spans under their names; outside a
+        # profiler session this costs a fraction of a microsecond
+        prof = _jax_profiler()
+        if prof:
+            self._ann = prof.TraceAnnotation(self.span.name)
+            self._ann.__enter__()
         return self.span
+
+    def cancel(self) -> None:
+        """Record nothing when the block is left (a ``next()`` that found
+        the iterator's end waited for no batch)."""
+        self._cancelled = True
 
     def __exit__(self, *exc):
         sp = self.span
         sp.duration_s = time.perf_counter() - sp.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         _current_span.reset(self._token)
-        if registry._enabled:
+        if registry._enabled and not self._cancelled:
             registry.histogram(sp.name).observe(sp.duration_s,
                                                 **(sp.labels or {}))
-            ev = {"type": "span", "name": sp.name,
+            t1_ns = sp.t0_ns + int(sp.duration_s * 1e9)
+            ev = {"t": t1_ns / 1e9, "type": "span", "name": sp.name,
                   "trace": sp.trace_id, "span": sp.span_id,
                   "parent": sp.parent_id, "duration_s": sp.duration_s,
+                  "t0_ns": sp.t0_ns, "t1_ns": t1_ns,
                   **(sp.labels or {}), **sp.attrs}
             if exc and exc[0] is not None:
                 ev["status"] = "error"
@@ -674,6 +719,9 @@ class _NullSpanCtx:
     def __enter__(self):
         return None
 
+    def cancel(self):
+        pass
+
     def __exit__(self, *exc):
         return False
 
@@ -688,8 +736,11 @@ def span(name: str, labels: Optional[dict] = None, **attrs):
     the histogram cell's labels so distinct instances don't blend into
     one p99; free-form ``attrs`` (row counts, shapes) go to the event
     log only. Nested spans inherit the trace id and point at their
-    parent; a root span starts a fresh trace. Disabled telemetry returns
-    a no-op context (the body still runs; nothing is recorded)."""
+    parent; a root span starts a fresh trace. The finished event (name,
+    ``t0_ns``/``t1_ns`` on the wall clock, ``trace``/``span``/``parent``)
+    lands in the ``flight`` ring, where :func:`spans` finds it. Disabled
+    telemetry returns a no-op context (the body still runs; nothing is
+    recorded)."""
     if not registry._enabled:
         return _NULL_SPAN
     parent = _current_span.get()
@@ -700,29 +751,28 @@ def span(name: str, labels: Optional[dict] = None, **attrs):
                          attrs, labels))
 
 
-_step_annotation_cls = None  # resolved on first use; False = unavailable
+def spans(names=None, since_ns: int = 0) -> List[dict]:
+    """The finished span events still in the ``flight`` ring, oldest
+    first: those named in ``names`` (all when None) that ended at or after
+    ``since_ns`` on the wall clock. The ring is bounded and shared with
+    compile and fault events, so a reader that needs a whole interval
+    checks that the ring's oldest event (``flight.events()[0]["t"]``) is
+    older than the interval's start."""
+    return [e for e in flight.events()
+            if e.get("type") == "span" and e["t1_ns"] >= since_ns
+            and (names is None or e["name"] in names)]
 
 
 def step_annotation(step_num: int, name: str = "train"):
     """``jax.profiler.StepTraceAnnotation`` for one training step (or a
     no-op when telemetry is off / jax is unavailable): device traces
     captured by ``ui.profiler.ProfilingListener`` then carry the step
-    number, so trace timelines line up with the step-phase histograms.
-    The class lookup resolves once — this runs on every fit-loop step."""
-    global _step_annotation_cls
-    if not registry._enabled:
-        return _NULL_SPAN
-    cls = _step_annotation_cls
-    if cls is None:
-        try:
-            import jax
-            cls = _step_annotation_cls = jax.profiler.StepTraceAnnotation
-        except Exception:
-            cls = _step_annotation_cls = False
-    if cls is False:
+    number, so trace timelines line up with the step-phase histograms."""
+    prof = _jax_profiler() if registry._enabled else False
+    if not prof:
         return _NULL_SPAN
     try:
-        return cls(name, step_num=step_num)
+        return prof.StepTraceAnnotation(name, step_num=step_num)
     except Exception:
         return _NULL_SPAN
 

@@ -10,6 +10,7 @@ off around them (such entries cannot be read back without a chip).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -45,12 +46,22 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, one_chip, *avals):
+def _custom_call_names(text):
+    """The instruction names of the compiled text's custom calls."""
+    return re.findall(r"%([\w.\-]+) = [^\n]*? custom-call\(", text)
+
+
+def _compile(fn, one_chip, *avals, kernels):
     """Compile ``fn`` for the described chip on ``(shape, dtype)`` avals and
-    return the compiled text; the kernel must be in it."""
+    return the compiled text; each of ``kernels`` must be in it under its
+    ``pallas_call(name=)``, which is what a device trace, the benchmark's
+    readers and the ledger's breakdown call it."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in avals]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    calls = _custom_call_names(text)
+    for kernel in kernels:
+        assert any(kernel in c for c in calls), (kernel, calls)
     return text
 
 
@@ -77,7 +88,9 @@ def test_flash_attention_compiles(one_chip, shape, has_bias, grad):
     avals = [(shape, BF16)] * 3
     if has_bias:
         avals.append(((B, 1, 1, T), jnp.float32))
-    text = _compile(fn, one_chip, *avals)
+    text = _compile(fn, one_chip, *avals, kernels=(
+        ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv") if grad
+        else ("flash_fwd",)))
     # forward, and in the backward the dq and dk/dv kernels beside it
     assert text.count("tpu_custom_call") >= (3 if grad else 1)
 
@@ -90,7 +103,7 @@ def test_decode_attention_compiles(one_chip, page):
         return fa.decode_attention(q, k, v, lengths, block_k=128, page=page)
 
     _compile(fn, one_chip, ((B, H, 1, d), BF16), ((B, H, C, d), BF16),
-             ((B, H, C, d), BF16), ((B,), jnp.int32))
+             ((B, H, C, d), BF16), ((B,), jnp.int32), kernels=("flash_fwd",))
 
 
 def test_paged_decode_step_compiles(one_chip):
@@ -110,7 +123,7 @@ def test_paged_decode_step_compiles(one_chip):
     tok = ((B, H, 1, d), BF16)
     pool = ((rows, H, d), BF16)
     _compile(fn, one_chip, tok, tok, tok, pool, pool,
-             ((B, MP), jnp.int32), ((B,), jnp.int32))
+             ((B, MP), jnp.int32), ((B,), jnp.int32), kernels=("flash_fwd",))
 
 
 def test_decode_multiquery_compiles(one_chip):
@@ -120,7 +133,8 @@ def test_decode_multiquery_compiles(one_chip):
         return fa.decode_multiquery_attention(q, k, v, lengths, block_k=128)
 
     _compile(fn, one_chip, ((B, H, Tq, d), BF16), ((B, H, C, d), BF16),
-             ((B, H, C, d), BF16), ((B,), jnp.int32))
+             ((B, H, C, d), BF16), ((B,), jnp.int32),
+             kernels=("flash_decode_mq",))
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
@@ -136,7 +150,9 @@ def test_layer_norm_act_compiles(one_chip, grad):
         return jnp.sum(fwd(x, g, b).astype(jnp.float32))
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    _compile(fn, one_chip, ((R, C), BF16), ((1, C), BF16), ((1, C), BF16))
+    _compile(fn, one_chip, ((R, C), BF16), ((1, C), BF16), ((1, C), BF16),
+             kernels=("layer_norm_act_fwd",) + (("layer_norm_act_bwd",)
+                                               if grad else ()))
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
@@ -153,7 +169,10 @@ def test_affine_act_compiles(one_chip, grad):
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
     _compile(fn, one_chip, ((R, C), BF16), ((1, C), jnp.float32),
-             ((1, C), jnp.float32))
+             ((1, C), jnp.float32),
+             # the sum's gradient needs no forward output: only the
+             # backward kernel is left in that program
+             kernels=("affine_act_bwd",) if grad else ("affine_act_fwd",))
 
 
 def test_lstm_cell_compiles(one_chip):
@@ -162,7 +181,7 @@ def test_lstm_cell_compiles(one_chip):
     f32 = jnp.float32
     _compile(pk.lstm_cell_fused, one_chip, ((B, U), f32), ((B, U), f32),
              ((B, U), f32), ((U, 4 * U), f32), ((U, 4 * U), f32),
-             ((4 * U,), f32))
+             ((4 * U,), f32), kernels=("lstm_cell",))
 
 
 def test_data_parallel_step_compiles_for_the_mesh(topo, one_chip,
