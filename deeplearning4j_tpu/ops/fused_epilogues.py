@@ -10,11 +10,20 @@ shows naive conv kernels lose to XLA's conv pipeline):
 
 - :func:`bn_act` — batch-norm normalize + activation as one row-tiled
   affine kernel ``y = act(x*scale + shift)`` with scale/shift folded from
-  the BN statistics outside the kernel ([C]-sized math, XLA's job). The
-  ResNet hot-block tail (conv -> BN -> relu) stops round-tripping the conv
-  output through HBM twice.
+  the BN statistics outside the kernel ([C]-sized math, XLA's job). On a
+  TPU in ``auto`` a convolution's feature map (rank 4, channel-last) does
+  NOT take the kernel: it takes the reference path, counted
+  ``fallback_conv_layout``. The premise that the kernel saves the conv
+  output an HBM round trip came from a CPU cost model and is false on the
+  chip: XLA folds normalize+activation into the convolutions' own
+  fusions, and 92 custom calls in a ResNet-50 step cost layout copies,
+  reshapes into the ``[rows, C]`` view and those fusions — 85.0 ms a step
+  against 45.4, 1,407 examples/s against 2,498, with the kernels
+  themselves at 83% of their roofline (``PERF_LEDGER.jsonl``, PR 29 and
+  PR 30, ``resnet50.train.resident``; ``PERF.md`` sections 5 and 6).
+  ``force`` still runs the kernel on any shape it can tile.
 - :func:`bias_act` — conv/matmul bias + activation epilogue on the same
-  affine kernel (scale absent).
+  affine kernel (scale absent); rank-4 feature maps as for ``bn_act``.
 - :func:`layer_norm_act` — LayerNorm + affine + activation for the
   transformer blocks; spliced into TF-imported SameDiff graphs by
   ``autodiff/fusion.py``'s ``fuse_epilogues`` rewrite (the r8
@@ -464,7 +473,7 @@ def fits_vmem_epilogue(br: int, cols: int, itemsize: int = 4,
 
 _COUNTER_KEYS = ("fused", "fallback_mode", "fallback_platform",
                  "fallback_act", "fallback_dtype", "fallback_shape",
-                 "fallback_vmem", "fallback_gspmd",
+                 "fallback_vmem", "fallback_gspmd", "fallback_conv_layout",
                  # master-cast+updater decisions ride the same registry
                  # counter so the whole library's mix is one metric family
                  "fused_updater", "fallback_updater_mode",
@@ -483,10 +492,13 @@ def mode() -> str:
 
 
 def set_mode(m: str) -> str:
-    """"auto" (TPU -> kernels, elsewhere -> exact unfused reference),
-    "force" (kernels everywhere — Pallas interpret off-TPU; how the CPU
-    tier-1 suite exercises the kernel code), "off" (reference everywhere,
-    fused updater disabled). Returns the previous mode.
+    """"auto" (TPU -> kernels for rank-2/3 sites, the exact unfused
+    reference for a convolution's rank-4 feature map, where XLA's own
+    epilogue measured 1.77x faster on the chip, ``fallback_conv_layout``;
+    elsewhere -> the reference), "force" (kernels everywhere — Pallas
+    interpret off-TPU; how the CPU tier-1 suite exercises the kernel
+    code), "off" (reference everywhere, fused updater disabled). Returns
+    the previous mode.
 
     Consulted at TRACE time, exactly like flash attention's mode: flip it
     BEFORE building/tracing, or invalidate compiled caches after."""
@@ -533,6 +545,10 @@ def route_elementwise(shape, dtype, axis=-1, act="identity", alpha=None,
     ndim = len(shape)
     if ndim < 2 or axis not in (-1, ndim - 1):
         return "fallback_shape"  # kernels are channel-last row-tiled
+    if _state["mode"] == "auto" and kind == "affine" and ndim == 4:
+        # a convolution's feature map: XLA's own epilogue is faster on the
+        # chip than a custom call and the copies around it (module docstring)
+        return "fallback_conv_layout"
     cols = int(shape[-1])
     rows = 1
     for s in shape[:-1]:

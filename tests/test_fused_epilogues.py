@@ -308,6 +308,8 @@ def test_dispatch_fallbacks_and_counters(rng):
         assert c["fallback_updater_penalty"] == 1
         # zero silent decisions: every call above is attributed
         assert sum(c.values()) == 12, c
+        # forced kernels and a CPU's auto never read the TPU-auto key
+        assert c["fallback_conv_layout"] == 0
     finally:
         fe.set_mode(old)
     with pytest.raises(ValueError, match="mode"):
@@ -817,3 +819,74 @@ def test_partitioned_trace_routes_to_the_reference():
         assert fe.route_elementwise(shape, dt) is None
     finally:
         fe.set_mode(old)
+
+
+# ---------------------------------------------------------------------------
+# auto on a TPU: conv feature maps take XLA's epilogue (fallback_conv_layout)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def on_the_chip(monkeypatch):
+    """The dispatcher asks the backend, which is the CPU here: steer it
+    onto its TPU branch, in ``auto``."""
+    monkeypatch.setattr(fe, "_tpu_available", lambda: True)
+    old = fe.set_mode("auto")
+    fe.reset_counters()
+    yield
+    fe.set_mode(old)
+
+
+@pytest.mark.parametrize("shape,kind,mode,want", [
+    # a convolution's feature map, lane-aligned or not: XLA's own epilogue
+    ((128, 56, 56, 256), "affine", "auto", "fallback_conv_layout"),
+    ((128, 112, 112, 64), "affine", "auto", "fallback_conv_layout"),
+    # rank 2 and 3 keep the decision they had
+    ((4096, 768), "affine", "auto", None),
+    ((4096, 768), "ln", "auto", None),
+    ((32, 512, 768), "affine", "auto", None),
+    ((32, 512, 768), "ln", "auto", None),
+    ((32, 512, 3072), "affine", "auto", "fallback_vmem"),
+    ((32, 512, 3072), "ln", "auto", "fallback_vmem"),
+    # the LayerNorm kernels are not a conv epilogue at any rank
+    ((8, 16, 16, 128), "ln", "auto", None),
+    # force: kernels wherever they can run
+    ((128, 56, 56, 256), "affine", "force", None),
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_route_on_the_chip(on_the_chip, shape, kind, mode, want):
+    fe.set_mode(mode)
+    assert fe.route_elementwise(shape, jnp.bfloat16, kind=kind) == want
+
+
+def test_route_conv_layout_gives_way_to_earlier_checks(on_the_chip):
+    """A mesh trace still reads ``fallback_gspmd``, a channel-first map
+    ``fallback_shape``, an activation without a kernel ``fallback_act``."""
+    from jax.sharding import Mesh
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+    shape, dt = (128, 56, 56, 256), jnp.bfloat16
+    with pk.gspmd_trace(Mesh(np.array(jax.devices()[:2]), ("data",))):
+        assert fe.route_elementwise(shape, dt) == "fallback_gspmd"
+    assert fe.route_elementwise(shape, dt, axis=1) == "fallback_shape"
+    assert fe.route_elementwise(shape, dt, act="leakyrelu",
+                                alpha=0.2) == "fallback_act"
+
+
+@pytest.mark.parametrize("op", ["bn_act", "bn_act_no_affine", "bias_act"])
+def test_conv_layout_fallback_is_the_unfused_pair(rng, on_the_chip, op):
+    """On a feature map in ``auto`` on a TPU the epilogue IS the legacy
+    formula, bit for bit, counted once under the new key."""
+    x = jnp.asarray(rng.normal(size=(2, 4, 4, 128)), jnp.bfloat16)
+    gamma = jnp.asarray(rng.normal(size=(128,)) + 1.0, jnp.bfloat16)
+    beta = jnp.asarray(rng.normal(size=(128,)), jnp.bfloat16)
+    mean = jnp.asarray(rng.normal(size=(128,)) * 0.1, jnp.bfloat16)
+    var = jnp.asarray(rng.random(128) + 0.5, jnp.bfloat16)
+    relu = fe.reference_act("relu")
+    if op == "bias_act":
+        got = fe.bias_act(x, beta, act="relu")
+        want = relu(x + beta.reshape(1, 1, 1, 128))
+    else:
+        g, b = (None, None) if op == "bn_act_no_affine" else (gamma, beta)
+        got = fe.bn_act(x, g, b, mean, var, 1e-5, act="relu")
+        want = relu(nnops.batch_norm(x, g, b, mean, var, 1e-5, -1))
+    _assert_tree_bits_equal(got, want, op)
+    c = fe.counters()
+    assert c["fallback_conv_layout"] == 1 and sum(c.values()) == 1, c

@@ -188,9 +188,11 @@ def test_data_parallel_step_compiles_for_the_mesh(topo, one_chip,
                                                   monkeypatch):
     """A Mosaic kernel cannot be partitioned by GSPMD, so the step that
     ``ParallelWrapper`` traces routes the epilogue kernels to their
-    reference path, counted; the same network's one-device step keeps
-    them. The dispatchers ask the backend, which is the CPU here: the test
-    steers them onto their TPU branch."""
+    reference path, counted. The same network's one-device step holds no
+    kernel either: in ``auto`` a convolution's feature map takes XLA's own
+    epilogue (``fallback_conv_layout``), so one chip and four run the same
+    program. The dispatchers ask the backend, which is the CPU here: the
+    test steers them onto their TPU branch."""
     from jax.sharding import Mesh
     from deeplearning4j_tpu.nn import memory
     from deeplearning4j_tpu.nn.config import (InputType,
@@ -236,9 +238,18 @@ def test_data_parallel_step_compiles_for_the_mesh(topo, one_chip,
     args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=one_chip), args)
     text = net._build_train_step(1).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    assert "tpu_custom_call" not in text
     c = fe.counters()
-    assert c["fused"] > 0 and c["fallback_gspmd"] == 0
+    assert c["fallback_conv_layout"] > 0
+    assert c["fused"] == 0 and c["fallback_gspmd"] == 0
+
+    # a one-device program still carries an epilogue kernel where the
+    # dispatcher keeps one: a rank-2 LayerNorm site, through the dispatcher
+    fe.reset_counters()
+    _compile(lambda x, g, b: fe.layer_norm_act(x, g, b, 1e-12, act="gelu"),
+             one_chip, ((4096, 768), BF16), ((768,), BF16), ((768,), BF16),
+             kernels=("layer_norm_act_fwd",))
+    assert fe.counters()["fused"] == 1
 
 
 def _on_the_chip(monkeypatch):
