@@ -270,6 +270,7 @@ class ComputationGraph(_caches.CompiledCacheMixin):
         self._train_output_fn = None
         self._epoch_fn = None
         self._inference_engine = None
+        self._layer_counts_seen: Dict[str, Any] = {}
         self._key = jax.random.PRNGKey(conf.seed)
         self._out_layers: Dict[str, Any] = {}
         for o in conf.outputs:
@@ -304,6 +305,7 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             shapes[name] = tuple(out_shape)
         self.params = params
         self.state = state
+        self._layer_counts_seen = {}
         self._shapes = shapes
         self.updater_state = self.conf.updater.init_state(params) \
             if self.conf.updater else {}
@@ -634,12 +636,32 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                 raise ValueError("fused_cast requires accum_steps == 1 "
                                  "(the microbatch scan has its own hoist)")
 
+            # under a recomputing workspace_mode (the memory knob) the
+            # gradient's float32 copy is not held either: see below
+            from . import memory as _memory
+            late_cast = (_memory.resolve_policy(
+                getattr(self.conf, "workspace_mode", None)).remat
+                and grad_transform is None
+                and not self.conf.gradient_normalization
+                and self.conf.gradient_clip_value is None
+                and self.conf.gradient_clip_l2 is None)
+
             def fused_step_fn(params, params_c, opt_state, bn_state, step,
                               key, xs, ys, fms, lms, sentinel=None):
                 (loss, new_bn), grads = vg_fn(
                     params_c, bn_state, key, xs, ys, fms, lms)
-                # exact upcast — the unfused cast's transpose, bitwise
-                grads = _dt.cast_floating(grads, pdt)
+                # exact upcast — the unfused cast's transpose, bitwise.
+                # Under a recomputing workspace_mode, where nothing reads the
+                # gradient between here and the updater (no transform, no
+                # clipping; the sentinel upcasts what it sums), the upcast
+                # moves into the updater's own sweep, so the float32 copy of
+                # the whole gradient is never held: 4 bytes a parameter of
+                # peak memory. Not bit-equal to the early cast (there XLA may
+                # keep the backward's float32 values unrounded; here the
+                # compute-dtype gradient is what crosses into the updater),
+                # so the default mode keeps the early cast
+                if not late_cast:
+                    grads = _dt.cast_floating(grads, pdt)
                 if grad_transform is not None:
                     grads = grad_transform(grads)
                 with jax.named_scope("clip"):
@@ -648,7 +670,8 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                 def _apply(pair, opt_state):
                     p, _ = pair
                     new_p, new_pc, new_opt = _upd.apply_leafwise_cast(
-                        updater, grads, opt_state, p, step, cdt)
+                        updater, _dt.cast_floating(grads, pdt), opt_state, p,
+                        step, cdt)
                     if self.conf.constraints:
                         new_p = _constraints.apply_constraints(
                             self.conf.constraints, new_p, skip=frozen_keys)
@@ -757,6 +780,10 @@ class ComputationGraph(_caches.CompiledCacheMixin):
         rest emitted by the fused updater write; external signature
         unchanged (masters in, masters out).
         """
+        # one dispatch decision per compiled program, as ``fit`` counts it
+        from ..ops import fused_epilogues as _fe
+        _fe.dispatch_updater(self.conf.dtype,
+                             has_penalty=self._uses_regularization())
         if self.fused_updater_active():
             step = self._build_train_step(fused_cast=True).__wrapped__
             cdt = _dt.resolve(self.conf.dtype)
@@ -877,8 +904,23 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                 self._notify_listeners(span_labels, "on_epoch_end")
             with _tel.span("train.phase.readback_s", span_labels):
                 out = np.concatenate([np.asarray(h) for h in history])
+                self._publish_layer_counters()
             self._score = float(out[-1])
             return out
+
+    def _publish_layer_counters(self):
+        """Layers that count on the device (a sparse-expert layer's tokens
+        per expert) keep the counts in their state, which came back from the
+        scanned call with the losses: hand each its state and the one
+        published last, and it adds the growth to its telemetry counters."""
+        seen = self._layer_counts_seen
+        for name, (v, _) in self._vertex_map.items():
+            publish = getattr(getattr(v, "layer", None), "publish_counters",
+                              None)
+            if publish is not None and name in self.state:
+                now = jax.device_get(self.state[name])
+                publish(name, now, seen.get(name))
+                seen[name] = now
 
     def fit(self, data, labels=None, epochs: int = 1,
             resilience=None) -> "ComputationGraph":

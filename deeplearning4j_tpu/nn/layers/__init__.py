@@ -1,6 +1,6 @@
 from .base import DETERMINISTIC_BUILTINS, LAYERS, Layer  # noqa: F401
 from . import (attention, conv, conv3d, conv_extra, core,  # noqa: F401
-               recurrent, special, wrappers)
+               decoder, recurrent, special, wrappers)
 
 # Stochastic built-ins: these consume the per-layer PRNG key in apply().
 # Every other BUILT-IN layer class is recorded as deterministic so the

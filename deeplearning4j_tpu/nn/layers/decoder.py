@@ -1,0 +1,315 @@
+"""The layers of a causal decoder on token ids: RMSNorm, rotary grouped-query
+attention under a causal or sliding-window mask, a gated feed-forward, a
+sparse-expert feed-forward that is told which experts it holds, and the
+next-token loss head.
+
+Every setting that differs between the layers of one stack (query heads,
+rotary share, base and scaling, mask) is a field of the layer, so a builder
+lays out full and window layers of different head counts from one class
+(``models/laguna.py``). Activations are ``[B, T, features]``.
+"""
+
+from __future__ import annotations
+
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ...ops import activations as _act
+from ...ops import causal_attention as _ca
+from ...ops import moe as _moe
+from ...runtime import telemetry as _tel
+from .. import weights as _winit
+from .base import Layer, layer
+
+
+def _w(init, key, shape, dtype):
+    return _winit.init(init, key, shape, shape[-2], shape[-1], dtype)
+
+
+@layer("rms_norm")
+class RMSNormLayer(Layer):
+    """``x / sqrt(mean(x^2) + eps) * g`` over the last axis, the statistics
+    in float32."""
+    decode_pointwise = True
+    eps: float = 1e-6
+    name: Optional[str] = None
+
+    def initialize(self, key, input_shape, dtype):
+        return ({"g": jnp.ones((int(input_shape[-1]),), dtype)}, {},
+                tuple(input_shape))
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        xf = x.astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                               + self.eps)
+        return y.astype(x.dtype) * params["g"], state, mask
+
+
+@layer("causal_attention")
+class CausalSelfAttentionLayer(Layer):
+    """Rotary grouped-query self-attention under a causal mask, or with
+    ``window`` a sliding-window one (key ``j`` open to query ``i`` where ``i -
+    window < j <= i``). ``n_heads`` query heads share ``n_kv_heads``; the
+    first ``rotary_dim`` of each head's ``head_size`` dimensions are rotated
+    (0: all), with ``rope_type`` ``default`` or ``yarn``. ``gated``
+    multiplies the heads' output by ``sigmoid(x Wg)`` element-wise before
+    the output projection. No biases. The scores are never materialised
+    (``ops/causal_attention.py``)."""
+    quantizable = True
+    n_heads: int = 1
+    n_kv_heads: int = 1
+    head_size: int = 64
+    window: Optional[int] = None
+    gated: bool = False
+    rotary_dim: int = 0
+    rope_theta: float = 10000.0
+    rope_type: str = "default"
+    rope_factor: float = 1.0
+    rope_original_max_position: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_attention_factor: float = 1.0
+    weight_init: str = "xavier"
+    name: Optional[str] = None
+
+    def initialize(self, key, input_shape, dtype):
+        f = int(input_shape[-1])
+        hq, hkv = self.n_heads * self.head_size, self.n_kv_heads * self.head_size
+        ks = jax.random.split(key, 5)
+        params = {"Wq": _w(self.weight_init, ks[0], (f, hq), dtype),
+                  "Wk": _w(self.weight_init, ks[1], (f, hkv), dtype),
+                  "Wv": _w(self.weight_init, ks[2], (f, hkv), dtype),
+                  "Wo": _w(self.weight_init, ks[3], (hq, f), dtype)}
+        if self.gated:
+            params["Wg"] = _w(self.weight_init, ks[4], (f, hq), dtype)
+        return params, {}, tuple(input_shape)
+
+    def quantize_spec(self, params):
+        return {k: 1 for k in params}
+
+    def inv_freq(self) -> np.ndarray:
+        rot = self.rotary_dim or self.head_size
+        if self.rope_type == "default":
+            return _ca.default_inv_freq(rot, self.rope_theta)
+        if self.rope_type == "yarn":
+            return _ca.yarn_inv_freq(
+                rot, self.rope_theta, self.rope_factor,
+                self.rope_original_max_position, self.rope_beta_fast,
+                self.rope_beta_slow)
+        raise ValueError(f"unknown rope_type {self.rope_type!r}")
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        B, T, _ = x.shape
+        q = jnp.dot(x, params["Wq"]).reshape(B, T, self.n_heads, self.head_size)
+        k = jnp.dot(x, params["Wk"]).reshape(B, T, self.n_kv_heads,
+                                             self.head_size)
+        v = jnp.dot(x, params["Wv"]).reshape(B, T, self.n_kv_heads,
+                                             self.head_size)
+        cos, sin = _ca.rotary_tables(T, self.inv_freq(),
+                                     self.rope_attention_factor)
+        q, k = _ca.apply_rotary(q, cos, sin), _ca.apply_rotary(k, cos, sin)
+        o = _ca.causal_attention(q, k, v, window=self.window)
+        o = o.reshape(B, T, self.n_heads * self.head_size)
+        if self.gated:
+            o = o * jax.nn.sigmoid(jnp.dot(x, params["Wg"]))
+        return jnp.dot(o, params["Wo"]), state, mask
+
+
+def _gated_ffn(x, w1, w3, w2, activation):
+    return jnp.dot(_act.get(activation)(jnp.dot(x, w1)) * jnp.dot(x, w3), w2)
+
+
+@layer("gated_dense")
+class GatedDenseLayer(Layer):
+    """``(act(x W1) * (x W3)) W2`` at width ``n_hidden``, no biases."""
+    decode_pointwise = True
+    quantizable = True
+    n_hidden: int = 0
+    activation: str = "swish"
+    weight_init: str = "xavier"
+    name: Optional[str] = None
+
+    def initialize(self, key, input_shape, dtype):
+        f = int(input_shape[-1])
+        ks = jax.random.split(key, 3)
+        return ({"W1": _w(self.weight_init, ks[0], (f, self.n_hidden), dtype),
+                 "W3": _w(self.weight_init, ks[1], (f, self.n_hidden), dtype),
+                 "W2": _w(self.weight_init, ks[2], (self.n_hidden, f), dtype)},
+                {}, tuple(input_shape))
+
+    def quantize_spec(self, params):
+        return {k: 1 for k in params}
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        return (_gated_ffn(x, params["W1"], params["W3"], params["W2"],
+                           self.activation), state, mask)
+
+
+_MOE_TOKENS = _tel.counter(
+    "moe.tokens", "tokens routed to each expert held here, by layer")
+_MOE_ASSIGNMENTS = _tel.counter(
+    "moe.assignments", "token-to-expert assignments by where the expert is "
+    "held: here (computed) or elsewhere (left out)")
+_MOE_DROPPED = _tel.counter(
+    "moe.dropped", "assignments to a held expert that were not computed "
+    "(stays 0: the layer has no capacity)")
+
+
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+@layer("sparse_experts")
+class SparseExpertLayer(Layer):
+    """A routed feed-forward that is told which experts it holds.
+
+    The router scores every token against all ``num_experts`` (sigmoid, in
+    float32), keeps the ``top_k`` largest and weights them ``routed_scale * s
+    / sum(s)``. Of the chosen experts this layer computes those in ``held =
+    (first, count)``, gated feed-forwards of width ``n_hidden`` run as
+    grouped products over the tokens routed to them with no token dropped
+    (``ops/moe.py``), and adds a shared expert of width ``shared_hidden``
+    (0: none) that every token goes through. What experts held elsewhere
+    would add is left out; ``held=None`` holds them all. The state counts,
+    since ``init``, the tokens each held expert got and the assignments here,
+    elsewhere and dropped; ``fit_on_device`` publishes their growth as
+    ``moe.*`` counters with the losses it reads back."""
+    num_experts: int = 8
+    top_k: int = 2
+    n_hidden: int = 0
+    shared_hidden: int = 0
+    held: Optional[Tuple[int, int]] = None
+    routed_scale: float = 1.0
+    weight_init: str = "xavier"
+    name: Optional[str] = None
+
+    def _held(self):
+        first, count = self.held or (0, self.num_experts)
+        if not 0 <= first < first + count <= self.num_experts:
+            raise ValueError(f"held={self.held} is no range of "
+                             f"{self.num_experts} experts")
+        return int(first), int(count)
+
+    def initialize(self, key, input_shape, dtype):
+        f, h = int(input_shape[-1]), self.n_hidden
+        _, count = self._held()
+        ks = jax.random.split(key, 7)
+        wi = self.weight_init
+        params = {"Wr": _w(wi, ks[0], (f, self.num_experts), dtype),
+                  "W1": _w(wi, ks[1], (count, f, h), dtype),
+                  "W3": _w(wi, ks[2], (count, f, h), dtype),
+                  "W2": _w(wi, ks[3], (count, h, f), dtype)}
+        if self.shared_hidden:
+            s = self.shared_hidden
+            params.update(S1=_w(wi, ks[4], (f, s), dtype),
+                          S3=_w(wi, ks[5], (f, s), dtype),
+                          S2=_w(wi, ks[6], (s, f), dtype))
+        state = {"tokens": jnp.zeros((count,), jnp.uint32),
+                 "here": jnp.zeros((), jnp.uint32),
+                 "elsewhere": jnp.zeros((), jnp.uint32),
+                 "dropped": jnp.zeros((), jnp.uint32)}
+        return params, state, tuple(input_shape)
+
+    def chunk_rows(self, n_tokens: int) -> int:
+        """Rows of the sorted assignments walked at a time: a quarter over
+        what a uniform routing sends here, so that one chunk is the rule and
+        a second the exception."""
+        _, count = self._held()
+        expected = -(-n_tokens * self.top_k * count // self.num_experts)
+        rows = min(-(-expected * 5 // 4), n_tokens * min(self.top_k, count))
+        return _round_up(rows, 256 if rows >= 256 else 8)
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        first, count = self._held()
+        lead, f = x.shape[:-1], x.shape[-1]
+        xt = x.reshape(-1, f)
+        top_e, w = _moe.route(xt, params["Wr"], self.top_k, self.routed_scale)
+        order, ends, tokens = _moe.plan(top_e, first, count)
+        with jax.named_scope("moe.experts"):
+            routed, done = _moe.held_experts(
+                xt, w, params["W1"], params["W3"], params["W2"], order, ends,
+                self.chunk_rows(xt.shape[0]), self.top_k)
+        with jax.named_scope("moe.combine"):
+            y = routed
+            if self.shared_hidden:
+                y = y + _gated_ffn(xt, params["S1"], params["S3"],
+                                   params["S2"], "swish")
+            y = y.astype(x.dtype).reshape(lead + (f,))
+        if train and state:
+            here = ends[-1].astype(jnp.uint32)
+            state = {
+                "tokens": state["tokens"] + tokens.astype(jnp.uint32),
+                "here": state["here"] + here,
+                "elsewhere": state["elsewhere"]
+                + jnp.uint32(top_e.size) - here,
+                "dropped": state["dropped"] + here - done.astype(jnp.uint32)}
+        return y, state, mask
+
+    def publish_counters(self, vertex: str, now: dict, before) -> None:
+        """Add what the state's counts grew by since ``before`` (None: since
+        zero) to the ``moe.*`` counters; counts wrap at 2**32."""
+        def grew(key):
+            old = 0 if before is None else before[key]
+            return (np.asarray(now[key], np.int64)
+                    - np.asarray(old, np.int64)) % (1 << 32)
+
+        first, _ = self._held()
+        for i, n in enumerate(grew("tokens")):
+            if n:
+                _MOE_TOKENS.inc(int(n), layer=vertex, expert=str(first + i))
+        _MOE_ASSIGNMENTS.inc(int(grew("here")), layer=vertex, where="here")
+        _MOE_ASSIGNMENTS.inc(int(grew("elsewhere")), layer=vertex,
+                             where="elsewhere")
+        _MOE_DROPPED.inc(int(grew("dropped")), layer=vertex)
+
+
+@layer("causal_lm_output")
+class CausalLMOutputLayer(Layer):
+    """The untied head of a causal language model with its loss: takes the
+    hidden states and the token ids that went in, and scores position ``t``
+    against token ``t + 1``. The loss is the mean cross-entropy, in float32,
+    over the positions that have a next token (each row's last has none and
+    is left out); the labels handed to ``fit`` are not read. In training
+    ``apply`` returns the per-position losses ``[B, T - 1]``, otherwise the
+    probabilities ``[B, T, n_out]``."""
+    n_inputs = 2
+    quantizable = True
+    n_out: int = 0
+    weight_init: str = "xavier"
+    name: Optional[str] = None
+
+    def initialize(self, key, input_shapes, dtype):
+        hidden, tokens = input_shapes
+        f = int(hidden[-1])
+        return ({"W": _w(self.weight_init, key, (f, self.n_out), dtype)}, {},
+                tuple(hidden[:-1]) + (self.n_out,))
+
+    def quantize_spec(self, params):
+        return {"W": 1}
+
+    def apply(self, params, xs, state, *, train=False, rng=None, mask=None):
+        h, tokens = xs
+        if not train:
+            logits = jnp.dot(h, params["W"],
+                             preferred_element_type=jnp.float32)
+            return jax.nn.softmax(logits, axis=-1), state, mask
+
+        # a sequence at a time, recomputed in the backward pass: the float32
+        # logits of one sequence are all that is ever held
+        @jax.checkpoint
+        def row(args):
+            hr, nxt = args
+            logits = jnp.dot(hr, params["W"],
+                             preferred_element_type=jnp.float32)
+            picked = jnp.take_along_axis(logits, nxt[:, None], axis=-1)[:, 0]
+            return jax.nn.logsumexp(logits, axis=-1) - picked
+
+        nll = jax.lax.map(row, (h[:, :-1],
+                                jnp.asarray(tokens, jnp.int32)[:, 1:]))
+        return nll, state, None
+
+    def loss_value(self, nll, labels, mask=None, weights=None):
+        return jnp.mean(nll.astype(jnp.float32))

@@ -547,6 +547,10 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
         subsequent copy is emitted by the fused updater write — the
         per-scan-step cast sweep is gone. External signature unchanged
         (masters in, masters out)."""
+        # one dispatch decision per compiled program, as ``fit`` counts it
+        from ..ops import fused_epilogues as _fe
+        _fe.dispatch_updater(self.conf.dtype,
+                             has_penalty=self._uses_regularization())
         if self.fused_updater_active():
             step = self._build_train_step(fused_cast=True).__wrapped__
             cdt = _dt.resolve(self.conf.dtype)

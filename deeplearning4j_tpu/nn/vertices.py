@@ -101,7 +101,9 @@ def _first_mask(masks):
 
 @vertex("layer")
 class LayerVertex(GraphVertex):
-    """Wraps a Layer as a single-input vertex (DL4J ``LayerVertex``).
+    """Wraps a Layer as a vertex (DL4J ``LayerVertex``): single-input, or
+    as many inputs as the layer's ``n_inputs`` says (a loss head that reads
+    the token ids beside the hidden states), handed over as a list.
 
     Auto-flatten: when a Dense/Output layer receives a rank-3 CNN shape, the
     input is flattened first (DL4J's CnnToFeedForwardPreProcessor inserted by
@@ -121,9 +123,13 @@ class LayerVertex(GraphVertex):
         return self.layer.has_params()
 
     def initialize(self, key, input_shapes, dtype):
-        if len(input_shapes) != 1:
-            raise ValueError(f"LayerVertex({self.layer.kind}) takes one input, "
-                             f"got {len(input_shapes)}")
+        n = getattr(self.layer, "n_inputs", 1)
+        if len(input_shapes) != n:
+            raise ValueError(f"LayerVertex({self.layer.kind}) takes {n} "
+                             f"input(s), got {len(input_shapes)}")
+        if n > 1:
+            return self.layer.initialize(
+                key, [tuple(s) for s in input_shapes], dtype)
         from .layers.core import DenseLayer, OutputLayer
         shape = tuple(input_shapes[0])
         self._flatten = (isinstance(self.layer, (DenseLayer, OutputLayer))
@@ -137,7 +143,7 @@ class LayerVertex(GraphVertex):
 
     def apply(self, params, xs, state, *, train=False, rng=None, masks=None,
               fold_act=None):
-        x = xs[0]
+        x = xs[0] if getattr(self.layer, "n_inputs", 1) == 1 else list(xs)
         if self._flatten:
             x = x.reshape(x.shape[0], -1)
         mask = _first_mask(masks)

@@ -175,6 +175,46 @@ def test_affine_act_compiles(one_chip, grad):
              kernels=("affine_act_bwd",) if grad else ("affine_act_fwd",))
 
 
+@pytest.mark.parametrize("heads,window", [(48, None), (64, 512)],
+                         ids=["full48", "window64"])
+def test_blocked_causal_attention_compiles(one_chip, heads, window):
+    """Laguna-XS.2's two attention layers at their published head counts over
+    8 KV heads of 128: the blocked XLA path, forward and backward, with no
+    [T, T] array anywhere in the program."""
+    from deeplearning4j_tpu.ops import causal_attention as ca
+    T = 4096
+
+    def loss(q, k, v):
+        return jnp.sum(ca.causal_attention(q, k, v, window=window)
+                       .astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct(s, BF16, sharding=one_chip)
+            for s in ((1, T, heads, 128), (1, T, 8, 128), (1, T, 8, 128))]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args) \
+        .compile().as_text()
+    assert not re.search(rf"\[(\d+,)*{T},{T}\]", text)
+
+
+def test_held_experts_compile_as_grouped_products(one_chip):
+    """16 experts of 2048 x 512 held, 8 of 256 chosen a token: the chunk
+    loop with the compiler's ragged-dot kernels, forward and backward."""
+    from deeplearning4j_tpu.ops import moe
+    N, d, f, held, k = 4096, 2048, 512, 16, 8
+
+    def loss(x, wr, w1, w3, w2):
+        top_e, w = moe.route(x, wr, k, 2.5)
+        order, ends, _ = moe.plan(top_e, 0, held)
+        out, _ = moe.held_experts(x, w, w1, w3, w2, order, ends, 2560, k)
+        return jnp.sum(out)
+
+    avals = [((N, d), BF16), ((d, 256), BF16), ((held, d, f), BF16),
+             ((held, d, f), BF16), ((held, f, d), BF16)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in avals]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*args) \
+        .compile().as_text()
+    assert "ragged-dot" in text
+
+
 def test_lstm_cell_compiles(one_chip):
     B, U = 64, 256
     assert pk.fits_vmem(B, U, U)
