@@ -1,21 +1,26 @@
 """Pallas block-shape autotuner for the flash-attention kernel (ISSUE 7).
 
-The kernel shipped with ``block_q = block_k = 128`` hardcoded — the right
-tile for bert-shaped f32 at seq 1024, and a guess everywhere else. The TVM
-line of work (PAPERS.md, 1802.04799) says the honest way out is the boring
-one: enumerate the feasible schedule space, MEASURE each candidate on the
-device, and cache the winner per shape key so the sweep runs once. This
-module is that loop for the one schedule knob the flash kernel exposes,
-its (block_q, block_k) tiling:
+What a shape gets when nobody tuned it is ``flash_attention.default_blocks``:
+the largest tile that fits VMEM, the whole key row where it does (512 x 512
+for BERT-base at 512 tokens, one tile a head). Every traced program gets
+that default, because a sweep cannot run mid-trace, and ``fit`` traces its
+step; the chip read it 8.9x faster than the 128 x 128 this module used to
+seed (1.21 against 10.83 ms a layer; PERF.md section 6, PR 32). The tuner is for the user who wants to
+check the rule against the device for a shape of their own: enumerate the
+feasible schedule space (the TVM line of work, PAPERS.md, 1802.04799),
+MEASURE each candidate, and cache the winner per shape key so the sweep
+runs once; a warmed cache overrides the default. The one schedule knob is
+the kernel's (block_q, block_k) tiling:
 
 - **Key**: ``(Tq, Tk, head_dim, dtype, has_bias)`` — the quantities that
   change the kernel's grid, VMEM footprint, and MXU utilization. Batch and
   head count only scale the embarrassingly-parallel grid dimension and are
   normalized out of the sweep (relative block ranking transfers).
 - **Candidates**: the largest few multiple-of-8 divisor blocks per axis
-  (``axis_blocks``), cross-producted and filtered through the kernel's own
-  ``fits_vmem_attention`` guard — every candidate is a shape the dispatcher
-  itself would accept.
+  (``axis_blocks``; the query axis up to ``flash_attention.MAX_BLOCK``, the
+  key axis up to the whole row), cross-producted and filtered through the
+  kernel's own ``fits_vmem_attention`` guard, with the default itself —
+  every candidate is a shape the dispatcher itself would accept.
 - **Measurement**: each candidate compiles the REAL train-shaped work
   (forward + custom-VJP backward through ``_flash``) and is timed to
   ``block_until_ready``; min over repeats. Sweeps only run on
@@ -26,9 +31,9 @@ its (block_q, block_k) tiling:
   way the serving engine's AOT bucket cache makes warmup a once-per-deploy
   cost (``DL4J_TPU_AUTOTUNE_CACHE=<path>`` auto-loads before the first
   lookup and auto-saves after every sweep). A key with no sweep yet is
-  seeded with the dispatcher's classic target-128 defaults and marked
+  seeded with the dispatcher's default tiling and marked
   ``source="default"`` — CPU/tier-1 runs therefore NEVER sweep (guarded by
-  a regression test) and behave exactly as before this module existed.
+  a regression test).
 
 Observability (ISSUE 7 satellite): every sweep compile goes through the
 retrace tracker as ``record_compile("flash_attention.autotune",
@@ -40,6 +45,7 @@ assertion, and every lookup outcome bumps the
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -52,7 +58,10 @@ import numpy as np
 
 from ..runtime import telemetry as _tel
 
-#: largest block the candidate enumeration will consider per axis
+#: largest block the candidate enumeration considers for the decode
+#: kernels' cache axis and the epilogue kernels' rows. The one-shot flash
+#: kernels' candidates follow the default tiling's own limits: the query
+#: axis up to ``flash_attention.MAX_BLOCK``, the key axis up to the whole row
 MAX_BLOCK = 256
 #: candidates per axis (the largest N feasible divisor blocks)
 AXIS_CANDIDATES = 4
@@ -77,7 +86,7 @@ def mode() -> str:
 
 def set_mode(m: str) -> str:
     """"auto" (cache miss on TPU with concrete operands sweeps inline),
-    "off" (never sweep — cache hits and target-128 defaults only; explicit
+    "off" (never sweep — cache hits and the default tiling only; explicit
     :func:`sweep` calls still work). Returns the previous mode."""
     if m not in ("auto", "off"):
         raise ValueError(f"autotune mode {m!r} not in ('auto', 'off')")
@@ -132,14 +141,8 @@ def axis_blocks(t: int, cap: int = MAX_BLOCK,
                 limit: int = AXIS_CANDIDATES) -> List[int]:
     """The largest ``limit`` multiple-of-8 blocks <= ``cap`` that divide
     ``t`` — the per-axis candidate set (descending)."""
-    out: List[int] = []
-    b = min(int(cap), int(t))
-    b -= b % 8
-    while b >= 8 and len(out) < limit:
-        if t % b == 0:
-            out.append(b)
-        b -= 8
-    return out
+    from . import flash_attention as _fa
+    return list(itertools.islice(_fa.divisor_blocks(t, cap), limit))
 
 
 def candidates(tq: int, tk: int, d: int, itemsize: int = 4,
@@ -154,23 +157,29 @@ def candidates(tq: int, tk: int, d: int, itemsize: int = 4,
     out = []
     # decode keys pin the query block to the whole (small) query window:
     # 1 for single-query decode, k for the speculative Tq=k verify
-    q_blocks = [int(tq)] if decode else axis_blocks(tq)
+    q_blocks = [int(tq)] if decode else axis_blocks(tq, _fa.MAX_BLOCK)
     for bq in q_blocks:
-        for bk in axis_blocks(tk):
+        for bk in axis_blocks(tk, MAX_BLOCK if decode else tk):
             if _fa.kv_block_ok(bk, tk, has_bias) and \
                     _fa.fits_vmem_attention(bq, bk, d, itemsize):
                 out.append((bq, bk))
+    default = _default_blocks(tq, tk, d, itemsize, decode, has_bias)
+    if default is not None and default not in out:
+        out.append(default)
     return out
 
 
-def _default_blocks(tq: int, tk: int, decode: bool = False,
+def _default_blocks(tq: int, tk: int, d: int, itemsize: int,
+                    decode: bool = False,
                     has_bias: bool = False) -> Optional[Tuple[int, int]]:
+    """The one-shot kernels' default is the dispatcher's own rule,
+    ``flash_attention.default_blocks``; the decode kernels keep the whole
+    query window and a 128-target cache block."""
     from . import flash_attention as _fa
-    bq = int(tq) if decode else _fa.pick_block(tq)
+    if not decode:
+        return _fa.default_blocks(tq, tk, d, itemsize, has_bias)
     bk = _fa.pick_kv_block(tk, has_bias=has_bias)
-    if bq is None or bk is None:
-        return None
-    return bq, bk
+    return None if bk is None else (int(tq), bk)
 
 
 # ---------------------------------------------------------------- cache
@@ -240,8 +249,9 @@ def get_blocks(tq, tk, d, dtype, has_bias, *, concrete: bool = False,
     """(block_q, block_k) for one attention shape key.
 
     A SWEPT cache hit returns the stored blocks. A miss (or a
-    default-seeded entry) seeds and returns the classic target-128
-    defaults — UNLESS ``concrete=True`` (the operands are real arrays,
+    default-seeded entry) seeds and returns the default tiling
+    (``flash_attention.default_blocks``; the decode kernels' 128-target
+    cache block) — UNLESS ``concrete=True`` (the operands are real arrays,
     not tracers), the mode is "auto" and the backend is TPU, in which
     case it sweeps inline and returns the winner (a default seed left by
     an earlier traced dispatch is UPGRADED, not pinned forever). Dispatch
@@ -272,7 +282,8 @@ def get_blocks(tq, tk, d, dtype, has_bias, *, concrete: bool = False,
     if can_sweep:
         e = sweep(tq, tk, d, dtype, has_bias, decode=decode, page=page)
         return tuple(e["blocks"]) if e else None
-    default = _default_blocks(tq, tk, decode, has_bias)
+    default = _default_blocks(tq, tk, d, np.dtype(dtype).itemsize, decode,
+                              has_bias)
     if default is None:
         return None
     with _lock:
@@ -445,7 +456,7 @@ def _norm_shape(shape) -> tuple:
 
 
 def seed_defaults(shapes) -> None:
-    """Pre-seed target-128 defaults for an iterable of
+    """Pre-seed the default tiling for an iterable of
     ``(Tq, Tk, head_dim, dtype, has_bias[, decode])`` keys (no sweeps —
     the CPU/CI posture; on TPU use :func:`warmup`)."""
     for shape in shapes:

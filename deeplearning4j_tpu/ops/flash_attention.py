@@ -9,15 +9,24 @@ round-trips the quadratic scores tensor through HBM in both forward and
 backward — the exact fusion the TVM line of work (PAPERS.md) says must be
 done by hand. This module is that hand fusion:
 
-- :func:`flash_attention` — the raw fused op. Online-softmax forward over a
-  (batch*heads, q-blocks, kv-blocks) grid with f32 running max/sum
-  accumulators in VMEM scratch; kv is the innermost ("arbitrary") grid
-  dimension so the scores tile never leaves VMEM. A custom VJP recomputes
-  p = exp(s - m)/l per tile in the backward (two kernels: dq, and dk/dv),
-  saving only the per-row logsumexp — carried as its two pieces (running
-  max m, running sum l) so a finfo.min mask bias can't absorb log(l) —
-  plus the output, for di = sum(o*do). Training steps benefit, not just
-  serving.
+- :func:`flash_attention` — the raw fused op, in one of two tilings that
+  :func:`default_blocks` chooses from the shape (the largest tile that
+  fits VMEM; PERF.md section 6, PR 32, has the chip's readings):
+
+  * **whole row** (a query block's whole key row in one tile; up to 4,096
+    keys of d = 64 in bf16): a plain softmax in the forward, and ONE
+    backward kernel that works the softmax out again from the tile, forms
+    p and ds once and writes dk, dv and dq. Nothing is saved for it but the
+    operands: no output, no statistics. Short rows take several heads a
+    grid step (:func:`heads_per_step`).
+  * **blocked** (a longer row): online-softmax forward over a
+    (batch*heads, q-blocks, kv-blocks) grid with f32 running max/sum
+    accumulators in VMEM scratch; kv is the innermost ("arbitrary") grid
+    dimension so the scores tile never leaves VMEM. The backward
+    recomputes p = exp(s - m)/l per tile (two kernels: dq, and dk/dv),
+    saving only the per-row logsumexp — carried as its two pieces (running
+    max m, running sum l) so a finfo.min mask bias can't absorb log(l) —
+    plus the output, for di = sum(o*do).
 - :func:`reference_attention` — the quadratic einsum path, scores upcast to
   f32 before softmax (matching the kernel's f32 accumulators; this is also
   the numerics fix for the layers' bf16 dtype policy).
@@ -60,7 +69,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import register
 from ..environment import precision_for
-from .pallas_kernels import (_VMEM_BUDGET, available as _tpu_available,
+from .pallas_kernels import (available as _tpu_available,
                              partitioned as _partitioned)
 
 _LANES = 128          # TPU lane count: running max/sum ride replicated lanes
@@ -218,6 +227,84 @@ def _bwd_dkv_kernel(*refs, scale, nq, has_bias):
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+def _fwd_row_kernel(*refs, scale, has_bias):
+    """Forward where one tile holds a query block's whole key row (nk = 1):
+    a plain softmax, so no running max/sum, no ``alpha`` rescale, no
+    init/finish branches and no statistics to save (the backward has the
+    whole row too and works the softmax out again). Blocks carry ``hb``
+    heads of one batch row; the loop over them is unrolled."""
+    refs = list(refs)
+    bias = refs.pop(3)[0].astype(jnp.float32) if has_bias else None
+    q_ref, k_ref, v_ref, o_ref = refs
+    for h in range(q_ref.shape[0]):
+        s = jax.lax.dot_general(
+            q_ref[h], k_ref[h], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale     # [bq, Tk] f32
+        if bias is not None:
+            s = s + bias                                    # [1, Tk] rows
+        e = jnp.exp(s - jnp.max(s, axis=1, keepdims=True))
+        l = jnp.sum(e, axis=1, keepdims=True)               # >= 1: the max
+        acc = jax.lax.dot(e.astype(v_ref.dtype), v_ref[h],
+                          preferred_element_type=jnp.float32)
+        o_ref[h] = (acc / l).astype(o_ref.dtype)
+
+
+def _bwd_row_kernel(*refs, scale, nq, has_bias):
+    """dk, dv and dq of a whole-key-row tile in ONE kernel: p and ds are
+    formed once. The tile is held TRANSPOSED, ``[Tk, bq]``, so the softmax
+    runs down the sublanes with per-query ``[1, bq]`` rows (nothing
+    lane-replicated), dv = p^T do and dk = ds^T q are plain products and only
+    dq contracts the tile's first axis. ``di = sum_k p dp`` (equal to
+    ``sum(o * do)``, in f32 from the tile itself), so neither ``o`` nor any
+    saved statistic is read. With nq > 1 the q-blocks are the inner,
+    sequential grid axis and dk/dv accumulate in f32 scratch."""
+    refs = list(refs)
+    bias = refs.pop(3)[0].astype(jnp.float32) if has_bias else None
+    q_ref, k_ref, v_ref, do_ref, dk_ref, dv_ref, dq_ref, *scratch = refs
+    if nq > 1:
+        dk_scr, dv_scr = scratch
+        i = pl.program_id(1)
+
+        @pl.when(i == 0)
+        def _init():
+            dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
+            dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    for h in range(q_ref.shape[0]):
+        q, k, do = q_ref[h], k_ref[h], do_ref[h]
+        s = jax.lax.dot_general(                            # k @ q^T
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale     # [Tk, bq] f32
+        if bias is not None:
+            s = s + bias                                    # [Tk, 1] column
+        e = jnp.exp(s - jnp.max(s, axis=0, keepdims=True))
+        p = e * (1.0 / jnp.sum(e, axis=0, keepdims=True))
+        dp = jax.lax.dot_general(                           # v @ do^T
+            v_ref[h], do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        di = jnp.sum(p * dp, axis=0, keepdims=True)         # [1, bq]
+        ds = p * (dp - di) * scale
+        dv = jax.lax.dot(p.astype(do.dtype), do,
+                         preferred_element_type=jnp.float32)
+        dk = jax.lax.dot(ds.astype(q.dtype), q,
+                         preferred_element_type=jnp.float32)
+        dq_ref[h] = jax.lax.dot_general(                    # ds^T @ k
+            ds.astype(k.dtype), k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(dq_ref.dtype)
+        if nq > 1:
+            dk_scr[h] += dk
+            dv_scr[h] += dv
+        else:
+            dk_ref[h] = dk.astype(dk_ref.dtype)
+            dv_ref[h] = dv.astype(dv_ref.dtype)
+
+    if nq > 1:
+        @pl.when(i == nq - 1)
+        def _finish():
+            dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+            dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+
 def _mq_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                       m_scr, l_scr, acc_scr, *, scale, nk, bk, heads):
     """Multi-query decode forward (speculative verify, ISSUE 12): the
@@ -273,9 +360,17 @@ def _load_pallas():
     return pl, pltpu
 
 
-def _compiler_params(pltpu):
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+def _compiler_params(pltpu, semantics=("parallel", "parallel", "arbitrary"),
+                     vmem_bytes: int = 0):
+    """``vmem_bytes``: what :func:`vmem_bytes_attention` counts for the
+    tiling. One that passes three quarters of Mosaic's default scoped VMEM
+    asks the compiler for its bytes and half again, rather than the tiling
+    staying under a guess."""
+    limit = None
+    if vmem_bytes > _SCOPED_VMEM * 3 // 4:
+        limit = vmem_bytes * 3 // 2
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=limit)
 
 
 # --------------------------------------------------------------------------
@@ -313,7 +408,8 @@ def _fwd_impl(q3, k3, v3, kb, scale, heads, bq, bk, interpret):
         scratch_shapes=[pltpu.VMEM((bq, _LANES), jnp.float32),
                         pltpu.VMEM((bq, _LANES), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_compiler_params(pltpu),
+        compiler_params=_compiler_params(pltpu, vmem_bytes=vmem_bytes_attention(
+            bq, bk, d, q3.dtype.itemsize)),
         interpret=interpret,
         name="flash_fwd",
     )(*args)
@@ -342,6 +438,8 @@ def _bwd_impl(q3, k3, v3, kb, m, l, di, do, scale, heads, bq, bk, interpret):
         pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),       # do
     ]
     args = [q3, k3, v3] + ([kb] if has_bias else []) + [m, l, di, do]
+    params = _compiler_params(pltpu, vmem_bytes=vmem_bytes_attention(
+        bq, bk, d, q3.dtype.itemsize))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, nk=nk,
@@ -351,7 +449,7 @@ def _bwd_impl(q3, k3, v3, kb, m, l, di, do, scale, heads, bq, bk, interpret):
         out_shape=jax.ShapeDtypeStruct((G, Tq, d), q3.dtype),
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_compiler_params(pltpu),
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dq",
     )(*args)
@@ -382,10 +480,80 @@ def _bwd_impl(q3, k3, v3, kb, m, l, di, do, scale, heads, bq, bk, interpret):
                    pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0))),
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_compiler_params(pltpu),
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dkv",
     )(*args)
+    return dq, dk, dv
+
+
+def _fwd_row_impl(q3, k3, v3, kb, scale, heads, bq, interpret):
+    """grid = (B*H / hb, q-blocks): each step holds ``hb`` heads of one batch
+    row (:func:`heads_per_step`), a query block of them and their whole key
+    rows."""
+    pl, pltpu = _load_pallas()
+    G, Tq, d = q3.shape
+    Tk = k3.shape[1]
+    hb = heads_per_step(heads, bq, Tk, d, q3.dtype.itemsize)
+    has_bias = kb is not None
+    in_specs = [
+        pl.BlockSpec((hb, bq, d), lambda g, i: (g, i, 0)),
+        pl.BlockSpec((hb, Tk, d), lambda g, i: (g, 0, 0)),
+        pl.BlockSpec((hb, Tk, d), lambda g, i: (g, 0, 0)),
+    ]
+    args = [q3, k3, v3]
+    if has_bias:
+        in_specs.append(
+            pl.BlockSpec((1, 1, Tk), lambda g, i: (g * hb // heads, 0, 0)))
+        args.append(kb)
+    return pl.pallas_call(
+        functools.partial(_fwd_row_kernel, scale=scale, has_bias=has_bias),
+        grid=(G // hb, Tq // bq),
+        in_specs=in_specs,
+        out_shape=jax.ShapeDtypeStruct((G, Tq, d), q3.dtype),
+        out_specs=pl.BlockSpec((hb, bq, d), lambda g, i: (g, i, 0)),
+        compiler_params=_compiler_params(
+            pltpu, ("parallel", "parallel"),
+            vmem_bytes_attention(bq, Tk, d, q3.dtype.itemsize, hb)),
+        interpret=interpret,
+        name="flash_fwd",
+    )(*args)
+
+
+def _bwd_row_impl(q3, k3, v3, kb, do, scale, heads, bq, interpret):
+    """The fused whole-row backward; ``flash_bwd_dkv`` with dk as its first
+    result, which is how the benchmark's readers know the dk/dv kernel."""
+    pl, pltpu = _load_pallas()
+    G, Tq, d = q3.shape
+    Tk = k3.shape[1]
+    nq = Tq // bq
+    hb = heads_per_step(heads, bq, Tk, d, q3.dtype.itemsize)
+    has_bias = kb is not None
+    by_q = pl.BlockSpec((hb, bq, d), lambda g, i: (g, i, 0))
+    by_k = pl.BlockSpec((hb, Tk, d), lambda g, i: (g, 0, 0))
+    in_specs, args = [by_q, by_k, by_k], [q3, k3, v3]
+    if has_bias:
+        # the tile is [Tk, bq]: the key bias rides as a column
+        in_specs.append(
+            pl.BlockSpec((1, Tk, 1), lambda g, i: (g * hb // heads, 0, 0)))
+        args.append(kb.reshape(kb.shape[0], Tk, 1))
+    dk, dv, dq = pl.pallas_call(
+        functools.partial(_bwd_row_kernel, scale=scale, nq=nq,
+                          has_bias=has_bias),
+        grid=(G // hb, nq),
+        in_specs=in_specs + [by_q],
+        out_shape=(jax.ShapeDtypeStruct((G, Tk, d), k3.dtype),
+                   jax.ShapeDtypeStruct((G, Tk, d), v3.dtype),
+                   jax.ShapeDtypeStruct((G, Tq, d), q3.dtype)),
+        out_specs=(by_k, by_k, by_q),
+        scratch_shapes=[pltpu.VMEM((hb, Tk, d), jnp.float32)] * 2
+        if nq > 1 else [],
+        compiler_params=_compiler_params(
+            pltpu, ("parallel", "arbitrary"),
+            vmem_bytes_attention(bq, Tk, d, q3.dtype.itemsize, hb)),
+        interpret=interpret,
+        name="flash_bwd_dkv",
+    )(*args, do)
     return dq, dk, dv
 
 
@@ -424,22 +592,32 @@ def _mq_impl(q3, k3, v3, lens, scale, heads, bk, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash(q3, k3, v3, kb, scale, heads, bq, bk, interpret):
-    o, _, _ = _fwd_impl(q3, k3, v3, kb, scale, heads, bq, bk, interpret)
-    return o
+    return _flash_fwd(q3, k3, v3, kb, scale, heads, bq, bk, interpret)[0]
 
 
 def _flash_fwd(q3, k3, v3, kb, scale, heads, bq, bk, interpret):
+    """``bk`` the whole key row takes the whole-row kernels, which save
+    nothing but their operands; a blocked grid saves o and the two pieces of
+    the logsumexp for its two backward kernels."""
+    if bk == k3.shape[1]:
+        o = _fwd_row_impl(q3, k3, v3, kb, scale, heads, bq, interpret)
+        return o, (q3, k3, v3, kb)
     o, m, l = _fwd_impl(q3, k3, v3, kb, scale, heads, bq, bk, interpret)
     return o, (q3, k3, v3, kb, o, m, l)
 
 
 def _flash_bwd(scale, heads, bq, bk, interpret, res, do):
-    q3, k3, v3, kb, o, m, l = res
-    di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
-                 axis=-1, keepdims=True)
-    di = jnp.broadcast_to(di, m.shape)  # lane-replicated like m/l
-    dq, dk, dv = _bwd_impl(q3, k3, v3, kb, m, l, di, do,
-                           scale, heads, bq, bk, interpret)
+    q3, k3, v3, kb = res[:4]
+    if bk == k3.shape[1]:
+        dq, dk, dv = _bwd_row_impl(q3, k3, v3, kb, do, scale, heads, bq,
+                                   interpret)
+    else:
+        o, m, l = res[4:]
+        di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
+                     axis=-1, keepdims=True)
+        di = jnp.broadcast_to(di, m.shape)  # lane-replicated like m/l
+        dq, dk, dv = _bwd_impl(q3, k3, v3, kb, m, l, di, do,
+                               scale, heads, bq, bk, interpret)
     # bias is mask-derived here: zero cotangent (recorded divergence)
     dkb = None if kb is None else jnp.zeros_like(kb)
     return dq, dk, dv, dkb
@@ -452,6 +630,16 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # public fused op
 # --------------------------------------------------------------------------
 
+def divisor_blocks(t: int, cap: int):
+    """Multiple-of-8 blocks <= ``cap`` that divide ``t``, largest first."""
+    b = min(int(cap), int(t))
+    b -= b % 8
+    while b >= 8:
+        if t % b == 0:
+            yield b
+        b -= 8
+
+
 def pick_block(t: int, target: int = 128) -> Optional[int]:
     """Largest block <= target that divides ``t`` and is a multiple of 8
     (layout-friendly sublanes); None when nothing tiles.
@@ -459,13 +647,7 @@ def pick_block(t: int, target: int = 128) -> Optional[int]:
     r12: any multiple-of-8 divisor qualifies, not only power-of-two tiles —
     odd sequence lengths like 24, 120 or 384 now tile (fewer dispatcher
     ``fallback_shape`` exits) instead of demanding a power-of-two factor."""
-    b = min(int(target), int(t))
-    b -= b % 8
-    while b >= 8:
-        if t % b == 0:
-            return b
-        b -= 8
-    return None
+    return next(divisor_blocks(t, target), None)
 
 
 def kv_block_ok(bk: int, tk: int, has_bias: bool) -> bool:
@@ -489,24 +671,85 @@ def pick_kv_block(tk: int, target: int = 128,
     return None
 
 
+#: the largest query block: the default tiling and the autotuner's candidates
+#: stop here on the query axis (1024 rows read 3% faster than 512 at
+#: [192, 1024, 64] and hold twice the VMEM; PERF.md section 6, PR 32). The
+#: key axis has no cap but the VMEM guard.
+MAX_BLOCK = 512
+_SCOPED_VMEM = 16 * 2 ** 20      # Mosaic's default scoped VMEM
+#: what a tiling may count in :func:`vmem_bytes_attention`. The compiler is
+#: asked for half again (``_compiler_params``): 48 MiB, under the 64 MiB of
+#: the smallest current core (v5e and v6e have 128 MiB).
+_VMEM_TILE_BUDGET = 32 * 2 ** 20
+
+
+def heads_per_step(heads: int, bq: int, bk: int, d: int,
+                   itemsize: int = 4) -> int:
+    """Heads a whole-row grid step carries: the most (dividing ``heads``, so
+    a step stays inside one batch row and one key-bias row) whose score
+    tiles together stay within one ``MAX_BLOCK`` square and whose blocks
+    fit. A short row is a small tile, and a grid step costs about 0.35 us
+    whatever is in it: at [1536, 128, 64] twelve heads a step take the
+    forward from 0.65 to 0.19 ms (PERF.md section 6, PR 32)."""
+    room = MAX_BLOCK * MAX_BLOCK // (bq * bk)
+    return max([1] + [h for h in range(2, min(heads, room) + 1)
+                      if heads % h == 0 and vmem_bytes_attention(
+                          bq, bk, d, itemsize, h) <= _VMEM_TILE_BUDGET])
+
+
+def vmem_bytes_attention(bq: int, bk: int, d: int, itemsize: int = 4,
+                         hb: int = 1) -> int:
+    """VMEM a (bq, bk) tiling holds in the worst of its kernels, the
+    backward: the blocks the pipeline fetches and writes back (q, do, k, v
+    in; dq, dk, dv out; the m/l/di rows of a blocked grid or the key bias
+    as a column of a whole-row tile), which are double-buffered; and, once,
+    the f32 dk/dv scratch and two f32 score-sized temporaries. Mosaic
+    streams the elementwise chains between the products and keeps about one
+    and a half such tiles (the compiler's own count for a 2048 x 2048 bf16
+    whole-row backward is 31.25 MiB, this one's 42.5)."""
+    fetched = (2 * (bq + bk) + bq + 2 * bk) * hb * d * itemsize
+    rows = max(3 * bq, bk) * _LANES * 4
+    scratch = 2 * hb * bk * d * 4
+    tiles = 2 * hb * bq * bk * 4
+    return 2 * (fetched + rows) + scratch + tiles
+
+
 def fits_vmem_attention(bq: int, bk: int, d: int, itemsize: int = 4) -> bool:
-    """Per-grid-cell VMEM estimate over the WORST of the three kernels —
-    dispatching commits the backward too, and the dkv kernel holds the
-    largest set (q/k/v/do blocks, four f32 score-sized tiles, dk/dv
-    scratch AND outputs). x2 for pipelining double-buffers."""
-    bias = 8 * bk * 4           # (1, 1, bk) f32 key bias, 8-sublane padded
-    fwd = ((bq * d + 2 * bk * d) * itemsize           # q, k, v blocks
-           + 2 * bq * bk * 4                          # scores + p (f32)
-           + (2 * bq * _LANES + bq * d) * 4           # m/l/acc scratch
-           + (bq * d + 2 * bq * _LANES) * 4           # o + m/l out blocks
-           + bias)
-    dkv = ((2 * bq * d + 2 * bk * d) * itemsize       # q, do, k, v blocks
-           + 4 * bq * bk * 4                          # s/p/dp/ds (f32)
-           + 3 * bq * _LANES * 4                      # m/l/di row blocks
-           + 2 * bk * d * 4                           # dk/dv scratch
-           + 2 * bk * d * itemsize                    # dk/dv out blocks
-           + bias)
-    return 2 * max(fwd, dkv) < _VMEM_BUDGET
+    """The one guard the dispatcher, the default tiling, the autotuner's
+    candidates and the kernels' wrappers share."""
+    return vmem_bytes_attention(bq, bk, d, itemsize) <= _VMEM_TILE_BUDGET
+
+
+def _tilings(tq: int, tk: int, has_bias: bool):
+    """The (block_q, block_k) pairs that tile, in the default's order of
+    preference: the largest query block up to ``MAX_BLOCK`` first, and under
+    it the most keys."""
+    for bq in divisor_blocks(tq, MAX_BLOCK):
+        for bk in divisor_blocks(tk, tk):
+            if kv_block_ok(bk, tk, has_bias):
+                yield bq, bk
+
+
+def default_blocks(tq: int, tk: int, d: int, itemsize: int = 4,
+                   has_bias: bool = False):
+    """(block_q, block_k) a shape gets when nobody tuned it, which is what
+    every traced program gets: the largest tile that fits. The query block
+    comes first, up to ``MAX_BLOCK`` rows (a backward's dk/dv pass over
+    their f32 accumulators once a query block, so a short one costs: 128
+    rows under 4,096 keys read 1.7x slower than 512), then the most keys
+    that fit beside it. Where that is the whole key row (4,096 keys of
+    d = 64 in bf16 under 512 queries) the whole-row kernels run: no
+    online-softmax carry, one fused backward, nothing saved but the
+    operands. A longer row keeps a blocked grid, and 128 is what the rule
+    yields only where nothing larger divides the length. None when nothing
+    tiles or fits."""
+    return next((t for t in _tilings(tq, tk, has_bias)
+                 if fits_vmem_attention(*t, d, itemsize)), None)
+
+
+def tiling_kind(bk: int, tk: int) -> str:
+    """``whole_row`` (nk = 1: the whole-row kernels) or ``blocked``."""
+    return "whole_row" if bk == tk else "blocked"
 
 
 def _key_bias(bias, batch, tk):
@@ -536,11 +779,13 @@ def flash_attention(q, k, v, bias=None, scale: Optional[float] = None, *,
     go through :func:`attention` for guarded dispatch.
 
     ``block_q``/``block_k``: explicit TARGET tile sizes (the largest
-    divisor block <= target is used, the pre-r12 contract). The default
-    ``None`` consults the block-shape autotuner (``ops/autotune.py``):
-    swept blocks when the cache is warm for this (Tq, Tk, d, dtype, bias)
-    key, else the classic 128-target defaults (seeded, never swept, when
-    the operands are tracers or the backend is not TPU).
+    divisor block <= target is used, the pre-r12 contract; 128 for the one
+    left out). The default ``None`` takes :func:`default_blocks`, the
+    largest tile that fits: up to 512 query rows under their whole key row
+    (4,096 keys of d = 64 in bf16), a blocked grid beyond. A traced
+    program (every ``fit``) always gets it, because a
+    sweep cannot run mid-trace; a block-shape cache warmed beforehand
+    (``ops/autotune.py``) overrides it for its (Tq, Tk, d, dtype, bias) key.
     """
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError(f"flash_attention wants [B,H,T,d]; got {q.shape}")
@@ -549,6 +794,7 @@ def flash_attention(q, k, v, bias=None, scale: Optional[float] = None, *,
     if k.shape != (B, H, Tk, d) or v.shape != (B, H, Tk, d):
         raise ValueError(f"q/k/v shapes disagree: {q.shape} {k.shape} "
                          f"{v.shape}")
+    itemsize = np.dtype(q.dtype).itemsize
     if block_q is None and block_k is None:
         from . import autotune as _autotune
         tuned = _autotune.get_blocks(
@@ -557,18 +803,18 @@ def flash_attention(q, k, v, bias=None, scale: Optional[float] = None, *,
         bq, bk = tuned if tuned is not None else (None, None)
         # belt over the autotuner's own validation: blocks that do not
         # tile would silently truncate the grid (Tq // bq); a poisoned
-        # entry falls back to the target-128 defaults, never garbage
+        # entry falls back to the default tiling, never garbage
         if bq is not None and (Tq % bq or Tk % bk or not kv_block_ok(
                 bk, Tk, bias is not None)):
-            bq, bk = pick_block(Tq), pick_kv_block(
-                Tk, has_bias=bias is not None)
+            bq, bk = default_blocks(Tq, Tk, d, itemsize,
+                                    bias is not None) or (None, None)
     else:
         bq = pick_block(Tq, block_q or 128)
         bk = pick_kv_block(Tk, block_k or 128, bias is not None)
     if bq is None or bk is None:
         raise ValueError(f"sequence lengths ({Tq}, {Tk}) do not tile into "
                          f"({block_q or 128}, {block_k or 128}) blocks")
-    if not fits_vmem_attention(bq, bk, d, np.dtype(q.dtype).itemsize):
+    if not fits_vmem_attention(bq, bk, d, itemsize):
         raise ValueError(f"attention tiles exceed the VMEM budget "
                          f"(bq={bq}, bk={bk}, d={d})")
     if scale is None:
@@ -577,6 +823,7 @@ def flash_attention(q, k, v, bias=None, scale: Optional[float] = None, *,
     if bias is not None and kb is None:
         raise ValueError(f"bias shape {bias.shape} is not key-reducible "
                          "([B,1,1,Tk]); use attention() for fallback")
+    _TILING.inc(kind=tiling_kind(bk, Tk))
     o = _flash(q.reshape(B * H, Tq, d), k.reshape(B * H, Tk, d),
                v.reshape(B * H, Tk, d), kb, float(scale), H, bq, bk,
                bool(interpret))
@@ -876,6 +1123,11 @@ from ..runtime import telemetry as _tel  # noqa: E402  (stdlib-only import)
 _DISPATCH = _tel.counter(
     "flash_attention.dispatch",
     "attention dispatch decisions at trace time (fused vs fallback_*)")
+#: which tiling a traced one-shot site took (:func:`tiling_kind`), one count
+#: a site like the dispatch decisions
+_TILING = _tel.counter(
+    "flash_attention.tiling",
+    "tiling of a traced flash-attention site (whole_row vs blocked)")
 _state = {"mode": os.environ.get("DL4J_TPU_FLASH_ATTENTION", "auto")}
 _FUSABLE_DTYPES = (jnp.float32, jnp.bfloat16, jnp.float16)
 
@@ -936,13 +1188,11 @@ def _route(q, k, v, bias) -> Optional[str]:
         return "fallback_dtype"
     if bias is not None and _key_bias(bias, q.shape[0], k.shape[2]) is None:
         return "fallback_bias"
-    bq = pick_block(q.shape[2])
-    bk = pick_kv_block(k.shape[2], has_bias=bias is not None)
-    if bq is None or bk is None:
-        return "fallback_shape"
-    if not fits_vmem_attention(bq, bk, q.shape[-1],
-                               np.dtype(q.dtype).itemsize):
-        return "fallback_vmem"
+    tq, tk, has_bias = q.shape[2], k.shape[2], bias is not None
+    if default_blocks(tq, tk, q.shape[-1], np.dtype(q.dtype).itemsize,
+                      has_bias) is None:
+        tiles = next(_tilings(tq, tk, has_bias), None) is not None
+        return "fallback_vmem" if tiles else "fallback_shape"
     return None
 
 

@@ -103,6 +103,40 @@ def test_candidate_enumeration_properties():
     assert len(at.axis_blocks(2048)) <= at.AXIS_CANDIDATES
 
 
+@pytest.mark.parametrize("key", [
+    (512, 512, 64, 2, True), (128, 128, 64, 2, True), (384, 384, 64, 4, False),
+    (2048, 2048, 64, 2, True), (8192, 8192, 128, 2, False),
+    (640, 640, 64, 2, True), (1200, 1200, 64, 4, False)],
+    ids=lambda k: f"{k[0]}d{k[2]}b{k[3]}{'bias' if k[4] else ''}")
+def test_candidates_and_seed_read_the_dispatchers_rule(key, clean_autotune):
+    """The one-shot kernels' candidates follow the default tiling's own
+    limits (query blocks up to ``flash_attention.MAX_BLOCK``, key blocks up
+    to the whole row, so a sweep can try what the default takes), hold the
+    default itself, and every one passes the shared VMEM guard;
+    the seed of an unswept key is ``flash_attention.default_blocks``. The
+    decode kernels keep their 128-target block and their own cap."""
+    tq, tk, d, itemsize, has_bias = key
+    dtype = {2: "bfloat16", 4: "float32"}[itemsize]
+    default = fa.default_blocks(*key)
+    cands = at.candidates(tq, tk, d, itemsize, has_bias=has_bias)
+    assert default in cands
+    assert max(bq for bq, _ in cands) == default[0] <= fa.MAX_BLOCK
+    assert max(bk for _, bk in cands) >= default[1]
+    for bq, bk in cands:
+        assert tq % bq == 0 and tk % bk == 0
+        assert fa.kv_block_ok(bk, tk, has_bias)
+        assert fa.fits_vmem_attention(bq, bk, d, itemsize)
+    assert at._default_blocks(tq, tk, d, itemsize, False, has_bias) == default
+    assert at.get_blocks(tq, tk, d, dtype, has_bias) == default
+    assert at.lookup(tq, tk, d, dtype, has_bias)["source"] == "default"
+    # decode keys: as before this rule existed
+    bk = fa.pick_kv_block(tk, has_bias=True)
+    assert at._default_blocks(1, tk, d, itemsize, True, True) \
+        == (None if bk is None else (1, bk))
+    assert all(c[1] <= at.MAX_BLOCK for c in at.candidates(
+        1, tk, d, itemsize, decode=True, has_bias=True))
+
+
 @pytest.mark.parametrize("has_bias", [False, True])
 def test_every_candidate_block_shape_parity(rng, has_bias):
     """Interpret-mode numerical parity for EVERY candidate block shape the

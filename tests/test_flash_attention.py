@@ -96,6 +96,210 @@ def test_flash_gradient_parity_bf16(rng):
                                np.asarray(gr, np.float32), atol=5e-2)
 
 
+def _parity(q, k, v, bias, tol, **blocks):
+    """Forward and all three gradients of the kernel path against the
+    reference, through a loss that keeps the forward alive."""
+    def loss(path, q, k, v):
+        return jnp.sum(jnp.sin(path(q, k, v).astype(jnp.float32)))
+
+    fused = lambda q, k, v: fa.flash_attention(q, k, v, bias, interpret=True,
+                                               **blocks)
+    ref = lambda q, k, v: fa.reference_attention(q, k, v, bias)
+    f32 = lambda x: np.asarray(x, np.float32)
+    np.testing.assert_allclose(f32(fused(q, k, v)), f32(ref(q, k, v)),
+                               atol=tol)
+    gf = jax.grad(lambda *a: loss(fused, *a), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: loss(ref, *a), argnums=(0, 1, 2))(q, k, v)
+    for got, want in zip(gf, gr):
+        np.testing.assert_allclose(f32(got), f32(want), atol=4 * tol)
+
+
+@pytest.mark.parametrize("has_bias", [False, True], ids=["nobias", "keybias"])
+@pytest.mark.parametrize("dtype,tol", [(np.float32, 1e-5),
+                                       ("bfloat16", 2e-2)])
+def test_whole_row_tile_parity(rng, dtype, tol, has_bias):
+    """The default tiling of a short row is one tile a head (nk = 1): the
+    whole-row forward and the fused backward, which keeps no statistics and
+    works the softmax out again from the tile. A fully masked batch row
+    (every key at finfo.min) must still read UNIFORM attention in both
+    directions, the contract the blocked kernels keep with their two-piece
+    logsumexp. Tq != Tk, four heads a grid step."""
+    q, k, v = _qkv(rng, H=4, Tq=64, Tk=128, d=32, dtype=dtype)
+    assert fa.default_blocks(64, 128, 32, q.dtype.itemsize, has_bias) \
+        == (64, 128)
+    assert fa.heads_per_step(4, 64, 128, 32) == 4
+    bias = _ragged_bias(rng, 2, 128) if has_bias else None
+    _parity(q, k, v, bias, tol)
+
+
+def test_whole_row_tile_over_query_blocks(rng):
+    """A whole key row under several query blocks (explicit block_q): dk
+    and dv accumulate over the inner grid axis in f32 scratch."""
+    q, k, v = _qkv(rng, Tq=128, Tk=64, d=16)
+    _parity(q, k, v, _ragged_bias(rng, 2, 64), 1e-5, block_q=32, block_k=64)
+
+
+@pytest.mark.parametrize("has_bias", [False, True], ids=["nobias", "keybias"])
+def test_long_row_keeps_the_blocked_grid(rng, has_bias, monkeypatch):
+    """A key row that does not fit beside its query block keeps a blocked
+    grid: the online-softmax forward and the dq and dk/dv kernels, with the
+    logsumexp saved in its two pieces (a fully masked row included). On the
+    chip that is past 4,096 keys; here the budget is cut so that 512 keys
+    are too many."""
+    T = 512
+    monkeypatch.setattr(fa, "_VMEM_TILE_BUDGET", 3 << 19)
+    blocks = fa.default_blocks(T, T, 8, 4, has_bias)
+    assert blocks == (256, 256) and fa.tiling_kind(blocks[1], T) == "blocked"
+    q, k, v = _qkv(rng, B=2, H=1, Tq=T, Tk=T, d=8)
+    fa._TILING.zero()
+    _parity(q, k, v, _ragged_bias(rng, 2, T) if has_bias else None, 1e-5)
+    assert fa._TILING.value(kind="blocked") > 0
+    assert fa._TILING.value(kind="whole_row") == 0
+
+
+# (Tq, Tk, d, itemsize, has_bias) -> (block_q, block_k), kind
+_TILING_TABLE = [
+    ((512, 512, 64, 2, True), (512, 512), "whole_row"),   # BERT-base s512
+    ((128, 128, 64, 2, True), (128, 128), "whole_row"),   # ... s128
+    ((128, 128, 64, 4, False), (128, 128), "whole_row"),
+    ((384, 384, 64, 2, True), (384, 384), "whole_row"),
+    ((1024, 1024, 64, 4, True), (512, 1024), "whole_row"),
+    ((2048, 2048, 64, 2, True), (512, 2048), "whole_row"),
+    ((2048, 2048, 128, 4, True), (512, 2048), "whole_row"),
+    ((4096, 4096, 64, 2, False), (512, 4096), "whole_row"),
+    ((8192, 8192, 64, 2, True), (512, 4096), "blocked"),
+    ((8192, 8192, 128, 2, False), (512, 2048), "blocked"),
+    ((128, 16384, 64, 2, True), (128, 8192), "blocked"),
+    ((120, 120, 16, 4, False), (120, 120), "whole_row"),
+    ((1200, 1200, 64, 2, False), (400, 1200), "whole_row"),  # 1200 = 3 x 400
+    ((640, 640, 64, 2, True), (320, 640), "whole_row"),
+    ((100, 100, 16, 4, False), None, None),               # nothing tiles
+    ((192, 192, 16, 4, True), (192, 192), "whole_row"),
+]
+
+
+@pytest.mark.parametrize("key,blocks,kind", _TILING_TABLE,
+                         ids=[f"{k[0]}x{k[1]}d{k[2]}b{k[3]}"
+                              f"{'bias' if k[4] else ''}"
+                              for k, _, _ in _TILING_TABLE])
+def test_default_tiling_table(key, blocks, kind, force_mode):
+    """One function decides the default tile. What it picks tiles, passes
+    the VMEM guard, is what the autotuner seeds, is what the dispatcher
+    admitted and what ``flash_attention()`` then takes (traced, so no
+    sweep could run), and the site's tiling is counted."""
+    from deeplearning4j_tpu.ops import autotune as at
+    tq, tk, d, itemsize, has_bias = key
+    dtype = {2: jnp.bfloat16, 4: jnp.float32}[itemsize]
+    assert fa.default_blocks(*key) == blocks
+    q = jax.ShapeDtypeStruct((2, 4, tq, d), dtype)
+    kv = jax.ShapeDtypeStruct((2, 4, tk, d), dtype)
+    bias = jax.ShapeDtypeStruct((2, 1, 1, tk), jnp.float32) \
+        if has_bias else None
+    at.reset()
+    fa._TILING.zero()
+    try:
+        jax.eval_shape(lambda *a: fa.attention(*a), q, kv, kv, bias)
+    finally:
+        seeded = at.lookup(tq, tk, d, dtype, has_bias)
+        at.reset()
+    c = fa.counters()
+    if blocks is None:
+        assert c["fallback_shape"] == 1 and c["fused"] == 0
+        assert seeded is None
+        return
+    bq, bk = blocks
+    assert tq % bq == 0 and tk % bk == 0 and bq % 8 == 0
+    assert bq <= fa.MAX_BLOCK
+    assert fa.kv_block_ok(bk, tk, has_bias)
+    assert fa.fits_vmem_attention(bq, bk, d, itemsize)
+    assert c["fused"] == 1
+    assert seeded == {"blocks": [bq, bk], "source": "default"}
+    assert fa.tiling_kind(bk, tk) == kind
+    other = "blocked" if kind == "whole_row" else "whole_row"
+    assert fa._TILING.value(kind=kind) == 1
+    assert fa._TILING.value(kind=other) == 0
+
+
+def test_dispatcher_never_admits_a_tile_the_kernel_refuses(force_mode,
+                                                           monkeypatch):
+    """``_route`` and ``flash_attention()`` read the same rule: over a
+    grid of lengths, head sizes, dtypes and biases, whatever the dispatcher
+    sends to the kernel the kernel takes (no ValueError), and where the rule
+    finds no tile the decision is a counted fallback. The same with a VMEM
+    budget so small that large tiles are refused: the rule then yields a
+    smaller tile, or the dispatcher a ``fallback_vmem``."""
+    from deeplearning4j_tpu.ops import autotune as at
+
+    def sweep():
+        fused = fell = 0
+        for tq, tk in [(8, 8), (64, 200), (96, 96), (100, 128), (256, 512),
+                       (384, 384), (512, 640), (1000, 1000), (1024, 4096)]:
+            for d in (16, 64, 256):
+                for dtype in (jnp.float32, jnp.bfloat16):
+                    for has_bias in (False, True):
+                        q = jax.ShapeDtypeStruct((1, 2, tq, d), dtype)
+                        kv = jax.ShapeDtypeStruct((1, 2, tk, d), dtype)
+                        b = jnp.zeros((1, 1, 1, tk)) if has_bias else None
+                        at.reset()
+                        route = fa._route(q, kv, kv, b)
+                        rule = fa.default_blocks(
+                            tq, tk, d, np.dtype(dtype).itemsize, has_bias)
+                        assert (route is None) == (rule is not None)
+                        # a fresh function: eval_shape keeps its traces
+                        kernel = lambda *a: fa.flash_attention(*a)
+                        if route is None:
+                            jax.eval_shape(kernel, q, kv, kv, b)
+                            fused += 1
+                        else:
+                            assert route in ("fallback_shape",
+                                             "fallback_vmem")
+                            with pytest.raises(ValueError):
+                                jax.eval_shape(kernel, q, kv, kv, b)
+                            fell += 1
+        at.reset()
+        return fused, fell
+
+    fused, fell = sweep()
+    assert fused >= 80 and fell >= 12
+    monkeypatch.setattr(fa, "_VMEM_TILE_BUDGET", 1 << 20)
+    assert fa.default_blocks(512, 512, 64, 2, True) == (128, 128)
+    assert fa.default_blocks(2048, 2048, 64, 2, True) == (128, 128)
+    assert fa._route(jax.ShapeDtypeStruct((1, 2, 8, 2048), jnp.float32),
+                     *[jax.ShapeDtypeStruct((1, 2, 8, 2048), jnp.float32)] * 2,
+                     None) == "fallback_vmem"
+    fused_small, _ = sweep()
+    assert 0 < fused_small < fused
+
+
+def test_heads_per_step_and_vmem_accounting():
+    """A short row takes several heads a grid step, never across a batch
+    row; the accounting doubles the fetched blocks only and asks the
+    compiler for more than its default only where the count says so."""
+    assert fa.heads_per_step(12, 512, 512, 64) == 1
+    assert fa.heads_per_step(12, 128, 128, 64) == 12
+    assert fa.heads_per_step(12, 256, 256, 64) == 4
+    assert fa.heads_per_step(7, 128, 128, 64) == 7
+    assert fa.heads_per_step(16, 128, 256, 64) == 8
+    assert fa.heads_per_step(5, 64, 64, 64) == 5
+    assert fa.heads_per_step(12, 512, 4096, 64, 2) == 1
+    assert fa.heads_per_step(12, 128, 128, 1024, 4) == 3   # VMEM, not room
+    for heads in (1, 5, 12, 16):
+        for t in (8, 64, 128, 384, 512):
+            hb = fa.heads_per_step(heads, t, t, 64)
+            assert heads % hb == 0 and (hb == 1
+                                        or hb * t * t <= fa.MAX_BLOCK ** 2)
+    one = fa.vmem_bytes_attention(512, 512, 64, 2)
+    assert 4 << 20 < one < fa._SCOPED_VMEM * 3 // 4
+    assert fa.vmem_bytes_attention(128, 128, 64, 2, hb=12) < 2 * one
+    _, pltpu = fa._load_pallas()
+    assert fa._compiler_params(pltpu, vmem_bytes=one).vmem_limit_bytes is None
+    big = fa.vmem_bytes_attention(512, 512, 512, 4)
+    assert fa.fits_vmem_attention(512, 512, 512, 4)
+    assert fa._compiler_params(pltpu, ("parallel", "arbitrary"), big) \
+        .vmem_limit_bytes == big * 3 // 2
+    assert not fa.fits_vmem_attention(512, 512, 4096, 4)
+
+
 def test_flash_raises_on_non_tiling_and_bad_bias(rng):
     q, k, v = _qkv(rng, Tq=100, Tk=128, d=16)
     with pytest.raises(ValueError, match="do not tile"):

@@ -82,17 +82,71 @@ def test_flash_attention_compiles(one_chip, shape, has_bias, grad):
                                   block_q=bq, block_k=bk)
 
     def loss(q, k, v, *bias):
-        return jnp.sum(fwd(q, k, v, *bias).astype(jnp.float32))
+        # sin keeps the forward in the program: a whole-row backward reads
+        # no output of it
+        return jnp.sum(jnp.sin(fwd(q, k, v, *bias).astype(jnp.float32)))
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
     avals = [(shape, BF16)] * 3
     if has_bias:
         avals.append(((B, 1, 1, T), jnp.float32))
+    # an explicit 128 is the whole row at s128 (forward and one fused
+    # backward) and a blocked grid at s512 (forward, dq, dk/dv)
+    backward = ("flash_bwd_dkv",) if bk == T \
+        else ("flash_bwd_dq", "flash_bwd_dkv")
     text = _compile(fn, one_chip, *avals, kernels=(
-        ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv") if grad
-        else ("flash_fwd",)))
-    # forward, and in the backward the dq and dk/dv kernels beside it
-    assert text.count("tpu_custom_call") >= (3 if grad else 1)
+        ("flash_fwd",) + backward if grad else ("flash_fwd",)))
+    assert text.count("tpu_custom_call") >= (1 + len(backward) if grad else 1)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("shape,dtype,blocks", [
+    ((32, 12, 512, 64), BF16, (512, 512)),     # bert_base.finetune.s512
+    ((128, 12, 128, 64), BF16, (128, 128)),    # the same tokens at s128
+    ((8, 12, 512, 64), jnp.float32, (512, 512)),
+    ((1, 12, 8192, 64), BF16, (512, 4096)),    # a row that does not fit
+], ids=["bert_b32_s512", "bert_b128_s128", "f32_s512", "b1_s8192"])
+def test_flash_attention_default_tiling_compiles(one_chip, monkeypatch,
+                                                 shape, dtype, blocks, grad):
+    """The cells' own shapes with a key bias at the tiling the dispatcher
+    chooses, through ``attention()`` as a traced program reaches it: a
+    whole-row tile (twelve heads a grid step at s128) with its fused
+    backward under ``flash_bwd_dkv``, and the blocked grid of 512 x 4,096
+    where 8,192 keys do not fit beside 512 queries. The loss keeps the forward alive (the whole-row backward
+    needs no output of it)."""
+    from deeplearning4j_tpu.ops import autotune as at
+    B, H, T, d = shape
+    assert fa.default_blocks(T, T, d, np.dtype(dtype).itemsize, True) \
+        == blocks
+    monkeypatch.setattr(fa, "_tpu_available", lambda: True)
+    at.reset()
+    fa.reset_counters()
+    fa._TILING.zero()
+
+    def fwd(q, k, v, bias):
+        return fa.attention(q, k, v, bias)
+
+    def loss(q, k, v, bias):
+        return jnp.sum(jnp.sin(fwd(q, k, v, bias).astype(jnp.float32)))
+
+    whole = blocks[1] == T
+    kernels = ("flash_fwd",) if not grad else (
+        ("flash_fwd", "flash_bwd_dkv") if whole
+        else ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd,
+                    one_chip, (shape, dtype), (shape, dtype), (shape, dtype),
+                    ((B, 1, 1, T), jnp.float32), kernels=kernels)
+    at.reset()
+    assert fa.counters()["fused"] == 1
+    assert fa._TILING.value(kind="whole_row" if whole else "blocked") == 1
+    calls = _custom_call_names(text)
+    if grad and whole:
+        assert not any("flash_bwd_dq" in c for c in calls)
+        # dk first: [G, T, d], which is how the benchmark's reader finds it
+        assert re.search(r"flash_bwd_dkv[\w.\-]* = \((bf16|f32)\[%d,%d,%d\]"
+                         % (B * H, T, d), text)
+    # no lane-replicated statistics leave a whole-row kernel
+    assert (f"f32[{B * H},{T},128]" in text) == (not whole)
 
 
 @pytest.mark.parametrize("page", [0, 16], ids=["contiguous", "page16"])
