@@ -715,57 +715,65 @@ class SameDiff(_SentinelCounterMixin):
 
         return loss_fn
 
+    def _fit_spec(self):
+        """The key of the compiled fit step: everything its trace bakes in.
+        Loss/updater/train-config, the dtype policy, the workspace_mode
+        remat policy, the Environment's f32 matmul-precision mode, and the
+        VARIABLE set — mutating any of them must retrace instead of silently
+        reusing the old executable (:meth:`_fit_step_cached` compares
+        specs)."""
+        from .. import environment as _envmod
+        return ("fit", self.loss_name,
+                json.dumps(self.updater.to_dict(), sort_keys=True,
+                           default=str),
+                json.dumps(self.train_config, sort_keys=True, default=str),
+                str(self.dtype),
+                str(getattr(self, "workspace_mode", "none")),
+                str(_envmod.Environment.instance().f32_matmul_precision),
+                tuple(n for n, v in self._vars.items()
+                      if v.kind == VARIABLE),
+                "fused_cast" if self.fused_updater_active() else "plain")
+
     def _make_fit_step(self):
-        """(spec, jitted step fn) for the compiled fit step. The spec keys
-        everything the trace bakes in: loss/updater/train-config, the
-        dtype policy, the workspace_mode remat policy, the Environment's
-        f32 matmul-precision mode, and the VARIABLE set — mutating any of
-        them must retrace instead of silently reusing the old executable
-        (the cache in :meth:`fit` compares specs)."""
-        loss_name = self.loss_name
-        train_names = [n for n, v in self._vars.items() if v.kind == VARIABLE]
-        updater = self.updater
+        """(spec, jitted step fn) for the compiled fit step; the spec is
+        :meth:`_fit_spec`'s. The step differentiates the loss here (its own
+        split-penalty gradient under the fused master-cast updater) and
+        hands the gradient to ``nn/trainstep.py``'s tail, the engines' own:
+        clip, divergence sentinel, guarded updater, counters."""
         tc = dict(self.train_config)
         fused_cast = self.fused_updater_active()
         loss_fn = self._fit_loss_fn(split_penalty=fused_cast)
         penalty = bool(tc.get("l1")) or bool(tc.get("l2"))
         from .. import dtypes as _dt
-        cdt = _dt.resolve(self.dtype)
+        from ..nn import gradnorm as _gn
+        from ..nn import trainstep as _ts
 
-        from ..runtime import sentinel as _sent
-        from ..nn import updaters as _updaters
-
-        def _clip_and_ok(loss, grads):
-            from ..nn import gradnorm as _gn
+        def clip(grads):
             # the shared engine clip pipeline; per-VARIABLE grouping means
             # each leaf is wrapped as its own "layer" for the mode step
             # (value/L2 clip are tree-shape agnostic, so the wrap is safe)
-            with jax.named_scope("clip"):
-                wrapped = {k: {"g": g} for k, g in grads.items()}
-                wrapped, clip_events = _gn.clip_with_events(
-                    tc.get("grad_norm"), tc.get("grad_norm_threshold", 1.0),
-                    tc.get("clip_value"), tc.get("clip_l2"), wrapped)
-                grads = {k: v["g"] for k, v in wrapped.items()}
-            # DIVERGENCE SENTINEL — engine-parity contract (see
-            # MultiLayerNetwork._build_train_step): non-finite loss or
-            # global grad norm skips the weight update inside lax.cond and
-            # bumps the on-device counters; zero host syncs/retraces.
-            with jax.named_scope("sentinel"):
-                ok = _sent.finite_ok(loss, grads)
-            return grads, ok, clip_events
+            wrapped = {k: {"g": g} for k, g in grads.items()}
+            wrapped, clip_events = _gn.clip_with_events(
+                tc.get("grad_norm"), tc.get("grad_norm_threshold", 1.0),
+                tc.get("clip_value"), tc.get("clip_l2"), wrapped)
+            return {k: v["g"] for k, v in wrapped.items()}, clip_events
 
-        if fused_cast:
-            # FUSED MASTER-CAST UPDATER STEP (ISSUE 16): the first arg is
-            # the ``(masters, compute_copies)`` carry from _fit_carry().
-            # The forward reads the pre-cast compute copies (cast_floating
-            # on them is identity -> bit-equal forward); cotangents come
-            # back 16-bit and are upcast EXACTLY like the unfused cast's
-            # transpose (f32<-16-bit convert is value-exact); the updater
-            # emits the fresh compute copy in the same fusion that writes
-            # the f32 master (apply_leafwise_cast), so the standalone
-            # per-step master-cast sweep disappears from the program.
-            def step(carry, opt_state, other_vals, step_i, feeds,
-                     sentinel=None):
+        tail = _ts.gradient_tail(self.updater, clip,
+                                 cdt=_dt.resolve(self.dtype))
+
+        def step(carry, opt_state, other_vals, step_i, feeds,
+                 sentinel=None):
+            if fused_cast:
+                # FUSED MASTER-CAST UPDATER STEP (ISSUE 16): the first arg
+                # is the ``(masters, compute_copies)`` carry from
+                # _fit_carry(). The forward reads the pre-cast compute
+                # copies (cast_floating on them is identity -> bit-equal
+                # forward); cotangents come back 16-bit and are upcast
+                # EXACTLY like the unfused cast's transpose (f32<-16-bit
+                # convert is value-exact); the tail's updater emits the
+                # fresh compute copy in the same fusion that writes the f32
+                # master, so the standalone per-step master-cast sweep
+                # disappears from the program.
                 tv, tv_c = carry
                 if penalty:
                     # penalties read the f32 masters (argnum 0), the
@@ -783,56 +791,16 @@ class SameDiff(_SentinelCounterMixin):
                         lambda b: loss_fn(tv, b, other_vals, feeds))(tv_c)
                     grads = jax.tree.map(lambda p, gc: gc.astype(p.dtype),
                                          tv, g_c)
-                grads, ok, clip_events = _clip_and_ok(loss, grads)
-
-                def _apply(pair, opt_state):
-                    p, _ = pair
-                    new_p, new_pc, new_opt = _updaters.apply_leafwise_cast(
-                        updater, grads, opt_state, p, step_i, cdt)
-                    return (new_p, new_pc), new_opt
-
-                with jax.named_scope("updater"):
-                    new_carry, new_opt = _sent.guarded_apply(
-                        ok, _apply, (tv, tv_c), opt_state)
-                if sentinel is None:  # pre-sentinel call signature
-                    return new_carry, new_opt, loss
-                return (new_carry, new_opt,
-                        _sent.update_counters(sentinel, ok, clip_events),
-                        loss)
-        else:
-            def step(train_vals, opt_state, other_vals, step_i, feeds,
-                     sentinel=None):
+            else:
                 loss, grads = jax.value_and_grad(
-                    lambda tv: loss_fn(tv, other_vals, feeds))(train_vals)
-                grads, ok, clip_events = _clip_and_ok(loss, grads)
+                    lambda tv: loss_fn(tv, other_vals, feeds))(carry)
+            carry, opt_state, _, sentinel = tail(loss, grads, carry,
+                                                 opt_state, step_i, sentinel)
+            if sentinel is None:  # pre-sentinel call signature
+                return carry, opt_state, loss
+            return carry, opt_state, sentinel, loss
 
-                def _apply(train_vals, opt_state):
-                    delta, new_opt = updater.apply(grads, opt_state,
-                                                   train_vals, step_i)
-                    return (jax.tree.map(lambda p, d: p - d, train_vals,
-                                         delta),
-                            new_opt)
-
-                with jax.named_scope("updater"):
-                    new_vals, new_opt = _sent.guarded_apply(
-                        ok, _apply, train_vals, opt_state)
-                if sentinel is None:  # pre-sentinel call signature
-                    return new_vals, new_opt, loss
-                return (new_vals, new_opt,
-                        _sent.update_counters(sentinel, ok, clip_events),
-                        loss)
-
-        import json as _json
-        from .. import environment as _envmod
-        spec = ("fit", loss_name,
-                _json.dumps(updater.to_dict(), sort_keys=True, default=str),
-                _json.dumps(self.train_config, sort_keys=True, default=str),
-                str(self.dtype),
-                str(getattr(self, "workspace_mode", "none")),
-                str(_envmod.Environment.instance().f32_matmul_precision),
-                tuple(train_names),
-                "fused_cast" if fused_cast else "plain")
-        return spec, jax.jit(step, donate_argnums=(0, 1))
+        return self._fit_spec(), jax.jit(step, donate_argnums=(0, 1))
 
     # ------------------------------------------- fused master-cast carry
     def fused_updater_active(self) -> bool:
@@ -878,10 +846,11 @@ class SameDiff(_SentinelCounterMixin):
         Every rebuild reports to the retrace tracker with the spec field
         that changed as its cause — a silent retrace of a BERT-sized
         import is exactly what ISSUE 6 makes visible."""
-        spec, step = self._make_fit_step()
+        spec = self._fit_spec()
         cached = self._fn_cache.get("__fit_step__")
         if cached is not None and cached[0] == spec:
             return cached[1]
+        step = self._make_fit_step()[1]
         from ..runtime import telemetry as _tel
         # the mutators (set_dtype/set_workspace_mode/...) pop the cache to
         # release the old executable's device memory, so the cause diff

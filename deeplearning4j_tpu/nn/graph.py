@@ -597,171 +597,36 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             self.conf.dtype,
             has_penalty=self._uses_regularization()) is None
 
-    def _build_train_step(self, accum_steps: int = 1,
-                          sentinel_guard: bool = True, grad_transform=None,
+    def _build_train_step(self, accum_steps: int = 1, grad_transform=None,
                           fused_cast: bool = False):
         """Fused pure train step; ``accum_steps=k`` scans the gradient over
         k microbatches before the single updater application (same contract
-        as ``MultiLayerNetwork._build_train_step`` — see
-        ``nn/microbatch.py``). The conf's ``workspace_mode`` remat policy
-        (``nn/memory.py``) composes with both. ``sentinel_guard=False``
-        compiles out the divergence sentinel (A/B baseline for bench.py's
-        ``resilience`` metric). ``grad_transform`` and the r12 mixed-
-        precision cast hoist follow the MultiLayerNetwork twin's contract
-        (see its docstring): the transform is value-identity scheduling
-        structure applied BEFORE clip/sentinel; the hoist casts fp32
-        masters to the compute dtype once per step instead of once per
-        microbatch (bit-equivalent, gated on no l1/l2). ``fused_cast=True``
-        (ISSUE 16, gated on :meth:`fused_updater_active`) compiles the
-        fused master-cast variant — ``params_c`` compute copy in the
-        signature, cast folded into the updater write; see
-        ``MultiLayerNetwork._build_train_step`` for the exactness
-        argument."""
-        updater = self.conf.updater
+        as ``MultiLayerNetwork._build_train_step`` and the same body,
+        ``nn/trainstep.py``; see ``nn/microbatch.py``). The conf's
+        ``workspace_mode`` remat policy (``nn/memory.py``) composes with
+        both. ``grad_transform`` and the r12 mixed-precision cast hoist
+        follow the MultiLayerNetwork twin's contract (see its docstring):
+        the transform is value-identity scheduling structure applied BEFORE
+        clip/sentinel; the hoist casts fp32 masters to the compute dtype
+        once per step instead of once per microbatch (bit-equivalent, gated
+        on no l1/l2). ``fused_cast=True`` (ISSUE 16, gated on
+        :meth:`fused_updater_active`) compiles the fused master-cast variant
+        — ``params_c`` compute copy in the signature, cast folded into the
+        updater write; see ``MultiLayerNetwork._build_train_step`` for the
+        exactness argument."""
         from .layers.wrappers import FrozenLayer
-        from .vertices import LayerVertex
         from . import microbatch as _micro
+        from . import trainstep as _ts
         frozen_keys = frozenset(
             n for n, v, _ in self.conf.vertices
             if isinstance(v, LayerVertex) and isinstance(v.layer, FrozenLayer))
-        vg_fn = jax.value_and_grad(self._build_loss_fn(), has_aux=True)
-        cast_hoist = (accum_steps > 1 and _dt.is_mixed(self.conf.dtype)
-                      and not self._uses_regularization())
-        cdt = _dt.resolve(self.conf.dtype)
-        pdt = _dt.param_dtype(self.conf.dtype)
-        from ..runtime import sentinel as _sent
-
-        if fused_cast:
-            if accum_steps != 1:
-                raise ValueError("fused_cast requires accum_steps == 1 "
-                                 "(the microbatch scan has its own hoist)")
-
-            # under a recomputing workspace_mode (the memory knob) the
-            # gradient's float32 copy is not held either: see below
-            from . import memory as _memory
-            late_cast = (_memory.resolve_policy(
-                getattr(self.conf, "workspace_mode", None)).remat
-                and grad_transform is None
-                and not self.conf.gradient_normalization
-                and self.conf.gradient_clip_value is None
-                and self.conf.gradient_clip_l2 is None)
-
-            def fused_step_fn(params, params_c, opt_state, bn_state, step,
-                              key, xs, ys, fms, lms, sentinel=None):
-                (loss, new_bn), grads = vg_fn(
-                    params_c, bn_state, key, xs, ys, fms, lms)
-                # exact upcast — the unfused cast's transpose, bitwise.
-                # Under a recomputing workspace_mode, where nothing reads the
-                # gradient between here and the updater (no transform, no
-                # clipping; the sentinel upcasts what it sums), the upcast
-                # moves into the updater's own sweep, so the float32 copy of
-                # the whole gradient is never held: 4 bytes a parameter of
-                # peak memory. Not bit-equal to the early cast (there XLA may
-                # keep the backward's float32 values unrounded; here the
-                # compute-dtype gradient is what crosses into the updater),
-                # so the default mode keeps the early cast
-                if not late_cast:
-                    grads = _dt.cast_floating(grads, pdt)
-                if grad_transform is not None:
-                    grads = grad_transform(grads)
-                with jax.named_scope("clip"):
-                    grads, clip_events = self._clip(grads)
-
-                def _apply(pair, opt_state):
-                    p, _ = pair
-                    new_p, new_pc, new_opt = _upd.apply_leafwise_cast(
-                        updater, _dt.cast_floating(grads, pdt), opt_state, p,
-                        step, cdt)
-                    if self.conf.constraints:
-                        new_p = _constraints.apply_constraints(
-                            self.conf.constraints, new_p, skip=frozen_keys)
-                        new_pc = _dt.cast_floating(new_p, cdt)
-                    return (new_p, new_pc), new_opt
-
-                if not sentinel_guard:  # A/B baseline
-                    (new_p, new_pc), new_opt = _apply(
-                        (params, params_c), opt_state)
-                    if sentinel is None:
-                        return new_p, new_pc, new_opt, new_bn, loss
-                    return (new_p, new_pc, new_opt, new_bn,
-                            _sent.update_counters(sentinel, jnp.bool_(True),
-                                                  clip_events), loss)
-                with jax.named_scope("sentinel"):
-                    ok = _sent.finite_ok(loss, grads)
-                with jax.named_scope("updater"):
-                    (new_p, new_pc), new_opt = _sent.guarded_apply(
-                        ok, _apply, (params, params_c), opt_state)
-                out_bn = jax.tree.map(
-                    lambda new, old: jnp.where(ok, new, old),
-                    new_bn, bn_state) if bn_state else new_bn
-                if sentinel is None:
-                    return new_p, new_pc, new_opt, out_bn, loss
-                return (new_p, new_pc, new_opt, out_bn,
-                        _sent.update_counters(sentinel, ok, clip_events),
-                        loss)
-
-            return jax.jit(fused_step_fn, donate_argnums=(0, 1, 2, 3),
-                           compiler_options=_env.engine_compiler_options())
-
-        def step_fn(params, opt_state, bn_state, step, key, xs, ys, fms, lms,
-                    sentinel=None):
-            if accum_steps == 1:
-                (loss, new_bn), grads = vg_fn(
-                    params, bn_state, key, xs, ys, fms, lms)
-            else:
-                vg_params = _dt.cast_floating(params, cdt) if cast_hoist \
-                    else params
-                (loss, new_bn), grads = _micro.accumulate_gradients(
-                    vg_fn, vg_params, bn_state, key, accum_steps,
-                    (xs, ys, fms, lms),
-                    weight_fn=_micro.multi_output_weight)
-                if cast_hoist:
-                    grads = _dt.cast_floating(grads, pdt)
-            if grad_transform is not None:
-                grads = grad_transform(grads)
-            with jax.named_scope("clip"):
-                grads, clip_events = self._clip(grads)
-
-            def _apply(params, opt_state):
-                # leaf-wise updater application. The flat-buffer variant
-                # (updaters.apply_fused) measured a LARGE regression here on
-                # the real chip — ResNet-50 bf16: -13 MFU points at batch
-                # 128, -7.7 at 256 (DIAG3_r05.json, interleaved A/B) — the
-                # ravel/unravel round-trip defeats XLA's in-place param
-                # update through the scan carry. r4's "perf-neutral"
-                # adoption was wrong; reverted.
-                new_params, new_opt = _upd.apply_leafwise(
-                    updater, grads, opt_state, params, step)
-                new_params = _constraints.apply_constraints(
-                    self.conf.constraints, new_params, skip=frozen_keys)
-                return new_params, new_opt
-
-            if not sentinel_guard:  # A/B baseline (bench resilience metric)
-                new_params, new_opt = _apply(params, opt_state)
-                if sentinel is None:
-                    return new_params, new_opt, new_bn, loss
-                return (new_params, new_opt, new_bn,
-                        _sent.update_counters(sentinel, jnp.bool_(True),
-                                              clip_events), loss)
-
-            # DIVERGENCE SENTINEL — same contract as MultiLayerNetwork._
-            # build_train_step: non-finite loss/grad-norm lax.cond-skips the
-            # updater application and BN commit, bumps on-device counters;
-            # zero host syncs, zero retraces in steady state.
-            with jax.named_scope("sentinel"):
-                ok = _sent.finite_ok(loss, grads)
-            with jax.named_scope("updater"):
-                new_params, new_opt = _sent.guarded_apply(
-                    ok, _apply, params, opt_state)
-            out_bn = jax.tree.map(
-                lambda new, old: jnp.where(ok, new, old),
-                new_bn, bn_state) if bn_state else new_bn
-            if sentinel is None:  # pre-sentinel call signature (tests/tools)
-                return new_params, new_opt, out_bn, loss
-            return (new_params, new_opt, out_bn,
-                    _sent.update_counters(sentinel, ok, clip_events), loss)
-
-        return jax.jit(step_fn, donate_argnums=(0, 1, 2),
+        step = _ts.engine_step(
+            self, self._build_loss_fn(), frozen_keys,
+            _micro.multi_output_weight, accum_steps, grad_transform,
+            fused_cast)
+        return jax.jit(step,
+                       donate_argnums=(0, 1, 2, 3) if fused_cast
+                       else (0, 1, 2),
                        compiler_options=_env.engine_compiler_options())
 
     # ------------------------------------------------- on-device epoch loop
@@ -772,64 +637,28 @@ class ComputationGraph(_caches.CompiledCacheMixin):
 
         Why this exists (TPU-first divergence from DL4J's per-batch fit
         loop): each host->device dispatch costs fixed latency (PJRT call
-        overhead). Scanning on device removes it entirely and is how XLA-era trainers are meant to run
-        epochs whose data fits in HBM.
+        overhead). Scanning on device removes it entirely and is how XLA-era
+        trainers are meant to run epochs whose data fits in HBM.
 
         Under the fused master-cast updater (ISSUE 16) the scan carries
         the ``params_c`` compute copy — one cast per epoch launch, the
         rest emitted by the fused updater write; external signature
-        unchanged (masters in, masters out).
+        unchanged (masters in, masters out). ``xs``/``ys`` are tuples of
+        stacked arrays ``[n_batches, B, ...]`` aligned with
+        ``conf.inputs``/``conf.outputs``; masks are unsupported on this path.
         """
         # one dispatch decision per compiled program, as ``fit`` counts it
         from ..ops import fused_epilogues as _fe
+        from . import trainstep as _ts
         _fe.dispatch_updater(self.conf.dtype,
                              has_penalty=self._uses_regularization())
-        if self.fused_updater_active():
-            step = self._build_train_step(fused_cast=True).__wrapped__
-            cdt = _dt.resolve(self.conf.dtype)
-
-            def epoch_fn(params, opt_state, bn_state, sentinel, start_step,
-                         key, xs, ys):
-                params_c = _dt.cast_floating(params, cdt)  # once per epoch
-                def body(carry, xy):
-                    params, params_c, opt_state, bn_state, sentinel, i = carry
-                    bx, by = xy
-                    k = jax.random.fold_in(key, i)
-                    (params, params_c, opt_state, bn_state, sentinel,
-                     loss) = step(params, params_c, opt_state, bn_state, i,
-                                  k, bx, by, (None,) * len(bx),
-                                  (None,) * len(by), sentinel)
-                    return (params, params_c, opt_state, bn_state, sentinel,
-                            i + 1), loss
-                (params, _, opt_state, bn_state, sentinel, _), losses = \
-                    jax.lax.scan(
-                        body, (params, params_c, opt_state, bn_state,
-                               sentinel, start_step), (xs, ys))
-                return params, opt_state, bn_state, sentinel, losses
-
-            return jax.jit(epoch_fn, donate_argnums=(0, 1, 2, 3),
-                           compiler_options=_env.engine_compiler_options())
-
-        step = self._build_train_step().__wrapped__
-
-        def epoch_fn(params, opt_state, bn_state, sentinel, start_step, key,
-                     xs, ys):
-            # xs/ys: tuples of stacked arrays [n_batches, B, ...] aligned
-            # with conf.inputs/outputs. Masks unsupported on this path.
-            def body(carry, xy):
-                params, opt_state, bn_state, sentinel, i = carry
-                bx, by = xy
-                k = jax.random.fold_in(key, i)
-                params, opt_state, bn_state, sentinel, loss = step(
-                    params, opt_state, bn_state, i, k, bx, by,
-                    (None,) * len(bx), (None,) * len(by), sentinel)
-                return (params, opt_state, bn_state, sentinel, i + 1), loss
-            (params, opt_state, bn_state, sentinel, _), losses = jax.lax.scan(
-                body, (params, opt_state, bn_state, sentinel, start_step),
-                (xs, ys))
-            return params, opt_state, bn_state, sentinel, losses
-
-        return jax.jit(epoch_fn, donate_argnums=(0, 1, 2, 3),
+        fused = self.fused_updater_active()
+        step = self._build_train_step(fused_cast=fused).__wrapped__
+        masks = ((None,) * len(self.conf.inputs),
+                 (None,) * len(self.conf.outputs))
+        return jax.jit(_ts.build_epoch(step, fused,
+                                       _dt.resolve(self.conf.dtype), masks),
+                       donate_argnums=(0, 1, 2, 3),
                        compiler_options=_env.engine_compiler_options())
 
     def fit_on_device(self, features, labels, epochs: int = 1,

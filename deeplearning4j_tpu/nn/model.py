@@ -31,8 +31,6 @@ from .. import dtypes as _dt
 from .. import environment as _env
 from . import caches as _caches
 from ..data.dataset import DataSet, DataSetIterator, NumpyDataSetIterator
-from . import constraints as _constraints
-from . import updaters as _updaters
 from ..ops import losses as _loss
 from ..runtime import telemetry as _tel
 from .config import MultiLayerConfiguration
@@ -365,23 +363,18 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
             self.conf.dtype,
             has_penalty=self._uses_regularization()) is None
 
-    def _build_train_step(self, accum_steps: int = 1,
-                          sentinel_guard: bool = True, grad_transform=None,
+    def _build_train_step(self, accum_steps: int = 1, grad_transform=None,
                           fused_cast: bool = False):
-        """Fused pure train step. ``accum_steps=k`` splits the batch into k
-        microbatches and accumulates the mean gradient via ``lax.scan``
-        before the SINGLE updater application (see ``nn/microbatch.py`` for
-        the exactness contract) — peak activation memory drops to one
-        microbatch, so global batch can grow past HBM. The conf's
+        """Fused pure train step (the body is ``nn/trainstep.py``'s, shared
+        with ``ComputationGraph`` and, from the gradient on, ``SameDiff``).
+        ``accum_steps=k`` splits the batch into k microbatches and
+        accumulates the mean gradient via ``lax.scan`` before the SINGLE
+        updater application (see ``nn/microbatch.py`` for the exactness
+        contract) — peak activation memory drops to one microbatch, so
+        global batch can grow past HBM. The conf's
         ``workspace_mode`` remat policy (``nn/memory.py``) composes: inside
         each microbatch, intra-segment activations are recomputed in the
         backward pass instead of cached.
-
-        ``sentinel_guard=False`` compiles the step WITHOUT the divergence
-        sentinel's finite-check/cond (the pre-ISSUE-5 program) — the A/B
-        baseline bench.py's ``resilience`` metric measures the sentinel's
-        steady-state overhead against; fit() always builds the guarded
-        step.
 
         ``grad_transform`` (value-identity, e.g. the collective-overlap
         sharding pins from ``parallel/overlap.py``) is applied to the raw
@@ -410,130 +403,20 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
         the standalone per-step cast sweep is gone from the program.
         Bit-parity of params AND updater state vs the unfused step is
         asserted in tests."""
-        updater = self.conf.updater
         from .layers.wrappers import FrozenLayer
         from . import microbatch as _micro
-        from ..runtime import sentinel as _sent
+        from . import trainstep as _ts
         frozen_keys = frozenset(str(i) for i, l in enumerate(self.layers)
                                 if isinstance(l, FrozenLayer))
-        vg_fn = jax.value_and_grad(self._build_loss_fn(), has_aux=True)
-        cast_hoist = (accum_steps > 1 and _dt.is_mixed(self.conf.dtype)
-                      and not self._uses_regularization())
-        cdt = _dt.resolve(self.conf.dtype)
-        pdt = _dt.param_dtype(self.conf.dtype)
-
-        if fused_cast:
-            if accum_steps != 1:
-                raise ValueError("fused_cast requires accum_steps == 1 "
-                                 "(the microbatch scan has its own hoist)")
-
-            def fused_step_fn(params, params_c, opt_state, bn_state, step,
-                              key, x, y, fmask, lmask, sentinel=None):
-                (loss, new_bn), grads = vg_fn(
-                    params_c, bn_state, key, x, y, fmask, lmask)
-                # exact upcast: the transpose of convert f32->16-bit is
-                # convert 16-bit->f32, value-exact — same bits as the
-                # unfused step's through-the-cast cotangents
-                grads = _dt.cast_floating(grads, pdt)
-                if grad_transform is not None:
-                    grads = grad_transform(grads)
-                with jax.named_scope("clip"):
-                    grads, clip_events = self._clip(grads)
-
-                def _apply(pair, opt_state):
-                    p, _ = pair
-                    new_p, new_pc, new_opt = _updaters.apply_leafwise_cast(
-                        updater, grads, opt_state, p, step, cdt)
-                    if self.conf.constraints:
-                        # constraints rewrite the masters post-update, so
-                        # the fused copy must be re-derived from them
-                        new_p = _constraints.apply_constraints(
-                            self.conf.constraints, new_p, skip=frozen_keys)
-                        new_pc = _dt.cast_floating(new_p, cdt)
-                    return (new_p, new_pc), new_opt
-
-                if not sentinel_guard:  # A/B baseline
-                    (new_p, new_pc), new_opt = _apply(
-                        (params, params_c), opt_state)
-                    if sentinel is None:
-                        return new_p, new_pc, new_opt, new_bn, loss
-                    return (new_p, new_pc, new_opt, new_bn,
-                            _sent.update_counters(sentinel, jnp.bool_(True),
-                                                  clip_events), loss)
-                with jax.named_scope("sentinel"):
-                    ok = _sent.finite_ok(loss, grads)
-                with jax.named_scope("updater"):
-                    (new_p, new_pc), new_opt = _sent.guarded_apply(
-                        ok, _apply, (params, params_c), opt_state)
-                out_bn = jax.tree.map(
-                    lambda new, old: jnp.where(ok, new, old),
-                    new_bn, bn_state) if bn_state else new_bn
-                if sentinel is None:
-                    return new_p, new_pc, new_opt, out_bn, loss
-                return (new_p, new_pc, new_opt, out_bn,
-                        _sent.update_counters(sentinel, ok, clip_events),
-                        loss)
-
-            return jax.jit(fused_step_fn, donate_argnums=(0, 1, 2, 3),
-                           compiler_options=_env.engine_compiler_options())
-
-        def step_fn(params, opt_state, bn_state, step, key, x, y, fmask,
-                    lmask, sentinel=None):
-            if accum_steps == 1:
-                (loss, new_bn), grads = vg_fn(
-                    params, bn_state, key, x, y, fmask, lmask)
-            else:
-                vg_params = _dt.cast_floating(params, cdt) if cast_hoist \
-                    else params
-                (loss, new_bn), grads = _micro.accumulate_gradients(
-                    vg_fn, vg_params, bn_state, key, accum_steps,
-                    (x, y, fmask, lmask),
-                    weight_fn=lambda x, y, fm, lm:
-                        _micro.label_count_weight(lm))
-                if cast_hoist:
-                    grads = _dt.cast_floating(grads, pdt)
-            if grad_transform is not None:
-                grads = grad_transform(grads)
-            with jax.named_scope("clip"):
-                grads, clip_events = self._clip(grads)
-
-            def _apply(params, opt_state):
-                new_params, new_opt = _updaters.apply_leafwise(
-                    updater, grads, opt_state, params, step)
-                new_params = _constraints.apply_constraints(
-                    self.conf.constraints, new_params, skip=frozen_keys)
-                return new_params, new_opt
-
-            if not sentinel_guard:  # A/B baseline (bench resilience metric)
-                new_params, new_opt = _apply(params, opt_state)
-                if sentinel is None:
-                    return new_params, new_opt, new_bn, loss
-                return (new_params, new_opt, new_bn,
-                        _sent.update_counters(sentinel, jnp.bool_(True),
-                                              clip_events), loss)
-
-            # DIVERGENCE SENTINEL (runtime/sentinel.py): non-finite loss or
-            # global grad norm -> lax.cond SKIPS the updater application and
-            # the BN-state commit (the bad batch leaves no trace in any
-            # carried state), bumps the on-device counters, and training
-            # continues — no host sync, no retrace, no exception (DL4J
-            # throws on NaN gradients; divergence recorded in PARITY.md).
-            with jax.named_scope("sentinel"):
-                ok = _sent.finite_ok(loss, grads)
-            with jax.named_scope("updater"):
-                new_params, new_opt = _sent.guarded_apply(
-                    ok, _apply, params, opt_state)
-            out_bn = jax.tree.map(
-                lambda new, old: jnp.where(ok, new, old),
-                new_bn, bn_state) if bn_state else new_bn
-            if sentinel is None:  # pre-sentinel call signature (tests/tools)
-                return new_params, new_opt, out_bn, loss
-            return (new_params, new_opt, out_bn,
-                    _sent.update_counters(sentinel, ok, clip_events), loss)
-
+        step = _ts.engine_step(
+            self, self._build_loss_fn(), frozen_keys,
+            lambda x, y, fm, lm: _micro.label_count_weight(lm),
+            accum_steps, grad_transform, fused_cast)
         # donate params/opt/bn buffers: in-place update on device (workspace
         # arenas' moral equivalent, handled by XLA)
-        return jax.jit(step_fn, donate_argnums=(0, 1, 2),
+        return jax.jit(step,
+                       donate_argnums=(0, 1, 2, 3) if fused_cast
+                       else (0, 1, 2),
                        compiler_options=_env.engine_compiler_options())
 
     # ------------------------------------------------- on-device epoch loop
@@ -549,51 +432,15 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
         (masters in, masters out)."""
         # one dispatch decision per compiled program, as ``fit`` counts it
         from ..ops import fused_epilogues as _fe
+        from . import trainstep as _ts
         _fe.dispatch_updater(self.conf.dtype,
                              has_penalty=self._uses_regularization())
-        if self.fused_updater_active():
-            step = self._build_train_step(fused_cast=True).__wrapped__
-            cdt = _dt.resolve(self.conf.dtype)
-
-            def epoch_fn(params, opt_state, bn_state, sentinel, start_step,
-                         key, xs, ys):
-                params_c = _dt.cast_floating(params, cdt)  # once per epoch
-                def body(carry, xy):
-                    params, params_c, opt_state, bn_state, sentinel, i = carry
-                    bx, by = xy
-                    k = jax.random.fold_in(key, i)
-                    (params, params_c, opt_state, bn_state, sentinel,
-                     loss) = step(params, params_c, opt_state, bn_state, i,
-                                  k, bx, by, None, None, sentinel)
-                    return (params, params_c, opt_state, bn_state, sentinel,
-                            i + 1), loss
-                (params, _, opt_state, bn_state, sentinel, _), losses = \
-                    jax.lax.scan(
-                        body, (params, params_c, opt_state, bn_state,
-                               sentinel, start_step), (xs, ys))
-                return params, opt_state, bn_state, sentinel, losses
-
-            return jax.jit(epoch_fn, donate_argnums=(0, 1, 2, 3),
-                           compiler_options=_env.engine_compiler_options())
-
-        step = self._build_train_step().__wrapped__
-
-        def epoch_fn(params, opt_state, bn_state, sentinel, start_step, key,
-                     xs, ys):
-            def body(carry, xy):
-                params, opt_state, bn_state, sentinel, i = carry
-                bx, by = xy
-                k = jax.random.fold_in(key, i)
-                params, opt_state, bn_state, sentinel, loss = step(
-                    params, opt_state, bn_state, i, k, bx, by, None, None,
-                    sentinel)
-                return (params, opt_state, bn_state, sentinel, i + 1), loss
-            (params, opt_state, bn_state, sentinel, _), losses = jax.lax.scan(
-                body, (params, opt_state, bn_state, sentinel, start_step),
-                (xs, ys))
-            return params, opt_state, bn_state, sentinel, losses
-
-        return jax.jit(epoch_fn, donate_argnums=(0, 1, 2, 3),
+        fused = self.fused_updater_active()
+        step = self._build_train_step(fused_cast=fused).__wrapped__
+        return jax.jit(_ts.build_epoch(step, fused,
+                                       _dt.resolve(self.conf.dtype),
+                                       (None, None)),
+                       donate_argnums=(0, 1, 2, 3),
                        compiler_options=_env.engine_compiler_options())
 
     def fit_on_device(self, features, labels, epochs: int = 1,

@@ -43,7 +43,7 @@ def _tmap(fn, *trees):
 
 class Updater:
     kind = "base"
-    elementwise = True  # apply() is per-element -> eligible for apply_fused
+    elementwise = True  # apply() is per-element (apply_leaf's shard contract)
     learning_rate: Any = 1e-3
 
     def lr_at(self, step):
@@ -296,8 +296,8 @@ def apply_leaf(updater, grad, slots, param, step):
 def apply_leafwise(updater, grads, state, params, step):
     """Per-tensor updater application + subtraction — the form the engines'
     hot train steps use (one small XLA fusion per parameter tensor, which
-    XLA schedules in place through the donated scan carry). See
-    ``apply_fused`` for why the flat-buffer alternative is NOT used there.
+    XLA schedules in place through the donated scan carry; a raveled flat
+    buffer defeats that, PERF.md section 6, DIAG3_r05).
 
     Returns ``(new_params, new_state)``.
     """
@@ -345,53 +345,3 @@ def apply_leafwise_cast(updater, grads, state, params, step, compute_dtype):
                                            step)
     new_params_c = _tmap(lambda p: _cast_leaf(p, compute_dtype), new_params)
     return new_params, new_params_c, new_state
-
-
-def apply_fused(updater, grads, state, params, step):
-    """Flat-buffer updater application — the TPU rendition of DL4J's
-    flat-param contract (SURVEY.md §7.3.5: one contiguous param/grad
-    buffer per network, updaters sweep it once).
-
-    Every updater in this module is strictly elementwise, so applying it
-    to ONE raveled vector is algebraically identical (bit-identical per
-    element) to leaf-wise application.
-
-    **NEGATIVE PERF RESULT (r5) — do NOT use this in a hot train step.**
-    Round 4 adopted it in the engines' fused steps claiming perf-neutral;
-    round 5's interleaved 2x2 A/B on the real chip (DIAG3_r05.json)
-    measured it as a large regression on ResNet-50 bf16: 32.5 -> 19.2 MFU
-    at batch 128, 30.9 -> 23.3 at batch 256. The ravel/unravel round-trip
-    (concat of every param/grad leaf + slice-back, ~100 MB each way at
-    ResNet-50 scale) defeats XLA's in-place donated param update through
-    the scan carry; the "single fused sweep" intuition was wrong on TPU.
-    Both engines and rl4j reverted to leaf-wise ``updater.apply``. The
-    function stays for the flat-param *semantic* contract (bit-identical
-    result, tested) and for small models where the copies are noise.
-
-    Returns ``(new_params, new_state)`` — subtraction is fused in.
-    Falls back to leaf-wise application when ``updater.elementwise`` is
-    False (future per-tensor-norm updaters, e.g. LARS-style) or when any
-    state entry is not a param-shaped pytree.
-    """
-    def _mismatched(v):
-        if jax.tree.structure(v) != jax.tree.structure(params):
-            return True
-        return any(getattr(a, "shape", None) != getattr(p, "shape", None)
-                   for a, p in zip(jax.tree.leaves(v),
-                                   jax.tree.leaves(params)))
-
-    if (not getattr(updater, "elementwise", True)
-            or not jax.tree.leaves(grads)
-            or any(_mismatched(v) for v in state.values())):
-        # leaf-wise fallback: non-elementwise updaters, and any updater whose
-        # state entries are not param-shaped pytrees (raveling those with the
-        # params unraveller would silently corrupt them)
-        return apply_leafwise(updater, grads, state, params, step)
-    from jax.flatten_util import ravel_pytree
-    flat_g, _ = ravel_pytree(grads)
-    flat_p, unravel = ravel_pytree(params)
-    flat_state = {k: ravel_pytree(v)[0] for k, v in state.items()}
-    delta, new_flat_state = updater.apply(flat_g, flat_state, flat_p, step)
-    new_params = unravel(flat_p - delta)
-    new_state = {k: unravel(v) for k, v in new_flat_state.items()}
-    return new_params, new_state

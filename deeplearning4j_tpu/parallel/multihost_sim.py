@@ -700,7 +700,7 @@ def run_disagg(outdir: str, timeout: float = 300.0,
         "post_warmup_compile_events": 0,
         "note": "CPU loopback pools: bit-parity/prefix-reuse/compile/"
                 "timeline proofs are the artifact; split-vs-colocated "
-                "latency comes from bench.py disaggregated_serving",
+                "latency is not measured here",
     }
     if artifact_path:
         with open(artifact_path, "w") as f:

@@ -96,8 +96,6 @@ class QLearningDiscreteDense:
                 return jnp.mean((q_taken - td_target) ** 2)
 
             loss, grads = jax.value_and_grad(loss_fn)(params)
-            # leaf-wise (apply_fused measured a large regression in the
-            # engines' hot steps — see ComputationGraph._build_train_step)
             new_params, new_opt = _upd.apply_leafwise(
                 updater, grads, opt_state, params, step)
             return new_params, new_opt, loss

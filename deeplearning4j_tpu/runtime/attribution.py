@@ -31,9 +31,8 @@ ROADMAP item 4's joint schedule tuner can rank remat/overlap/batch
 configurations without re-measuring (``cached_report``/``report_keys``).
 
 Surfaces: ``model.attribution_report(batch)`` (``memory_report``'s
-sibling, both engines via ``nn/caches.py``), the serving engines'
-``attribution_report(bucket)`` / ``attribution_report(cache_len)``, and
-``bench.py`` artifact embedding for the ResNet/BERT configs.
+sibling, both engines via ``nn/caches.py``) and the serving engines'
+``attribution_report(bucket)`` / ``attribution_report(cache_len)``.
 """
 
 from __future__ import annotations

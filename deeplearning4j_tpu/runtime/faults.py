@@ -294,8 +294,8 @@ def reset() -> None:
 
 # -------------------------------------------------------------- telemetry
 #: Cross-cutting resilience telemetry, written by the checkpointer and the
-#: resilient fit driver, read by PerformanceListener / ui.StatsListener /
-#: bench.py. Since ISSUE 6 the storage is the process-wide MetricsRegistry
+#: resilient fit driver, read by PerformanceListener / ui.StatsListener.
+#: Since ISSUE 6 the storage is the process-wide MetricsRegistry
 #: (``resilience.*`` counters/gauges); the bump/set/snapshot API is the
 #: historical view over it, so every pre-existing caller keeps working and
 #: the values scrape through ``GET /metrics``.

@@ -1,9 +1,10 @@
 """Divergence-sentinel device helpers (ISSUE 5 tentpole, layer 1).
 
 Non-finite detection of the loss and the global gradient norm is fused
-*into* the compiled train step of every engine (``MultiLayerNetwork`` /
-``ComputationGraph._build_train_step``, SameDiff ``__fit_step__``, and
-the ParallelWrapper's sharded step, which reuses the engine step): the
+*into* the compiled train step of every engine, in one place:
+``nn/trainstep.py``'s ``gradient_tail``, which ``MultiLayerNetwork`` /
+``ComputationGraph._build_train_step``, SameDiff's ``__fit_step__`` and
+the ParallelWrapper's sharded step (the engine's step) all run: the
 skip decision is a ``lax.cond`` around the updater application, and the
 bad-step bookkeeping is a tree of on-device int32 scalars threaded
 through the step like the optimizer state. Steady state therefore adds

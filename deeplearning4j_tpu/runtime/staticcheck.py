@@ -866,7 +866,9 @@ def _check_host_sync(idx: ModuleIndex):
 #: SPMD divergence or a retrace-per-step
 BUILDER_FUNCS = ("_build_train_step", "_build_epoch_fn", "_build_loss_fn",
                  "_lower_bucket", "_make_fit_step", "_fit_loss_fn",
-                 "_build", "_lower_step")
+                 "_build", "_lower_step",
+                 # nn/trainstep.py, the step bodies the first two wrap
+                 "gradient_tail", "engine_step", "build_epoch")
 
 _TIME_ATTRS = ("time", "time_ns", "perf_counter", "monotonic")
 
@@ -1458,20 +1460,6 @@ def decode_probe() -> List[Finding]:
 
 
 # ------------------------------------------------------------------- CLI
-
-
-def findings_snapshot() -> dict:
-    """Compact per-rule snapshot of the findings counter — bench.py
-    embeds this next to the registry snapshot so every benchmark artifact
-    records the lint state it ran under."""
-    m = _tel.registry.get("staticcheck.findings")
-    if m is None:
-        return {}
-    try:
-        return {",".join(f"{lk}={lv}" for lk, lv in k) or "total": int(v)
-                for k, v in m.series().items()}
-    except Exception:
-        return {}
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:

@@ -39,8 +39,7 @@ Four pieces:
   a silent retrace was invisible until the step time doubled.
 - **Export** — ``prometheus_text()`` (text exposition served by
   ``JsonModelServer GET /metrics``), ``event_log(path)`` (JSONL sink for
-  spans + compile events), and ``snapshot()`` (embedded in every bench.py
-  artifact).
+  spans + compile events), and ``snapshot()``.
 
 Kill switch: ``DL4J_TPU_TELEMETRY=off`` (or :func:`set_enabled`) gates
 the *timing* instrumentation — histogram observes, spans, step

@@ -1709,9 +1709,6 @@ class PagedGenerativeEngine(GenerativeEngine):
         return sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
                    for a in jax.tree.leaves(spec))
 
-    def bytes_per_token(self) -> int:
-        return self.pool_bytes() // (self.pages * self.page_size)
-
     def new_state(self, cache_len: int = 0) -> PagedDecodeState:
         """Fresh zeroed pool + empty page table. ``cache_len`` picks the
         initial page-table width bucket (defaults to one page)."""

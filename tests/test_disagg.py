@@ -21,7 +21,7 @@ The tier-1 contract for the prefill/decode split:
   pool cell (fixture positive/negative);
 - the REAL two-process topology works: ``multihost_sim --disagg``
   ships pages over a socket and the decode process serves them
-  (``run_disagg``, the fast tier-1 gate for ``make bench-disagg``).
+  (``run_disagg``, the fast tier-1 gate of ``make disagg-sim``).
 """
 
 import time
@@ -333,7 +333,7 @@ def test_package_passes_pool_rule():
 
 
 # ---------------------------------------------------------------------------
-# the REAL two-process topology (fast tier-1 gate for make bench-disagg)
+# the REAL two-process topology (fast tier-1 gate of make disagg-sim)
 # ---------------------------------------------------------------------------
 
 def test_disagg_two_process_sim(tmp_path):
@@ -342,8 +342,7 @@ def test_disagg_two_process_sim(tmp_path):
     serves them bit-equal to its colocated oracle in both kv modes, a
     repeat prompt rides the migrated registry entry, the stitched
     cross-process timeline tiles the measured latency, and neither pool
-    compiles after warmup. The timed colocated-vs-split A/B is the slow
-    ``make bench-disagg``."""
+    compiles after warmup."""
     from deeplearning4j_tpu.parallel.multihost_sim import run_disagg
     art = run_disagg(str(tmp_path), timeout=280.0)
     assert art["value"] == 1.0

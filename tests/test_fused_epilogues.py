@@ -773,27 +773,6 @@ def test_fusion_rules_fire_on_unfused_step():
     assert "fusion-applied-updater" in rules, rules
 
 
-# ---------------------------------------------------------------------------
-# bench helpers
-# ---------------------------------------------------------------------------
-
-def test_rederive_phase_split_unit():
-    """The r18 phase-audit bugfix: the re-derived split moves the
-    measured master-cast cost from the fwd phase into the updater phase,
-    keeping the original fields side by side."""
-    import bench
-
-    out = bench._rederive_phase_split(10.0, 4.0, 6.0, 2.0, 1.5)
-    assert out["bf16_updater_ms_incl_cast"] == pytest.approx(3.5)
-    assert out["bf16_fwd_ms_excl_cast"] == pytest.approx(4.5)
-    assert out["bf16_vs_f32_rederived"]["fwd"] == pytest.approx(
-        10.0 / 4.5, abs=2e-3)
-    assert out["bf16_vs_f32_rederived"]["updater"] == pytest.approx(
-        4.0 / 3.5, abs=2e-3)
-    # no measured cast -> no re-derivation (field absent, not garbage)
-    assert bench._rederive_phase_split(10.0, 4.0, 6.0, 2.0, None) == {}
-
-
 def test_partitioned_trace_routes_to_the_reference():
     """A Mosaic kernel cannot be partitioned by GSPMD: while a program
     partitioned over a mesh is traced (``pallas_kernels.gspmd_trace``) the

@@ -9,8 +9,6 @@ Two tiers, both asserted here:
   the module claims LeNet reaches high-90s on it — asserted at >=0.95.
 - real idx files (``MnistDataSetIterator.source == "idx"``): >=0.99,
   skip-guarded so the bar arms automatically the moment real data exists.
-
-bench.py's ``accuracy_reason`` cites this file — keep the claims in sync.
 """
 
 import numpy as np

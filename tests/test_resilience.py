@@ -179,15 +179,24 @@ def test_sentinel_zero_retrace_and_no_host_sync():
 
 def test_sentinel_equivalence_guarded_vs_baseline():
     """On finite data the guarded step is bit-identical to the
-    sentinel-free baseline program (the lax.cond never takes the skip
-    branch)."""
+    sentinel-free baseline, written out here: the gradient of the engine's
+    loss function through ``apply_leafwise`` (the lax.cond never takes the
+    skip branch)."""
+    from deeplearning4j_tpu.nn.updaters import apply_leafwise
     x, y = _data(32)
     args = (jnp.int32(0), jax.random.PRNGKey(0), jnp.asarray(x),
             jnp.asarray(y), None, None)
     a = MultiLayerNetwork(_conf()).init()
     b = MultiLayerNetwork(_conf()).init()
-    pa, _, _, _ = a._build_train_step(sentinel_guard=False)(
-        a.params, a.updater_state, a.state, *args)
+
+    @jax.jit
+    def baseline(params, opt_state, bn_state, step, key, *batch):
+        (_, _), grads = jax.value_and_grad(a._build_loss_fn(), has_aux=True)(
+            params, bn_state, key, *batch)
+        return apply_leafwise(a.conf.updater, grads, opt_state, params,
+                              step)[0]
+
+    pa = baseline(a.params, a.updater_state, a.state, *args)
     pb, _, _, _ = b._build_train_step()(
         b.params, b.updater_state, b.state, *args)
     jax.tree.map(np.testing.assert_array_equal, pa, pb)

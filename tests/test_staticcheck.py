@@ -220,12 +220,21 @@ def test_nondeterminism_in_compiled_positive_negative():
             "        k1, k2 = jax.random.split(key)\n"
             "        return params\n"
             "    return jax.jit(step_fn)\n")
+    # the shared core's bodies (nn/trainstep.py) are walked like the
+    # engines' builders that wrap them
+    bad_core = ("def gradient_tail(updater, clip):\n"
+                "    def tail(loss, grads):\n"
+                "        return grads * time.time()\n"
+                "    return tail\n")
     outside = "def fit(self):\n    t0 = time.time()\n"
     assert rules_of(sc.check_source(
         bad_time, rules=["nondeterminism-in-compiled"])) \
         == ["nondeterminism-in-compiled"]
     assert rules_of(sc.check_source(
         bad_np, rules=["nondeterminism-in-compiled"])) \
+        == ["nondeterminism-in-compiled"]
+    assert rules_of(sc.check_source(
+        bad_core, rules=["nondeterminism-in-compiled"])) \
         == ["nondeterminism-in-compiled"]
     assert sc.check_source(good, rules=["nondeterminism-in-compiled"]) == []
     assert sc.check_source(outside,
