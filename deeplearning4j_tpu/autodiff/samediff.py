@@ -37,6 +37,18 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import ops as _catalog
+from ..runtime import telemetry as _tel
+
+#: how a fit() call made its carry, constants' casts and optimizer state,
+#: and when it read its losses: one count a call each
+_FIT_PREPARE = _tel.counter(
+    "samediff.fit.prepare",
+    "SameDiff.fit calls by how the call's state was prepared (compiled: "
+    "one launch)")
+_FIT_READBACK = _tel.counter(
+    "samediff.fit.readback",
+    "SameDiff.fit calls by when a step's loss is read (deferred: behind "
+    "the next launch, no listener attached; per_step: before it)")
 
 VARIABLE = "VARIABLE"
 PLACEHOLDER = "PLACEHOLDER"
@@ -206,6 +218,9 @@ class SameDiff(_SentinelCounterMixin):
         self._ops: List[_OpRecord] = []             # creation order == topo
         self._counter = 0
         self._fn_cache: Dict[Tuple, Callable] = {}
+        # fit()'s programs: the spec each was last built for, kept past the
+        # mutators' pops so a rebuild can name its cause
+        self._last_fit_specs: Dict[str, Tuple] = {}
         self.updater = None
         self.loss_name: Optional[str] = None
         self._listeners: List[Any] = []
@@ -573,7 +588,7 @@ class SameDiff(_SentinelCounterMixin):
         from .. import dtypes as _dt
         _dt.resolve(dtype)  # validate early
         self.dtype = dtype
-        self._fn_cache.pop("__fit_step__", None)
+        self._drop_fit_programs()
         return self
 
     def set_workspace_mode(self, mode) -> "SameDiff":
@@ -588,7 +603,7 @@ class SameDiff(_SentinelCounterMixin):
         to trade against)."""
         from ..nn import memory as _memory
         self.workspace_mode = _memory.resolve_policy(mode).name
-        self._fn_cache.pop("__fit_step__", None)
+        self._drop_fit_programs()
         return self
 
     def set_training_config(self, updater=None, l1: float = 0.0,
@@ -613,7 +628,7 @@ class SameDiff(_SentinelCounterMixin):
             "grad_norm": gradient_normalization,
             "grad_norm_threshold": float(gradient_normalization_threshold),
         }
-        self._fn_cache.pop("__fit_step__", None)
+        self._drop_fit_programs()
         return self
 
     def grad(self, feeds: Dict[str, Any],
@@ -838,24 +853,33 @@ class SameDiff(_SentinelCounterMixin):
     _SPEC_CAUSES = {4: "dtype_policy", 5: "workspace_mode", 6: "precision",
                     8: "fused_updater"}
 
-    def _fit_step_cached(self):
-        """The cached compiled fit step (built if absent/stale). ONE step
-        is kept across fit() calls — re-jitting a large imported graph per
-        call costs seconds (found fine-tuning BERT-base); old compiled
-        executables for big graphs are device memory worth releasing.
-        Every rebuild reports to the retrace tracker with the spec field
-        that changed as its cause — a silent retrace of a BERT-sized
-        import is exactly what ISSUE 6 makes visible."""
+    #: the two compiled programs of fit(), kept in ``_fn_cache`` under these
+    #: keys and dropped together
+    _FIT_PROGRAMS = ("__fit_step__", "__fit_prepare__")
+
+    def _drop_fit_programs(self):
+        """Release fit()'s compiled programs (the mutators of what
+        :meth:`_fit_spec` holds call this: an old executable of a big graph
+        is device memory)."""
+        for key in self._FIT_PROGRAMS:
+            self._fn_cache.pop(key, None)
+
+    def _fit_program_cached(self, key, site, build):
+        """``_fn_cache[key]``'s program if it was built for today's
+        :meth:`_fit_spec`, else ``build()``'s, kept in its place. ONE of
+        each is kept across fit() calls — re-jitting a large imported graph
+        per call costs seconds (found fine-tuning BERT-base). Every rebuild
+        reports to the retrace tracker under ``site`` with the spec field
+        that changed as its cause — a silent retrace of a BERT-sized import
+        is exactly what ISSUE 6 makes visible."""
         spec = self._fit_spec()
-        cached = self._fn_cache.get("__fit_step__")
+        cached = self._fn_cache.get(key)
         if cached is not None and cached[0] == spec:
             return cached[1]
-        step = self._make_fit_step()[1]
-        from ..runtime import telemetry as _tel
-        # the mutators (set_dtype/set_workspace_mode/...) pop the cache to
-        # release the old executable's device memory, so the cause diff
-        # runs against the last-built spec kept separately
-        prev_spec = getattr(self, "_last_fit_spec", None)
+        fn = build()
+        # the mutators pop the cache to release the old executable, so the
+        # cause diff runs against the last-built spec kept separately
+        prev_spec = self._last_fit_specs.get(key)
         if prev_spec is None:
             cause = "first_build"
         else:
@@ -863,16 +887,60 @@ class SameDiff(_SentinelCounterMixin):
                        if a != b]
             cause = next((self._SPEC_CAUSES[i] for i in changed
                           if i in self._SPEC_CAUSES), "config_change")
-        _tel.record_compile("samediff.fit_step", cause,
-                            loss=str(spec[1]))
-        # dispatch accounting rides the cache miss: ONE decision count per
-        # compiled step, not one per fit() call (mirrors the kernel-side
-        # fused_epilogues.dispatch discipline: zero silent fallbacks)
-        from ..ops import fused_epilogues as _fe
-        _fe.dispatch_updater(self.dtype)
-        self._fn_cache["__fit_step__"] = (spec, step)
-        self._last_fit_spec = spec
-        return step
+        _tel.record_compile(site, cause, loss=str(spec[1]))
+        self._fn_cache[key] = (spec, fn)
+        self._last_fit_specs[key] = spec
+        return fn
+
+    def _fit_step_cached(self):
+        """The cached compiled fit step (built if absent/stale)."""
+        def build():
+            # dispatch accounting rides the cache miss: ONE decision count
+            # per compiled step, not one per fit() call (mirrors the
+            # kernel-side fused_epilogues.dispatch discipline: zero silent
+            # fallbacks)
+            from ..ops import fused_epilogues as _fe
+            _fe.dispatch_updater(self.dtype)
+            return self._make_fit_step()[1]
+
+        return self._fit_program_cached("__fit_step__", "samediff.fit_step",
+                                        build)
+
+    def _fit_prepare_cached(self):
+        """The cached compiled preparation of one fit() call: ``(train_vals,
+        castable) -> (compute copies | None, cast castable, optimizer
+        state)``, where ``castable`` is :meth:`_castable`'s share of the
+        non-trainable values. It is :meth:`_fit_carry`,
+        :meth:`_cast_other_vals` and ``updater.init_state`` traced into ONE
+        program: done eagerly they are an ``astype`` a VARIABLE, one a
+        floating constant and a ``zeros_like`` a state leaf, some 670
+        dispatches a call for an imported BERT-base, during which the
+        device waits. The same XLA converts and broadcasts, so bit-equal.
+        The masters are arguments only: as results they would be copied."""
+        def build():
+            updater, fused = self.updater, self.fused_updater_active()
+
+            def prepare(train_vals, castable):
+                copies = self._fit_carry(train_vals)[1] if fused else None
+                return (copies, self._cast_other_vals(castable),
+                        updater.init_state(train_vals))
+            return jax.jit(prepare)
+
+        return self._fit_program_cached(
+            "__fit_prepare__", "samediff.fit_prepare", build)
+
+    def _castable(self, other_vals):
+        """The leaves of ``other_vals`` that :meth:`_cast_other_vals`
+        changes: only they go through the compiled preparation (a leaf that
+        a jitted function returns as it came is a copy)."""
+        from .. import dtypes as _dt
+        if not _dt.is_mixed(self.dtype):
+            return {}
+        cdt = np.dtype(_dt.resolve(self.dtype))
+        return {n: v for n, v in other_vals.items()
+                if getattr(v, "dtype", cdt) != cdt
+                and not getattr(v, "__quantized_tensor__", False)
+                and jnp.issubdtype(v.dtype, jnp.floating)}
 
     def fit(self, feeds_iter, epochs: int = 1, listeners: Optional[List] = None
             ) -> "History":
@@ -886,7 +954,6 @@ class SameDiff(_SentinelCounterMixin):
             raise ValueError("set_loss(...) and set_updater(...) first")
         from ..nn.caches import _TimedDispatch
         from ..runtime import faults as _faults
-        from ..runtime import telemetry as _tel
         # the train.phase.* spans of the engines' fit loops (nn/caches.py,
         # "phase tracing"): one call_s, and per feed stage_s, prepare_s,
         # step_s and readback_s (+ listeners_s where one is attached)
@@ -896,30 +963,42 @@ class SameDiff(_SentinelCounterMixin):
             feeds_list = [feeds_iter] if isinstance(feeds_iter, dict) \
                 else list(feeds_iter)
             with _tel.span("train.phase.prepare_s", span_labels):
-                train_names = [n for n, v in self._vars.items()
-                               if v.kind == VARIABLE]
-                updater = self.updater
                 step = self._fit_step_cached()
-                # fused master-cast carry (ISSUE 16): under a 16-bit policy
-                # the step carries (masters, compute_copies) — built ONCE
-                # here, then the fused updater re-emits the copies every
-                # step on-device
-                carry = self._fit_carry(
-                    {n: self._values[n] for n in train_names})
-                train_vals = self._carry_masters(carry)
-                # cast hoist (ISSUE 14 satellite): constants/frozen values
-                # go to the compute dtype ONCE here, not once per compiled
-                # step — self._values keeps the f32 originals (masters
-                # discipline)
-                other_vals = self._cast_other_vals(
-                    {n: v for n, v in self._values.items()
-                     if n not in train_names})
-                opt_state = updater.init_state(train_vals)
+                train_vals = {n: self._values[n]
+                              for n, v in self._vars.items()
+                              if v.kind == VARIABLE}
+                other_vals = {n: v for n, v in self._values.items()
+                              if n not in train_vals}
+                # ONE launch a call (_fit_prepare_cached): the fused
+                # master-cast carry's compute copies (ISSUE 16: built once
+                # here, the fused updater re-emits them every step
+                # on-device), the constants'/frozen values' cast to the
+                # compute dtype (ISSUE 14 satellite: once a call, not once
+                # a step; self._values keeps the f32 originals) and a fresh
+                # optimizer state
+                copies, cast, opt_state = self._fit_prepare_cached()(
+                    train_vals, self._castable(other_vals))
+                carry = train_vals if copies is None else (train_vals, copies)
+                other_vals.update(cast)
+                _FIT_PREPARE.inc(decision="compiled")
             cbs = list(self._listeners) + list(listeners or [])
+            # a listener reads score() and the published weights of ITS
+            # step, so with one attached each loss is read before the next
+            # launch; with none, nobody can tell, and the read of step k
+            # waits behind the launch of step k+1: the device goes from
+            # step to step without the host in between
+            _FIT_READBACK.inc(decision="per_step" if cbs else "deferred")
             history = History()
+
+            def read(loss):
+                with _tel.span("train.phase.readback_s", span_labels):
+                    loss = float(loss)
+                history.losses.append(loss)
+                self._score = loss
+
             i = self.iteration
+            pending = None  # the newest step's loss, where reads are deferred
             for _ in range(epochs):
-                epoch_losses = []
                 for feeds in feeds_list:
                     with _tel.span("train.phase.stage_s", span_labels):
                         feeds = {k: jnp.asarray(v) for k, v in feeds.items()}
@@ -937,21 +1016,19 @@ class SameDiff(_SentinelCounterMixin):
                                     k: jnp.full_like(v, jnp.nan)
                                     if jnp.issubdtype(v.dtype, jnp.floating)
                                     else v for k, v in feeds.items()}
-                        step_i = jnp.asarray(i, jnp.int32)
+                        # a host scalar of jnp.asarray(i, int32)'s aval: no
+                        # primitive is dispatched for it
+                        step_i = np.int32(i)
                         sentinel = self._ensure_sentinel()
                     with _TimedDispatch(span_labels, i):
                         carry, opt_state, self._sentinel, loss = step(
                             carry, opt_state, other_vals, step_i, feeds,
                             sentinel)
                     train_vals = self._carry_masters(carry)
-                    with _tel.span("train.phase.readback_s", span_labels):
-                        loss = float(loss)
-                    history.losses.append(loss)
-                    epoch_losses.append(loss)
-                    self._score = loss
                     i += 1
                     self.iteration = i
                     if cbs:
+                        read(loss)
                         with _tel.span("train.phase.listeners_s",
                                        span_labels):
                             # listeners may save/inspect: publish updated
@@ -959,14 +1036,22 @@ class SameDiff(_SentinelCounterMixin):
                             self._values.update(train_vals)
                             for cb in cbs:
                                 cb.iteration_done(self, i, self.epoch)
+                    else:
+                        if pending is not None:
+                            read(pending)
+                        pending = loss
                 self.epoch += 1
-                history.epoch_losses.append(
-                    sum(epoch_losses) / max(1, len(epoch_losses)))
                 if cbs:
                     with _tel.span("train.phase.listeners_s", span_labels):
                         self._values.update(train_vals)
                         for cb in cbs:
                             cb.on_epoch_end(self)
+            if pending is not None:
+                read(pending)
+            n = len(feeds_list)
+            history.epoch_losses = [
+                sum(history.losses[e * n:(e + 1) * n]) / max(1, n)
+                for e in range(epochs)]
             self._values.update(train_vals)
             # no cache clear: sessions/steps take values as ARGUMENTS, so
             # the updated weights flow through; only graph mutation (call())
@@ -1034,7 +1119,6 @@ class SameDiff(_SentinelCounterMixin):
             "device": _memory.device_memory_stats(),
         }
         from ..runtime import sentinel as _sent
-        from ..runtime import telemetry as _tel
         # sentinel counters included: accounts the REAL step fit() runs;
         # the accounting compile is attributed like every other probe
         _tel.record_compile("samediff.fit_step", "probe", batch=batch)

@@ -175,6 +175,38 @@ def test_samediff_fit_spans():
     assert rec.counts() == {"call_s": 1, "stage_s": 4, "prepare_s": 5,
                             "step_s": 4, "readback_s": 4}
     assert [e["step"] for e in rec.named("step_s")] == [0, 1, 2, 3]
+    # no listener: the loss of step k is read behind the launch of step k+1
+    # (ISSUE 34), so each readback_s but the last starts after the next
+    # step_s has ended
+    steps, reads = rec.named("step_s"), rec.named("readback_s")
+    for k in range(3):
+        assert reads[k]["t0_ns"] >= steps[k + 1]["t1_ns"]
+    assert reads[3]["t0_ns"] >= reads[2]["t1_ns"]
+
+
+def test_samediff_fit_spans_with_a_listener_keep_the_per_step_order():
+    """A listener reads score() and the weights of ITS step: each loss is
+    read before the next launch, as it always was."""
+    class Listener:
+        seen = []
+
+        def iteration_done(self, model, iteration, epoch):
+            self.seen.append((iteration, model.score()))
+
+        def on_epoch_end(self, model):
+            pass
+
+    sd = _samediff()
+    with _Recorded(sd) as rec:
+        hist = sd.fit(_feeds(4), listeners=[Listener()])
+    _assert_one_call_tree(rec, "SameDiff.fit")
+    assert rec.counts() == {"call_s": 1, "stage_s": 4, "prepare_s": 5,
+                            "step_s": 4, "readback_s": 4, "listeners_s": 5}
+    steps, reads = rec.named("step_s"), rec.named("readback_s")
+    for k in range(3):
+        assert steps[k]["t1_ns"] <= reads[k]["t0_ns"]
+        assert reads[k]["t1_ns"] <= steps[k + 1]["t0_ns"]
+    assert Listener.seen == list(zip([1, 2, 3, 4], hist.losses))
 
 
 # --------------------------------------------------------- ParallelWrapper
