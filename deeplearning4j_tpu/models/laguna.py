@@ -5,10 +5,9 @@ leading dense feed-forward, then sparse experts with a shared expert.
 
 ``laguna(config, ...)`` lays the stack out from the published keys
 ``layer_types``, ``mlp_layer_types`` and ``num_attention_heads_per_layer``
-as a :class:`ComputationGraph` on token ids. One decoder layer is six
-vertices (``l<i>.attn_norm``, ``.attn``, ``.attn_res``, ``.mlp_norm``,
-``.mlp``, ``.mlp_res``), so ``workspace_mode="every_6"`` recomputes one
-decoder layer at a time in the backward pass. ``held=(first, count)`` tells
+as a :class:`ComputationGraph` on token ids (the six-vertex layer layout of
+``models/decoder_stack.py``, so ``workspace_mode="every_6"`` recomputes one
+decoder layer at a time in the backward pass). ``held=(first, count)`` tells
 every sparse layer which of ``num_experts`` experts it holds (one chip's
 share of a layer that is divided over several); the router keeps its width.
 """
@@ -17,16 +16,10 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
-from ..nn.config import NeuralNetConfiguration
 from ..nn.graph import ComputationGraph
-from ..nn.layers.core import EmbeddingLayer
-from ..nn.layers.decoder import (CausalLMOutputLayer,
-                                 CausalSelfAttentionLayer, GatedDenseLayer,
-                                 RMSNormLayer, SparseExpertLayer)
-from ..nn.updaters import Adam
-from ..nn.vertices import ElementWiseVertex
-
-VERTICES_PER_LAYER = 6
+from ..nn.layers.decoder import (CausalSelfAttentionLayer, GatedDenseLayer,
+                                 SparseExpertLayer)
+from .decoder_stack import VERTICES_PER_LAYER, decoder_stack  # noqa: F401
 
 
 def attention_layer(config: dict, i: int) -> CausalSelfAttentionLayer:
@@ -58,40 +51,20 @@ def laguna(config: dict, seq_len: int, *,
     """The stack of ``config`` (the keys of the model's ``config.json``) for
     sequences of ``seq_len`` token ids, not yet initialised. ``held``: the
     experts every sparse layer holds (None: all)."""
-    eps = config["rms_norm_eps"]
-    b = (NeuralNetConfiguration.builder().seed(seed).data_type(dtype)
-         .updater(updater or Adam(learning_rate=1e-4, beta2=0.95)))
-    if workspace_mode:
-        b = b.workspace_mode(workspace_mode)
-    g = (b.graph_builder().add_inputs("tokens").set_input_types((seq_len,))
-         .add_layer("embed", EmbeddingLayer(n_in=config["vocab_size"],
-                                            n_out=config["hidden_size"]),
-                    "tokens"))
-    h = "embed"
-    for i in range(config["num_hidden_layers"]):
-        p = f"l{i}."
+    def mlp(i):
         if config["mlp_layer_types"][i] == "dense":
-            mlp = GatedDenseLayer(n_hidden=config["intermediate_size"])
-        else:
-            mlp = SparseExpertLayer(
-                num_experts=config["num_experts"],
-                top_k=config["num_experts_per_tok"],
-                n_hidden=config["moe_intermediate_size"],
-                shared_hidden=config.get("shared_expert_intermediate_size", 0),
-                held=held,
-                routed_scale=config.get("moe_routed_scaling_factor", 1.0))
-        g = (g.add_layer(p + "attn_norm", RMSNormLayer(eps=eps), h)
-             .add_layer(p + "attn", attention_layer(config, i),
-                        p + "attn_norm")
-             .add_vertex(p + "attn_res", ElementWiseVertex(op="add"),
-                         h, p + "attn")
-             .add_layer(p + "mlp_norm", RMSNormLayer(eps=eps), p + "attn_res")
-             .add_layer(p + "mlp", mlp, p + "mlp_norm")
-             .add_vertex(p + "mlp_res", ElementWiseVertex(op="add"),
-                         p + "attn_res", p + "mlp"))
-        h = p + "mlp_res"
-    g = (g.add_layer("norm", RMSNormLayer(eps=eps), h)
-         .add_layer("lm_head", CausalLMOutputLayer(n_out=config["vocab_size"]),
-                    "norm", "tokens")
-         .set_outputs("lm_head"))
-    return ComputationGraph(g.build())
+            return GatedDenseLayer(n_hidden=config["intermediate_size"])
+        return SparseExpertLayer(
+            num_experts=config["num_experts"],
+            top_k=config["num_experts_per_tok"],
+            n_hidden=config["moe_intermediate_size"],
+            shared_hidden=config.get("shared_expert_intermediate_size", 0),
+            held=held,
+            routed_scale=config.get("moe_routed_scaling_factor", 1.0))
+
+    return decoder_stack(
+        vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
+        n_layers=config["num_hidden_layers"], eps=config["rms_norm_eps"],
+        attention=lambda i: attention_layer(config, i), mlp=mlp,
+        seq_len=seq_len, updater=updater, dtype=dtype,
+        workspace_mode=workspace_mode, seed=seed)

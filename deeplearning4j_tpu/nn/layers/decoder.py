@@ -1,12 +1,14 @@
 """The layers of a causal decoder on token ids: RMSNorm, rotary grouped-query
-attention under a causal or sliding-window mask, a gated feed-forward, a
-sparse-expert feed-forward that is told which experts it holds, and the
-next-token loss head.
+attention under a causal or sliding-window mask, latent attention (keys and
+values from one low-rank projection, one rotary key for all heads), a gated
+feed-forward, a sparse-expert feed-forward that is told which experts it
+holds, and the next-token loss head.
 
 Every setting that differs between the layers of one stack (query heads,
 rotary share, base and scaling, mask) is a field of the layer, so a builder
 lays out full and window layers of different head counts from one class
-(``models/laguna.py``). Activations are ``[B, T, features]``.
+(``models/laguna.py``, ``models/kanana.py``). Activations are ``[B, T,
+features]``.
 """
 
 from __future__ import annotations
@@ -29,6 +31,12 @@ def _w(init, key, shape, dtype):
     return _winit.init(init, key, shape, shape[-2], shape[-1], dtype)
 
 
+def _rms_norm(x, g, eps):
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return y.astype(x.dtype) * g
+
+
 @layer("rms_norm")
 class RMSNormLayer(Layer):
     """``x / sqrt(mean(x^2) + eps) * g`` over the last axis, the statistics
@@ -42,10 +50,7 @@ class RMSNormLayer(Layer):
                 tuple(input_shape))
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
-        xf = x.astype(jnp.float32)
-        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
-                               + self.eps)
-        return y.astype(x.dtype) * params["g"], state, mask
+        return _rms_norm(x, params["g"], self.eps), state, mask
 
 
 @layer("causal_attention")
@@ -118,6 +123,78 @@ class CausalSelfAttentionLayer(Layer):
         return jnp.dot(o, params["Wo"]), state, mask
 
 
+@layer("latent_attention")
+class LatentAttentionLayer(Layer):
+    """Multi-head latent attention as DeepSeek-V3 trains it
+    (arXiv:2412.19437, section 2.1), without the query's low-rank
+    projection. Queries are ``n_heads x (nope_head_size + rope_head_size)``.
+    Keys and values come from ONE projection ``Wkva`` of width ``kv_rank +
+    rope_head_size``: the first ``kv_rank`` go through an RMSNorm of their
+    own (gain ``g_kv``, statistics in float32) and the up-projection
+    ``Wkvb`` to ``n_heads x (nope_head_size + v_head_size)``; the last
+    ``rope_head_size`` are one rotary key, rotated once and shared by every
+    head. Rotary pairs are the interleaved ``(2i, 2i + 1)`` at base
+    ``rope_theta``, no scaling. Scores over ``nope + rope`` channels scaled
+    by their root, values and output ``v_head_size`` a head, causal mask.
+    No biases. The scores are never materialised
+    (``ops/causal_attention.py``); the latent is expanded, not absorbed:
+    this is the training form, there is no cache."""
+    n_heads: int = 1
+    nope_head_size: int = 128
+    rope_head_size: int = 64
+    v_head_size: int = 128
+    kv_rank: int = 512
+    rope_theta: float = 10000.0
+    eps: float = 1e-6
+    weight_init: str = "xavier"
+    name: Optional[str] = None
+
+    def initialize(self, key, input_shape, dtype):
+        f, h = int(input_shape[-1]), self.n_heads
+        qk = self.nope_head_size + self.rope_head_size
+        ks = jax.random.split(key, 4)
+        wi = self.weight_init
+        return ({"Wq": _w(wi, ks[0], (f, h * qk), dtype),
+                 "Wkva": _w(wi, ks[1],
+                            (f, self.kv_rank + self.rope_head_size), dtype),
+                 "g_kv": jnp.ones((self.kv_rank,), dtype),
+                 "Wkvb": _w(wi, ks[2], (self.kv_rank, h * (
+                     self.nope_head_size + self.v_head_size)), dtype),
+                 "Wo": _w(wi, ks[3], (h * self.v_head_size, f), dtype)},
+                {}, tuple(input_shape))
+
+    def project(self, params, x):
+        """-> ``q`` ``[B, T, H, nope + rope]``, ``k`` the same, ``v`` ``[B,
+        T, H, v_head_size]``: the rotary part of ``k`` is the one shared
+        key, the same for every head."""
+        B, T, _ = x.shape
+        h, nope, rope = self.n_heads, self.nope_head_size, self.rope_head_size
+        q = jnp.dot(x, params["Wq"]).reshape(B, T, h, nope + rope)
+        ckv = jnp.dot(x, params["Wkva"])
+        latent = _rms_norm(ckv[..., :self.kv_rank], params["g_kv"], self.eps)
+        kv = jnp.dot(latent, params["Wkvb"]).reshape(
+            B, T, h, nope + self.v_head_size)
+        cos, sin = _ca.rotary_tables(
+            T, _ca.default_inv_freq(rope, self.rope_theta))
+        q_pe = _ca.apply_rotary(_ca.deinterleave(q[..., nope:]), cos, sin)
+        # one rotary key: rotated once, then read by every head
+        k_pe = _ca.apply_rotary(
+            _ca.deinterleave(ckv[..., self.kv_rank:])[:, :, None, :], cos,
+            sin)
+        q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_pe, (B, T, h, rope))],
+            axis=-1)
+        return q, k, kv[..., nope:]
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        with jax.named_scope("attn.latent.project"):
+            q, k, v = self.project(params, x)
+        o = _ca.causal_attention(q, k, v, kind="latent")
+        return (jnp.dot(o.reshape(o.shape[:2] + (-1,)), params["Wo"]),
+                state, mask)
+
+
 def _gated_ffn(x, w1, w3, w2, activation):
     return jnp.dot(_act.get(activation)(jnp.dot(x, w1)) * jnp.dot(x, w3), w2)
 
@@ -168,7 +245,11 @@ class SparseExpertLayer(Layer):
 
     The router scores every token against all ``num_experts`` (sigmoid, in
     float32), keeps the ``top_k`` largest and weights them ``routed_scale * s
-    / sum(s)``. Of the chosen experts this layer computes those in ``held =
+    / sum(s)``. With ``select_bias`` the ``top_k`` are taken by ``s + bias``
+    and weighted by their unbiased ``s``; the bias ``[num_experts]`` is
+    layer state (``state["select_bias"]``, float32, zeros until a checkpoint
+    or a balancing rule sets it): no gradient reaches it and no updater
+    sweeps it. Of the chosen experts this layer computes those in ``held =
     (first, count)``, gated feed-forwards of width ``n_hidden`` run as
     grouped products over the tokens routed to them with no token dropped
     (``ops/moe.py``), and adds a shared expert of width ``shared_hidden``
@@ -183,6 +264,7 @@ class SparseExpertLayer(Layer):
     shared_hidden: int = 0
     held: Optional[Tuple[int, int]] = None
     routed_scale: float = 1.0
+    select_bias: bool = False
     weight_init: str = "xavier"
     name: Optional[str] = None
 
@@ -211,6 +293,8 @@ class SparseExpertLayer(Layer):
                  "here": jnp.zeros((), jnp.uint32),
                  "elsewhere": jnp.zeros((), jnp.uint32),
                  "dropped": jnp.zeros((), jnp.uint32)}
+        if self.select_bias:
+            state["select_bias"] = jnp.zeros((self.num_experts,), jnp.float32)
         return params, state, tuple(input_shape)
 
     def chunk_rows(self, n_tokens: int) -> int:
@@ -226,7 +310,9 @@ class SparseExpertLayer(Layer):
         first, count = self._held()
         lead, f = x.shape[:-1], x.shape[-1]
         xt = x.reshape(-1, f)
-        top_e, w = _moe.route(xt, params["Wr"], self.top_k, self.routed_scale)
+        top_e, w = _moe.route(
+            xt, params["Wr"], self.top_k, self.routed_scale,
+            state["select_bias"] if self.select_bias else None)
         order, ends, tokens = _moe.plan(top_e, first, count)
         with jax.named_scope("moe.experts"):
             routed, done = _moe.held_experts(
@@ -238,9 +324,10 @@ class SparseExpertLayer(Layer):
                 y = y + _gated_ffn(xt, params["S1"], params["S3"],
                                    params["S2"], "swish")
             y = y.astype(x.dtype).reshape(lead + (f,))
-        if train and state:
+        if train and "tokens" in state:
             here = ends[-1].astype(jnp.uint32)
             state = {
+                **state,
                 "tokens": state["tokens"] + tokens.astype(jnp.uint32),
                 "here": state["here"] + here,
                 "elsewhere": state["elsewhere"]

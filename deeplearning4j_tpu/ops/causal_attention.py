@@ -8,8 +8,9 @@ one block's scores at a time. ``ops/flash_attention.py`` is left as it is:
 its kernels know neither a causal nor a window mask nor grouped KV heads,
 and the encoder path that runs on them must not move.
 
-Layout: ``q`` ``[B, T, H, d]``, ``k`` / ``v`` ``[B, T, KV, d]``; query head
-``i`` reads KV head ``i // (H // KV)``.
+Layout: ``q`` ``[B, T, H, d]``, ``k`` ``[B, T, KV, d]``, ``v`` ``[B, T, KV,
+dv]``; query head ``i`` reads KV head ``i // (H // KV)``. ``dv`` need not be
+``d`` (latent attention scores over 192 and carries values of 128).
 """
 
 from __future__ import annotations
@@ -85,6 +86,16 @@ def apply_rotary(x, cos, sin):
     return out.astype(x.dtype)
 
 
+def deinterleave(x):
+    """The last axis' pairs ``(2i, 2i + 1)`` moved to ``(i, i + n / 2)``, the
+    order :func:`apply_rotary` pairs: how HF's DeepSeek-V3 reads
+    ``rope_interleave``. Scores do not depend on the channel order as long
+    as queries and keys share it."""
+    n = x.shape[-1]
+    return x.reshape(x.shape[:-1] + (n // 2, 2)).swapaxes(-1, -2) \
+        .reshape(x.shape)
+
+
 # ---------------------------------------------------------------- attention
 def _softmax(s):
     """Softmax over the last axis with the row's maximum and sum held
@@ -115,19 +126,30 @@ def _block(q, k, v, q0: int, k0: int, window: Optional[int]):
     return jnp.einsum("...gqk,...kd->...gqd", p.astype(v.dtype), v)
 
 
-def _rows_full(q, k, v, block: int, window: Optional[int]):
+def _rows_full(q, k, v, block: int, window: Optional[int],
+               cut_inside: bool = False):
     """``q`` ``[G, T, d]``, ``k`` / ``v`` ``[T, d]``: query blocks one after
     another, each over the keys from the first block its mask reaches to its
-    own."""
+    own. What a block's ``jax.checkpoint`` is handed is what the backward
+    pass keeps of it: its own slices of the row or, with ``cut_inside``, the
+    whole row, cut inside the checkpoint. Slices are new arrays, kept once a
+    block (4.5x the row at 8 causal blocks); the whole row is the caller's
+    own array and is kept once."""
     T = q.shape[1]
     outs = []
     for q0 in range(0, T, block):
         k0 = 0 if window is None else \
             max(0, (q0 - window + 1) // block * block)
         k1 = q0 + block
-        fn = jax.checkpoint(
-            lambda a, b, c, q0=q0, k0=k0: _block(a, b, c, q0, k0, window))
-        outs.append(fn(q[:, q0:k1], k[k0:k1], v[k0:k1]))
+        if cut_inside:
+            fn = jax.checkpoint(
+                lambda a, b, c, q0=q0, k0=k0, k1=k1: _block(
+                    a[:, q0:k1], b[k0:k1], c[k0:k1], q0, k0, window))
+            outs.append(fn(q, k, v))
+        else:
+            fn = jax.checkpoint(
+                lambda a, b, c, q0=q0, k0=k0: _block(a, b, c, q0, k0, window))
+            outs.append(fn(q[:, q0:k1], k[k0:k1], v[k0:k1]))
     return jnp.concatenate(outs, axis=1)
 
 
@@ -139,7 +161,7 @@ def _rows_window(q, k, v, block: int, window: int):
     nb = T // block
 
     def pair(a):
-        a = a.reshape(nb, block, d)
+        a = a.reshape(nb, block, a.shape[-1])
         before = jnp.concatenate([jnp.zeros_like(a[:1]), a[:-1]], axis=0)
         return jnp.concatenate([before, a], axis=1)            # [nb, 2b, d]
 
@@ -151,26 +173,28 @@ def _rows_window(q, k, v, block: int, window: int):
         return _block(qi, ki, vi, i * block, (i - 1) * block, window)
 
     out = jax.checkpoint(jax.vmap(one))(qb, pair(k), pair(v), jnp.arange(nb))
-    return out.transpose(1, 0, 2, 3).reshape(G, T, d)
+    return out.transpose(1, 0, 2, 3).reshape(G, T, v.shape[-1])
 
 
 def causal_attention(q, k, v, *, window: Optional[int] = None,
-                     block: int = 1024):
+                     block: int = 1024, kind: Optional[str] = None):
     """softmax(q k^T / sqrt(d) + mask) v with a causal mask and, with
     ``window``, key ``j`` open to query ``i`` only where ``i - window < j <=
-    i``. -> ``[B, T, H, d]``. A sequence no longer than ``block`` (or not a
-    multiple of it) is one block."""
+    i``. -> ``[B, T, H, dv]``. A sequence no longer than ``block`` (or not a
+    multiple of it) is one block. ``kind`` names the site in the
+    ``attention.dispatch`` counter and the ``attn.<kind>`` scope (default:
+    ``full`` or ``window``, by the mask)."""
     B, T, H, d = q.shape
-    KV = k.shape[2]
+    KV, dv = k.shape[2], v.shape[-1]
     if H % KV:
         raise ValueError(f"{H} query heads do not divide over {KV} KV heads")
     G = H // KV
-    kind = "full" if window is None else "window"
+    kind = kind or ("full" if window is None else "window")
     if window is not None:
         block = min(block, max(window, 128))
-    qg = q.reshape(B, T, KV, G, d).transpose(0, 2, 3, 1, 4)    # [B,KV,G,T,d]
-    kg, vg = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)  # [B,KV,T,d]
     with jax.named_scope(f"attn.{kind}"):
+        qg = q.reshape(B, T, KV, G, d).transpose(0, 2, 3, 1, 4)  # [B,KV,G,T,d]
+        kg, vg = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)  # [B,KV,T,d]
         if T <= block or T % block:
             _DISPATCH.inc(kind=kind, decision="one_block")
             out = _block(qg, kg, vg, 0, 0, window)
@@ -179,9 +203,18 @@ def causal_attention(q, k, v, *, window: Optional[int] = None,
                 _DISPATCH.inc(kind=kind, decision="blocked_pairs")
                 rows = lambda a: _rows_window(*a, block, window)
             else:
+                # one query head a KV head (latent attention's expanded
+                # keys): as many rows as heads, and each row's key and value
+                # slices kept again for every block would be 1.5 GB a layer
+                # at 32 heads of 192 + 128 over 8,192 positions. PROVISIONAL:
+                # `G == 1` only keeps the grouped-head cell's program what it
+                # was when the whole-row form came; nobody has measured that
+                # form for G > 1 (ROADMAP R4: try it for all G first, and
+                # delete the slice form and `cut_inside` if memory falls and
+                # time holds)
                 _DISPATCH.inc(kind=kind, decision="blocked_rows")
-                rows = lambda a: _rows_full(*a, block, window)
+                rows = lambda a: _rows_full(*a, block, window, G == 1)
             flat = lambda a: a.reshape((B * KV,) + a.shape[2:])
             out = jax.lax.map(rows, (flat(qg), flat(kg), flat(vg)))
-            out = out.reshape(B, KV, G, T, d)
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, T, H, d)
+            out = out.reshape(B, KV, G, T, dv)
+        return out.transpose(0, 3, 1, 2, 4).reshape(B, T, H, dv)

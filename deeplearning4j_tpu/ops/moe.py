@@ -19,16 +19,34 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..runtime import telemetry as _tel
 
-def route(x, w_router, top_k: int, scale: float):
+_ROUTE = _tel.counter(
+    "moe.route", "router sites by what the top-k is taken on: the scores "
+    "(plain) or the scores plus a selection bias (biased), once a traced "
+    "site")
+
+
+def route(x, w_router, top_k: int, scale: float, select_bias=None):
     """Sigmoid scores in float32 over all experts, the ``top_k`` largest per
-    token, weights ``scale * s / sum(s)`` over the chosen.
+    token, weights ``scale * s / sum(s)`` over the chosen. With
+    ``select_bias`` ``[experts]`` (DeepSeek-V3's ``noaux_tc``) the ``top_k``
+    are taken by ``s + select_bias`` and weighted by their unbiased ``s``:
+    the bias steers the load and never scales an expert's output, and no
+    gradient reaches it.
     -> (expert ids ``[N, k]`` int32, weights ``[N, k]`` float32)."""
+    _ROUTE.inc(select="plain" if select_bias is None else "biased")
     with jax.named_scope("moe.route"):
         s = jax.nn.sigmoid(jnp.dot(x, w_router,
                                    preferred_element_type=jnp.float32))
-        top_s, top_e = jax.lax.top_k(s, top_k)
-        w = scale * top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+        if select_bias is None:
+            top_s, top_e = jax.lax.top_k(s, top_k)
+            w = scale * top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+        else:
+            _, top_e = jax.lax.top_k(s + select_bias, top_k)
+            top_s = jnp.take_along_axis(s, top_e, axis=-1)
+            w = scale * top_s / (jnp.sum(top_s, axis=-1, keepdims=True)
+                                 + 1e-20)
         return top_e.astype(jnp.int32), w
 
 

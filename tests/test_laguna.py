@@ -417,3 +417,34 @@ def test_reference_layer_in_chunks_equals_one_chunk(monkeypatch, i):
     for leaf in g1[0]:
         if leaf.startswith(f"l{i}."):
             close(g2[0][leaf], g1[0][leaf], tol=1e-5)
+
+
+def test_the_shared_layout_lists_the_vertices_and_leaves_laguna_had(world):
+    """``laguna()`` over ``models/decoder_stack.py``: vertex names, order,
+    kinds and inputs as they were written out before the layout was shared
+    (PR 31), every leaf's shape as the reference's table has it, and no
+    selection bias in any expert layer's state."""
+    net = world["net"]
+    want = [("embed", "embedding", ["tokens"])]
+    below = "embed"
+    for i, mlp in enumerate(["gated_dense"] + ["sparse_experts"] * 4):
+        p = f"l{i}."
+        want += [(p + "attn_norm", "rms_norm", [below]),
+                 (p + "attn", "causal_attention", [p + "attn_norm"]),
+                 (p + "attn_res", "elementwise", [below, p + "attn"]),
+                 (p + "mlp_norm", "rms_norm", [p + "attn_res"]),
+                 (p + "mlp", mlp, [p + "mlp_norm"]),
+                 (p + "mlp_res", "elementwise", [p + "attn_res", p + "mlp"])]
+        below = p + "mlp_res"
+    want += [("norm", "rms_norm", [below]),
+             ("lm_head", "causal_lm_output", ["norm", "tokens"])]
+    got = [(n, v.layer.kind if hasattr(v, "layer") else v.kind, list(ins))
+           for n, v, ins in net.conf.vertices]
+    assert got == want
+    assert net._topo == [n for n, _, _ in want]
+    assert {k: tuple(v.shape) for k, v in flat(net.params).items()} == \
+        {n: tuple(s) for n, s, _ in ref.layer_table(world["cfg"])}
+    assert {k: sorted(s) for k, s in net.state.items()} == {
+        f"l{i}.mlp": ["dropped", "elsewhere", "here", "tokens"]
+        for i in range(1, LAYERS)}
+    assert VERTICES_PER_LAYER == 6

@@ -249,6 +249,27 @@ def test_blocked_causal_attention_compiles(one_chip, heads, window):
     assert not re.search(rf"\[(\d+,)*{T},{T}\]", text)
 
 
+def test_blocked_latent_attention_compiles(one_chip):
+    """kanana-2's latent attention as the blocked path sees it: 32 heads,
+    one query head a KV head, scores over 192 channels beside values of 128,
+    forward and backward, with no [T, T] array anywhere in the program."""
+    from deeplearning4j_tpu.ops import causal_attention as ca
+    T = 4096
+
+    def loss(q, k, v):
+        return jnp.sum(ca.causal_attention(q, k, v, kind="latent")
+                       .astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct(s, BF16, sharding=one_chip)
+            for s in ((1, T, 32, 192), (1, T, 32, 192), (1, T, 32, 128))]
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args) \
+        .compile()
+    text = compiled.as_text()
+    assert not re.search(rf"\[(\d+,)*{T},{T}\]", text)
+    # a row's keys are kept once, not once a block: no stacked slice of them
+    assert not re.search(rf"bf16\[32,{T - 1024},192\]", text)
+
+
 def test_held_experts_compile_as_grouped_products(one_chip):
     """16 experts of 2048 x 512 held, 8 of 256 chosen a token: the chunk
     loop with the compiler's ragged-dot kernels, forward and backward."""
