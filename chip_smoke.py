@@ -64,6 +64,9 @@ class Sizes:
     # token; sharpened, the tokens vary, so equal tokens mean something
     gen_sharpen: float = 100.0
     flash_shapes: tuple = ((32, 12, 128, 64), (8, 12, 512, 64))
+    causal_shape: tuple = (1, 4096, 12, 2, 128)   # B, T, q heads, KV heads, d
+    causal_window: int = 512
+    causal_block: int = 1024                   # the XLA path's
     decode_shape: tuple = (8, 12, 1024, 64)    # B, H, cache, d
     page: int = 16
     verify_window: int = 4
@@ -443,6 +446,38 @@ def kernels_phase(sz: Sizes, interpret: bool = False):
                                     argnums=(0, 1, 2)))(q, k, v)
             for g, w, n in zip(got, want, "qkv"):
                 close(f"{name} d{n}", g, w, 4e-2)
+
+    # masked attention over grouped KV heads: the kernels the dispatcher
+    # takes on the chip against the blocked XLA path it takes elsewhere
+    from deeplearning4j_tpu.ops import causal_attention as ca
+    from deeplearning4j_tpu.runtime import telemetry as tel
+    B, T, H, KV, d = sz.causal_shape
+    q, k, v = arr((B, T, H, d)), arr((B, T, KV, d)), arr((B, T, KV, d))
+    for window, kind in ((None, "full"), (sz.causal_window, "window")):
+        def both(mode):
+            # a function of its own for each mode: jit would hand a second
+            # wrapper of the same function the first one's trace
+            def attend(q, k, v):
+                return ca.causal_attention(q, k, v, window=window,
+                                           block=sz.causal_block)
+
+            old = fa.set_mode(mode)
+            try:
+                return (jax.jit(attend)(q, k, v),) + jax.jit(jax.grad(
+                    lambda *a: total(attend(*a)), argnums=(0, 1, 2)))(q, k, v)
+            finally:
+                fa.set_mode(old)
+
+        counter = tel.registry.get("attention.dispatch")
+        before = counter.value(kind=kind, decision="kernel")
+        got, want = both("force" if interpret else "auto"), both("off")
+        took = counter.value(kind=kind, decision="kernel") - before
+        say(f"  attention.dispatch{{kind={kind},decision=kernel}} +{took}")
+        if took < 1:
+            raise AssertionError(f"{kind} attention did not take the kernel")
+        name = f"causal {kind} {[B, T, H, d]} over {KV} KV heads"
+        for g, w, n in zip(got, want, ("fwd", "dq", "dk", "dv")):
+            close(f"{name} {n}", g, w, 2e-2 if n == "fwd" else 4e-2)
 
     B, H, C, d = sz.decode_shape
     q1, kc, vc = arr((B, H, 1, d)), arr((B, H, C, d)), arr((B, H, C, d))

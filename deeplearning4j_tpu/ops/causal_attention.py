@@ -1,12 +1,17 @@
 """Causal and sliding-window attention over grouped KV heads without a
 ``[T, T]`` score matrix, and the rotary embeddings that go with it.
 
-A blocked XLA path, not a kernel: queries are cut into blocks, each block
-sees only the key blocks its mask leaves open (whole masked blocks are never
-computed), and every block is a ``jax.checkpoint`` so the backward pass holds
-one block's scores at a time. ``ops/flash_attention.py`` is left as it is:
-its kernels know neither a causal nor a window mask nor grouped KV heads,
-and the encoder path that runs on them must not move.
+Two paths, and :func:`causal_attention` counts which a traced site took
+(``attention.dispatch``). On a TPU a sequence that tiles goes to the blocked
+Pallas kernels of ``ops/flash_attention.py`` under a static
+:class:`~.flash_attention.BlockMask` (:func:`causal_flash`): scores stay in
+VMEM, a block pair the mask closes is neither computed nor fetched, the
+query heads of a KV head read its rows through the index map, and the
+backward keeps the output and one ``[rows, T]`` logsumexp. Everywhere else
+(the CPU in ``auto``, a GSPMD-partitioned trace, a sequence of one block) a
+blocked XLA path: queries are cut into blocks, each block sees only the key
+blocks its mask leaves open, and every block is a ``jax.checkpoint`` so the
+backward pass holds one block's scores at a time.
 
 Layout: ``q`` ``[B, T, H, d]``, ``k`` ``[B, T, KV, d]``, ``v`` ``[B, T, KV,
 dv]``; query head ``i`` reads KV head ``i // (H // KV)``. ``dv`` need not be
@@ -15,6 +20,7 @@ dv]``; query head ``i`` reads KV head ``i // (H // KV)``. ``dv`` need not be
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -22,6 +28,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import flash_attention as _fa
+from .pallas_kernels import (available as _tpu_available,
+                             partitioned as _partitioned)
 from ..runtime import telemetry as _tel
 
 _NEG = -1e30
@@ -126,30 +135,23 @@ def _block(q, k, v, q0: int, k0: int, window: Optional[int]):
     return jnp.einsum("...gqk,...kd->...gqd", p.astype(v.dtype), v)
 
 
-def _rows_full(q, k, v, block: int, window: Optional[int],
-               cut_inside: bool = False):
+def _rows_full(q, k, v, block: int, window: Optional[int]):
     """``q`` ``[G, T, d]``, ``k`` / ``v`` ``[T, d]``: query blocks one after
     another, each over the keys from the first block its mask reaches to its
-    own. What a block's ``jax.checkpoint`` is handed is what the backward
-    pass keeps of it: its own slices of the row or, with ``cut_inside``, the
-    whole row, cut inside the checkpoint. Slices are new arrays, kept once a
-    block (4.5x the row at 8 causal blocks); the whole row is the caller's
-    own array and is kept once."""
+    own. A block's ``jax.checkpoint`` is handed the whole row, the caller's
+    own array, and cuts its slices inside, so the backward pass keeps the
+    row once and no slice of it (as new arrays the slices were 4.5x the row
+    at 8 causal blocks)."""
     T = q.shape[1]
     outs = []
     for q0 in range(0, T, block):
         k0 = 0 if window is None else \
             max(0, (q0 - window + 1) // block * block)
         k1 = q0 + block
-        if cut_inside:
-            fn = jax.checkpoint(
-                lambda a, b, c, q0=q0, k0=k0, k1=k1: _block(
-                    a[:, q0:k1], b[k0:k1], c[k0:k1], q0, k0, window))
-            outs.append(fn(q, k, v))
-        else:
-            fn = jax.checkpoint(
-                lambda a, b, c, q0=q0, k0=k0: _block(a, b, c, q0, k0, window))
-            outs.append(fn(q[:, q0:k1], k[k0:k1], v[k0:k1]))
+        fn = jax.checkpoint(
+            lambda a, b, c, q0=q0, k0=k0, k1=k1: _block(
+                a[:, q0:k1], b[k0:k1], c[k0:k1], q0, k0, window))
+        outs.append(fn(q, k, v))
     return jnp.concatenate(outs, axis=1)
 
 
@@ -176,14 +178,218 @@ def _rows_window(q, k, v, block: int, window: int):
     return out.transpose(1, 0, 2, 3).reshape(G, T, v.shape[-1])
 
 
+# ------------------------------------------------------------------- kernel
+#: what a grid step costs, in pairs of (query, key): about 0.35 us, the time
+#: the products and the softmax of a 256 x 256 tile take (PERF.md, PR 36)
+_STEP_PAIRS = 256 * 256
+_BLOCKS = (1024, 512, 256, 128)
+
+
+def causal_blocks(t: int, d: int, dv: int, window: Optional[int],
+                  itemsize: int = 2):
+    """(block_q, block_k) for the masked kernels, from the shape and the
+    mask: of the multiples of 128 that divide ``t`` and fit VMEM, the pair
+    that costs least in pairs computed (every block the mask leaves open is
+    computed whole) plus grid steps (the closed ones are skipped, not free).
+    ``flash_attention.default_blocks`` wants the most keys that fit, which
+    under a causal mask are mostly closed pairs. None where nothing tiles."""
+    def cost(blocks):
+        mask = _fa.BlockMask(*blocks, t, window)
+        return (mask.open_blocks() * mask.bq * mask.bk
+                + mask.nq * mask.key_span * _STEP_PAIRS)
+
+    fit = [(bq, bk) for bq in _BLOCKS for bk in _BLOCKS
+           if t % bq == 0 and t % bk == 0
+           and _fa.fits_vmem_attention(bq, bk, max(d, dv), itemsize)]
+    return min(fit, key=cost, default=None)
+
+
+def _kv_map(mask, group: int):
+    """Index map of a key or value block under the (query rows, query
+    blocks, key span) grid: query row ``b`` reads KV row ``b // group``, and
+    grid position ``j`` is key block ``first_key(i) + j``. Past the last
+    block a query block reaches the index stays put, so the pipeline fetches
+    nothing for a step that computes nothing."""
+    def kv(b, i, j):
+        return (jax.lax.div(b, group),
+                jnp.minimum(mask.first_key(i) + j, mask.last_key(i)), 0)
+    return kv
+
+
+def _params(pltpu, mask, d, dv, dtype):
+    return _fa._compiler_params(pltpu, vmem_bytes=_fa.vmem_bytes_attention(
+        mask.bq, mask.bk, max(d, dv), np.dtype(dtype).itemsize))
+
+
+def _fwd_call(q3, k3, v3, mask, group, scale, interpret):
+    pl, pltpu = _fa._load_pallas()
+    R, T, d = q3.shape
+    dv = v3.shape[-1]
+    bq, bk = mask.bq, mask.bk
+    kv = _kv_map(mask, group)
+    return pl.pallas_call(
+        functools.partial(_fa._fwd_kernel, scale=scale, nk=mask.key_span,
+                          has_bias=False, mask=mask),
+        grid=(R, mask.nq, mask.key_span),
+        in_specs=[pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                  pl.BlockSpec((1, bk, d), kv),
+                  pl.BlockSpec((1, bk, dv), kv)],
+        out_shape=(jax.ShapeDtypeStruct((R, T, dv), q3.dtype),
+                   jax.ShapeDtypeStruct((R, 1, T), jnp.float32)),
+        out_specs=(pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
+                   pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))),
+        scratch_shapes=[pltpu.VMEM((bq, _fa._LANES), jnp.float32),
+                        pltpu.VMEM((bq, _fa._LANES), jnp.float32),
+                        pltpu.VMEM((bq, dv), jnp.float32)],
+        compiler_params=_params(pltpu, mask, d, dv, q3.dtype),
+        interpret=interpret,
+        name="causal_flash_fwd",
+    )(q3, k3, v3)
+
+
+def _bwd_call(q3, k3, v3, lse, di, do, mask, group, scale, interpret):
+    pl, pltpu = _fa._load_pallas()
+    R, T, d = q3.shape
+    dv = v3.shape[-1]
+    bq, bk = mask.bq, mask.bk
+    params = _params(pltpu, mask, d, dv, q3.dtype)
+    kv = _kv_map(mask, group)
+    by_q = lambda b, i, j: (b, i, 0)
+    row = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
+    dq = pl.pallas_call(
+        functools.partial(_fa._bwd_dq_kernel, scale=scale, nk=mask.key_span,
+                          has_bias=False, mask=mask),
+        grid=(R, mask.nq, mask.key_span),
+        in_specs=[pl.BlockSpec((1, bq, d), by_q),
+                  pl.BlockSpec((1, bk, d), kv),
+                  pl.BlockSpec((1, bk, dv), kv),
+                  row, row,
+                  pl.BlockSpec((1, bq, dv), by_q)],
+        out_shape=jax.ShapeDtypeStruct((R, T, d), q3.dtype),
+        out_specs=pl.BlockSpec((1, bq, d), by_q),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                        pltpu.VMEM((bq, _fa._LANES), jnp.float32),
+                        pltpu.VMEM((bq, _fa._LANES), jnp.float32)],
+        compiler_params=params,
+        interpret=interpret,
+        name="causal_flash_bwd_dq",
+    )(q3, k3, v3, lse, di, do)
+
+    # dk/dv grid: key blocks outer; inside, the group's heads and under each
+    # the query blocks that reach the key block (the reduction axis)
+    span = mask.query_span
+
+    def head(b, t):
+        return b * group + jax.lax.div(t, span)
+
+    def block(j, t):
+        return jnp.minimum(mask.first_query(j) + jax.lax.rem(t, span),
+                           mask.last_query(j))
+
+    by_qt = lambda b, j, t: (head(b, t), block(j, t), 0)
+    row_t = pl.BlockSpec((1, 1, bq), lambda b, j, t: (head(b, t), 0,
+                                                       block(j, t)))
+    by_k = lambda b, j, t: (b, j, 0)
+    dk, dv_ = pl.pallas_call(
+        functools.partial(_fa._bwd_dkv_masked_kernel, scale=scale, mask=mask,
+                          group=group),
+        grid=(R // group, mask.nk, group * span),
+        in_specs=[pl.BlockSpec((1, bq, d), by_qt),
+                  pl.BlockSpec((1, bk, d), by_k),
+                  pl.BlockSpec((1, bk, dv), by_k),
+                  row_t, row_t,
+                  pl.BlockSpec((1, bq, dv), by_qt)],
+        out_shape=(jax.ShapeDtypeStruct(k3.shape, k3.dtype),
+                   jax.ShapeDtypeStruct(v3.shape, v3.dtype)),
+        out_specs=(pl.BlockSpec((1, bk, d), by_k),
+                   pl.BlockSpec((1, bk, dv), by_k)),
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, dv), jnp.float32)],
+        compiler_params=params,
+        interpret=interpret,
+        name="causal_flash_bwd_dkv",
+    )(q3, k3, v3, lse, di, do)
+    return dq, dk, dv_
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q3, k3, v3, mask, group, scale, interpret):
+    return _fwd_call(q3, k3, v3, mask, group, scale, interpret)[0]
+
+
+def _flash_fwd(q3, k3, v3, mask, group, scale, interpret):
+    o, lse = _fwd_call(q3, k3, v3, mask, group, scale, interpret)
+    return o, (q3, k3, v3, o, lse)
+
+
+def _flash_bwd(mask, group, scale, interpret, res, do):
+    q3, k3, v3, o, lse = res
+    di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    return _bwd_call(q3, k3, v3, lse, di[:, None, :], do, mask, group, scale,
+                     interpret)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def causal_flash(q, k, v, *, window: Optional[int] = None, blocks=None,
+                 interpret: bool = False):
+    """The masked kernels on ``q`` ``[B, H, T, d]``, ``k`` ``[B, KV, T, d]``,
+    ``v`` ``[B, KV, T, dv]`` -> ``[B, H, T, dv]``. ``blocks``: (block_q,
+    block_k), multiples of 128 that divide ``T`` (default:
+    :func:`causal_blocks`). Raises ValueError where nothing tiles: callers go
+    through :func:`causal_attention` for guarded dispatch."""
+    B, H, T, d = q.shape
+    KV, dv = k.shape[1], v.shape[-1]
+    blocks = blocks or causal_blocks(T, d, dv, window,
+                                     np.dtype(q.dtype).itemsize)
+    if blocks is None or T % blocks[0] or T % blocks[1]:
+        raise ValueError(f"a sequence of {T} does not tile into {blocks}")
+    o = _flash(q.reshape(B * H, T, d), k.reshape(B * KV, T, d),
+               v.reshape(B * KV, T, dv), _fa.BlockMask(*blocks, T, window),
+               H // KV, 1.0 / math.sqrt(d), bool(interpret))
+    return o.reshape(B, H, T, dv)
+
+
+def _xla_reason(q, group: int, T: int, d: int, dv: int,
+                window: Optional[int]):
+    """Why a sequence that tiles goes to the XLA path all the same, or None
+    where the kernels take it."""
+    if _partitioned() is not None:
+        return "gspmd"
+    if _fa.mode() == "off":
+        return "mode"
+    if _fa.mode() != "force" and not _tpu_available():
+        return "platform"
+    if q.dtype not in _fa._FUSABLE_DTYPES or T % _BLOCKS[-1]:
+        return "shape"
+    if causal_blocks(T, d, dv, window, np.dtype(q.dtype).itemsize) is None:
+        return "vmem"
+    if group == 1 and _fa.mode() != "force":
+        # one query head a KV head: XLA's blocks are [1, block, keys] there
+        # and run their products at twice the rate of its grouped blocks.
+        # On a v5e at 8,192 positions, forward + backward of 64 rows: XLA
+        # 37.9 ms against the kernels' 40.9 at a scored width of 128, 42.0
+        # against 59.0 at 192 (which fills one and a half MXU tiles); with 8
+        # query heads a KV head 84.4 against 37.4 and 83.9 against 54.4
+        # (PERF.md, PR 36)
+        return "ungrouped"
+    return None
+
+
 def causal_attention(q, k, v, *, window: Optional[int] = None,
                      block: int = 1024, kind: Optional[str] = None):
     """softmax(q k^T / sqrt(d) + mask) v with a causal mask and, with
     ``window``, key ``j`` open to query ``i`` only where ``i - window < j <=
-    i``. -> ``[B, T, H, dv]``. A sequence no longer than ``block`` (or not a
-    multiple of it) is one block. ``kind`` names the site in the
-    ``attention.dispatch`` counter and the ``attn.<kind>`` scope (default:
-    ``full`` or ``window``, by the mask)."""
+    i``. -> ``[B, T, H, dv]``. ``block`` is the XLA path's: a sequence no
+    longer than it (or not a multiple of it) is one block there
+    (``decision=one_block``); one that tiles takes the kernels
+    (``decision=kernel``; ``flash_attention.set_mode`` is the switch, and
+    ``force`` runs them in interpret mode off the chip) or the blocked XLA
+    path with the reason (``decision=blocked_rows | blocked_pairs``,
+    ``why=platform | mode | gspmd | shape | vmem | ungrouped``). ``kind``
+    names the site in the ``attention.dispatch`` counter and the
+    ``attn.<kind>`` scope (default: ``full`` or ``window``, by the mask)."""
     B, T, H, d = q.shape
     KV, dv = k.shape[2], v.shape[-1]
     if H % KV:
@@ -193,27 +399,26 @@ def causal_attention(q, k, v, *, window: Optional[int] = None,
     if window is not None:
         block = min(block, max(window, 128))
     with jax.named_scope(f"attn.{kind}"):
+        tiles = T > block and T % block == 0
+        why = _xla_reason(q, G, T, d, dv, window) if tiles else None
+        if tiles and why is None:
+            _DISPATCH.inc(kind=kind, decision="kernel")
+            heads_first = lambda a: a.transpose(0, 2, 1, 3)
+            out = causal_flash(heads_first(q), heads_first(k), heads_first(v),
+                               window=window, interpret=_fa._interpret())
+            return heads_first(out)
         qg = q.reshape(B, T, KV, G, d).transpose(0, 2, 3, 1, 4)  # [B,KV,G,T,d]
         kg, vg = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)  # [B,KV,T,d]
-        if T <= block or T % block:
+        if not tiles:
             _DISPATCH.inc(kind=kind, decision="one_block")
             out = _block(qg, kg, vg, 0, 0, window)
         else:
             if window is not None and window <= block:
-                _DISPATCH.inc(kind=kind, decision="blocked_pairs")
+                _DISPATCH.inc(kind=kind, decision="blocked_pairs", why=why)
                 rows = lambda a: _rows_window(*a, block, window)
             else:
-                # one query head a KV head (latent attention's expanded
-                # keys): as many rows as heads, and each row's key and value
-                # slices kept again for every block would be 1.5 GB a layer
-                # at 32 heads of 192 + 128 over 8,192 positions. PROVISIONAL:
-                # `G == 1` only keeps the grouped-head cell's program what it
-                # was when the whole-row form came; nobody has measured that
-                # form for G > 1 (ROADMAP R4: try it for all G first, and
-                # delete the slice form and `cut_inside` if memory falls and
-                # time holds)
-                _DISPATCH.inc(kind=kind, decision="blocked_rows")
-                rows = lambda a: _rows_full(*a, block, window, G == 1)
+                _DISPATCH.inc(kind=kind, decision="blocked_rows", why=why)
+                rows = lambda a: _rows_full(*a, block, window)
             flat = lambda a: a.reshape((B * KV,) + a.shape[2:])
             out = jax.lax.map(rows, (flat(qg), flat(kg), flat(vg)))
             out = out.reshape(B, KV, G, T, dv)

@@ -26,7 +26,11 @@ done by hand. This module is that hand fusion:
     recomputes p = exp(s - m)/l per tile (two kernels: dq, and dk/dv),
     saving only the per-row logsumexp — carried as its two pieces (running
     max m, running sum l) so a finfo.min mask bias can't absorb log(l) —
-    plus the output, for di = sum(o*do).
+    plus the output, for di = sum(o*do). The forward and dq bodies also run
+    under a static :class:`BlockMask` (causal, or causal with a window) for
+    ``ops/causal_attention.py``, with a dk/dv body of their own that sums
+    over the query heads of a KV head: a block pair the mask closes is
+    skipped, and the statistics leave as one compact logsumexp row.
 - :func:`reference_attention` — the quadratic einsum path, scores upcast to
   f32 before softmax (matching the kernel's f32 accumulators; this is also
   the numerics fix for the layers' bf16 dtype policy).
@@ -60,7 +64,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -112,6 +116,118 @@ def _lanes(x, n):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
+class BlockMask(NamedTuple):
+    """A causal mask over a (query block, key block) grid, static: key ``j``
+    is open to query ``i`` where ``i - window < j <= i`` (``window`` None: no
+    lower edge). What the blocked kernels ask of it is which blocks a block
+    reaches, so that a pair the mask closes wholly is neither computed nor
+    fetched, and whether the mask cuts through a pair, so that only those pay
+    for the ``where``. The block arguments are Python ints (the tiling rule
+    counts with them) or the kernels' and index maps' traced grid positions."""
+    bq: int
+    bk: int
+    t: int
+    window: Optional[int] = None
+
+    @property
+    def nq(self) -> int:
+        return self.t // self.bq
+
+    @property
+    def nk(self) -> int:
+        return self.t // self.bk
+
+    def first_key(self, i):
+        """The first key block query block ``i`` reaches."""
+        if self.window is None:
+            return 0 * i
+        return _at_least_0(i * self.bq - self.window + 1) // self.bk
+
+    def last_key(self, i):
+        return (i * self.bq + self.bq - 1) // self.bk
+
+    def first_query(self, j):
+        """The first query block that reaches key block ``j``."""
+        return j * self.bk // self.bq
+
+    def last_query(self, j):
+        if self.window is None:
+            return 0 * j + self.nq - 1
+        last = (j * self.bk + self.bk + self.window - 2) // self.bq
+        return _at_most(last, self.nq - 1)
+
+    @property
+    def key_span(self) -> int:
+        """The most key blocks any query block reaches: the key axis of a
+        grid whose position ``j`` is key block ``first_key(i) + j``."""
+        return max(self.last_key(i) - self.first_key(i) + 1
+                   for i in range(self.nq))
+
+    @property
+    def query_span(self) -> int:
+        return max(self.last_query(j) - self.first_query(j) + 1
+                   for j in range(self.nk))
+
+    def open_blocks(self) -> int:
+        """Pairs of blocks with an open pair inside: the grid steps that
+        compute."""
+        return sum(self.last_key(i) - self.first_key(i) + 1
+                   for i in range(self.nq))
+
+    def cuts(self, i, j):
+        """Whether a pair inside (query block ``i``, key block ``j``) is
+        closed. The pair is taken to hold an open one."""
+        cut = j * self.bk + self.bk - 1 > i * self.bq
+        if self.window is not None:
+            cut |= j * self.bk <= i * self.bq + self.bq - 1 - self.window
+        return cut
+
+    def open(self, i, j, transposed: bool = False):
+        """The tile of (query block ``i``, key block ``j``), ``[bq, bk]`` or
+        transposed ``[bk, bq]``: True where the pair is open."""
+        shape = (self.bk, self.bq) if transposed else (self.bq, self.bk)
+        q_axis = 1 if transposed else 0
+        ahead = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis) \
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis) \
+            + (i * self.bq - j * self.bk)         # query - key position
+        ok = ahead >= 0
+        if self.window is not None:
+            ok &= ahead < self.window
+        return ok
+
+
+def _at_least_0(x):
+    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
+
+
+def _at_most(x, cap: int):
+    return min(x, cap) if isinstance(x, int) else jnp.minimum(x, cap)
+
+
+def _masked_steps(mask: Optional[BlockMask], i, j, step):
+    """Run ``step(cut)`` for the pair (query block ``i``, key block ``j``):
+    always without a mask; under one only where the pair holds an open
+    pair, with ``cut`` saying whether it needs the ``where``."""
+    if mask is None:
+        step(False)
+        return
+    run = (j >= mask.first_key(i)) & (j <= mask.last_key(i)) \
+        & (i < mask.nq)
+    cut = mask.cuts(i, j)
+    pl.when(run & cut)(functools.partial(step, True))
+    pl.when(run & jnp.logical_not(cut))(functools.partial(step, False))
+
+
+def _as_row(col):
+    """``[rows, _LANES]`` lane-replicated -> ``[1, rows]``."""
+    return jnp.transpose(col)[:1]
+
+
+def _as_column(row):
+    """``[1, rows]`` -> ``[rows, _LANES]`` lane-replicated."""
+    return jnp.transpose(jnp.broadcast_to(row, (_LANES, row.shape[1])))
+
+
 def _scores(q_ref, k_ref, bias_ref, scale):
     s = jax.lax.dot_general(
         q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
@@ -121,14 +237,23 @@ def _scores(q_ref, k_ref, bias_ref, scale):
     return s
 
 
-def _fwd_kernel(*refs, scale, nk, has_bias):
+def _fwd_kernel(*refs, scale, nk, has_bias, mask: Optional[BlockMask] = None):
+    """Under a ``mask`` the key axis of the grid spans only the blocks a
+    query block reaches (position ``j`` is key block ``first_key(i) + j``),
+    and the statistics leave as one compact row, the logsumexp ``[1, bq]``:
+    a causal row always has its own key open, so its maximum is a score and
+    cannot absorb ``log(l)`` as a mask bias can."""
+    bias_ref = None
     if has_bias:
         (q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
          m_scr, l_scr, acc_scr) = refs
+    elif mask is not None:
+        q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     else:
         q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
-        bias_ref = None
-    j = pl.program_id(2)
+    i, j = pl.program_id(1), pl.program_id(2)
+    kb = j if mask is None else mask.first_key(i) + j
+    d = acc_scr.shape[1]
 
     @pl.when(j == 0)
     def _init():
@@ -136,23 +261,31 @@ def _fwd_kernel(*refs, scale, nk, has_bias):
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    s = _scores(q_ref, k_ref, bias_ref, scale)             # [bq, bk] f32
-    m_prev, l_prev = m_scr[...], l_scr[...]                # [bq, LANES]
-    m_curr = jnp.max(s, axis=1, keepdims=True)             # [bq, 1]
-    m_next = jnp.maximum(m_prev, m_curr)                   # [bq, LANES]
-    alpha = jnp.exp(m_prev - m_next)
-    p = jnp.exp(s - _lanes(m_next, s.shape[1]))            # [bq, bk]
-    m_scr[...] = m_next
-    l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    d = acc_scr.shape[1]
-    acc_scr[...] = acc_scr[...] * _lanes(alpha, d) + jax.lax.dot(
-        p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32)
+    def step(cut):
+        s = _scores(q_ref, k_ref, bias_ref, scale)         # [bq, bk] f32
+        if cut:
+            s = jnp.where(mask.open(i, kb), s, _NEG)
+        m_prev, l_prev = m_scr[...], l_scr[...]            # [bq, LANES]
+        m_curr = jnp.max(s, axis=1, keepdims=True)         # [bq, 1]
+        m_next = jnp.maximum(m_prev, m_curr)               # [bq, LANES]
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(s - _lanes(m_next, s.shape[1]))        # [bq, bk]
+        m_scr[...] = m_next
+        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * _lanes(alpha, d) + jax.lax.dot(
+            p.astype(v_ref.dtype), v_ref[0],
+            preferred_element_type=jnp.float32)
+
+    _masked_steps(mask, i, kb, step)
 
     @pl.when(j == nk - 1)
     def _finish():
         l_fin = l_scr[...]
         safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
         o_ref[0] = (acc_scr[...] / _lanes(safe, d)).astype(o_ref.dtype)
+        if mask is not None:
+            lse_ref[0] = _as_row(m_scr[...] + jnp.log(safe))
+            return
         # the softmax stats are saved as SEPARATE max + sum (the logsumexp
         # in two pieces): m + log(l) would absorb log(l) entirely when m is
         # a finfo.min mask bias (ulp(3e38) >> log l), and the backward's
@@ -162,29 +295,50 @@ def _fwd_kernel(*refs, scale, nk, has_bias):
         l_ref[0] = safe
 
 
-def _bwd_dq_kernel(*refs, scale, nk, has_bias):
+def _bwd_dq_kernel(*refs, scale, nk, has_bias,
+                   mask: Optional[BlockMask] = None):
+    """Under a ``mask``: the grid of :func:`_fwd_kernel`, and the compact
+    ``[1, bq]`` logsumexp and ``di`` rows turned into columns once a query
+    block."""
+    bias_ref = None
     if has_bias:
         (q_ref, k_ref, v_ref, bias_ref, m_ref, l_ref, di_ref, do_ref,
          dq_ref, dq_scr) = refs
+    elif mask is not None:
+        (q_ref, k_ref, v_ref, lse_ref, di_ref, do_ref,
+         dq_ref, dq_scr, lse_scr, di_scr) = refs
     else:
         (q_ref, k_ref, v_ref, m_ref, l_ref, di_ref, do_ref,
          dq_ref, dq_scr) = refs
-        bias_ref = None
-    j = pl.program_id(2)
+    i, j = pl.program_id(1), pl.program_id(2)
+    kb = j if mask is None else mask.first_key(i) + j
 
     @pl.when(j == 0)
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+        if mask is not None:
+            lse_scr[...] = _as_column(lse_ref[0])
+            di_scr[...] = _as_column(di_ref[0])
 
-    s = _scores(q_ref, k_ref, bias_ref, scale)
-    bk = s.shape[1]
-    p = jnp.exp(s - _lanes(m_ref[0], bk)) * _lanes(1.0 / l_ref[0], bk)
-    dp = jax.lax.dot_general(                               # do @ v^T
-        do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    ds = p * (dp - _lanes(di_ref[0], bk)) * scale           # [bq, bk] f32
-    dq_scr[...] += jax.lax.dot(ds.astype(k_ref.dtype), k_ref[0],
-                               preferred_element_type=jnp.float32)
+    def step(cut):
+        s = _scores(q_ref, k_ref, bias_ref, scale)
+        bk = s.shape[1]
+        if mask is None:
+            p = jnp.exp(s - _lanes(m_ref[0], bk)) * _lanes(1.0 / l_ref[0], bk)
+            di = di_ref[0]
+        else:
+            if cut:
+                s = jnp.where(mask.open(i, kb), s, _NEG)
+            p = jnp.exp(s - _lanes(lse_scr[...], bk))
+            di = di_scr[...]
+        dp = jax.lax.dot_general(                           # do @ v^T
+            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = p * (dp - _lanes(di, bk)) * scale              # [bq, bk] f32
+        dq_scr[...] += jax.lax.dot(ds.astype(k_ref.dtype), k_ref[0],
+                                   preferred_element_type=jnp.float32)
+
+    _masked_steps(mask, i, kb, step)
 
     @pl.when(j == nk - 1)
     def _finish():
@@ -222,6 +376,50 @@ def _bwd_dkv_kernel(*refs, scale, nq, has_bias):
         preferred_element_type=jnp.float32)
 
     @pl.when(jq == nq - 1)
+    def _finish():
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _bwd_dkv_masked_kernel(q_ref, k_ref, v_ref, lse_ref, di_ref, do_ref,
+                           dk_ref, dv_ref, dk_scr, dv_scr, *, scale,
+                           mask: BlockMask, group: int):
+    """dk and dv of one key block under a ``mask``, summed over the query
+    blocks that reach it and over the ``group`` query heads that read its KV
+    head: grid position ``t`` of the inner axis is head ``t // span`` and
+    query block ``first_query(j) + t % span``. The tile is held TRANSPOSED,
+    ``[bk, bq]``, as in :func:`_bwd_row_kernel`: the compact ``[1, bq]``
+    logsumexp and ``di`` rows broadcast down the keys as they are, and dv =
+    p^T do and dk = ds^T q are plain products."""
+    j, t = pl.program_id(1), pl.program_id(2)
+    span = mask.query_span
+    i = mask.first_query(j) + jax.lax.rem(t, span)
+
+    @pl.when(t == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
+        dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    def step(cut):
+        q, do = q_ref[0], do_ref[0]
+        s = jax.lax.dot_general(                            # k @ q^T
+            k_ref[0], q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale     # [bk, bq] f32
+        if cut:
+            s = jnp.where(mask.open(i, j, transposed=True), s, _NEG)
+        p = jnp.exp(s - lse_ref[0])
+        dv_scr[...] += jax.lax.dot(p.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(                           # v @ do^T
+            v_ref[0], do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = p * (dp - di_ref[0]) * scale
+        dk_scr[...] += jax.lax.dot(ds.astype(q.dtype), q,
+                                   preferred_element_type=jnp.float32)
+
+    _masked_steps(mask, i, j, step)
+
+    @pl.when(t == group * span - 1)
     def _finish():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)

@@ -32,6 +32,7 @@ def tiny(smoke):
         gen_width=32, gen_heads=2, gen_cache=32, gen_slots=2,
         gen_prompt_lens=(5, 9), gen_new_tokens=4,
         flash_shapes=((2, 2, 32, 16),), decode_shape=(2, 2, 64, 16),
+        causal_shape=(1, 256, 4, 2, 16), causal_window=100, causal_block=128,
         page=8, verify_window=4, ln_shape=(32, 128),
         affine_shape=(64, 128), lstm_shape=(8, 16), dp_batch=8, dp_steps=2,
         dp_tol=0.5, lr=1e-3)

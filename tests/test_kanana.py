@@ -272,9 +272,10 @@ def test_values_narrower_than_the_scored_width(window, decision):
             q, k, v, window=window, block=block, kind="latent")
 
     counter = tel.registry.get("attention.dispatch")
-    before = counter.value(kind="latent", decision=decision)
+    labels = dict(kind="latent", decision=decision, why="platform")
+    before = counter.value(**labels)
     blocked = run(8)(q, k, v)
-    assert counter.value(kind="latent", decision=decision) == before + 1
+    assert counter.value(**labels) == before + 1
     assert blocked.shape == (2, 32, 4, 6)
     close(blocked, run(32)(q, k, v), tol=1e-5)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(12.0)

@@ -350,11 +350,12 @@ def test_blocked_attention_equals_one_block(window, decision):
         return lambda q, k, v: ca.causal_attention(q, k, v, window=window,
                                                    block=block)
 
-    before = tel.registry.get("attention.dispatch").value(
-        kind="full" if window is None else "window", decision=decision)
+    # a sequence that tiles and goes to XLA says why: off the chip, in `auto`
+    labels = dict(kind="full" if window is None else "window",
+                  decision=decision, why="platform")
+    before = tel.registry.get("attention.dispatch").value(**labels)
     close(run(8)(q, k, v), run(32)(q, k, v), tol=1e-5)
-    after = tel.registry.get("attention.dispatch").value(
-        kind="full" if window is None else "window", decision=decision)
+    after = tel.registry.get("attention.dispatch").value(**labels)
     assert after == before + 1
     for arg in range(3):
         g = lambda block: jax.grad(

@@ -229,12 +229,69 @@ def test_affine_act_compiles(one_chip, grad):
              kernels=("affine_act_bwd",) if grad else ("affine_act_fwd",))
 
 
+#: the decoder cells' attention layers at their published widths:
+#: (q heads, KV heads, scored width, value width, window, kind)
+CAUSAL_LAYOUTS = {
+    "full48": (48, 8, 128, 128, None, "full"),          # laguna_xs2
+    "window64": (64, 8, 128, 128, 512, "window"),       # laguna_xs2
+    "latent32": (32, 32, 192, 128, None, "latent"),     # kanana2_30b_a3b
+}
+
+
+@pytest.mark.parametrize("layout", list(CAUSAL_LAYOUTS))
+def test_causal_flash_compiles(one_chip, monkeypatch, layout):
+    """The masked kernels as the cells' steps reach them, two sequences of
+    8,192, forward and backward through ``causal_attention`` with a TPU in
+    sight: the three kernels under their names, and nothing in the program
+    that is [T, T] or a [rows, 1024, T] block of scores. The grouped layouts
+    take them in ``auto``; one query head a KV head goes to XLA there
+    (``why=ungrouped``) and compiles them under ``force``."""
+    from deeplearning4j_tpu.ops import causal_attention as ca
+    from deeplearning4j_tpu.runtime import telemetry as tel
+    H, KV, d, dv, window, kind = CAUSAL_LAYOUTS[layout]
+    B, T = 2, 8192
+    monkeypatch.setattr(ca, "_tpu_available", lambda: True)
+    monkeypatch.setattr(fa, "_tpu_available", lambda: True)
+    counter = tel.registry.get("attention.dispatch")
+
+    def loss(q, k, v):
+        return jnp.sum(jnp.sin(ca.causal_attention(
+            q, k, v, window=window, kind=kind).astype(jnp.float32)))
+
+    avals = (((B, T, H, d), BF16), ((B, T, KV, d), BF16),
+             ((B, T, KV, dv), BF16))
+    if H == KV:
+        went = dict(kind=kind, decision="blocked_rows", why="ungrouped")
+        before = counter.value(**went)
+        jax.eval_shape(loss, *(jax.ShapeDtypeStruct(*a) for a in avals))
+        assert counter.value(**went) == before + 1
+        monkeypatch.setattr(fa, "_state", {"mode": "force"})
+    before = counter.value(kind=kind, decision="kernel")
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *avals,
+                    kernels=("causal_flash_fwd", "causal_flash_bwd_dq",
+                             "causal_flash_bwd_dkv"))
+    assert counter.value(kind=kind, decision="kernel") == before + 1
+    assert not re.search(rf"\[(\d+,)*{T},{T}\]", text)
+    assert not re.search(rf"f32\[(\d+,)*1024,{T}\]", text)
+    # the statistics the backward keeps: one compact row a (head, sequence)
+    assert f"f32[{B * H},1,{T}]" in text
+
+
+@pytest.fixture
+def xla_attention():
+    """The blocked XLA path, whatever the platform: ``off``."""
+    old = fa.set_mode("off")
+    yield
+    fa.set_mode(old)
+
+
 @pytest.mark.parametrize("heads,window", [(48, None), (64, 512)],
                          ids=["full48", "window64"])
-def test_blocked_causal_attention_compiles(one_chip, heads, window):
+def test_blocked_causal_attention_compiles(one_chip, xla_attention, heads,
+                                           window):
     """Laguna-XS.2's two attention layers at their published head counts over
-    8 KV heads of 128: the blocked XLA path, forward and backward, with no
-    [T, T] array anywhere in the program."""
+    8 KV heads of 128: the blocked XLA path (the kernels' fallback), forward
+    and backward, with no [T, T] array anywhere in the program."""
     from deeplearning4j_tpu.ops import causal_attention as ca
     T = 4096
 
@@ -249,8 +306,8 @@ def test_blocked_causal_attention_compiles(one_chip, heads, window):
     assert not re.search(rf"\[(\d+,)*{T},{T}\]", text)
 
 
-def test_blocked_latent_attention_compiles(one_chip):
-    """kanana-2's latent attention as the blocked path sees it: 32 heads,
+def test_blocked_latent_attention_compiles(one_chip, xla_attention):
+    """kanana-2's latent attention as the blocked XLA path sees it: 32 heads,
     one query head a KV head, scores over 192 channels beside values of 128,
     forward and backward, with no [T, T] array anywhere in the program."""
     from deeplearning4j_tpu.ops import causal_attention as ca
