@@ -35,6 +35,7 @@ same contract as MultiLayerNetwork.
 
 from __future__ import annotations
 
+import dataclasses
 import json
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -57,6 +58,32 @@ from .model import _get_path, _param_paths, _set_path
 from .vertices import GraphVertex, LayerVertex
 
 
+_LOOP_PASSES = _tel.counter(
+    "loop.passes", "passes walked of a repeated run of vertices, by graph "
+    "and run: the run's times a launched training step")
+
+
+@dataclasses.dataclass(frozen=True)
+class RepeatedRun:
+    """A stretch of vertices the walk applies ``times`` times with one set
+    of weights (resolved from ``ComputationGraphConfiguration.repeats``):
+    ``carry`` names the one activation the stretch reads from outside, and
+    on every pass after the first that name stands for the previous pass's
+    ``output`` (the stretch's last vertex). ``name`` is what a later vertex
+    reads to get every pass's output stacked ``[times, batch, ...]``."""
+    name: str
+    times: int
+    carry: str
+    output: str
+    vertices: Tuple[str, ...]
+
+
+def _scan_passes(body, x0, times: int):
+    """``body`` applied ``times`` times from ``x0`` as one ``lax.scan``:
+    -> (the last pass's output, every pass's output stacked)."""
+    return jax.lax.scan(body, x0, None, length=times)
+
+
 class ComputationGraphConfiguration:
     """Immutable DAG description (the thing that serializes)."""
 
@@ -71,7 +98,8 @@ class ComputationGraphConfiguration:
                  gradient_normalization_threshold: float = 1.0,
                  tbptt_length: Optional[int] = None,
                  constraints: Any = None,
-                 workspace_mode: str = "none"):
+                 workspace_mode: str = "none",
+                 repeats: Optional[Sequence[Dict[str, Any]]] = None):
         self.inputs = list(inputs)
         self.outputs = list(outputs)
         self.vertices = list(vertices)  # [(name, vertex, [input names])]
@@ -92,15 +120,24 @@ class ComputationGraphConfiguration:
         from . import memory as _memory
         _memory.resolve_policy(workspace_mode)  # validate at build time
         self.workspace_mode = str(workspace_mode).strip().lower()
+        # runs of vertices applied several times with one set of weights:
+        # [{"name", "first", "last", "times"}], see GraphBuilder.repeat
+        self.repeats = [
+            {"name": str(r["name"]), "first": str(r["first"]),
+             "last": str(r["last"]), "times": int(r["times"])}
+            for r in (repeats or [])]
         self._validate()
+        self._runs = self._resolve_repeats()
 
     def _validate(self):
         names = set(self.inputs)
+        stacked = {r["name"] for r in self.repeats}
         for name, v, ins in self.vertices:
             if name in names:
                 raise ValueError(f"duplicate vertex name {name!r}")
             for i in ins:
-                if i not in names and i not in {n for n, _, _ in self.vertices}:
+                if i not in names and i not in stacked and \
+                        i not in {n for n, _, _ in self.vertices}:
                     raise ValueError(
                         f"vertex {name!r} input {i!r} is not a network input "
                         "or a declared vertex")
@@ -108,14 +145,138 @@ class ComputationGraphConfiguration:
         for o in self.outputs:
             if o not in names:
                 raise ValueError(f"output {o!r} is not a declared vertex")
+        for k, r in enumerate(self.repeats):
+            name = r["name"]
+            if name in names or name in {x["name"]
+                                         for x in self.repeats[:k]}:
+                raise ValueError(f"repeated run {name!r}: the name is taken")
+            if r["times"] < 1:
+                raise ValueError(
+                    f"repeated run {name!r}: times={r['times']}")
+            for end in (r["first"], r["last"]):
+                if end not in names or end in self.inputs:
+                    raise ValueError(f"repeated run {name!r}: {end!r} is "
+                                     "not a declared vertex")
+
+    def _resolve_repeats(self) -> List["RepeatedRun"]:
+        """Check every repeated run against the graph and -> the runs in
+        the order the walk meets them. What a run cannot be is refused here,
+        at build time: see :meth:`GraphBuilder.repeat`."""
+        if not self.repeats:
+            return []
+        declared = [n for n, _, _ in self.vertices]
+        reads = {n: list(ins) for n, _, ins in self.vertices}
+        topo = self.topo_order()
+        taken: Dict[str, str] = {}
+        runs = []
+        for r in self.repeats:
+            name, times = r["name"], r["times"]
+            lo, hi = declared.index(r["first"]), declared.index(r["last"])
+            if lo > hi:
+                raise ValueError(f"repeated run {name!r}: {r['last']!r} is "
+                                 f"declared before {r['first']!r}")
+            inside = declared[lo:hi + 1]
+            for n in inside:
+                if n in taken:
+                    raise ValueError(f"vertex {n!r} is in the repeated runs "
+                                     f"{taken[n]!r} and {name!r}")
+                taken[n] = name
+            at = sorted(topo.index(n) for n in inside)
+            if at != list(range(at[0], at[0] + len(inside))):
+                raise ValueError(
+                    f"repeated run {name!r}: its vertices are not one "
+                    "stretch of the topological order")
+            members = set(inside)
+            carried = {i for n in inside for i in reads[n]
+                       if i not in members}
+            if len(carried) != 1:
+                raise ValueError(
+                    f"repeated run {name!r} reads {sorted(carried)} from "
+                    "outside: a run has one input, the activation it "
+                    "carries from pass to pass")
+            for n, ins in reads.items():
+                if n in members:
+                    continue
+                for i in ins:
+                    if i in members and i != r["last"]:
+                        raise ValueError(
+                            f"vertex {n!r} reads {i!r} inside the repeated "
+                            f"run {name!r}: outside it only the last pass "
+                            f"({r['last']!r}) and the stacked passes "
+                            f"({name!r}) can be read")
+            for o in self.outputs:
+                if o in members and o != r["last"]:
+                    raise ValueError(
+                        f"network output {o!r} lies inside the repeated "
+                        f"run {name!r}")
+            runs.append(RepeatedRun(
+                name=name, times=times, carry=carried.pop(),
+                output=r["last"],
+                vertices=tuple(n for n in topo if n in members)))
+        runs.sort(key=lambda x: topo.index(x.vertices[0]))
+        if self.input_shapes and set(self.input_shapes) >= set(self.inputs):
+            self._check_repeated_vertices(runs, topo)
+        return runs
+
+    def _check_repeated_vertices(self, runs, topo):
+        """With the input types known, initialise every vertex on avals
+        (nothing is allocated) and refuse a run whose output is not shaped
+        as its input, or that holds a vertex the walk cannot repeat: one
+        with layer state (a pass would update it) or one that draws random
+        numbers (the passes would share a key)."""
+        by_vertex = {n: r for r in runs for n in r.vertices}
+        vmap = {n: (v, ins) for n, v, ins in self.vertices}
+        found: Dict[str, Any] = {}
+
+        def walk(key):
+            shapes = {k: tuple(v) for k, v in self.input_shapes.items()}
+            for r in runs:
+                shapes[r.name] = None
+            for n in topo:
+                v, ins = vmap[n]
+                _, state, out = v.initialize(
+                    key, [shapes[i] for i in ins], jnp.float32)
+                shapes[n] = tuple(out)
+                found[n] = bool(state)
+                r = by_vertex.get(n)
+                if r is not None and n == r.output:
+                    shapes[r.name] = (r.times,) + shapes[n]
+            found["shapes"] = shapes
+            return 0
+
+        jax.eval_shape(walk, jax.random.PRNGKey(0))
+        shapes = found["shapes"]
+        for r in runs:
+            for n in r.vertices:
+                if found[n]:
+                    raise ValueError(
+                        f"vertex {n!r} keeps layer state and lies inside "
+                        f"the repeated run {r.name!r}: every pass would "
+                        "update it, and a run walks stateless vertices only")
+                if vmap[n][0].stochastic:
+                    raise ValueError(
+                        f"vertex {n!r} draws random numbers and lies inside "
+                        f"the repeated run {r.name!r}: the passes would "
+                        "share its key, and a run walks deterministic "
+                        "vertices only")
+            if shapes[r.output] != shapes[r.carry]:
+                raise ValueError(
+                    f"repeated run {r.name!r}: its output {r.output!r} is "
+                    f"shaped {shapes[r.output]}, its input {r.carry!r} "
+                    f"{shapes[r.carry]}; a pass must hand on what it took")
 
     def topo_order(self) -> List[str]:
-        """Kahn topological order over vertex names (inputs excluded)."""
-        ins = {name: set(i for i in inp if i not in self.inputs)
+        """Kahn topological order over vertex names (inputs excluded). The
+        stacked passes of a repeated run exist once its last vertex has
+        run."""
+        done_by = {r["name"]: r["last"] for r in self.repeats}
+        ins = {name: set(done_by.get(i, i) for i in inp
+                         if i not in self.inputs)
                for name, _, inp in self.vertices}
         dependents: Dict[str, List[str]] = {}
         for name, _, inp in self.vertices:
-            for i in set(inp):  # dedupe: a vertex may consume an input twice
+            # dedupe: a vertex may consume an input twice
+            for i in set(done_by.get(i, i) for i in inp):
                 dependents.setdefault(i, []).append(name)
         ready = [n for n, deps in ins.items() if not deps]
         order: List[str] = []
@@ -148,6 +309,7 @@ class ComputationGraphConfiguration:
             "tbptt_length": self.tbptt_length,
             "constraints": _constraints.encode_constraints(self.constraints),
             "workspace_mode": self.workspace_mode,
+            "repeats": self.repeats,
             "network_inputs": self.inputs,
             "network_outputs": self.outputs,
             "input_shapes": {k: list(v) for k, v in self.input_shapes.items()},
@@ -174,7 +336,8 @@ class ComputationGraphConfiguration:
                 "gradient_normalization_threshold", 1.0),
             tbptt_length=d.get("tbptt_length"),
             constraints=_constraints.decode_constraints(d.get("constraints")),
-            workspace_mode=d.get("workspace_mode", "none"))
+            workspace_mode=d.get("workspace_mode", "none"),
+            repeats=d.get("repeats"))
 
 
 class GraphBuilder:
@@ -187,6 +350,7 @@ class GraphBuilder:
         self._outputs: List[str] = []
         self._vertices: List[Tuple[str, GraphVertex, List[str]]] = []
         self._input_shapes: Dict[str, Tuple[int, ...]] = {}
+        self._repeats: List[Dict[str, Any]] = []
 
     def add_inputs(self, *names: str) -> "GraphBuilder":
         self._inputs.extend(names)
@@ -211,6 +375,30 @@ class GraphBuilder:
 
     def add_vertex(self, name: str, vertex: GraphVertex, *inputs: str) -> "GraphBuilder":
         self._vertices.append((name, vertex, list(inputs)))
+        return self
+
+    def repeat(self, name: str, first: str, last: str,
+               times: int) -> "GraphBuilder":
+        """Mark the vertices declared from ``first`` to ``last`` as one run
+        that the walk applies ``times`` times with ONE set of weights. The
+        run has one input, the single activation its vertices read from
+        outside; on every later pass that name stands for the previous
+        pass's ``last``. Outside the run ``last`` reads the final pass and
+        ``name`` reads every pass's output stacked ``[times, batch, ...]``;
+        no other vertex of the run can be read from outside. Parameters,
+        updater state, ``num_params()`` and a checkpoint hold each weight
+        once, and its gradient is the sum over the passes.
+        ``workspace_mode="every_<k>"`` segments the run's vertices inside a
+        pass, so the backward pass keeps the carried activation at every
+        segment boundary of every pass and recomputes the rest.
+
+        Refused at ``build()``: a pass whose output is not shaped as its
+        input, a vertex inside that keeps layer state (BatchNorm's
+        statistics, an expert layer's counts: every pass would update it)
+        or draws random numbers (the passes would share its key), a second
+        outside input, and a reader of the run's inside."""
+        self._repeats.append({"name": name, "first": first, "last": last,
+                              "times": int(times)})
         return self
 
     def set_outputs(self, *names: str) -> "GraphBuilder":
@@ -240,7 +428,8 @@ class GraphBuilder:
                 b._grad_norm_threshold if b else 1.0),
             tbptt_length=b._tbptt if b else None,
             constraints=(b._constraints or None) if b else None,
-            workspace_mode=b._workspace_mode if b else "none")
+            workspace_mode=b._workspace_mode if b else "none",
+            repeats=self._repeats)
 
 
 class ComputationGraph(_caches.CompiledCacheMixin):
@@ -259,6 +448,14 @@ class ComputationGraph(_caches.CompiledCacheMixin):
         self._vertex_map: Dict[str, Tuple[GraphVertex, List[str]]] = {
             n: (v, ins) for n, v, ins in conf.vertices}
         self._topo = conf.topo_order()
+        # the repeated run every vertex of one lies in: the walk enters a
+        # run at its first vertex and skips the rest
+        self._run_of: Dict[str, RepeatedRun] = {
+            n: r for r in conf._runs for n in r.vertices}
+        if conf._runs:
+            import weakref
+            weakref.finalize(self, _tel.registry.discard_cells,
+                             graph=self.telemetry_label)
         self.params: Dict[str, Dict[str, jax.Array]] = {}
         self.state: Dict[str, Dict[str, jax.Array]] = {}
         self.updater_state: Any = None
@@ -303,6 +500,10 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             if s:
                 state[name] = s
             shapes[name] = tuple(out_shape)
+            run = self._run_of.get(name)
+            if run is not None and name == run.output:
+                # every pass's output, stacked before the batch axis
+                shapes[run.name] = (run.times,) + shapes[name]
         self.params = params
         self.state = state
         self._layer_counts_seen = {}
@@ -324,8 +525,16 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             n = sum(int(np.prod(a.shape))
                     for a in jax.tree.leaves(self.params.get(name, {})))
             shape = getattr(self, "_shapes", {}).get(name, "?")
+            run = self._run_of.get(name)
             lines.append(f"{name:<24}{kind:<22}{','.join(ins):<30}"
-                         f"{str(shape):<18}{n}")
+                         f"{str(shape):<18}{n}"
+                         + (f"  (x{run.times}, {run.name})" if run else ""))
+        for run in self.conf._runs:
+            lines.append(
+                f"repeated run {run.name!r}: {run.vertices[0]} .. "
+                f"{run.output} walked {run.times} times with one set of "
+                f"weights, carrying {run.carry}; each weight is counted "
+                "once")
         lines.append(f"total params: {self.num_params()}")
         return "\n".join(lines)
 
@@ -362,6 +571,11 @@ class ComputationGraph(_caches.CompiledCacheMixin):
         fold, skip = self._epilogue_fold_plan() if fold_epilogues \
             else ({}, frozenset())
         for name in self._topo:
+            run = self._run_of.get(name)
+            if run is not None:
+                if name == run.vertices[0]:
+                    self._walk_run(run, params, acts, mks, train=train)
+                continue
             v, ins = self._vertex_map[name]
             if rng is not None and v.stochastic:
                 rng, sub = jax.random.split(rng)
@@ -381,6 +595,37 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             if s_new:
                 new_state[name] = s_new
         return acts, new_state, mks
+
+    def _walk_run(self, run: RepeatedRun, params, acts, mks, *, train,
+                  policy=None):
+        """Apply a repeated run: ONE traced pass body under ``lax.scan``, so
+        the compiled program holds the run's vertices once however many
+        passes it makes. The body reads ``acts[run.carry]`` on the first
+        pass and its own output after that; afterwards ``acts[run.output]``
+        is the last pass and ``acts[run.name]`` every pass's output stacked
+        ``[times, batch, ...]``. The parameters are the scan's constants:
+        each weight's cotangent is summed over the passes in the dtype the
+        walk holds it in (the compute dtype under mixed precision). The
+        run's vertices keep no state and draw no random numbers (refused at
+        build), and the carried activation's mask rides through unchanged.
+        With a recomputing ``policy`` the body is cut into checkpointed
+        segments like the walk outside it, so what the backward pass keeps
+        of a pass is the live activations at its segment boundaries; without
+        one the same walk goes vertex by vertex and checkpoints nothing."""
+        from . import memory as _memory
+        policy = policy or _memory.resolve_policy("none")
+        mask = mks.get(run.carry)
+
+        def body(x, _):
+            with jax.named_scope("loop.pass"):
+                a, _, _, _ = self._walk_segments(
+                    run.vertices, {run.output}, params, {}, {run.carry: x},
+                    {run.carry: mask}, None, policy, train)
+            return a[run.output], a[run.output]
+
+        acts[run.output], acts[run.name] = _scan_passes(
+            body, acts[run.carry], run.times)
+        mks[run.output] = mks[run.name] = mask
 
     def _epilogue_fold_plan(self):
         """Static BN+activation fold plan over the vertex graph
@@ -432,28 +677,72 @@ class ComputationGraph(_caches.CompiledCacheMixin):
         the backward pass. Skip connections spanning segments ride through
         as checkpoint pass-through args. The rng stream threads through
         with the exact split sequence of the plain walk (remat on/off is
-        bit-equivalent, dropout included). ``params``/``inputs`` arrive
-        already cast."""
-        from . import memory as _memory
-        topo = self._topo
-        bounds = _memory.segment_ranges(len(topo), policy.every)
-        # needed_after[j] = names read by any vertex in bounds[j:], plus
-        # the network outputs — ONE right-to-left suffix pass (quadratic
-        # per-segment rescans would bite trace time on imported graphs)
-        needed_after = [set(self.conf.outputs)]
-        for s, e in reversed(bounds):
-            nxt = set(needed_after[-1])
-            for n in topo[s:e]:
-                nxt.update(self._vertex_map[n][1])
-            needed_after.append(nxt)
-        needed_after.reverse()
+        bit-equivalent, dropout included). A repeated run is a stretch of
+        its own: the vertices before it, the run (segmented inside its
+        pass body, ``_walk_run``) and the vertices after it are segmented
+        one after the other. ``params``/``inputs`` arrive already cast."""
+        # the stretches of the topological order between repeated runs
+        stretches: List[Any] = []
+        for name in self._topo:
+            run = self._run_of.get(name)
+            if run is not None:
+                if name == run.vertices[0]:
+                    stretches.append(run)
+                continue
+            if not stretches or isinstance(stretches[-1], RepeatedRun):
+                stretches.append([])
+            stretches[-1].append(name)
+        # read_after[k]: names read by anything after stretch k, plus the
+        # network outputs
+        read_after = [set(self.conf.outputs)]
+        for st in reversed(stretches):
+            nxt = set(read_after[-1])
+            if isinstance(st, RepeatedRun):
+                nxt.add(st.carry)
+            else:
+                for n in st:
+                    nxt.update(self._vertex_map[n][1])
+            read_after.append(nxt)
+        read_after.reverse()
         acts: Dict[str, jax.Array] = dict(inputs)
         mks: Dict[str, Any] = dict(masks or {})
         new_state = dict(state)
+        for k, st in enumerate(stretches):
+            if isinstance(st, RepeatedRun):
+                self._walk_run(st, params, acts, mks, train=train,
+                               policy=policy)
+                keep = read_after[k + 1]
+                acts = {n: a for n, a in acts.items() if n in keep}
+                mks = {n: m for n, m in mks.items() if n in keep}
+                continue
+            acts, mks, ns, rng = self._walk_segments(
+                st, read_after[k + 1], params, state, acts, mks, rng,
+                policy, train)
+            new_state.update(ns)
+        return acts, new_state, mks
+
+    def _walk_segments(self, names, needed_end, params, state, acts, mks,
+                       rng, policy, train):
+        """Walk ``names`` in checkpointed segments of ``policy.every``;
+        ``needed_end`` is what is read once they are done. -> (the live
+        activations, their masks, the state the vertices wrote, rng)."""
+        from . import memory as _memory
+        bounds = _memory.segment_ranges(len(names), policy.every)
+        # needed_after[j] = names read by any vertex in bounds[j:], plus
+        # ``needed_end`` — ONE right-to-left suffix pass (quadratic
+        # per-segment rescans would bite trace time on imported graphs)
+        needed_after = [set(needed_end)]
+        for s, e in reversed(bounds):
+            nxt = set(needed_after[-1])
+            for n in names[s:e]:
+                nxt.update(self._vertex_map[n][1])
+            needed_after.append(nxt)
+        needed_after.reverse()
+        new_state = {}
         for j, (s, e) in enumerate(bounds):
-            seg_names = tuple(topo[s:e])
+            seg_names = tuple(names[s:e])
             # live set after this segment: anything a later vertex reads,
-            # plus the network outputs
+            # plus what is needed at the end
             live_out = tuple(sorted(
                 (set(acts) | set(seg_names)) & needed_after[j + 1]))
 
@@ -490,7 +779,7 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             acts, mks, ns, rng = _memory.checkpoint(seg_fn, policy)(
                 seg_params, seg_state, acts, mks, rng)
             new_state.update(ns)
-        return acts, new_state, mks
+        return acts, mks, new_state, rng
 
     def _regularization(self, params):
         total = 0.0
@@ -726,6 +1015,7 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                                        ys)
                 self.iteration += nb
                 self.epoch += 1
+                self._count_passes(nb)
                 # lazy device scalar — listeners calling score() get this
                 # epoch's final loss without forcing a mid-chain host sync
                 self._score = losses[-1]
@@ -736,6 +1026,13 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                 self._publish_layer_counters()
             self._score = float(out[-1])
             return out
+
+    def _count_passes(self, steps: int):
+        """``loop.passes``: what the launched steps walked of each repeated
+        run, counted on the host from the launches."""
+        for run in self.conf._runs:
+            _LOOP_PASSES.inc(steps * run.times, graph=self.telemetry_label,
+                             run=run.name)
 
     def _publish_layer_counters(self):
         """Layers that count on the device (a sparse-expert layer's tokens
@@ -829,6 +1126,7 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                                                  ys, fms, lms, sentinel)
                     self._score = loss
                     self.iteration += 1
+                    self._count_passes(1)
                     self._notify_listeners(span_labels, "iteration_done",
                                            self.iteration, self.epoch)
                 self.epoch += 1

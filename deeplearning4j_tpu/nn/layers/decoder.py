@@ -2,7 +2,9 @@
 attention under a causal or sliding-window mask, latent attention (keys and
 values from one low-rank projection, one rotary key for all heads), a gated
 feed-forward, a sparse-expert feed-forward that is told which experts it
-holds, and the next-token loss head.
+holds, the next-token loss head, and the head of a stack whose layers are
+walked several times (it scores every pass and weighs the passes by a
+learned exit distribution).
 
 Every setting that differs between the layers of one stack (query heads,
 rotary share, base and scaling, mask) is a field of the layer, so a builder
@@ -400,3 +402,116 @@ class CausalLMOutputLayer(Layer):
 
     def loss_value(self, nll, labels, mask=None, weights=None):
         return jnp.mean(nll.astype(jnp.float32))
+
+
+# positions of one pass whose logits ``ExitWeightedLMOutputLayer`` holds at a
+# time: 805 MB of float32 logits at a vocabulary of 49,152, and no width of
+# the decoders here, so that a trace tells the head's blocks by their shape
+LM_HEAD_BLOCK = 4096
+
+_EXIT_MASS = _tel.counter(
+    "loop.exit_mass", "exit probability summed over the positions that "
+    "carry loss, by head and by pass of the repeated run it scores")
+
+
+@layer("exit_weighted_lm_output")
+class ExitWeightedLMOutputLayer(Layer):
+    """The untied head of a causal language model whose layers are walked
+    ``R`` times (a repeated run of a :class:`ComputationGraph`), with the
+    entropy-regularised objective of arXiv:2510.25741's first training
+    stage. It takes every pass's hidden state stacked ``[R, B, T, d]`` and
+    the token ids. One weight ``W`` scores all passes, ``z(t) = h(t) W``; an
+    exit gate reads each, ``g(t) = sigmoid(h(t) Wg + bg)``, one scalar a
+    position. With ``ce(t, i)`` the cross-entropy of pass ``t`` at position
+    ``i`` against token ``i + 1`` and the exit distribution ``p(1) = g(1)``,
+    ``p(t) = g(t) prod_{j<t} (1 - g(j))``, ``p(R) = prod_{j<R} (1 -
+    g(j))``, the loss is the mean over the positions that have a next token
+    of ``sum_t p(t, i) ce(t, i) - beta H(p(., i))``.
+
+    Logits are float32 and are made ``LM_HEAD_BLOCK`` positions of one pass
+    at a time, each block recomputed in the backward pass, so that one
+    block's logits are all that is ever held (a sequence length the block
+    does not divide is taken a whole row at a time); ``W``'s cotangent is the sum
+    over the passes' blocks in the dtype ``W`` arrives in. The gate, the
+    exit distribution (in log space) and the entropy are float32. In
+    training ``apply`` returns the per-position objective ``[B, T - 1]``,
+    otherwise the last pass's probabilities ``[B, T, n_out]``: inference
+    walks every pass and stops at none. The state sums, since ``init``, the
+    exit probability of each pass over the positions with loss;
+    ``fit_on_device`` publishes its growth as ``loop.exit_mass``."""
+    n_inputs = 2
+    reads_passes = True
+    quantizable = True
+    n_out: int = 0
+    beta: float = 0.1
+    weight_init: str = "xavier"
+    name: Optional[str] = None
+
+    def initialize(self, key, input_shapes, dtype):
+        passes, tokens = input_shapes
+        if len(passes) != len(tokens) + 2:
+            raise ValueError(
+                "the head reads the stacked passes of a repeated run "
+                f"[R, T, d] beside the token ids [T], got {passes} and "
+                f"{tokens}")
+        f = int(passes[-1])
+        k1, k2 = jax.random.split(key)
+        return ({"W": _w(self.weight_init, k1, (f, self.n_out), dtype),
+                 "Wg": _w(self.weight_init, k2, (f, 1), dtype),
+                 "bg": jnp.zeros((1,), dtype)},
+                {"exit_mass": jnp.zeros((int(passes[0]),), jnp.float32)},
+                tuple(passes[1:-1]) + (self.n_out,))
+
+    def quantize_spec(self, params):
+        return {"W": 1}
+
+    def exit_log_probs(self, params, h):
+        """``h`` ``[R, ..., d]`` -> ``log p`` ``[R, ...]`` float32."""
+        a = (jnp.dot(h, params["Wg"],
+                     preferred_element_type=jnp.float32)[..., 0]
+             + params["bg"].astype(jnp.float32))
+        stay = jax.nn.log_sigmoid(-a)
+        before = jnp.cumsum(stay, axis=0) - stay   # sum over the passes j < t
+        return jnp.concatenate(
+            [(jax.nn.log_sigmoid(a) + before)[:-1], before[-1:]], axis=0)
+
+    def apply(self, params, xs, state, *, train=False, rng=None, mask=None):
+        h, tokens = xs
+        if not train:
+            logits = jnp.dot(h[-1], params["W"],
+                             preferred_element_type=jnp.float32)
+            return jax.nn.softmax(logits, axis=-1), state, mask
+        R, B, T, d = h.shape
+        C = LM_HEAD_BLOCK if T % LM_HEAD_BLOCK == 0 else T
+        nxt = jnp.roll(jnp.asarray(tokens, jnp.int32), -1, axis=1)
+
+        @jax.checkpoint
+        def block(args):
+            hb, yb = args
+            logits = jnp.dot(hb, params["W"],
+                             preferred_element_type=jnp.float32)
+            picked = jnp.take_along_axis(logits, yb[:, None], axis=-1)[:, 0]
+            return jax.nn.logsumexp(logits, axis=-1) - picked
+
+        with jax.named_scope("lm_head.passes"):
+            ce = jax.lax.map(block, (
+                h.reshape(R * B * T // C, C, d),
+                jnp.tile(nxt.reshape(B * T // C, C), (R, 1))))
+            ce = ce.reshape(R, B, T)
+        with jax.named_scope("lm_head.exit"):
+            logp = self.exit_log_probs(params, h)
+            p = jnp.exp(logp)
+            # sum_t p ce - beta H(p), with H(p) = - sum_t p log p
+            loss = jnp.sum(p * (ce + self.beta * logp), axis=0)[:, :-1]
+            mass = jnp.sum(jax.lax.stop_gradient(p)[:, :, :-1], axis=(1, 2))
+        return loss, {**state, "exit_mass": state["exit_mass"] + mass}, None
+
+    def loss_value(self, loss, labels, mask=None, weights=None):
+        return jnp.mean(loss.astype(jnp.float32))
+
+    def publish_counters(self, vertex: str, now: dict, before) -> None:
+        """Add what the state's sums grew by since ``before`` (None: since
+        zero) to ``loop.exit_mass``; the sums are float32."""
+        old = 0.0 if before is None else np.asarray(before["exit_mass"])
+        for t, grew in enumerate(np.asarray(now["exit_mass"]) - old):
+            _EXIT_MASS.inc(float(grew), layer=vertex, **{"pass": str(t + 1)})

@@ -287,7 +287,7 @@ class TransferLearning:
                       gradient_clip_value=conf.gradient_clip_value,
                       gradient_clip_l2=conf.gradient_clip_l2,
                       tbptt_length=conf.tbptt_length,
-                      constraints=conf.constraints)
+                      constraints=conf.constraints, repeats=conf.repeats)
             new_conf = ComputationGraphConfiguration(**self._ftc._apply(kw))
             net = ComputationGraph(new_conf).init()
             params = dict(net.params)

@@ -1,6 +1,7 @@
 """ComputationGraph DAG engine tests (SURVEY.md §2.4 ComputationGraph row,
 §3.2 — vertices, topo order, multi-in/out, residual training, serde)."""
 
+import json
 import os
 
 import jax.numpy as jnp
@@ -380,3 +381,182 @@ def test_resnet50_imagenet_param_count():
     net = resnet50()
     net.init()
     assert net.num_params() == 25_557_032
+
+
+# ------------------------------------------------- repeated runs of vertices
+
+from deeplearning4j_tpu.nn.layers.core import DropoutLayer  # noqa: E402
+from deeplearning4j_tpu.nn.vertices import GraphVertex, vertex  # noqa: E402
+from deeplearning4j_tpu.runtime import telemetry as tel  # noqa: E402
+
+
+@vertex("test_mean_passes")
+class _MeanPasses(GraphVertex):
+    """Test only: the mean over the stacked passes ``[R, B, ...]``."""
+
+    def initialize(self, key, input_shapes, dtype):
+        return {}, {}, tuple(input_shapes[0][1:])
+
+    def apply(self, params, xs, state, *, train=False, rng=None, masks=None):
+        return jnp.mean(xs[0], axis=0), state, None
+
+
+def _looped(times=3, mode="none", inside=None, read="res", width=8,
+            first="a", last="res"):
+    """in -> pre -> [a -> b -> res = pre + b] x times -> out."""
+    g = (NeuralNetConfiguration.builder().seed(4)
+         .updater(Adam(learning_rate=1e-2)).workspace_mode(mode)
+         .graph_builder().add_inputs("in").set_input_types((8,))
+         .add_layer("pre", DenseLayer(n_out=8, activation="tanh"), "in")
+         .add_layer("a", inside or DenseLayer(n_out=8, activation="tanh"),
+                    "pre")
+         .add_layer("b", DenseLayer(n_out=width, activation="tanh"), "a")
+         .add_vertex("res", ElementWiseVertex(op="add"), "pre", "b"))
+    if read == "loop":
+        g = g.add_vertex("mean", _MeanPasses(), "loop")
+        read = "mean"
+    g = (g.add_layer("out", OutputLayer(n_out=3), read)
+         .set_outputs("out").repeat("loop", first, last, times))
+    return g.build()
+
+
+def _unrolled(times=3):
+    g = (NeuralNetConfiguration.builder().seed(4)
+         .updater(Adam(learning_rate=1e-2)).graph_builder()
+         .add_inputs("in").set_input_types((8,))
+         .add_layer("pre", DenseLayer(n_out=8, activation="tanh"), "in"))
+    h = "pre"
+    for t in range(times):
+        g = (g.add_layer(f"a{t}", DenseLayer(n_out=8, activation="tanh"), h)
+             .add_layer(f"b{t}", DenseLayer(n_out=8, activation="tanh"),
+                        f"a{t}")
+             .add_vertex(f"res{t}", ElementWiseVertex(op="add"), h, f"b{t}"))
+        h = f"res{t}"
+    return (g.add_layer("out", OutputLayer(n_out=3), h).set_outputs("out")
+            .build())
+
+
+def _loop_data(n=16):
+    rng = np.random.default_rng(1)
+    return (rng.normal(size=(n, 8)).astype(np.float32),
+            np.eye(3, dtype=np.float32)[rng.integers(0, 3, n)])
+
+
+def _grads(net, x, y):
+    import jax
+    return jax.grad(lambda p: net._build_loss_fn()(
+        p, net.state, None, (jnp.asarray(x),), (jnp.asarray(y),), (None,),
+        (None,))[0])(net.params)
+
+
+def test_repeated_run_equals_the_vertices_written_out():
+    """Activations equal; the shared leaf's gradient is the sum of its
+    copies'; every pass's output is stacked under the run's name."""
+    x, y = _loop_data()
+    net = ComputationGraph(_looped()).init()
+    flat = ComputationGraph(_unrolled()).init()
+    for name in flat.params:
+        flat.params[name] = dict(net.params[name.rstrip("012")])
+    acts, want = net.feed_forward(x), flat.feed_forward(x)
+    assert acts["loop"].shape == (3, 16, 8)
+    for t in range(3):
+        np.testing.assert_allclose(acts["loop"][t], want[f"res{t}"],
+                                   rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(acts["res"], want["res2"], rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(net.output(x), flat.output(x), rtol=1e-5,
+                               atol=1e-6)
+    assert "a" not in acts   # the run's inside is not read from outside
+    g, gf = _grads(net, x, y), _grads(flat, x, y)
+    for v in ("a", "b"):
+        for k in g[v]:
+            np.testing.assert_allclose(
+                g[v][k], sum(gf[f"{v}{t}"][k] for t in range(3)),
+                rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(g["pre"]["W"], gf["pre"]["W"], rtol=1e-4,
+                               atol=1e-6)
+    assert net.num_params() == flat.num_params() - 2 * 2 * (8 * 8 + 8)
+
+
+@pytest.mark.parametrize("mode", ["every_1", "every_2", "full"])
+def test_repeated_run_recomputes_bit_for_bit(mode):
+    """The segments fall inside the pass body; recomputation on and off
+    agree bit for bit, through ``fit`` and ``fit_on_device``, with a later
+    vertex reading the stacked passes."""
+    x, y = _loop_data()
+    nets = [ComputationGraph(_looped(mode=m, read="loop")).init()
+            for m in ("none", mode)]
+    hist = [n.fit_on_device(x, y, epochs=2, batch_size=8) for n in nets]
+    assert (hist[0] == hist[1]).all() and hist[0][-1] < hist[0][0]
+    for n in nets:
+        n.fit(DataSet(x, y), epochs=1)
+    for k in nets[0].params:
+        for p in nets[0].params[k]:
+            np.testing.assert_array_equal(nets[0].params[k][p],
+                                          nets[1].params[k][p])
+
+
+def test_repeated_run_keeps_only_boundary_carries():
+    """Under ``every_1`` the backward pass keeps fewer bytes than without
+    recomputation (the residual accounting of ``nn/memory.py``)."""
+    from deeplearning4j_tpu.nn import memory
+    kept = {m: memory.memory_report(
+        ComputationGraph(_looped(mode=m, times=4)).init(), 8)
+        ["activation_bytes"] for m in ("none", "every_1")}
+    assert 0 < kept["every_1"] < kept["none"]
+
+
+def test_repeated_run_serialises_and_counts_its_passes(tmp_path):
+    conf = _looped(times=4, mode="every_2")
+    again = ComputationGraphConfiguration.from_json(conf.to_json())
+    assert again.repeats == [{"name": "loop", "first": "a", "last": "res",
+                              "times": 4}]
+    assert again._runs == conf._runs and again.workspace_mode == "every_2"
+    assert conf._runs[0].vertices == ("a", "b", "res")
+    assert conf._runs[0].carry == "pre"
+    x, y = _loop_data()
+    net = ComputationGraph(again).init()
+    assert "walked 4 times" in net.summary()
+    net.fit(DataSet(x, y), epochs=2)
+    net.fit_on_device(x, y, epochs=1, batch_size=8)
+    series = tel.snapshot()["loop.passes"]["series"]
+    # 2 steps of fit and 2 of fit_on_device, 4 passes each
+    assert series[json.dumps({"graph": net.telemetry_label,
+                              "run": "loop"})] == 16
+    path = os.path.join(tmp_path, "loop.zip")
+    net.save(path)
+    loaded = ComputationGraph.load(path)
+    assert loaded.conf._runs == net.conf._runs
+    np.testing.assert_array_equal(net.output(x), loaded.output(x))
+
+
+@pytest.mark.parametrize("why,kwargs", [
+    ("keeps layer state", dict(inside=BatchNormalization())),
+    ("draws random numbers", dict(inside=DropoutLayer(rate=0.5))),
+    ("must hand on what it took", dict(width=6, last="b")),
+    ("a run has one input", dict(first="b")),
+    ("can be read", dict(read="b")),
+    ("is not a declared vertex", dict(last="nope")),
+    ("declared before", dict(first="res", last="a")),
+    ("times=0", dict(times=0)),
+])
+def test_repeated_run_refusals(why, kwargs):
+    """What a run cannot be is refused when the configuration is built."""
+    with pytest.raises(ValueError, match=why):
+        _looped(**kwargs)
+
+
+def test_repeated_run_names_and_overlaps_are_checked():
+    base = (NeuralNetConfiguration.builder().graph_builder()
+            .add_inputs("in").set_input_types((8,))
+            .add_layer("a", DenseLayer(n_out=8), "in")
+            .add_layer("b", DenseLayer(n_out=8), "a")
+            .add_layer("out", OutputLayer(n_out=3), "b").set_outputs("out"))
+    with pytest.raises(ValueError, match="the name is taken"):
+        base.repeat("a", "a", "b", 2).build()
+    base._repeats.clear()
+    with pytest.raises(ValueError, match="is in the repeated runs"):
+        base.repeat("r1", "a", "b", 2).repeat("r2", "b", "b", 2).build()
+    base._repeats.clear()
+    with pytest.raises(ValueError, match="lies inside the repeated run"):
+        base.set_outputs("a", "out").repeat("r", "a", "b", 2).build()
