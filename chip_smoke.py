@@ -13,7 +13,12 @@ One process, one TPU v5e chip, the entry points a user would call:
              ``SelfAttentionLayer`` stack answering ``/generate`` through
              the fused decode kernel, compared with the reference path;
 4. kernels - every Pallas kernel on those paths, compiled on the device
-             at a real width, against its ``reference_*`` function.
+             at a real width, against its ``reference_*`` function;
+5. kept    - two steps of a small causal decoder whose attention output is
+             as wide as its hidden size, one decoder layer recomputed at a
+             time: ``attention.kept`` says the output is kept across the
+             segment, and the step's ``memory_analysis()`` is printed with
+             the output kept and with it recomputed.
 
 ``--chips 4`` runs only the data-parallel path instead: ResNet-50 under
 ``ParallelWrapper(shard_update=True)`` on a 4-device ``data`` mesh, and
@@ -73,6 +78,9 @@ class Sizes:
     ln_shape: tuple = (4096, 768)
     affine_shape: tuple = (128 * 56 * 56, 256)
     lstm_shape: tuple = (64, 256)
+    # batch, positions, hidden, heads x head size (1x the hidden size),
+    # feed-forward, vocabulary, layers
+    kept_shape: tuple = (2, 4096, 1024, 8, 128, 2816, 4096, 2)
     dp_batch: int = 256                        # global; 64 a chip on four
     dp_steps: int = 3
     # at the real size the sharded step agrees with the one-device step to
@@ -563,6 +571,70 @@ def kernels_phase(sz: Sizes, interpret: bool = False):
 
 
 # ------------------------------------------------------- --chips 4: DP path
+def kept_phase(sz: Sizes):
+    """A decoder whose heads' output is as wide as its input, recomputed a
+    layer at a time: the segments keep that output (``attention.kept``),
+    two ``fit_on_device`` steps train, and against the same stack with the
+    rule answering no (there is no switch: the rule reads shapes) the
+    losses agree and the compiled step's memory is printed for both."""
+    from unittest import mock
+
+    from deeplearning4j_tpu.models.decoder_stack import (decoder_stack,
+                                                         vertices_per_layer)
+    from deeplearning4j_tpu.nn.layers import decoder
+    from deeplearning4j_tpu.runtime import telemetry as tel
+
+    B, T, hidden, heads, head, ffn, vocab, layers = sz.kept_shape
+    ids = np.random.default_rng(sz.seed + 5).integers(
+        0, vocab, (B, T), dtype=np.int32)
+    counter = tel.registry.get("attention.kept")
+
+    def run():
+        net = decoder_stack(
+            vocab_size=vocab, hidden_size=hidden, n_layers=layers, eps=1e-6,
+            attention=lambda i: decoder.CausalSelfAttentionLayer(
+                n_heads=heads, n_kv_heads=heads, head_size=head),
+            mlp=lambda i: decoder.GatedDenseLayer(n_hidden=ffn), seq_len=T,
+            dtype="BFLOAT16", seed=sz.seed,
+            workspace_mode=f"every_{vertices_per_layer()}").init()
+        t0 = time.perf_counter()
+        # the labels are not read: the head takes the next token of the ids
+        losses = [float(x) for x in net.fit_on_device(
+            ids, np.ones((B, 1), np.float32), epochs=2, batch_size=B)]
+        took = time.perf_counter() - t0
+        report = net.memory_report(B)
+        return losses, took, {k: report[k] for k in (
+            "temp_bytes", "peak_bytes", "activation_bytes")}
+
+    before = counter.value(kind="full", decision="kept")
+    kept, took, kept_mem = run()
+    n = counter.value(kind="full", decision="kept") - before
+    say(f"  attention.kept{{kind=full,decision=kept}} +{n}; losses {kept} "
+        f"in {took:.1f}s; step memory {kept_mem}")
+    if n < layers:
+        raise AssertionError("a 1x-wide attention layer did not keep its "
+                             "output under every_<k>")
+    before = counter.value(kind="full", decision="recomputed", why="wide")
+    with mock.patch.object(decoder, "_keeps_output", lambda *a: False):
+        again, took, again_mem = run()
+    n = counter.value(kind="full", decision="recomputed",
+                      why="wide") - before
+    say(f"  attention.kept{{kind=full,decision=recomputed,why=wide}} +{n}; "
+        f"losses {again} in {took:.1f}s; step memory {again_mem}")
+    if not all(np.isfinite(kept)) or len(kept) != 2:
+        raise AssertionError(f"two finite losses expected, got {kept}")
+    close("kept against recomputed losses", kept, again, 1e-3)
+    if kept_mem["activation_bytes"] is not None:
+        # what the backward pass is handed: the tagged outputs and no more
+        extra = kept_mem["activation_bytes"] - again_mem["activation_bytes"]
+        want = layers * B * T * heads * head * 2
+        say(f"  kept for the backward pass: {extra} bytes more "
+            f"({layers} x [{B}, {T}, {heads * head}] bf16 = {want})")
+        if extra != want:
+            raise AssertionError("the segments keep more than the tagged "
+                                 "outputs")
+
+
 def _device_bytes(tree) -> dict:
     """Bytes each device holds of ``tree``, by device."""
     import jax
@@ -670,6 +742,7 @@ def main(argv=None) -> int:
         del net
         generate_phase(sz)
         kernels_phase(sz)
+        kept_phase(sz)
     say(f"compile cache: {cache.hits} hits, {cache.misses} misses; "
         f"total {time.perf_counter() - t0:.0f}s")
     print(json.dumps({"ok": True, "device": info}), flush=True)

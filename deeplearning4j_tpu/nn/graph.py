@@ -610,8 +610,10 @@ class ComputationGraph(_caches.CompiledCacheMixin):
         build), and the carried activation's mask rides through unchanged.
         With a recomputing ``policy`` the body is cut into checkpointed
         segments like the walk outside it, so what the backward pass keeps
-        of a pass is the live activations at its segment boundaries; without
-        one the same walk goes vertex by vertex and checkpoints nothing."""
+        of a pass is the live activations at its segment boundaries and what
+        the segments keep by name, stacked over the passes by the scan;
+        without one the same walk goes vertex by vertex and checkpoints
+        nothing."""
         from . import memory as _memory
         policy = policy or _memory.resolve_policy("none")
         mask = mks.get(run.carry)
@@ -620,7 +622,7 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             with jax.named_scope("loop.pass"):
                 a, _, _, _ = self._walk_segments(
                     run.vertices, {run.output}, params, {}, {run.carry: x},
-                    {run.carry: mask}, None, policy, train)
+                    {run.carry: mask}, None, policy, train, prevent_cse=False)
             return a[run.output], a[run.output]
 
         acts[run.output], acts[run.name] = _scan_passes(
@@ -673,8 +675,10 @@ class ComputationGraph(_caches.CompiledCacheMixin):
         chunks each wrapped in ``jax.checkpoint``. The activation dict is
         pruned to the LIVE set at every segment boundary (names still read
         by later vertices, or network outputs) — those boundary values are
-        what XLA keeps; everything inside a segment is rematerialized in
-        the backward pass. Skip connections spanning segments ride through
+        what XLA keeps, with what the policy lets a segment keep by rule or
+        by name (``nn/memory.py``: an attention's output where it is
+        narrow); everything else inside a segment is rematerialized in the
+        backward pass. Skip connections spanning segments ride through
         as checkpoint pass-through args. The rng stream threads through
         with the exact split sequence of the plain walk (remat on/off is
         bit-equivalent, dropout included). A repeated run is a stretch of
@@ -722,10 +726,13 @@ class ComputationGraph(_caches.CompiledCacheMixin):
         return acts, new_state, mks
 
     def _walk_segments(self, names, needed_end, params, state, acts, mks,
-                       rng, policy, train):
+                       rng, policy, train, prevent_cse=True):
         """Walk ``names`` in checkpointed segments of ``policy.every``;
-        ``needed_end`` is what is read once they are done. -> (the live
-        activations, their masks, the state the vertices wrote, rng)."""
+        ``needed_end`` is what is read once they are done. ``prevent_cse``
+        is False where the walk is the body of a repeated run's scan, whose
+        segments need no barrier against merging with the forward pass
+        (``nn/memory.py`` ``checkpoint``). -> (the live activations, their
+        masks, the state the vertices wrote, rng)."""
         from . import memory as _memory
         bounds = _memory.segment_ranges(len(names), policy.every)
         # needed_after[j] = names read by any vertex in bounds[j:], plus
@@ -776,8 +783,9 @@ class ComputationGraph(_caches.CompiledCacheMixin):
 
             seg_params = {n: params[n] for n in seg_names if n in params}
             seg_state = {n: state[n] for n in seg_names if n in state}
-            acts, mks, ns, rng = _memory.checkpoint(seg_fn, policy)(
-                seg_params, seg_state, acts, mks, rng)
+            acts, mks, ns, rng = _memory.checkpoint(
+                seg_fn, policy, prevent_cse=prevent_cse)(
+                    seg_params, seg_state, acts, mks, rng)
             new_state.update(ns)
         return acts, mks, new_state, rng
 

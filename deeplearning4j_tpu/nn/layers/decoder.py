@@ -33,6 +33,18 @@ def _w(init, key, shape, dtype):
     return _winit.init(init, key, shape, shape[-2], shape[-1], dtype)
 
 
+def _keeps_output(heads_width: int, hidden: int) -> bool:
+    """Whether an attention layer asks a recomputed segment to keep its
+    heads' output, ``[B, T, heads_width]``, instead of running the attention
+    forward again to rebuild it: where that is no more than twice the
+    layer's input. The bound is about memory. A segment keeps one hidden
+    state at its boundary anyway, and this lets attention add at most two
+    more; on a v5e at 8,192 positions a 1x layer costs 67 MB and a 2x layer
+    134 MB a sequence pair, and 3x and 4x layers (48 and 64 heads of 128 on
+    a hidden size of 2,048) do not fit beside the rest (PERF.md, PR 38)."""
+    return heads_width <= 2 * hidden
+
+
 def _rms_norm(x, g, eps):
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
@@ -118,7 +130,9 @@ class CausalSelfAttentionLayer(Layer):
         cos, sin = _ca.rotary_tables(T, self.inv_freq(),
                                      self.rope_attention_factor)
         q, k = _ca.apply_rotary(q, cos, sin), _ca.apply_rotary(k, cos, sin)
-        o = _ca.causal_attention(q, k, v, window=self.window)
+        o = _ca.causal_attention(
+            q, k, v, window=self.window,
+            keep=_keeps_output(self.n_heads * self.head_size, x.shape[-1]))
         o = o.reshape(B, T, self.n_heads * self.head_size)
         if self.gated:
             o = o * jax.nn.sigmoid(jnp.dot(x, params["Wg"]))
@@ -192,7 +206,9 @@ class LatentAttentionLayer(Layer):
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
         with jax.named_scope("attn.latent.project"):
             q, k, v = self.project(params, x)
-        o = _ca.causal_attention(q, k, v, kind="latent")
+        o = _ca.causal_attention(
+            q, k, v, kind="latent",
+            keep=_keeps_output(self.n_heads * self.v_head_size, x.shape[-1]))
         return (jnp.dot(o.reshape(o.shape[:2] + (-1,)), params["Wo"]),
                 state, mask)
 

@@ -30,6 +30,17 @@ pass. This module adds the TPU-native control:
                   (classic sqrt-style trade: larger k = less memory, more
                   recompute).
 
+  What a recomputing policy keeps of a segment: the live activations at its
+  boundary, whatever its ``saveable`` rule allows, and every array tagged
+  :data:`KEPT` (``jax.ad_checkpoint.checkpoint_name``). One thing is tagged
+  today: the output of a causal attention no wider than twice its layer's
+  input (``ops/causal_attention.py`` ``keep=``, asked for by the attention
+  layers of ``nn/layers/decoder.py``), and on the kernel path the logsumexp
+  beside it. The backward pass reads nothing else of an attention's forward,
+  so with the output kept the segment's recomputation holds no score
+  product (PERF.md, PR 38). A program that tags nothing lowers as it did
+  under ``policy=None``.
+
   A "block" is a layer (MultiLayerNetwork), a vertex (ComputationGraph),
   or an attention-anchored op segment (imported SameDiff graphs — see
   ``autodiff/remat.py``). Recorded divergences from the reference:
@@ -51,8 +62,10 @@ pass. This module adds the TPU-native control:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
+import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -114,12 +127,49 @@ def resolve_policy(mode) -> RematPolicy:
         "(DL4J WorkspaceMode parity alias for 'full')")
 
 
-def checkpoint(fn: Callable, policy: RematPolicy) -> Callable:
+#: the one name the recomputing policies keep
+KEPT = "attention.kept"
+_KEEP_TAGGED = jax.checkpoint_policies.save_only_these_names(KEPT)
+_tracing = threading.local()
+
+
+def recomputing() -> bool:
+    """Whether this thread is tracing a segment of :func:`checkpoint`, where
+    an array tagged :data:`KEPT` is kept for the backward pass."""
+    return getattr(_tracing, "segments", 0) > 0
+
+
+@contextlib.contextmanager
+def _segment():
+    _tracing.segments = getattr(_tracing, "segments", 0) + 1
+    try:
+        yield
+    finally:
+        _tracing.segments -= 1
+
+
+def checkpoint(fn: Callable, policy: RematPolicy, *,
+               prevent_cse: bool = True) -> Callable:
     """Wrap ``fn`` in ``jax.checkpoint`` under the policy's saveable rule
-    (identity when the policy is off)."""
+    joined with the arrays tagged :data:`KEPT` (identity when the policy is
+    off). ``prevent_cse=False`` is for a segment in the body of a
+    ``lax.scan``: there the forward pass and the backward pass are two
+    loops, XLA cannot merge the recomputation into the forward pass, and
+    the barriers that keep it from trying are in the way (``jax.checkpoint``
+    says as much). With them a looped decoder's backward scan ran its
+    attention blocks' operands through fast memory in slices and lost 5%
+    where it should have gained 6 (PERF.md, PR 38)."""
     if not policy.remat:
         return fn
-    return jax.checkpoint(fn, policy=policy.saveable)
+    keep = _KEEP_TAGGED if policy.saveable is None else \
+        jax.checkpoint_policies.save_from_both_policies(policy.saveable,
+                                                        _KEEP_TAGGED)
+    remat = jax.checkpoint(fn, policy=keep, prevent_cse=prevent_cse)
+
+    def call(*args):
+        with _segment():
+            return remat(*args)
+    return call
 
 
 def segment_ranges(n: int, every: int) -> List[Tuple[int, int]]:

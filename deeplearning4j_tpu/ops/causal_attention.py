@@ -13,6 +13,12 @@ blocked XLA path: queries are cut into blocks, each block sees only the key
 blocks its mask leaves open, and every block is a ``jax.checkpoint`` so the
 backward pass holds one block's scores at a time.
 
+A caller inside a recomputed segment (``nn/memory.py`` ``checkpoint``) may ask
+with ``keep=True`` that the result be kept for the backward pass, which reads
+nothing else of this forward: it is tagged ``memory.KEPT`` (on the kernel
+path the logsumexp with it), and the segment's recomputation then holds no
+score product. ``attention.kept`` counts what a traced site did.
+
 Layout: ``q`` ``[B, T, H, d]``, ``k`` ``[B, T, KV, d]``, ``v`` ``[B, T, KV,
 dv]``; query head ``i`` reads KV head ``i // (H // KV)``. ``dv`` need not be
 ``d`` (latent attention scores over 192 and carries values of 128).
@@ -27,6 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from . import flash_attention as _fa
 from .pallas_kernels import (available as _tpu_available,
@@ -39,6 +46,10 @@ _DISPATCH = _tel.counter(
     "attention.dispatch",
     "causal attention sites by mask kind and the path taken, once a traced "
     "site")
+_KEPT = _tel.counter(
+    "attention.kept",
+    "causal attention sites by mask kind and whether a recomputed segment "
+    "keeps the output for its backward pass, once a traced site")
 
 
 # ------------------------------------------------------------------- rotary
@@ -178,6 +189,30 @@ def _rows_window(q, k, v, block: int, window: int):
     return out.transpose(1, 0, 2, 3).reshape(G, T, v.shape[-1])
 
 
+# --------------------------------------------------------------------- keep
+def _memory():
+    from ..nn import memory                     # nn imports ops, not back
+    return memory
+
+
+def _tag(a):
+    return checkpoint_name(a, _memory().KEPT)
+
+
+def _keeps(kind: str, keep: bool) -> bool:
+    """Whether this site's result is kept, counted once a traced site. The
+    caller asks from its shapes; nothing is kept outside a recomputed
+    segment, where nothing is computed twice."""
+    if not keep:
+        _KEPT.inc(kind=kind, decision="recomputed", why="wide")
+    elif not _memory().recomputing():
+        _KEPT.inc(kind=kind, decision="recomputed", why="no_policy")
+    else:
+        _KEPT.inc(kind=kind, decision="kept")
+        return True
+    return False
+
+
 # ------------------------------------------------------------------- kernel
 #: what a grid step costs, in pairs of (query, key): about 0.35 us, the time
 #: the products and the softmax of a 256 x 256 tile take (PERF.md, PR 36)
@@ -312,17 +347,21 @@ def _bwd_call(q3, k3, v3, lse, di, do, mask, group, scale, interpret):
     return dq, dk, dv_
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q3, k3, v3, mask, group, scale, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q3, k3, v3, mask, group, scale, interpret, keep):
     return _fwd_call(q3, k3, v3, mask, group, scale, interpret)[0]
 
 
-def _flash_fwd(q3, k3, v3, mask, group, scale, interpret):
+def _flash_fwd(q3, k3, v3, mask, group, scale, interpret, keep):
     o, lse = _fwd_call(q3, k3, v3, mask, group, scale, interpret)
+    if keep:
+        # the backward kernels read both: the forward kernel leaves a
+        # segment's recomputation only if neither has to be rebuilt
+        o, lse = _tag(o), _tag(lse)
     return o, (q3, k3, v3, o, lse)
 
 
-def _flash_bwd(mask, group, scale, interpret, res, do):
+def _flash_bwd(mask, group, scale, interpret, keep, res, do):
     q3, k3, v3, o, lse = res
     di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
     return _bwd_call(q3, k3, v3, lse, di[:, None, :], do, mask, group, scale,
@@ -333,12 +372,14 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def causal_flash(q, k, v, *, window: Optional[int] = None, blocks=None,
-                 interpret: bool = False):
+                 interpret: bool = False, keep: bool = False):
     """The masked kernels on ``q`` ``[B, H, T, d]``, ``k`` ``[B, KV, T, d]``,
     ``v`` ``[B, KV, T, dv]`` -> ``[B, H, T, dv]``. ``blocks``: (block_q,
     block_k), multiples of 128 that divide ``T`` (default:
-    :func:`causal_blocks`). Raises ValueError where nothing tiles: callers go
-    through :func:`causal_attention` for guarded dispatch."""
+    :func:`causal_blocks`). ``keep`` tags the output and the logsumexp
+    ``memory.KEPT`` for a recomputing caller. Raises ValueError where
+    nothing tiles: callers go through :func:`causal_attention` for guarded
+    dispatch."""
     B, H, T, d = q.shape
     KV, dv = k.shape[1], v.shape[-1]
     blocks = blocks or causal_blocks(T, d, dv, window,
@@ -347,7 +388,7 @@ def causal_flash(q, k, v, *, window: Optional[int] = None, blocks=None,
         raise ValueError(f"a sequence of {T} does not tile into {blocks}")
     o = _flash(q.reshape(B * H, T, d), k.reshape(B * KV, T, d),
                v.reshape(B * KV, T, dv), _fa.BlockMask(*blocks, T, window),
-               H // KV, 1.0 / math.sqrt(d), bool(interpret))
+               H // KV, 1.0 / math.sqrt(d), bool(interpret), bool(keep))
     return o.reshape(B, H, T, dv)
 
 
@@ -378,7 +419,8 @@ def _xla_reason(q, group: int, T: int, d: int, dv: int,
 
 
 def causal_attention(q, k, v, *, window: Optional[int] = None,
-                     block: int = 1024, kind: Optional[str] = None):
+                     block: int = 1024, kind: Optional[str] = None,
+                     keep: bool = False):
     """softmax(q k^T / sqrt(d) + mask) v with a causal mask and, with
     ``window``, key ``j`` open to query ``i`` only where ``i - window < j <=
     i``. -> ``[B, T, H, dv]``. ``block`` is the XLA path's: a sequence no
@@ -389,7 +431,11 @@ def causal_attention(q, k, v, *, window: Optional[int] = None,
     path with the reason (``decision=blocked_rows | blocked_pairs``,
     ``why=platform | mode | gspmd | shape | vmem | ungrouped``). ``kind``
     names the site in the ``attention.dispatch`` counter and the
-    ``attn.<kind>`` scope (default: ``full`` or ``window``, by the mask)."""
+    ``attn.<kind>`` scope (default: ``full`` or ``window``, by the mask).
+    ``keep``: inside a recomputed segment, keep the result for the backward
+    pass (``attention.kept{decision=kept}``); a caller that finds its output
+    too wide to keep passes False (``decision=recomputed, why=wide``), and
+    outside such a segment there is nothing to keep (``why=no_policy``)."""
     B, T, H, d = q.shape
     KV, dv = k.shape[2], v.shape[-1]
     if H % KV:
@@ -398,6 +444,7 @@ def causal_attention(q, k, v, *, window: Optional[int] = None,
     kind = kind or ("full" if window is None else "window")
     if window is not None:
         block = min(block, max(window, 128))
+    keep = _keeps(kind, keep)
     with jax.named_scope(f"attn.{kind}"):
         tiles = T > block and T % block == 0
         why = _xla_reason(q, G, T, d, dv, window) if tiles else None
@@ -405,7 +452,8 @@ def causal_attention(q, k, v, *, window: Optional[int] = None,
             _DISPATCH.inc(kind=kind, decision="kernel")
             heads_first = lambda a: a.transpose(0, 2, 1, 3)
             out = causal_flash(heads_first(q), heads_first(k), heads_first(v),
-                               window=window, interpret=_fa._interpret())
+                               window=window, interpret=_fa._interpret(),
+                               keep=keep)
             return heads_first(out)
         qg = q.reshape(B, T, KV, G, d).transpose(0, 2, 3, 1, 4)  # [B,KV,G,T,d]
         kg, vg = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)  # [B,KV,T,d]
@@ -422,4 +470,8 @@ def causal_attention(q, k, v, *, window: Optional[int] = None,
             flat = lambda a: a.reshape((B * KV,) + a.shape[2:])
             out = jax.lax.map(rows, (flat(qg), flat(kg), flat(vg)))
             out = out.reshape(B, KV, G, T, dv)
-        return out.transpose(0, 3, 1, 2, 4).reshape(B, T, H, dv)
+        out = out.transpose(0, 3, 1, 2, 4).reshape(B, T, H, dv)
+        # the blocks' own checkpoints keep q, k, v, which the projections
+        # rebuild cheaply: the output alone takes the forward out of the
+        # segment's recomputation
+        return _tag(out) if keep else out

@@ -3,14 +3,17 @@ through the Pallas interpreter: output and all three gradients against one
 block of the XLA path on the whole sequence, in the three layouts the decoder
 cells send (grouped KV heads under a causal and a window mask, one query head
 a KV head with values narrower than the scored width); the dispatch counter
-for every decision and fallback reason; and a count of the grid steps that
-computed against the blocks the mask leaves open."""
+for every decision and fallback reason; a count of the grid steps that
+computed against the blocks the mask leaves open; and (ISSUE 38) the output
+and the logsumexp kept across a recomputed segment, so that its
+recomputation holds no forward kernel."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deeplearning4j_tpu.nn import memory as memmod
 from deeplearning4j_tpu.ops import causal_attention as ca
 from deeplearning4j_tpu.ops import flash_attention as fa
 from deeplearning4j_tpu.ops import pallas_kernels as pk
@@ -269,3 +272,117 @@ def test_tiling_rule_computes_few_closed_pairs(t, d, dv, window, want):
     wasteful = fa.BlockMask(*greedy, t, window)
     assert wasteful.open_blocks() * wasteful.bq * wasteful.bk > computed
     assert ca.causal_blocks(t + 8, d, dv, window, 2) is None   # nothing tiles
+
+
+# ---- kept across a recomputed segment (ISSUE 38) ---------------------------
+def _eqns(jaxpr, primitive):
+    """Every equation of ``primitive`` in a jaxpr, through the nested ones."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, primitive)
+
+
+def _two_segments(layout, keep):
+    """The gradient of two recomputed segments, each a projection, the
+    attention of ``layout`` and an output projection, and its arguments."""
+    H, KV, d, dv, window = LAYOUTS[layout]
+    kind = "latent" if layout.startswith("latent") else None
+    k0 = jax.random.PRNGKey(38)
+    x = jax.random.normal(k0, (1, T, 24), jnp.float32)
+    w = jax.random.normal(jax.random.fold_in(k0, 1),
+                          (24, H * d + KV * d + KV * dv), jnp.float32) * 0.2
+    wo = jax.random.normal(jax.random.fold_in(k0, 2), (H * dv, 24),
+                           jnp.float32) * 0.2
+
+    def segment(w, wo, x):
+        y = x @ w
+        q = y[..., :H * d].reshape(1, T, H, d)
+        k = y[..., H * d:(H + KV) * d].reshape(1, T, KV, d)
+        v = y[..., (H + KV) * d:].reshape(1, T, KV, dv)
+        o = ca.causal_attention(q, k, v, window=window, block=BLOCK,
+                                kind=kind, keep=keep)
+        return x + o.reshape(1, T, H * dv) @ wo
+
+    def loss(w, wo, x):
+        for _ in range(2):
+            x = memmod.checkpoint(segment, memmod.resolve_policy("full"))(
+                w, wo, x)
+        return jnp.sum(jnp.sin(x))
+
+    return jax.grad(loss, argnums=(0, 1)), (w, wo, x)
+
+
+def _kernel_names(jaxpr):
+    return [e.params["name"] for e in _eqns(jaxpr, "pallas_call")]
+
+
+@pytest.mark.parametrize("layout", ["full_g6", "window_gt_block",
+                                    "latent_g1"])
+def test_kernel_path_keeps_output_and_logsumexp(forced, layout):
+    """The backward kernels read the output and the logsumexp: with both
+    tagged the gradient holds one forward kernel a segment (the forward
+    pass's) where it held two, the backward kernels unchanged, and the
+    gradients are equal to the last bit."""
+    kind = {"full_g6": "full", "window_gt_block": "window",
+            "latent_g1": "latent"}[layout]
+    counter = tel.registry.get("attention.kept")
+    before = counter.value(kind=kind, decision="kept")
+    grad, args = _two_segments(layout, keep=True)
+    kept = jax.make_jaxpr(grad)(*args).jaxpr
+    # the two segments are one traced site: the same function and shapes
+    assert counter.value(kind=kind, decision="kept") == before + 1
+    names = _kernel_names(kept)
+    assert names.count("causal_flash_fwd") == 2
+    assert names.count("causal_flash_bwd_dq") == 2
+    assert names.count("causal_flash_bwd_dkv") == 2
+    tags = [e for e in _eqns(kept, "name")
+            if e.params["name"] == memmod.KEPT]
+    # the output [rows, T, dv] and the logsumexp [rows, 1, T] of each
+    shapes = sorted({tuple(e.outvars[0].aval.shape) for e in tags})
+    H, _, _, dv, _ = LAYOUTS[layout]
+    assert shapes == sorted({(H, T, dv), (H, 1, T)})
+    before = counter.value(kind=kind, decision="recomputed", why="wide")
+    again_grad, _ = _two_segments(layout, keep=False)
+    again = jax.make_jaxpr(again_grad)(*args).jaxpr
+    assert counter.value(kind=kind, decision="recomputed",
+                         why="wide") == before + 1
+    assert _kernel_names(again).count("causal_flash_fwd") == 4
+    assert not [e for e in _eqns(again, "name")
+                if e.params["name"] == memmod.KEPT]
+    for a, b in zip(grad(*args), again_grad(*args)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_blocked_xla_path_keeps_the_output_alone():
+    """Off the chip in ``auto`` the same segments take the blocked rows:
+    one tag a site, on the ``[B, T, H, dv]`` result, and the recomputation's
+    map over the rows is gone (one ``scan`` a segment fewer)."""
+    grad, args = _two_segments("full_g6", keep=True)
+    kept = jax.make_jaxpr(grad)(*args).jaxpr
+    tags = [e for e in _eqns(kept, "name")
+            if e.params["name"] == memmod.KEPT]
+    H, _, _, dv, _ = LAYOUTS["full_g6"]
+    assert {tuple(e.outvars[0].aval.shape) for e in tags} == {(1, T, H, dv)}
+    again = jax.make_jaxpr(_two_segments("full_g6", keep=False)[0])(
+        *args).jaxpr
+    assert len(list(_eqns(again, "scan"))) \
+        - len(list(_eqns(kept, "scan"))) == 2
+
+
+def test_a_caller_outside_a_segment_keeps_nothing(forced):
+    """``keep=True`` with no recomputing policy around the call: no tag, on
+    either path, and ``why=no_policy``."""
+    q, k, v = _qkv("full_g6", jnp.float32)
+    counter = tel.registry.get("attention.kept")
+    before = counter.value(kind="full", decision="recomputed",
+                           why="no_policy")
+    fn = jax.grad(lambda *a: jnp.sum(ca.causal_attention(
+        *a, block=BLOCK, keep=True) ** 2), argnums=(0, 1, 2))
+    assert not list(_eqns(jax.make_jaxpr(fn)(q, k, v).jaxpr, "name"))
+    assert counter.value(kind="full", decision="recomputed",
+                         why="no_policy") == before + 1
+    # and under a policy that recomputes nothing
+    none = memmod.checkpoint(fn, memmod.resolve_policy("none"))
+    assert none is fn and not memmod.recomputing()

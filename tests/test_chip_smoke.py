@@ -34,7 +34,8 @@ def tiny(smoke):
         flash_shapes=((2, 2, 32, 16),), decode_shape=(2, 2, 64, 16),
         causal_shape=(1, 256, 4, 2, 16), causal_window=100, causal_block=128,
         page=8, verify_window=4, ln_shape=(32, 128),
-        affine_shape=(64, 128), lstm_shape=(8, 16), dp_batch=8, dp_steps=2,
+        affine_shape=(64, 128), lstm_shape=(8, 16),
+        kept_shape=(2, 32, 32, 4, 8, 48, 40, 2), dp_batch=8, dp_steps=2,
         dp_tol=0.5, lr=1e-3)
 
 
@@ -96,6 +97,10 @@ def test_generate_on_auto_off_tpu_is_refused(smoke, tiny):
 
 def test_rehearse_kernels(smoke, tiny):
     smoke.kernels_phase(tiny, interpret=True)
+
+
+def test_rehearse_kept(smoke, tiny):
+    smoke.kept_phase(tiny)
 
 
 def test_rehearse_data_parallel(smoke, tiny):

@@ -14,13 +14,19 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.data.dataset import DataSet
+from deeplearning4j_tpu.models.decoder_stack import decoder_stack
 from deeplearning4j_tpu.nn import memory as memmod
 from deeplearning4j_tpu.nn.config import InputType, NeuralNetConfiguration
+from deeplearning4j_tpu.nn.layers import decoder as decmod
 from deeplearning4j_tpu.nn.layers.core import (DenseLayer, DropoutLayer,
                                                OutputLayer)
+from deeplearning4j_tpu.nn.layers.decoder import (CausalSelfAttentionLayer,
+                                                  GatedDenseLayer,
+                                                  LatentAttentionLayer)
 from deeplearning4j_tpu.nn.model import MultiLayerNetwork
 from deeplearning4j_tpu.nn.updaters import Adam
 from deeplearning4j_tpu.parallel.data_parallel import ParallelWrapper
+from deeplearning4j_tpu.runtime import telemetry as tel
 
 ATOL = 1e-6
 MODES = ("none", "full", "dots_saveable", "every_2")
@@ -504,3 +510,254 @@ def test_policy_ledger_marks():
     rep = memmod.policy_coverage_report()
     assert not rep["untested"], rep
     assert rep["coverage"] == 1.0
+
+
+# ---- the attention output a segment keeps (ISSUE 38) ----------------------
+# A recomputing policy keeps arrays tagged ``memmod.KEPT``; the two attention
+# layers of nn/layers/decoder.py tag their heads' output where it is no wider
+# than twice the layer's input.
+
+KEPT_T, KEPT_HIDDEN = 24, 32
+ATTENTION = {
+    "full": lambda heads=4: CausalSelfAttentionLayer(
+        n_heads=heads, n_kv_heads=2, head_size=8),
+    "window": lambda heads=4: CausalSelfAttentionLayer(
+        n_heads=heads, n_kv_heads=2, head_size=8, window=8),
+    "latent": lambda heads=4: LatentAttentionLayer(
+        n_heads=heads, nope_head_size=8, rope_head_size=4, v_head_size=8,
+        kv_rank=16),
+}
+#: (mask kind, positions, passes) -> the path ``causal_attention`` takes and
+#: the products of one attention forward that leave the recomputation. In
+#: one block the scores are what the backward pass keeps, so only the value
+#: product goes; a blocked path's blocks are checkpoints of their own and
+#: the whole forward goes: two products a block of rows (two blocks of 1,024
+#: at 2,048 positions), two batched ones for a window inside a block (the
+#: XLA block is 128 under a window of 8).
+KEPT_CASES = {
+    "full": ("full", KEPT_T, 1, "one_block", 1),
+    "window": ("window", KEPT_T, 1, "one_block", 1),
+    "latent": ("latent", KEPT_T, 1, "one_block", 1),
+    "repeated_run": ("full", KEPT_T, 3, "one_block", 1),
+    "full_blocked_rows": ("full", 2048, 1, "blocked_rows", 4),
+    "window_blocked_pairs": ("window", 256, 1, "blocked_pairs", 2),
+    "latent_blocked_rows": ("latent", 2048, 1, "blocked_rows", 4),
+}
+
+
+def _decoder(kind, mode="every_6", heads=4, passes=1, t=KEPT_T):
+    net = decoder_stack(
+        vocab_size=40, hidden_size=KEPT_HIDDEN, n_layers=2, eps=1e-6,
+        attention=lambda i: ATTENTION[kind](heads),
+        mlp=lambda i: GatedDenseLayer(n_hidden=48), seq_len=t,
+        workspace_mode=mode, passes=passes, seed=38).init()
+    ids = np.random.default_rng(38).integers(0, 40, (2, t), dtype=np.int32)
+    return net, ids
+
+
+def _decoder_grad(net, ids):
+    """The jitted gradient of the net's own training loss, and its
+    arguments."""
+    loss_fn = net._build_loss_fn()
+    y = jnp.ones((ids.shape[0], 1), jnp.float32)
+    fn = jax.jit(jax.grad(lambda p: loss_fn(
+        p, net.state, None, (jnp.asarray(ids),), (y,), (None,), (None,))[0]))
+    return fn, net.params
+
+
+def _kept(**labels):
+    return tel.registry.get("attention.kept").value(**labels)
+
+
+def _parent_checkpoint(fn, policy, prevent_cse=True):
+    """``memory.checkpoint`` as it was before anything was kept by name,
+    with the barrier against CSE around every segment."""
+    return jax.checkpoint(fn, policy=policy.saveable) if policy.remat else fn
+
+
+def _products(jaxpr):
+    """``dot_general``s in a jaxpr, through every nested one (a scan's or a
+    map's body counts once)."""
+    return sum((eqn.primitive.name == "dot_general")
+               + sum(_products(sub)
+                     for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("case", list(KEPT_CASES))
+def test_kept_output_leaves_the_gradients_bit_equal(monkeypatch, case):
+    """A kept array is the array the forward pass computed, where the
+    recomputation made an identical copy: every gradient leaf is equal to
+    the last bit, on every path of ``causal_attention`` and where the
+    segments lie in a repeated run's scanned pass body; and the compiled
+    backward holds fewer FLOPs."""
+    kind, t, passes, path, _ = KEPT_CASES[case]
+    net, ids = _decoder(kind, passes=passes, t=t)
+    before = _kept(kind=kind, decision="kept")
+    path = dict(kind=kind, decision=path,
+                **({} if path == "one_block" else {"why": "platform"}))
+    took = tel.registry.get("attention.dispatch").value(**path)
+    fn, params = _decoder_grad(net, ids)
+    kept_flops = fn.lower(params).compile().cost_analysis()["flops"]
+    kept = fn(params)
+    assert _kept(kind=kind, decision="kept") > before
+    assert tel.registry.get("attention.dispatch").value(**path) > took
+    monkeypatch.setattr(decmod, "_keeps_output", lambda *a: False)
+    before = _kept(kind=kind, decision="recomputed", why="wide")
+    fn, params = _decoder_grad(net, ids)
+    again_flops = fn.lower(params).compile().cost_analysis()["flops"]
+    again = fn(params)
+    assert _kept(kind=kind, decision="recomputed", why="wide") > before
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(again)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert kept_flops < again_flops
+
+
+@pytest.mark.parametrize("case", list(KEPT_CASES))
+def test_kept_output_takes_the_forward_out_of_the_recomputation(monkeypatch,
+                                                                case):
+    """Counted in the gradient's jaxpr: with the output kept, the products
+    of the attention forward are gone from the segment's recomputation, in
+    both layers, and no other product is."""
+    kind, t, passes, _, gone = KEPT_CASES[case]
+    net, ids = _decoder(kind, passes=passes, t=t)
+    fn, params = _decoder_grad(net, ids)
+    kept = _products(jax.make_jaxpr(fn)(params).jaxpr)
+    monkeypatch.setattr(decmod, "_keeps_output", lambda *a: False)
+    fn, params = _decoder_grad(net, ids)
+    again = _products(jax.make_jaxpr(fn)(params).jaxpr)
+    assert again - kept == 2 * gone, (kept, again)
+
+
+@pytest.mark.parametrize("heads,times,decision", [
+    (4, 1, "kept"), (8, 2, "kept"), (12, 3, "recomputed")],
+    ids=["1x", "2x", "3x"])
+@pytest.mark.parametrize("kind", ["full", "latent"])
+def test_a_layer_keeps_up_to_twice_its_input(monkeypatch, kind, heads, times,
+                                             decision):
+    """The rule reads the layer's own shapes: heads x value width against
+    the hidden size. A layer three times as wide recomputes as before
+    (``why=wide``) and its program lowers to the text it had when the
+    policies kept nothing by name."""
+    assert heads * 8 == times * KEPT_HIDDEN
+    net, ids = _decoder(kind, heads=heads)
+    labels = dict(kind=kind, decision=decision)
+    if decision == "recomputed":
+        labels["why"] = "wide"
+    before = _kept(**labels)
+    fn, params = _decoder_grad(net, ids)
+    text = fn.lower(params).as_text()
+    assert _kept(**labels) == before + 2                # one a layer
+    monkeypatch.setattr(memmod, "checkpoint", _parent_checkpoint)
+    fn, params = _decoder_grad(net, ids)
+    parent = fn.lower(params).as_text()
+    assert (text == parent) == (decision == "recomputed")
+
+
+def test_nothing_is_kept_without_a_recomputing_policy():
+    """Outside a recomputed segment nothing runs twice: the site counts
+    ``why=no_policy`` and carries no tag, in training under ``none`` and in
+    ``output()``."""
+    net, ids = _decoder("full", mode=None)
+    before = _kept(kind="full", decision="recomputed", why="no_policy")
+    fn, params = _decoder_grad(net, ids)
+    jaxpr = jax.make_jaxpr(fn)(params)
+    assert "attention.kept" not in str(jaxpr)
+    net.output(ids)
+    assert _kept(kind="full", decision="recomputed",
+                 why="no_policy") == before + 4
+    assert not memmod.recomputing()
+
+
+@pytest.mark.parametrize("mode", ["full", "dots_saveable", "every_2"])
+def test_kept_output_under_every_recomputing_policy(monkeypatch, mode):
+    """``full``, ``every_<k>`` and ``dots_saveable`` (joined with the name
+    by ``save_from_both_policies``) all keep the tagged output: the backward
+    holds fewer products than with the tag left off, and the gradients are
+    equal."""
+    memmod.mark_policy_tested(mode)
+    net, ids = _decoder("full", mode=mode)
+    fn, params = _decoder_grad(net, ids)
+    kept_n = _products(jax.make_jaxpr(fn)(params).jaxpr)
+    kept = fn(params)
+    monkeypatch.setattr(decmod, "_keeps_output", lambda *a: False)
+    fn, params = _decoder_grad(net, ids)
+    again_n = _products(jax.make_jaxpr(fn)(params).jaxpr)
+    again = fn(params)
+    # one value product a layer; under dots_saveable every product was kept
+    # already and there is nothing to gain
+    assert again_n - kept_n == (0 if mode == "dots_saveable" else 2)
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(again)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("mode", ["full", "dots_saveable", "every_2"])
+@pytest.mark.parametrize("engine", ["mln", "graph", "samediff"])
+def test_untagged_programs_lower_as_before(monkeypatch, engine, mode):
+    """A policy that keeps a name no array carries lowers to the program
+    ``policy=None`` gave, byte for byte: a ``MultiLayerNetwork``, a
+    ``ComputationGraph`` of dense layers and a SameDiff graph's segments,
+    none of which tags anything. ``dots_saveable`` joined with the name
+    decides as ``dots_saveable`` alone does, but jax then emits the SameDiff
+    graph's identical private ``_where`` functions once a call site, so that
+    policy is held to the same functions with the copies folded."""
+    def text_of(lowered):
+        if mode != "dots_saveable":
+            return lowered.as_text()
+        import re
+        funcs = [re.sub(r"[\s}]+$", "", f) for f in re.sub(
+            r"@(\w+?)_\d+\(", r"@\1(", lowered.as_text()).split(
+                "\n  func.func ")]
+        return funcs[:2], set(funcs[2:])           # header, main; the rest
+
+    def lowered():
+        if engine == "samediff":
+            sd = _mini_transformer_sd(mode)
+            names = [n for n, v in sd._vars.items() if v.kind == "VARIABLE"]
+            tv = {n: sd._values[n] for n in names}
+            ov = {n: v for n, v in sd._values.items() if n not in tv}
+            loss_fn = sd._fit_loss_fn()
+            return text_of(jax.jit(jax.grad(loss_fn)).lower(
+                tv, ov, _sd_feeds()))
+        x, y = _data(16)
+        if engine == "mln":
+            net = MultiLayerNetwork(_mln_conf(mode)).init()
+            args = (jnp.asarray(x), jnp.asarray(y), None, None)
+        else:
+            from deeplearning4j_tpu.nn.graph import ComputationGraph
+            net = ComputationGraph(_graph_conf(mode)).init()
+            args = ((jnp.asarray(x),), (jnp.asarray(y),), (None,), (None,))
+        loss_fn = net._build_loss_fn()
+        return text_of(jax.jit(jax.grad(lambda p: loss_fn(
+            p, net.state, jax.random.PRNGKey(0), *args)[0])).lower(
+                net.params))
+
+    text = lowered()
+    monkeypatch.setattr(memmod, "checkpoint", _parent_checkpoint)
+    assert text == lowered()
+
+
+def _remats(jaxpr, in_scan=False):
+    """(prevent_cse, lies in a scan's body) of every ``jax.checkpoint``
+    equation of a jaxpr, through the nested ones."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "remat2":
+            yield eqn.params["prevent_cse"], in_scan
+        inside = in_scan or eqn.primitive.name == "scan"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _remats(sub, inside)
+
+
+@pytest.mark.parametrize("passes", [1, 3], ids=["walked_once", "run"])
+def test_segments_in_a_run_carry_no_cse_barrier(passes):
+    """The segments of a repeated run's pass body lie under a scan, where
+    the forward and the backward pass are two loops and nothing can merge
+    the recomputation into the forward: they are checkpointed with
+    ``prevent_cse=False``. Every segment outside a scan keeps the barrier."""
+    net, ids = _decoder("full", passes=passes)
+    fn, params = _decoder_grad(net, ids)
+    seen = set(_remats(jax.make_jaxpr(fn)(params).jaxpr))
+    assert (True, False) in seen                    # embedding, head
+    assert ((False, True) in seen) == (passes > 1)
+    # (True, True) is the loss head's own checkpoint of its chunks
+    assert (False, False) not in seen
