@@ -976,8 +976,11 @@ class SameDiff(_SentinelCounterMixin):
                 # compute dtype (ISSUE 14 satellite: once a call, not once
                 # a step; self._values keeps the f32 originals) and a fresh
                 # optimizer state
-                copies, cast, opt_state = self._fit_prepare_cached()(
-                    train_vals, self._castable(other_vals))
+                prepare = self._fit_prepare_cached()
+                castable = self._castable(other_vals)
+                copies, cast, opt_state = prepare(train_vals, castable)
+                _tel.record_dispatch("samediff.fit_prepare", prepare,
+                                     (train_vals, castable))
                 carry = train_vals if copies is None else (train_vals, copies)
                 other_vals.update(cast)
                 _FIT_PREPARE.inc(decision="compiled")
@@ -1020,10 +1023,12 @@ class SameDiff(_SentinelCounterMixin):
                         # primitive is dispatched for it
                         step_i = np.int32(i)
                         sentinel = self._ensure_sentinel()
+                        args = (carry, opt_state, other_vals, step_i, feeds,
+                                sentinel)
                     with _TimedDispatch(span_labels, i):
-                        carry, opt_state, self._sentinel, loss = step(
-                            carry, opt_state, other_vals, step_i, feeds,
-                            sentinel)
+                        carry, opt_state, self._sentinel, loss = step(*args)
+                    _tel.record_dispatch("samediff.fit_step", step, args)
+                    del args
                     train_vals = self._carry_masters(carry)
                     i += 1
                     self.iteration = i

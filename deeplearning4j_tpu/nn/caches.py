@@ -290,3 +290,10 @@ class CompiledCacheMixin(SentinelCounterMixin):
         """Context manager for ONE train-step dispatch: the ``step_s``
         span + step annotation (see ``_TimedDispatch``)."""
         return _TimedDispatch(labels, self.iteration)
+
+    def _program_labels(self) -> dict:
+        """The labels of this model's programs in the scope registry
+        (``telemetry.record_dispatch``): ``vertices`` are the names its
+        forward walk opens a ``jax.named_scope`` for, a vertex or layer
+        each (``_scope_names``)."""
+        return {"vertices": list(self._scope_names())}

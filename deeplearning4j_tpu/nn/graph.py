@@ -586,10 +586,11 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                 mks[name] = mks.get(ins[0])
                 continue
             kw = {"fold_act": fold[name]} if name in fold else {}
-            y, s_new, m = v.apply(
-                params.get(name, {}), [acts[i] for i in ins],
-                state.get(name, {}), train=train, rng=sub,
-                masks=[mks.get(i) for i in ins], **kw)
+            with jax.named_scope(name):
+                y, s_new, m = v.apply(
+                    params.get(name, {}), [acts[i] for i in ins],
+                    state.get(name, {}), train=train, rng=sub,
+                    masks=[mks.get(i) for i in ins], **kw)
             acts[name] = y
             mks[name] = m
             if s_new:
@@ -770,10 +771,11 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                         m[name] = m.get(ins[0])
                         continue
                     kw = {"fold_act": fold[name]} if name in fold else {}
-                    y, s_new, mk = v.apply(
-                        seg_params.get(name, {}), [a[i] for i in ins],
-                        seg_state.get(name, {}), train=train, rng=sub,
-                        masks=[m.get(i) for i in ins], **kw)
+                    with jax.named_scope(name):
+                        y, s_new, mk = v.apply(
+                            seg_params.get(name, {}), [a[i] for i in ins],
+                            seg_state.get(name, {}), train=train, rng=sub,
+                            masks=[m.get(i) for i in ins], **kw)
                     a[name] = y
                     m[name] = mk
                     if s_new:
@@ -1015,12 +1017,14 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                     self._key, sub = jax.random.split(self._key)
                     sentinel = self._ensure_sentinel()
                     start = jnp.int32(self.iteration)
+                    args = (self.params, self.updater_state, self.state,
+                            sentinel, start, sub, xs, ys)
                 with self._timed_dispatch(span_labels):
                     (self.params, self.updater_state, self.state,
-                     self._sentinel, losses) = \
-                        self._epoch_fn(self.params, self.updater_state,
-                                       self.state, sentinel, start, sub, xs,
-                                       ys)
+                     self._sentinel, losses) = self._epoch_fn(*args)
+                _tel.record_dispatch("train.epoch_fn", self._epoch_fn, args,
+                                     self._program_labels)
+                del args
                 self.iteration += nb
                 self.epoch += 1
                 self._count_passes(nb)
@@ -1034,6 +1038,10 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                 self._publish_layer_counters()
             self._score = float(out[-1])
             return out
+
+    def _scope_names(self):
+        """The names under which the walks scope each vertex's forward."""
+        return self._topo
 
     def _count_passes(self, steps: int):
         """``loop.passes``: what the launched steps walked of each repeated
@@ -1116,22 +1124,21 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                                     else x for x in xs)  # sentinel site
                         step = jnp.asarray(self.iteration, dtype=jnp.int32)
                         sentinel = self._ensure_sentinel()
+                        args = (self.params,) + ((params_c,) if fused else ()) \
+                            + (self.updater_state, self.state, step, sub, xs,
+                               ys, fms, lms, sentinel)
                     self._last_batch = xs  # StatsListener activation sampling
                     with self._timed_dispatch(span_labels):
                         if fused:
                             (self.params, params_c, self.updater_state,
                              self.state, self._sentinel, loss) = \
-                                self._train_step(self.params, params_c,
-                                                 self.updater_state,
-                                                 self.state, step, sub, xs,
-                                                 ys, fms, lms, sentinel)
+                                self._train_step(*args)
                         else:
                             (self.params, self.updater_state, self.state,
-                             self._sentinel, loss) = \
-                                self._train_step(self.params,
-                                                 self.updater_state,
-                                                 self.state, step, sub, xs,
-                                                 ys, fms, lms, sentinel)
+                             self._sentinel, loss) = self._train_step(*args)
+                    _tel.record_dispatch("train.step", self._train_step,
+                                         args, self._program_labels)
+                    del args
                     self._score = loss
                     self.iteration += 1
                     self._count_passes(1)

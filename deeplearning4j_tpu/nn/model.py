@@ -165,6 +165,7 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
         # the true per-layer activations; the training/inference walk folds
         fold, skip = ({}, frozenset()) if collect \
             else self._epilogue_fold_plan()
+        scopes = self._scope_names()
         for i, layer in enumerate(self.layers):
             si = str(i)
             p = params.get(si, {})
@@ -176,17 +177,21 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
             if i in skip:
                 continue  # activation folded into the previous BN; its
                 # rng split above still ran, so the stream is unchanged
-            if i in fold:
+            kw = {"fold_act": fold[i]} if i in fold else {}
+            with jax.named_scope(scopes[i]):
                 x, s_new, mask = layer.apply(p, x, s, train=train, rng=sub,
-                                             mask=mask, fold_act=fold[i])
-            else:
-                x, s_new, mask = layer.apply(p, x, s, train=train, rng=sub,
-                                             mask=mask)
+                                             mask=mask, **kw)
             if collect:
                 acts.append(x)
             if s_new:
                 new_state[si] = s_new
         return (acts if collect else x), new_state, mask
+
+    def _scope_names(self):
+        """The names under which the walks scope each layer's forward: the
+        layer's own, or ``layer<i>``."""
+        return [layer.name or f"layer{i}"
+                for i, layer in enumerate(self.layers)]
 
     def _epilogue_fold_plan(self):
         """Static BN+activation fold plan (ISSUE 16): every
@@ -229,6 +234,7 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
         from . import memory as _memory
         new_state = dict(state)
         fold, skip = self._epilogue_fold_plan()
+        scopes = self._scope_names()
         for s, e in _memory.segment_ranges(len(self.layers), policy.every):
             seg = list(range(s, e))
 
@@ -244,9 +250,10 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
                     if i in skip:  # folded act: split consumed, apply no-op
                         continue
                     kw = {"fold_act": fold[i]} if i in fold else {}
-                    x, s_new, mask = layer.apply(
-                        seg_params.get(si, {}), x, seg_state.get(si, {}),
-                        train=train, rng=sub, mask=mask, **kw)
+                    with jax.named_scope(scopes[i]):
+                        x, s_new, mask = layer.apply(
+                            seg_params.get(si, {}), x, seg_state.get(si, {}),
+                            train=train, rng=sub, mask=mask, **kw)
                     if s_new:
                         ns[si] = s_new
                 return x, ns, mask, rng
@@ -490,12 +497,14 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
                     self._key, sub = jax.random.split(self._key)
                     sentinel = self._ensure_sentinel()
                     start = jnp.int32(self.iteration)
+                    args = (self.params, self.updater_state, self.state,
+                            sentinel, start, sub, xs, ys)
                 with self._timed_dispatch(span_labels):
                     (self.params, self.updater_state, self.state,
-                     self._sentinel, losses) = \
-                        self._epoch_fn(self.params, self.updater_state,
-                                       self.state, sentinel, start, sub, xs,
-                                       ys)
+                     self._sentinel, losses) = self._epoch_fn(*args)
+                _tel.record_dispatch("train.epoch_fn", self._epoch_fn, args,
+                                     self._program_labels)
+                del args
                 self.iteration += nb
                 self.epoch += 1
                 self._score = losses[-1]  # lazy device scalar for listeners
@@ -576,22 +585,21 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
                         # traced, no retrace per step
                         step = jnp.asarray(self.iteration, dtype=jnp.int32)
                         sentinel = self._ensure_sentinel()
+                        args = (self.params,) + ((params_c,) if fused else ()) \
+                            + (self.updater_state, self.state, step, sub, x,
+                               y, fm, lm, sentinel)
                     self._last_batch = x  # StatsListener activation sampling
                     with self._timed_dispatch(span_labels):
                         if fused:
                             (self.params, params_c, self.updater_state,
                              self.state, self._sentinel, loss) = \
-                                self._train_step(self.params, params_c,
-                                                 self.updater_state,
-                                                 self.state, step, sub, x, y,
-                                                 fm, lm, sentinel)
+                                self._train_step(*args)
                         else:
                             (self.params, self.updater_state, self.state,
-                             self._sentinel, loss) = \
-                                self._train_step(self.params,
-                                                 self.updater_state,
-                                                 self.state, step, sub, x, y,
-                                                 fm, lm, sentinel)
+                             self._sentinel, loss) = self._train_step(*args)
+                    _tel.record_dispatch("train.step", self._train_step,
+                                         args, self._program_labels)
+                    del args
                     # keep the loss on device: score() syncs lazily, so the
                     # train loop never blocks on the host (async dispatch
                     # back-to-back)

@@ -714,6 +714,8 @@ class ParallelWrapper:
                     with m._timed_dispatch(span_labels):
                         (m.params, m.updater_state, m.state, m._sentinel,
                          loss) = step_fn(*args)
+                    _tel.record_dispatch("parallel.step", step_fn, args,
+                                         m._program_labels)
                     m._score = loss
                     m.iteration += 1
                     m._notify_listeners(span_labels, "iteration_done",

@@ -295,6 +295,11 @@ def rule(name: str, help: str):
 #: know the cause), so a compile inside them is not a finding there.
 _COMPILE_HELPER_ATTRS = ("_record_build",)
 
+#: functions that reach, through JAX's lowering cache, the executable of a
+#: program a build site has already attributed (telemetry.record_dispatch:
+#: the call before it compiled the program, this compiles nothing more)
+_COMPILE_REUSE_FUNCS = ("record_dispatch",)
+
 
 @rule("compile-attribution",
       "every function that AOT-compiles (.lower(...).compile()) must "
@@ -324,7 +329,8 @@ def _check_compile_attribution(idx: ModuleIndex):
 
     for qual, fn in _function_scopes(idx.tree):
         sites = list(compile_calls(fn))
-        if not sites or records(fn):
+        if not sites or records(fn) or \
+                qual.rsplit(".", 1)[-1] in _COMPILE_REUSE_FUNCS:
             continue
         for node in sites:
             yield Finding(

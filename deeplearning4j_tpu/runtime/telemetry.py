@@ -12,7 +12,7 @@ that layer. Every pre-existing accessor (``flash_attention.counters()``,
 ``engine.stats()``, ``pi.stats()``, ``faults.telemetry_snapshot()``…)
 stays callable and is now a *view* over this registry.
 
-Four pieces:
+Five pieces:
 
 - **MetricsRegistry** — thread-safe counters, gauges, and bounded
   timestamped-reservoir histograms (p50/p99 over lifetime or any recent
@@ -37,19 +37,32 @@ Four pieces:
   ``params_placement`` / ``first_build`` …). Steady-state training must
   show zero post-warmup events (regression-tested); before this tracker
   a silent retrace was invisible until the step time doubled.
+- **Scope registry** — :func:`record_program` is handed the executable
+  of each training program after its first dispatch (through
+  :func:`record_dispatch`: both engines' ``fit`` / ``fit_on_device``,
+  ``SameDiff.fit``, ``ParallelWrapper.fit``) and keeps the optimized HLO
+  of the newest :data:`PROGRAMS_KEPT`; :func:`program_scopes` turns it,
+  on request, into a table of every instruction with the program's scope
+  path, its phase by :func:`scope_phase` (forward, recompute, backward,
+  updater, sentinel, clip) and its vertex. A device trace names operations
+  by HLO instruction and knows no scope; the benchmark's readers join the
+  two (``benchmarks/harness/scopes.py``).
 - **Export** — ``prometheus_text()`` (text exposition served by
   ``JsonModelServer GET /metrics``), ``event_log(path)`` (JSONL sink for
   spans + compile events), and ``snapshot()``.
 
 Kill switch: ``DL4J_TPU_TELEMETRY=off`` (or :func:`set_enabled`) gates
 the *timing* instrumentation — histogram observes, spans, step
-annotations, the phase clocks in the fit/serving loops — which is what
-the bench's ``telemetry_overhead`` metric A/Bs. Counters and gauges
-ALWAYS record: they are functional accounting (fault-injection ledgers,
-serving counters, compile counts) that product code and tests read, and
-each costs one dict add. Latency-derived surfaces (``stats()``
-percentiles, ``degraded_p99_ms`` health) go quiet when disabled —
-documented, deliberate. stdlib-only at import time so every layer can
+annotations, the phase clocks in the fit/serving loops. Nothing in the
+benchmark reads the switch (``bench.py`` and its ``telemetry_overhead``
+A/B went in PR 33): it is an operator's, and ``tests/test_telemetry.py``
+and ``tests/test_train_spans.py`` hold it to leaving no event and
+bit-equal results. Counters and gauges ALWAYS record: they are functional
+accounting (fault-injection ledgers, serving counters, compile counts)
+that product code and tests read, and each costs one dict add; the scope
+registry records under the switch too, once a program. Latency-derived
+surfaces (``stats()`` percentiles, ``degraded_p99_ms`` health) go quiet
+when disabled — documented, deliberate. stdlib-only at import time so every layer can
 import this module without cycles (same contract as ``faults.py``).
 
 Coverage floor: metrics registered at import time land in a ledger
@@ -74,7 +87,9 @@ __all__ = [
     "MetricsRegistry", "registry", "counter", "gauge", "histogram",
     "enabled", "set_enabled", "span", "spans", "current_span", "event_log",
     "emit_event", "record_compile", "compile_events",
-    "reset_compile_events", "step_annotation", "prometheus_text",
+    "reset_compile_events", "record_program", "record_dispatch",
+    "program_scopes", "scope_phase", "reset_programs",
+    "step_annotation", "prometheus_text",
     "snapshot", "coverage_report",
     # per-request distributed tracing (ISSUE 13)
     "RequestTrace", "start_request_trace", "get_trace", "recent_traces",
@@ -936,6 +951,205 @@ def compile_events(site: Optional[str] = None) -> List[dict]:
 def reset_compile_events() -> None:
     with _compiles_lock:
         _compile_log.clear()
+
+
+# --------------------------------------------------- program scope tables
+#: A device trace names every operation by its HLO instruction and carries
+#: none of the program's scopes; the optimized HLO of the executable that
+#: ran carries them, in each instruction's ``op_name``. The sites that
+#: dispatch a training program hand its executable to
+#: :func:`record_program` once; :func:`program_scopes` turns what was kept
+#: into plain tables a trace's events are joined with by instruction name.
+
+PROGRAMS_KEPT = 8    #: programs whose tables are kept, the newest
+
+#: what a scope path is counted as, first match in this order: under one of
+#: ``gradient_tail``'s scopes; recomputed inside a checkpointed segment; the
+#: transpose of the loss function's ``forward``; its forward
+PHASES = ("updater", "sentinel", "clip", "recompute", "backward", "forward",
+          "other")
+
+_programs_lock = threading.Lock()
+_programs: deque = deque(maxlen=PROGRAMS_KEPT)
+_DISPATCHED = "_dl4j_tpu_program_recorded"
+
+_HLO_INSTR = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_SHAPE = re.compile(r"[a-z][a-z0-9]*\[[^\]]*\]")
+_HLO_OPCODE = re.compile(r" ([a-z][a-z\-]*)\(")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+#: a scope of the program's is a vertex's name or a word, dots and dashes
+#: inside (``attn.latent.project``); an einsum's subscripts, which JAX puts
+#: on the path too, are not
+_SCOPE_NAME = re.compile(r"^[A-Za-z_][\w.\-]*$")
+#: path components that are JAX's own (control flow, checkpointing, custom
+#: derivatives), not scopes of the program
+_JAX_STRUCTURE = re.compile(
+    r"^(while|body|cond|closed_call|checkpoint|rematted_computation|"
+    r"branch_\d+_fun|custom_vjp_call\w*|custom_jvp_call\w*|pjit|remat\w*)$")
+
+
+def record_program(site: str, executable, **labels) -> None:
+    """Keep the optimized HLO of a training program for
+    :func:`program_scopes`. ``executable`` is the loaded executable the
+    site runs (``compiled.runtime_executable()``); what is kept
+    is its ``hlo_modules()``, host data that holds neither the executable
+    nor a device buffer, and the newest :data:`PROGRAMS_KEPT` of them.
+    Nothing is rendered or parsed here. ``labels`` are kept as given and
+    must be plain data; ``vertices`` (names) lets the tables say which
+    vertex or layer of the model a scope path lies under. Always records,
+    like :func:`record_compile`: once a program, never a hot path."""
+    modules = list(executable.hlo_modules())
+    with _programs_lock:
+        _programs.append({"site": site, "labels": labels,
+                          "module": modules[0].name if modules else "",
+                          "hlo": modules, "instructions": None})
+
+
+def record_dispatch(site: str, fn, args, labels=None) -> None:
+    """:func:`record_program` for the program that ``fn(*args)`` has just
+    run, where it was a new one. Called after the call, outside the phase
+    spans: a ``jax.jit`` function's executable comes through JAX's own
+    lowering cache, which the call filled (only the avals of ``args`` are
+    read, so donated arrays do), and nothing compiles twice. New is judged
+    by the function's count of specialisations, kept on the function: a
+    second batch shape of one step function is a second program and gets
+    its own table, and every other call costs that one count. An
+    ahead-of-time ``Compiled`` is its own executable, recorded once.
+    ``labels`` is called for the labels, then only. A stand-in that is
+    neither (a test's plain function) records nothing."""
+    if hasattr(fn, "_cache_size"):
+        compiled = fn._cache_size()
+        if compiled == getattr(fn, _DISPATCHED, 0):
+            return
+        setattr(fn, _DISPATCHED, compiled)
+        executable = fn.lower(*args).compile().runtime_executable()
+    elif hasattr(fn, "runtime_executable"):
+        if getattr(fn, _DISPATCHED, 0):
+            return
+        setattr(fn, _DISPATCHED, 1)
+        executable = fn.runtime_executable()
+    else:
+        return
+    record_program(site, executable, **(labels() if labels else {}))
+
+
+def _scope_cores(scope: str) -> List[str]:
+    """The path's components without the transformations around them:
+    ``transpose(jvp(forward))`` -> ``forward``."""
+    return [c.rsplit("(", 1)[-1].split(")", 1)[0] for c in scope.split("/")]
+
+
+def scope_phase(scope: str, cores: Optional[List[str]] = None) -> str:
+    """One of :data:`PHASES` for a scope path (an ``op_name`` without its
+    ``jit(...)`` wrappers). THE rule, for every reader."""
+    if cores is None:
+        cores = _scope_cores(scope)
+    for c in cores:
+        if c in ("updater", "sentinel", "clip"):
+            return c
+    if "rematted_computation" in cores:
+        return "recompute"
+    if "transpose(" in scope:
+        return "backward"
+    return "forward" if "forward" in cores else "other"
+
+
+def _parse_hlo(text: str, vertices=()) -> dict:
+    """``{instruction: {shape, scope, scopes, phase, phases_inside,
+    vertex}}`` of one module's text: every instruction but those of fused computations,
+    which only give their fusion's ``phases_inside``."""
+    vertices = frozenset(vertices)
+    comps, fused, cur = {}, set(), None
+    for line in text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            c = _HLO_COMPUTATION.match(line)
+            if c is not None:
+                cur = comps.setdefault(c.group(1), [])
+            continue
+        if cur is None:
+            continue
+        name, rest = m.groups()
+        shape = _HLO_SHAPE.search(rest)
+        opcode = _HLO_OPCODE.search(rest)
+        op_name = _HLO_OP_NAME.search(rest)
+        scope = "/".join(c for c in op_name.group(1).split("/")
+                         if not c.startswith(("jit(", "pjit("))) \
+            if op_name else ""
+        calls = None
+        if opcode is not None and opcode.group(1) == "fusion":
+            called = _HLO_CALLS.search(rest)
+            if called is not None:
+                calls = called.group(1)
+                fused.add(calls)
+        cur.append((name, shape.group(0) if shape else "", scope, calls))
+    inside = {c: sorted({scope_phase(s) for _, _, s, _ in comps.get(c, ())
+                         if s} - {"other"}) for c in fused}
+
+    def named_inside(comp, seen=()):
+        """The scope of the instruction nearest the root of a fused
+        computation that carries one, through the fusions nested in it."""
+        for _, _, scope, calls in reversed(comps.get(comp, ())):
+            if not scope and calls and calls not in seen:
+                scope = named_inside(calls, seen + (comp,))
+            if scope:
+                return scope
+        return ""
+
+    table = {}
+    for comp, instrs in comps.items():
+        if comp in fused:
+            continue
+        for name, shape, scope, calls in instrs:
+            if calls and not scope:
+                # a fusion the compiler made and named after nothing
+                scope = named_inside(calls)
+            cores = _scope_cores(scope)
+            # the last component is the primitive's name
+            scopes = [c for c in cores[:-1] if c in vertices
+                      or (_SCOPE_NAME.match(c)
+                          and not _JAX_STRUCTURE.match(c))]
+            table[name] = {
+                "shape": shape, "scope": scope, "scopes": scopes,
+                "phase": scope_phase(scope, cores),
+                "phases_inside": inside[calls] if calls else [],
+                "vertex": next((c for c in scopes if c in vertices), None)}
+    return table
+
+
+def program_scopes(site: Optional[str] = None) -> List[dict]:
+    """The kept programs' scope tables, oldest first: ``{site, labels,
+    module, instructions: {name: {shape, scope, scopes, phase,
+    phases_inside, vertex}}}`` of plain data. ``name`` is the HLO
+    instruction's (a trace event's name starts with it), ``shape`` its
+    first result's ``dtype[dims]``, ``scope`` its ``op_name`` path without
+    the ``jit(...)`` wrappers (a fusion that carries none is named after
+    the instruction nearest its root that does), ``scopes`` the program's
+    own names on that path, in order (the transformations around a name,
+    JAX's structural components, an einsum's subscripts and the primitive dropped), ``phase``
+    :func:`scope_phase` of the path, ``phases_inside`` for a fusion the
+    phases, ``other`` aside, of the instructions fused into it (a
+    weight-gradient kernel with the sentinel's sum riding it lists both),
+    ``vertex`` the first of ``scopes`` that the ``vertices`` label names. A
+    program is rendered and parsed at its first request; the HLO is let go
+    then."""
+    with _programs_lock:
+        kept = [p for p in _programs if site is None or p["site"] == site]
+    for p in kept:
+        if p["instructions"] is None:
+            table = {}
+            for module in p["hlo"]:
+                table.update(_parse_hlo(module.to_string(),
+                                        p["labels"].get("vertices", ())))
+            p["instructions"], p["hlo"] = table, None
+    return [{k: v for k, v in p.items() if k != "hlo"} for p in kept]
+
+
+def reset_programs() -> None:
+    with _programs_lock:
+        _programs.clear()
 
 
 # ---------------------------------------------------- per-request tracing

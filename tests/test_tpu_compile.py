@@ -424,6 +424,60 @@ def test_data_parallel_step_compiles_for_the_mesh(topo, one_chip,
     assert fe.counters()["fused"] == 1
 
 
+def test_the_scope_table_of_a_step_compiled_for_the_chip(one_chip):
+    """``telemetry.program_scopes`` on the TPU compiler's text (tiled
+    layouts, tuple results, fusions named after one of the instructions
+    fused into them): a train step's weight-gradient kernels are the
+    ``multiply_reduce_fusion f32[]`` of the device traces, the sentinel's
+    sum of squares riding the product as the tuple's first result, and
+    their ``phases_inside`` says so (PERF.md section 5, corrected in PR 34)."""
+    from deeplearning4j_tpu.nn import memory
+    from deeplearning4j_tpu.nn.config import (InputType,
+                                              NeuralNetConfiguration)
+    from deeplearning4j_tpu.nn.layers.core import DenseLayer, OutputLayer
+    from deeplearning4j_tpu.nn.model import MultiLayerNetwork
+    from deeplearning4j_tpu.nn.updaters import Adam
+    from deeplearning4j_tpu.runtime import sentinel
+    from deeplearning4j_tpu.runtime import telemetry as tel
+
+    conf = (NeuralNetConfiguration.builder().seed(3).data_type("BFLOAT16")
+            .updater(Adam(learning_rate=1e-2))
+            .input_type(InputType.feed_forward(256))
+            .list(DenseLayer(n_out=512, activation="tanh", name="hidden"),
+                  DenseLayer(n_out=256, activation="relu"),
+                  OutputLayer(n_out=128))
+            .build())
+    net = MultiLayerNetwork(conf).init()
+    x, y = memory._batch_avals(net, 256)
+    args = (jax.eval_shape(lambda: net.params),
+            jax.eval_shape(lambda: net.updater_state),
+            jax.eval_shape(lambda: net.state),
+            jax.ShapeDtypeStruct((), np.int32),
+            jax.eval_shape(lambda: jax.random.PRNGKey(0)), x, y, None, None,
+            sentinel.counter_avals())
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), args)
+    compiled = net._build_train_step(1).lower(*args).compile()
+    tel.reset_programs()
+    tel.record_program("train.step", compiled.runtime_executable(),
+                       **net._program_labels())
+    table, = tel.program_scopes()
+    tel.reset_programs()
+    ins = table["instructions"]
+    assert {i["phase"] for i in ins.values()} >= {
+        "forward", "backward", "updater", "sentinel"}
+    assert {i["vertex"] for i in ins.values()} >= {"hidden", "layer1",
+                                                   "layer2"}
+    # every shape is dtype[dims]: no layout, no tiling, no tuple
+    assert all(re.fullmatch(r"([a-z][a-z0-9]*\[[^\]{}()]*\])?", i["shape"])
+               for i in ins.values())
+    riding = [i for n, i in ins.items()
+              if n.startswith("multiply_reduce_fusion")
+              and i["shape"] == "f32[]"
+              and {"backward", "sentinel"} <= set(i["phases_inside"])]
+    assert len(riding) >= 3, sorted(ins)[:40]
+
+
 def _on_the_chip(monkeypatch):
     """The dispatchers ask the backend, which is the CPU here: steer them
     onto their TPU branch for a lowering meant for the described chip."""
