@@ -23,6 +23,7 @@ import numpy as np
 
 from ...ops import activations as _act
 from ...ops import causal_attention as _ca
+from ...ops import lm_loss as _lm
 from ...ops import moe as _moe
 from ...runtime import telemetry as _tel
 from .. import weights as _winit
@@ -377,9 +378,18 @@ class CausalLMOutputLayer(Layer):
     hidden states and the token ids that went in, and scores position ``t``
     against token ``t + 1``. The loss is the mean cross-entropy, in float32,
     over the positions that have a next token (each row's last has none and
-    is left out); the labels handed to ``fit`` are not read. In training
-    ``apply`` returns the per-position losses ``[B, T - 1]``, otherwise the
-    probabilities ``[B, T, n_out]``."""
+    is left out); the labels handed to ``fit`` are not read.
+
+    Logits are float32 and are made a sequence at a time, and each sequence
+    is visited once: where the loss is differentiated its logits' cotangent,
+    the product back to the hidden states and the sequence's term of ``W``'s
+    gradient are made while its logits exist (``ops/lm_loss.py``), and the
+    backward pass scales them. A recomputed segment keeps those gradients
+    and the per-position losses (``nn/memory.py`` ``KEPT``) and recomputes
+    nothing of the head. In training ``apply`` returns the loss itself, a
+    float32 scalar that ``loss_value`` hands on (only a scalar can be
+    differentiated through this head, and not in forward mode), otherwise
+    the probabilities ``[B, T, n_out]``."""
     n_inputs = 2
     quantizable = True
     n_out: int = 0
@@ -402,27 +412,21 @@ class CausalLMOutputLayer(Layer):
                              preferred_element_type=jnp.float32)
             return jax.nn.softmax(logits, axis=-1), state, mask
 
-        # a sequence at a time, recomputed in the backward pass: the float32
-        # logits of one sequence are all that is ever held
-        @jax.checkpoint
-        def row(args):
-            hr, nxt = args
-            logits = jnp.dot(hr, params["W"],
-                             preferred_element_type=jnp.float32)
-            picked = jnp.take_along_axis(logits, nxt[:, None], axis=-1)[:, 0]
-            return jax.nn.logsumexp(logits, axis=-1) - picked
+        B, T = h.shape[:2]
+        loss, _ = _lm.weighted_cross_entropy(
+            h[:, :-1], params["W"], jnp.asarray(tokens, jnp.int32)[:, 1:],
+            jnp.full((B, T - 1), 1.0 / (B * (T - 1)), jnp.float32),
+            layer="causal")
+        return loss, state, None
 
-        nll = jax.lax.map(row, (h[:, :-1],
-                                jnp.asarray(tokens, jnp.int32)[:, 1:]))
-        return nll, state, None
-
-    def loss_value(self, nll, labels, mask=None, weights=None):
-        return jnp.mean(nll.astype(jnp.float32))
+    def loss_value(self, loss, labels, mask=None, weights=None):
+        return loss
 
 
 # positions of one pass whose logits ``ExitWeightedLMOutputLayer`` holds at a
-# time: 805 MB of float32 logits at a vocabulary of 49,152, and no width of
-# the decoders here, so that a trace tells the head's blocks by their shape
+# time, and visits once: 805 MB of float32 logits at a vocabulary of 49,152
+# beside their cotangent, and no width of the decoders here, so that a trace
+# tells the head's blocks by their shape
 LM_HEAD_BLOCK = 4096
 
 _EXIT_MASS = _tel.counter(
@@ -445,12 +449,21 @@ class ExitWeightedLMOutputLayer(Layer):
     of ``sum_t p(t, i) ce(t, i) - beta H(p(., i))``.
 
     Logits are float32 and are made ``LM_HEAD_BLOCK`` positions of one pass
-    at a time, each block recomputed in the backward pass, so that one
-    block's logits are all that is ever held (a sequence length the block
-    does not divide is taken a whole row at a time); ``W``'s cotangent is the sum
-    over the passes' blocks in the dtype ``W`` arrives in. The gate, the
-    exit distribution (in log space) and the entropy are float32. In
-    training ``apply`` returns the per-position objective ``[B, T - 1]``,
+    at a time (a sequence length the block does not divide is taken a whole
+    row at a time), and each block is visited once. The exit distribution
+    comes first (the gate reads ``h``, not the logits), so the row weights
+    ``p(t, i) / N`` are known before the blocks are walked: where the loss is
+    differentiated a block's weighted ``softmax - onehot``, the product back
+    to the hidden states and the block's term of ``W``'s gradient are made
+    while its logits exist (``ops/lm_loss.py``), and the backward pass scales
+    them; ``p``'s gradient arrives through the weights' cotangent, the
+    cross-entropies. ``W``'s gradient is the sum over the passes' blocks in
+    the dtype ``W`` arrives in. A recomputed segment keeps the hidden states'
+    gradient, ``W``'s and the cross-entropies (``nn/memory.py`` ``KEPT``) and
+    recomputes nothing of the blocks. The gate, the exit distribution (in log
+    space) and the entropy are float32. In training ``apply`` returns the
+    objective itself, a float32 scalar that ``loss_value`` hands on (only a
+    scalar can be differentiated through this head, and not in forward mode),
     otherwise the last pass's probabilities ``[B, T, n_out]``: inference
     walks every pass and stops at none. The state sums, since ``init``, the
     exit probability of each pass over the positions with loss;
@@ -500,30 +513,26 @@ class ExitWeightedLMOutputLayer(Layer):
         R, B, T, d = h.shape
         C = LM_HEAD_BLOCK if T % LM_HEAD_BLOCK == 0 else T
         nxt = jnp.roll(jnp.asarray(tokens, jnp.int32), -1, axis=1)
-
-        @jax.checkpoint
-        def block(args):
-            hb, yb = args
-            logits = jnp.dot(hb, params["W"],
-                             preferred_element_type=jnp.float32)
-            picked = jnp.take_along_axis(logits, yb[:, None], axis=-1)[:, 0]
-            return jax.nn.logsumexp(logits, axis=-1) - picked
-
-        with jax.named_scope("lm_head.passes"):
-            ce = jax.lax.map(block, (
-                h.reshape(R * B * T // C, C, d),
-                jnp.tile(nxt.reshape(B * T // C, C), (R, 1))))
-            ce = ce.reshape(R, B, T)
+        n = B * (T - 1)
         with jax.named_scope("lm_head.exit"):
             logp = self.exit_log_probs(params, h)
             p = jnp.exp(logp)
-            # sum_t p ce - beta H(p), with H(p) = - sum_t p log p
-            loss = jnp.sum(p * (ce + self.beta * logp), axis=0)[:, :-1]
             mass = jnp.sum(jax.lax.stop_gradient(p)[:, :, :-1], axis=(1, 2))
-        return loss, {**state, "exit_mass": state["exit_mass"] + mass}, None
+            # each row's last position has no next token and weighs nothing
+            p = p * (jnp.arange(T) < T - 1)
+            # - beta H(p) a position, with H(p) = - sum_t p log p
+            spread = self.beta * jnp.sum(p * logp) / n
+        with jax.named_scope("lm_head.passes"):
+            # sum_t p ce over the positions, over their number
+            loss, _ = _lm.weighted_cross_entropy(
+                h.reshape(R * B * T // C, C, d), params["W"],
+                jnp.tile(nxt.reshape(B * T // C, C), (R, 1)),
+                (p / n).reshape(R * B * T // C, C), layer="exit_weighted")
+        return (loss + spread,
+                {**state, "exit_mass": state["exit_mass"] + mass}, None)
 
     def loss_value(self, loss, labels, mask=None, weights=None):
-        return jnp.mean(loss.astype(jnp.float32))
+        return loss
 
     def publish_counters(self, vertex: str, now: dict, before) -> None:
         """Add what the state's sums grew by since ``before`` (None: since

@@ -32,14 +32,18 @@ pass. This module adds the TPU-native control:
 
   What a recomputing policy keeps of a segment: the live activations at its
   boundary, whatever its ``saveable`` rule allows, and every array tagged
-  :data:`KEPT` (``jax.ad_checkpoint.checkpoint_name``). One thing is tagged
-  today: the output of a causal attention no wider than twice its layer's
-  input (``ops/causal_attention.py`` ``keep=``, asked for by the attention
-  layers of ``nn/layers/decoder.py``), and on the kernel path the logsumexp
-  beside it. The backward pass reads nothing else of an attention's forward,
-  so with the output kept the segment's recomputation holds no score
-  product (PERF.md, PR 38). A program that tags nothing lowers as it did
-  under ``policy=None``.
+  :data:`KEPT` (``jax.ad_checkpoint.checkpoint_name``). Two things are
+  tagged today. The output of a causal attention no wider than twice its
+  layer's input (``ops/causal_attention.py`` ``keep=``, asked for by the
+  attention layers of ``nn/layers/decoder.py``), and on the kernel path the
+  logsumexp beside it: the backward pass reads nothing else of an
+  attention's forward, so with the output kept the segment's recomputation
+  holds no score product (PERF.md, PR 38). And what a language-model head
+  made of its gradients in the forward pass (``ops/lm_loss.py``: the hidden
+  states' gradient, the weight's and the per-position cross-entropies), which
+  is all its backward rule reads, so the recomputation holds no logits
+  (PERF.md, PR 40). A program that tags nothing lowers as it did under
+  ``policy=None``.
 
   A "block" is a layer (MultiLayerNetwork), a vertex (ComputationGraph),
   or an attention-anchored op segment (imported SameDiff graphs — see

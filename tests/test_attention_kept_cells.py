@@ -11,6 +11,7 @@ the issue names as its fallback."""
 import importlib
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,7 @@ import pytest
 
 from deeplearning4j_tpu.nn import memory as memmod
 from deeplearning4j_tpu.nn.layers import decoder as decmod
+from deeplearning4j_tpu.ops import lm_loss
 from deeplearning4j_tpu.runtime import telemetry as tel
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -112,7 +114,10 @@ def test_which_layers_of_a_cell_keep(monkeypatch, name):
     with the rule switched off (or, for ``kanana2``, narrowed to the
     fallback ``H x dv <= hidden``) it lowers to the parent's text: but for
     ``ouro``, whose segments lie in a scan's body and are checkpointed
-    without the barrier against CSE since this change."""
+    without the barrier against CSE since this change. The loss head's
+    tags are taken out (``ops/lm_loss.py`` keeps its gradients under the
+    same name in every cell), so that attention's are the only ones."""
+    monkeypatch.setattr(lm_loss, "checkpoint_name", lambda a, name: a)
     net, ids = _cell(name)
     assert memmod.resolve_policy(net.conf.workspace_mode).every in (6, 8)
     before = _counts()
@@ -144,3 +149,23 @@ def test_which_layers_of_a_cell_keep(monkeypatch, name):
         memmod, "checkpoint", lambda fn, policy, prevent_cse=True:
         jax.checkpoint(fn, policy=policy.saveable, prevent_cse=prevent_cse))
     assert unkept == _lowered(net, ids)     # a name nothing carries: no-op
+
+
+@pytest.mark.parametrize("name,layer", [
+    ("laguna_xs2", "causal"), ("kanana2_30b_a3b", "causal"),
+    ("ouro_2_6b", "exit_weighted")])
+def test_every_cell_keeps_its_heads_gradients(name, layer):
+    """The head lies in the last segment of every cell's walk: its
+    gradients are formed in the forward pass and kept by name, once a traced
+    step, and the gradient's program holds the logits product once (the
+    forward's, the segment's and the block's own recomputation made
+    three)."""
+    net, ids = _cell(name)
+    c = tel.registry.get("lm_head.gradients")
+    before = c.value(layer=layer, decision="in_forward_kept")
+    text = _lowered(net, ids)
+    assert c.value(layer=layer, decision="in_forward_kept") == before + 1
+    vocab = net.params["lm_head"]["W"].shape[1]
+    rows = T if layer == "exit_weighted" else T - 1
+    assert len(re.findall(
+        rf"dot_general.*-> tensor<{rows}x{vocab}xf32>", text)) == 1

@@ -141,7 +141,8 @@ def test_every_gradient_leaf_matches_the_reference(world, leaf):
     close(world["grads"][leaf], world["ref_grads"][leaf], tol=5e-4)
 
 
-@pytest.mark.parametrize("workspace", ["none", f"every_{VERTICES_PER_LAYER}"])
+@pytest.mark.parametrize("workspace", ["none", f"every_{VERTICES_PER_LAYER}",
+                                       "full"])
 def test_three_adam_steps_through_fit_on_device(world, workspace):
     cfg, w = world["cfg"], world["weights"]
     rows = np.random.default_rng(5).integers(0, cfg["vocab_size"],

@@ -165,7 +165,7 @@ def test_every_gradient_leaf_matches_the_reference(world, leaf):
     close(world["grads"][leaf], world["ref_grads"][leaf], tol=5e-4)
 
 
-@pytest.mark.parametrize("mode", ["none", "every_8"])
+@pytest.mark.parametrize("mode", ["none", "every_8", "full"])
 def test_three_adam_steps_follow_the_reference(world, mode):
     """Through ``fit_on_device``; recomputation on and off agree bit for
     bit."""
