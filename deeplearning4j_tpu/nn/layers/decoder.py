@@ -1,10 +1,11 @@
 """The layers of a causal decoder on token ids: RMSNorm, rotary grouped-query
-attention under a causal or sliding-window mask, latent attention (keys and
-values from one low-rank projection, one rotary key for all heads), a gated
-feed-forward, a sparse-expert feed-forward that is told which experts it
-holds, the next-token loss head, and the head of a stack whose layers are
-walked several times (it scores every pass and weighs the passes by a
-learned exit distribution).
+attention under a causal or sliding-window mask or with the open keys chosen
+at run time by a learned indexer, latent attention (keys and values from one
+low-rank projection, one rotary key for all heads), a gated feed-forward, a
+sparse-expert feed-forward that is told which experts it holds, the
+next-token loss head, and the head of a stack whose layers are walked
+several times (it scores every pass and weighs the passes by a learned exit
+distribution).
 
 Every setting that differs between the layers of one stack (query heads,
 rotary share, base and scaling, mask) is a field of the layer, so a builder
@@ -25,6 +26,7 @@ from ...ops import activations as _act
 from ...ops import causal_attention as _ca
 from ...ops import lm_loss as _lm
 from ...ops import moe as _moe
+from ...ops import sparse_attention as _sa
 from ...runtime import telemetry as _tel
 from .. import weights as _winit
 from .base import Layer, layer
@@ -32,6 +34,14 @@ from .base import Layer, layer
 
 def _w(init, key, shape, dtype):
     return _winit.init(init, key, shape, shape[-2], shape[-1], dtype)
+
+
+def _grew(now: dict, before, key: str):
+    """What a uint32 count of a layer's state grew by since ``before`` (None:
+    since zero); counts wrap at 2**32."""
+    old = 0 if before is None else before[key]
+    return (np.asarray(now[key], np.int64)
+            - np.asarray(old, np.int64)) % (1 << 32)
 
 
 def _keeps_output(heads_width: int, hidden: int) -> bool:
@@ -74,9 +84,12 @@ class CausalSelfAttentionLayer(Layer):
     ``window`` a sliding-window one (key ``j`` open to query ``i`` where ``i -
     window < j <= i``). ``n_heads`` query heads share ``n_kv_heads``; the
     first ``rotary_dim`` of each head's ``head_size`` dimensions are rotated
-    (0: all), with ``rope_type`` ``default`` or ``yarn``. ``gated``
-    multiplies the heads' output by ``sigmoid(x Wg)`` element-wise before
-    the output projection. No biases. The scores are never materialised
+    (0: all), with ``rope_type`` ``default`` or ``yarn``. ``qk_norm`` puts
+    an RMSNorm over each head's ``head_size`` channels on queries and keys
+    before the rotation (gains ``gq`` / ``gk`` ``[head_size]``, one for all
+    heads, ``eps``, statistics in float32). ``gated`` multiplies the heads'
+    output by ``sigmoid(x Wg)`` element-wise before the output projection.
+    No biases. The scores are never materialised
     (``ops/causal_attention.py``)."""
     quantizable = True
     n_heads: int = 1
@@ -84,6 +97,8 @@ class CausalSelfAttentionLayer(Layer):
     head_size: int = 64
     window: Optional[int] = None
     gated: bool = False
+    qk_norm: bool = False
+    eps: float = 1e-6
     rotary_dim: int = 0
     rope_theta: float = 10000.0
     rope_type: str = "default"
@@ -105,10 +120,13 @@ class CausalSelfAttentionLayer(Layer):
                   "Wo": _w(self.weight_init, ks[3], (hq, f), dtype)}
         if self.gated:
             params["Wg"] = _w(self.weight_init, ks[4], (f, hq), dtype)
+        if self.qk_norm:
+            params["gq"] = jnp.ones((self.head_size,), dtype)
+            params["gk"] = jnp.ones((self.head_size,), dtype)
         return params, {}, tuple(input_shape)
 
     def quantize_spec(self, params):
-        return {k: 1 for k in params}
+        return {k: 1 for k in params if k.startswith("W")}
 
     def inv_freq(self) -> np.ndarray:
         rot = self.rotary_dim or self.head_size
@@ -121,23 +139,108 @@ class CausalSelfAttentionLayer(Layer):
                 self.rope_beta_slow)
         raise ValueError(f"unknown rope_type {self.rope_type!r}")
 
-    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+    def project(self, params, x):
+        """-> ``q`` ``[B, T, H, d]``, ``k`` and ``v`` ``[B, T, KV, d]``,
+        queries and keys normed (``qk_norm``) and rotated."""
         B, T, _ = x.shape
         q = jnp.dot(x, params["Wq"]).reshape(B, T, self.n_heads, self.head_size)
         k = jnp.dot(x, params["Wk"]).reshape(B, T, self.n_kv_heads,
                                              self.head_size)
         v = jnp.dot(x, params["Wv"]).reshape(B, T, self.n_kv_heads,
                                              self.head_size)
+        if self.qk_norm:
+            q = _rms_norm(q, params["gq"], self.eps)
+            k = _rms_norm(k, params["gk"], self.eps)
         cos, sin = _ca.rotary_tables(T, self.inv_freq(),
                                      self.rope_attention_factor)
-        q, k = _ca.apply_rotary(q, cos, sin), _ca.apply_rotary(k, cos, sin)
+        return _ca.apply_rotary(q, cos, sin), _ca.apply_rotary(k, cos, sin), v
+
+    def attend(self, params, x, q, k, v, select=None):
+        """The heads' output through the gate and the output projection."""
+        B, T, _ = x.shape
         o = _ca.causal_attention(
-            q, k, v, window=self.window,
+            q, k, v, window=self.window, select=select,
             keep=_keeps_output(self.n_heads * self.head_size, x.shape[-1]))
         o = o.reshape(B, T, self.n_heads * self.head_size)
         if self.gated:
             o = o * jax.nn.sigmoid(jnp.dot(x, params["Wg"]))
-        return jnp.dot(o, params["Wo"]), state, mask
+        return jnp.dot(o, params["Wo"])
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        q, k, v = self.project(params, x)
+        return self.attend(params, x, q, k, v), state, mask
+
+
+_SPARSE_KEYS = _tel.counter(
+    "sparse_attn.keys", "keys a selection left open, summed over the "
+    "queries of the steps run, by layer")
+_SPARSE_TIES = _tel.counter(
+    "sparse_attn.ties", "query rows whose topk-th and next index scores "
+    "were equal (the lower key index was opened), by layer")
+
+
+@layer("sparse_select_attention")
+class SparseSelectAttentionLayer(CausalSelfAttentionLayer):
+    """Grouped-query attention whose open keys a learned indexer chooses at
+    run time (DeepSeek-V3.2-Exp's lightning indexer). The indexer projects
+    the layer's input to ``index_heads`` queries of ``index_head_size``, ONE
+    index key of that size and a weight a head (``WqI``, ``WkI``, ``Ww``; no
+    rotation, no norm), scores ``I[t, s] = sum_j w[t, j] relu(qI[t, j] .
+    kI[s])`` in float32, and query ``t`` attends to the ``topk`` earlier
+    keys of largest ``I[t, s]`` (all of them where ``t + 1 <= topk``), the
+    same keys for every head (``ops/sparse_attention.py``). The choice
+    carries no gradient and the scores enter the output through it alone,
+    so the indexer's three matrices take a gradient of exactly nought: they
+    are parameters a checkpoint fills, and the loss that would train them
+    (the alignment of ``I`` with the attention's own probabilities) is not
+    built. The state counts, since ``init``, the open keys summed over the
+    queries and the rows with a tie at the ``topk``-th score;
+    ``fit_on_device`` publishes their growth as ``sparse_attn.keys`` and
+    ``sparse_attn.ties``."""
+    index_heads: int = 16
+    index_head_size: int = 64
+    topk: int = 2048
+
+    def initialize(self, key, input_shape, dtype):
+        if self.window is not None:
+            raise ValueError("a selection has no window")
+        k_att, *ks = jax.random.split(key, 4)
+        params, _, shape = super().initialize(k_att, input_shape, dtype)
+        f, wi = int(input_shape[-1]), self.weight_init
+        params.update(
+            WqI=_w(wi, ks[0], (f, self.index_heads * self.index_head_size),
+                   dtype),
+            WkI=_w(wi, ks[1], (f, self.index_head_size), dtype),
+            Ww=_w(wi, ks[2], (f, self.index_heads), dtype))
+        return (params, {"keys": jnp.zeros((), jnp.uint32),
+                         "ties": jnp.zeros((), jnp.uint32)}, shape)
+
+    def index(self, params, x):
+        """-> ``qI`` ``[B, T, Hi, di]``, ``kI`` ``[B, T, di]``, ``w`` ``[B,
+        T, Hi]`` float32."""
+        B, T, _ = x.shape
+        with jax.named_scope("attn.index"):
+            return (jnp.dot(x, params["WqI"]).reshape(
+                        B, T, self.index_heads, self.index_head_size),
+                    jnp.dot(x, params["WkI"]),
+                    jnp.dot(x, params["Ww"],
+                            preferred_element_type=jnp.float32))
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        q, k, v = self.project(params, x)
+        select, keys, ties = _sa.open_keys(*self.index(params, x), self.topk)
+        y = self.attend(params, x, q, k, v, select)
+        if train and "keys" in state:
+            state = {**state,
+                     "keys": state["keys"] + keys,
+                     "ties": state["ties"] + ties}
+        return y, state, mask
+
+    def publish_counters(self, vertex: str, now: dict, before) -> None:
+        """Add what the state's counts grew by since ``before`` (None: since
+        zero) to the ``sparse_attn.*`` counters; counts wrap at 2**32."""
+        _SPARSE_KEYS.inc(int(_grew(now, before, "keys")), layer=vertex)
+        _SPARSE_TIES.inc(int(_grew(now, before, "ties")), layer=vertex)
 
 
 @layer("latent_attention")
@@ -262,9 +365,10 @@ def _round_up(n: int, to: int) -> int:
 class SparseExpertLayer(Layer):
     """A routed feed-forward that is told which experts it holds.
 
-    The router scores every token against all ``num_experts`` (sigmoid, in
-    float32), keeps the ``top_k`` largest and weights them ``routed_scale * s
-    / sum(s)``. With ``select_bias`` the ``top_k`` are taken by ``s + bias``
+    The router scores every token against all ``num_experts`` in float32
+    (``scoring``: the ``sigmoid`` of each output, or the ``softmax`` over all
+    of them), keeps the ``top_k`` largest and weights them ``routed_scale *
+    s / sum(s)`` over the chosen. With ``select_bias`` the ``top_k`` are taken by ``s + bias``
     and weighted by their unbiased ``s``; the bias ``[num_experts]`` is
     layer state (``state["select_bias"]``, float32, zeros until a checkpoint
     or a balancing rule sets it): no gradient reaches it and no updater
@@ -284,6 +388,7 @@ class SparseExpertLayer(Layer):
     held: Optional[Tuple[int, int]] = None
     routed_scale: float = 1.0
     select_bias: bool = False
+    scoring: str = "sigmoid"
     weight_init: str = "xavier"
     name: Optional[str] = None
 
@@ -331,7 +436,8 @@ class SparseExpertLayer(Layer):
         xt = x.reshape(-1, f)
         top_e, w = _moe.route(
             xt, params["Wr"], self.top_k, self.routed_scale,
-            state["select_bias"] if self.select_bias else None)
+            state["select_bias"] if self.select_bias else None,
+            self.scoring)
         order, ends, tokens = _moe.plan(top_e, first, count)
         with jax.named_scope("moe.experts"):
             routed, done = _moe.held_experts(
@@ -357,11 +463,7 @@ class SparseExpertLayer(Layer):
     def publish_counters(self, vertex: str, now: dict, before) -> None:
         """Add what the state's counts grew by since ``before`` (None: since
         zero) to the ``moe.*`` counters; counts wrap at 2**32."""
-        def grew(key):
-            old = 0 if before is None else before[key]
-            return (np.asarray(now[key], np.int64)
-                    - np.asarray(old, np.int64)) % (1 << 32)
-
+        grew = lambda key: _grew(now, before, key)
         first, _ = self._held()
         for i, n in enumerate(grew("tokens")):
             if n:

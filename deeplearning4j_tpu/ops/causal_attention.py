@@ -11,7 +11,10 @@ backward keeps the output and one ``[rows, T]`` logsumexp. Everywhere else
 (the CPU in ``auto``, a GSPMD-partitioned trace, a sequence of one block) a
 blocked XLA path: queries are cut into blocks, each block sees only the key
 blocks its mask leaves open, and every block is a ``jax.checkpoint`` so the
-backward pass holds one block's scores at a time.
+backward pass holds one block's scores at a time. A mask that is data
+(``select=``: the keys a learned indexer chose, ``ops/sparse_attention.py``)
+always takes that XLA path: every causal block is computed and reads its
+slice of the mask.
 
 A caller inside a recomputed segment (``nn/memory.py`` ``checkpoint``) may ask
 with ``keep=True`` that the result be kept for the backward pass, which reads
@@ -129,11 +132,12 @@ def _softmax(s):
     return e / barrier(jnp.sum(e, axis=-1, keepdims=True))
 
 
-def _block(q, k, v, q0: int, k0: int, window: Optional[int]):
+def _block(q, k, v, q0: int, k0: int, window: Optional[int], select=None):
     """One query block against the keys its mask leaves open. ``q``
     ``[..., G, bq, d]``, ``k`` / ``v`` ``[..., nk, d]``; ``q0`` / ``k0`` the
     position of the first query / key (``k0`` may be negative: keys before
-    position 0 are padding)."""
+    position 0 are padding). ``select`` ``[..., bq, nk]`` bool: the keys a
+    selection leaves open to each query, the same for the ``G`` heads."""
     d = q.shape[-1]
     s = jnp.einsum("...gqd,...kd->...gqk", q, k,
                    preferred_element_type=jnp.float32) / math.sqrt(d)
@@ -142,6 +146,8 @@ def _block(q, k, v, q0: int, k0: int, window: Optional[int]):
     open_ = (kpos <= qpos) & (kpos >= 0)
     if window is not None:
         open_ &= kpos > qpos - window
+    if select is not None:
+        open_ = open_ & select[..., None, :, :]
     p = _softmax(jnp.where(open_, s, _NEG))
     return jnp.einsum("...gqk,...kd->...gqd", p.astype(v.dtype), v)
 
@@ -187,6 +193,24 @@ def _rows_window(q, k, v, block: int, window: int):
 
     out = jax.checkpoint(jax.vmap(one))(qb, pair(k), pair(v), jnp.arange(nb))
     return out.transpose(1, 0, 2, 3).reshape(G, T, v.shape[-1])
+
+
+def _rows_select(q, k, v, select, row, block: int):
+    """:func:`_rows_full` under a selection: ``select`` ``[B, T, T]`` bool is
+    the whole layer's mask and ``row`` the sequence this KV row belongs to.
+    A block pair is skipped only where causality closes it; the others read
+    their slice of the mask, cut inside the block's ``jax.checkpoint`` so
+    that the backward pass holds the one mask and no slice of it."""
+    T = q.shape[1]
+    outs = []
+    for q0 in range(0, T, block):
+        k1 = q0 + block
+        fn = jax.checkpoint(
+            lambda a, b, c, m, r, q0=q0, k1=k1: _block(
+                a[:, q0:k1], b[:k1], c[:k1], q0, 0, None,
+                jax.lax.dynamic_slice(m, (r, q0, 0), (1, block, k1))[0]))
+        outs.append(fn(q, k, v, select, row))
+    return jnp.concatenate(outs, axis=1)
 
 
 # --------------------------------------------------------------------- keep
@@ -420,7 +444,7 @@ def _xla_reason(q, group: int, T: int, d: int, dv: int,
 
 def causal_attention(q, k, v, *, window: Optional[int] = None,
                      block: int = 1024, kind: Optional[str] = None,
-                     keep: bool = False):
+                     keep: bool = False, select=None):
     """softmax(q k^T / sqrt(d) + mask) v with a causal mask and, with
     ``window``, key ``j`` open to query ``i`` only where ``i - window < j <=
     i``. -> ``[B, T, H, dv]``. ``block`` is the XLA path's: a sequence no
@@ -429,9 +453,14 @@ def causal_attention(q, k, v, *, window: Optional[int] = None,
     (``decision=kernel``; ``flash_attention.set_mode`` is the switch, and
     ``force`` runs them in interpret mode off the chip) or the blocked XLA
     path with the reason (``decision=blocked_rows | blocked_pairs``,
-    ``why=platform | mode | gspmd | shape | vmem | ungrouped``). ``kind``
+    ``why=platform | mode | gspmd | shape | vmem | ungrouped | select``).
+    ``select`` ``[B, T, T]`` bool is a mask that is data (``ops/
+    sparse_attention.py``): key ``j`` is open to query ``i`` only where it
+    is set too, for every head alike; the kernels' masks are static, so such
+    a site takes the XLA path (``why=select``) and has no window. ``kind``
     names the site in the ``attention.dispatch`` counter and the
-    ``attn.<kind>`` scope (default: ``full`` or ``window``, by the mask).
+    ``attn.<kind>`` scope (default: ``sparse`` with a selection, else
+    ``full`` or ``window`` by the mask).
     ``keep``: inside a recomputed segment, keep the result for the backward
     pass (``attention.kept{decision=kept}``); a caller that finds its output
     too wide to keep passes False (``decision=recomputed, why=wide``), and
@@ -441,13 +470,22 @@ def causal_attention(q, k, v, *, window: Optional[int] = None,
     if H % KV:
         raise ValueError(f"{H} query heads do not divide over {KV} KV heads")
     G = H // KV
-    kind = kind or ("full" if window is None else "window")
+    if select is not None and window is not None:
+        raise ValueError("a selection has no window: close the keys in the "
+                         "mask")
+    kind = kind or ("sparse" if select is not None
+                    else "full" if window is None else "window")
     if window is not None:
         block = min(block, max(window, 128))
     keep = _keeps(kind, keep)
+    if keep and select is not None:
+        # the blocks' backward reads the mask: kept with the output, it takes
+        # whatever made it (an indexer, a top-k) out of the recomputation too
+        select = _tag(select)
     with jax.named_scope(f"attn.{kind}"):
         tiles = T > block and T % block == 0
-        why = _xla_reason(q, G, T, d, dv, window) if tiles else None
+        why = None if not tiles else "select" if select is not None \
+            else _xla_reason(q, G, T, d, dv, window)
         if tiles and why is None:
             _DISPATCH.inc(kind=kind, decision="kernel")
             heads_first = lambda a: a.transpose(0, 2, 1, 3)
@@ -459,16 +497,23 @@ def causal_attention(q, k, v, *, window: Optional[int] = None,
         kg, vg = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)  # [B,KV,T,d]
         if not tiles:
             _DISPATCH.inc(kind=kind, decision="one_block")
-            out = _block(qg, kg, vg, 0, 0, window)
+            out = _block(qg, kg, vg, 0, 0, window,
+                         None if select is None else select[:, None])
         else:
-            if window is not None and window <= block:
+            flat = lambda a: a.reshape((B * KV,) + a.shape[2:])
+            xs = (flat(qg), flat(kg), flat(vg))
+            if select is not None:
+                _DISPATCH.inc(kind=kind, decision="blocked_rows", why=why)
+                # with each KV row, the sequence whose mask it reads
+                xs += (jnp.arange(B * KV) // KV,)
+                rows = lambda a: _rows_select(*a[:3], select, a[3], block)
+            elif window is not None and window <= block:
                 _DISPATCH.inc(kind=kind, decision="blocked_pairs", why=why)
                 rows = lambda a: _rows_window(*a, block, window)
             else:
                 _DISPATCH.inc(kind=kind, decision="blocked_rows", why=why)
                 rows = lambda a: _rows_full(*a, block, window)
-            flat = lambda a: a.reshape((B * KV,) + a.shape[2:])
-            out = jax.lax.map(rows, (flat(qg), flat(kg), flat(vg)))
+            out = jax.lax.map(rows, xs)
             out = out.reshape(B, KV, G, T, dv)
         out = out.transpose(0, 3, 1, 2, 4).reshape(B, T, H, dv)
         # the blocks' own checkpoints keep q, k, v, which the projections

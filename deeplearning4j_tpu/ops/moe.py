@@ -22,23 +22,30 @@ import jax.numpy as jnp
 from ..runtime import telemetry as _tel
 
 _ROUTE = _tel.counter(
-    "moe.route", "router sites by what the top-k is taken on: the scores "
-    "(plain) or the scores plus a selection bias (biased), once a traced "
-    "site")
+    "moe.route", "router sites by what the top-k is taken on, the scores "
+    "(plain) or the scores plus a selection bias (biased), and by how the "
+    "router's outputs become scores (sigmoid, softmax), once a traced site")
 
 
-def route(x, w_router, top_k: int, scale: float, select_bias=None):
-    """Sigmoid scores in float32 over all experts, the ``top_k`` largest per
-    token, weights ``scale * s / sum(s)`` over the chosen. With
+def route(x, w_router, top_k: int, scale: float, select_bias=None,
+          scoring: str = "sigmoid"):
+    """Scores in float32 over all experts, the ``top_k`` largest per token,
+    weights ``scale * s / sum(s)`` over the chosen. ``scoring``: ``sigmoid``
+    of each router output, or ``softmax`` over all of them (the weights are
+    then the probabilities renormalised over the chosen). With
     ``select_bias`` ``[experts]`` (DeepSeek-V3's ``noaux_tc``) the ``top_k``
     are taken by ``s + select_bias`` and weighted by their unbiased ``s``:
     the bias steers the load and never scales an expert's output, and no
     gradient reaches it.
     -> (expert ids ``[N, k]`` int32, weights ``[N, k]`` float32)."""
-    _ROUTE.inc(select="plain" if select_bias is None else "biased")
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    _ROUTE.inc(select="plain" if select_bias is None else "biased",
+               scoring=scoring)
     with jax.named_scope("moe.route"):
-        s = jax.nn.sigmoid(jnp.dot(x, w_router,
-                                   preferred_element_type=jnp.float32))
+        s = jnp.dot(x, w_router, preferred_element_type=jnp.float32)
+        s = jax.nn.sigmoid(s) if scoring == "sigmoid" \
+            else jax.nn.softmax(s, axis=-1)
         if select_bias is None:
             top_s, top_e = jax.lax.top_k(s, top_k)
             w = scale * top_s / jnp.sum(top_s, axis=-1, keepdims=True)

@@ -327,6 +327,60 @@ def test_blocked_latent_attention_compiles(one_chip, xla_attention):
     assert not re.search(rf"bf16\[32,{T - 1024},192\]", text)
 
 
+def test_indexer_and_selection_compile_without_a_sort(one_chip):
+    """Keye-VL-2.0's indexer at its published sizes (16 index heads of 64
+    against one index key, the 2,048 best of up to 8,192 keys a query): the
+    blocked index scores and the selection that counts its way to each row's
+    2,048th score, with no ``[16, T, T]`` array, no float ``[T, T]`` buffer
+    and no sort anywhere in the program; the one ``[T, T]`` array is the mask. None of its results has a
+    shape by which ``moe_time_pct`` tells the expert layers' fusions."""
+    import json
+    from benchmarks.metrics import moe_time_pct
+    from deeplearning4j_tpu.ops import sparse_attention as sa
+    B, T = 2, 8192
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+        ((B, T, 16, 64), BF16), ((B, T, 64), BF16), ((B, T, 16), jnp.float32))]
+    compiled = jax.jit(lambda q, k, w: sa.open_keys(q, k, w, 2048)) \
+        .lower(*args).compile()
+    text = compiled.as_text()
+    assert f"pred[{B},{T},{T}]" in text
+    assert not re.search(rf"\[(\d+,)*16,{T},{T}\]", text)
+    # beside the mask (134 MB) the program holds a block's products at most:
+    # one float32 [B, T, T] would be 537 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+    assert " sort(" not in text and "TopK" not in text
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "keye_vl2_30b_a3b.json")) as f:
+        cfg = json.load(f)
+    shapes = moe_time_pct.patterns(cfg, {"batch": B, "seq_len": T})
+    results = re.findall(r"\n\s*(?:ROOT )?%[\w.\-]+ = (\(?[a-z0-9]+\[[^=]*?) "
+                         r"(?:fusion|convolution|copy|reduce)\(", text)
+    assert len(results) > 20
+    assert not [r for r in results if shapes.search(r)]
+
+
+def test_selected_attention_compiles_backward(one_chip):
+    """The blocked XLA path under a mask that is data, at Keye-VL-2.0's head
+    counts (32 query heads on 4 KV heads of 128), forward and backward: the
+    mask is the one ``[T, T]`` array, every block reads its slice of it."""
+    from deeplearning4j_tpu.ops import causal_attention as ca
+    T = 4096
+
+    def loss(q, k, v, select):
+        return jnp.sum(ca.causal_attention(q, k, v, select=select)
+                       .astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+        ((1, T, 32, 128), BF16), ((1, T, 4, 128), BF16),
+        ((1, T, 4, 128), BF16), ((1, T, T), jnp.bool_))]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args) \
+        .compile().as_text()
+    assert not re.search(rf"(f32|bf16)\[(\d+,)*{T},{T}\]", text)
+    assert re.search(rf"pred\[(1,)?1024,{T}\]", text)      # a block's slice
+    assert "tpu_custom_call" not in text                   # no kernel yet
+
+
 def test_held_experts_compile_as_grouped_products(one_chip):
     """16 experts of 2048 x 512 held, 8 of 256 chosen a token: the chunk
     loop with the compiler's ragged-dot kernels, forward and backward."""
