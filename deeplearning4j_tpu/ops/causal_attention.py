@@ -13,8 +13,10 @@ blocked XLA path: queries are cut into blocks, each block sees only the key
 blocks its mask leaves open, and every block is a ``jax.checkpoint`` so the
 backward pass holds one block's scores at a time. A mask that is data
 (``select=``: the keys a learned indexer chose, ``ops/sparse_attention.py``)
-always takes that XLA path: every causal block is computed and reads its
-slice of the mask.
+goes the same way: on the kernels it is an 8-bit operand whose tile each
+scores tile reads beside the causal cut, the query heads of a KV head
+stacked in one tile so that it is fetched once for them; on the XLA path
+every causal block reads its slice of it.
 
 A caller inside a recomputed segment (``nn/memory.py`` ``checkpoint``) may ask
 with ``keep=True`` that the result be kept for the backward pass, which reads
@@ -245,21 +247,30 @@ _BLOCKS = (1024, 512, 256, 128)
 
 
 def causal_blocks(t: int, d: int, dv: int, window: Optional[int],
-                  itemsize: int = 2):
+                  itemsize: int = 2, stacked: int = 0):
     """(block_q, block_k) for the masked kernels, from the shape and the
     mask: of the multiples of 128 that divide ``t`` and fit VMEM, the pair
     that costs least in pairs computed (every block the mask leaves open is
     computed whole) plus grid steps (the closed ones are skipped, not free).
     ``flash_attention.default_blocks`` wants the most keys that fit, which
-    under a causal mask are mostly closed pairs. None where nothing tiles."""
+    under a causal mask are mostly closed pairs. ``stacked``: under a mask
+    that is data the tile stacks that many query heads, ``stacked *
+    block_q`` rows beside the mask's 8-bit ``[block_q, block_k]`` tile, and
+    a grid step costs as many steps as it stacks heads (it repeats the mask
+    tile for each and gathers their statistics): the order this gives is the
+    chip's over the four tilings measured at Keye's 8 heads a KV head
+    (PERF.md, PR 42). None where nothing tiles."""
+    heads = max(stacked, 1)
+
     def cost(blocks):
         mask = _fa.BlockMask(*blocks, t, window)
-        return (mask.open_blocks() * mask.bq * mask.bk
-                + mask.nq * mask.key_span * _STEP_PAIRS)
+        return heads * (mask.open_blocks() * mask.bq * mask.bk
+                        + mask.nq * mask.key_span * _STEP_PAIRS)
 
     fit = [(bq, bk) for bq in _BLOCKS for bk in _BLOCKS
            if t % bq == 0 and t % bk == 0
-           and _fa.fits_vmem_attention(bq, bk, max(d, dv), itemsize)]
+           and _fa.fits_vmem_attention(heads * bq, bk, max(d, dv), itemsize,
+                                       mask_rows=bq if stacked else 0)]
     return min(fit, key=cost, default=None)
 
 
@@ -270,69 +281,99 @@ def _kv_map(mask, group: int):
     block a query block reaches the index stays put, so the pipeline fetches
     nothing for a step that computes nothing."""
     def kv(b, i, j):
-        return (jax.lax.div(b, group),
-                jnp.minimum(mask.first_key(i) + j, mask.last_key(i)), 0)
+        return (jax.lax.div(b, group), _key_block(mask, i, j), 0)
     return kv
 
 
-def _params(pltpu, mask, d, dv, dtype):
+def _key_block(mask, i, j):
+    return jnp.minimum(mask.first_key(i) + j, mask.last_key(i))
+
+
+def _params(pltpu, mask, d, dv, dtype, lead=1, selected=False):
+    """``lead``: the query heads a query-side block stacks; ``selected``: a
+    mask tile is fetched beside the keys."""
     return _fa._compiler_params(pltpu, vmem_bytes=_fa.vmem_bytes_attention(
-        mask.bq, mask.bk, max(d, dv), np.dtype(dtype).itemsize))
+        lead * mask.bq, mask.bk, max(d, dv), np.dtype(dtype).itemsize,
+        mask_rows=mask.bq if selected else 0))
 
 
-def _fwd_call(q3, k3, v3, mask, group, scale, interpret):
+def _stacking(q3, k3, mask, group, sel):
+    """Under a mask that is data (``sel`` ``[B, T, T]``): the grid walks KV
+    rows, a query-side block holds the ``group`` query heads of one (the
+    mask's tile is fetched once for them all), and the mask's index map
+    reads the sequence of the row. -> (grid rows, heads a block, group of
+    the grid, the mask's spec or None)."""
+    if sel is None:
+        return q3.shape[0], 1, group, None
+    pl, _ = _fa._load_pallas()
+    rows_per_seq = k3.shape[0] // sel.shape[0]
+    return (k3.shape[0], group, 1, pl.BlockSpec(
+        (1, mask.bq, mask.bk), lambda b, i, j: (
+            jax.lax.div(b, rows_per_seq), i, _key_block(mask, i, j))))
+
+
+def _fwd_call(q3, k3, v3, mask, group, scale, interpret, sel=None):
     pl, pltpu = _fa._load_pallas()
     R, T, d = q3.shape
     dv = v3.shape[-1]
     bq, bk = mask.bq, mask.bk
+    rows, lead, group, sel_spec = _stacking(q3, k3, mask, group, sel)
     kv = _kv_map(mask, group)
+    tile = lead * bq
+    ins = (q3, k3, v3) + (() if sel is None else (sel,))
     return pl.pallas_call(
         functools.partial(_fa._fwd_kernel, scale=scale, nk=mask.key_span,
-                          has_bias=False, mask=mask),
-        grid=(R, mask.nq, mask.key_span),
-        in_specs=[pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                          has_bias=False, mask=mask, stacked=sel is not None),
+        grid=(rows, mask.nq, mask.key_span),
+        in_specs=[pl.BlockSpec((lead, bq, d), lambda b, i, j: (b, i, 0)),
                   pl.BlockSpec((1, bk, d), kv),
-                  pl.BlockSpec((1, bk, dv), kv)],
+                  pl.BlockSpec((1, bk, dv), kv)]
+        + ([] if sel is None else [sel_spec]),
         out_shape=(jax.ShapeDtypeStruct((R, T, dv), q3.dtype),
                    jax.ShapeDtypeStruct((R, 1, T), jnp.float32)),
-        out_specs=(pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
-                   pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))),
-        scratch_shapes=[pltpu.VMEM((bq, _fa._LANES), jnp.float32),
-                        pltpu.VMEM((bq, _fa._LANES), jnp.float32),
-                        pltpu.VMEM((bq, dv), jnp.float32)],
-        compiler_params=_params(pltpu, mask, d, dv, q3.dtype),
+        out_specs=(pl.BlockSpec((lead, bq, dv), lambda b, i, j: (b, i, 0)),
+                   pl.BlockSpec((lead, 1, bq), lambda b, i, j: (b, 0, i))),
+        scratch_shapes=[pltpu.VMEM((tile, _fa._LANES), jnp.float32),
+                        pltpu.VMEM((tile, _fa._LANES), jnp.float32),
+                        pltpu.VMEM((tile, dv), jnp.float32)],
+        compiler_params=_params(pltpu, mask, d, dv, q3.dtype, lead,
+                                sel is not None),
         interpret=interpret,
         name="causal_flash_fwd",
-    )(q3, k3, v3)
+    )(*ins)
 
 
-def _bwd_call(q3, k3, v3, lse, di, do, mask, group, scale, interpret):
+def _bwd_call(q3, k3, v3, lse, di, do, mask, group, scale, interpret,
+              sel=None):
     pl, pltpu = _fa._load_pallas()
     R, T, d = q3.shape
     dv = v3.shape[-1]
     bq, bk = mask.bq, mask.bk
-    params = _params(pltpu, mask, d, dv, q3.dtype)
+    rows, lead, group, sel_spec = _stacking(q3, k3, mask, group, sel)
+    params = _params(pltpu, mask, d, dv, q3.dtype, lead, sel is not None)
     kv = _kv_map(mask, group)
+    tile = lead * bq
     by_q = lambda b, i, j: (b, i, 0)
-    row = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
+    row = pl.BlockSpec((lead, 1, bq), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(_fa._bwd_dq_kernel, scale=scale, nk=mask.key_span,
-                          has_bias=False, mask=mask),
-        grid=(R, mask.nq, mask.key_span),
-        in_specs=[pl.BlockSpec((1, bq, d), by_q),
+                          has_bias=False, mask=mask, stacked=sel is not None),
+        grid=(rows, mask.nq, mask.key_span),
+        in_specs=[pl.BlockSpec((lead, bq, d), by_q),
                   pl.BlockSpec((1, bk, d), kv),
-                  pl.BlockSpec((1, bk, dv), kv),
-                  row, row,
-                  pl.BlockSpec((1, bq, dv), by_q)],
+                  pl.BlockSpec((1, bk, dv), kv)]
+        + ([] if sel is None else [sel_spec])
+        + [row, row,
+           pl.BlockSpec((lead, bq, dv), by_q)],
         out_shape=jax.ShapeDtypeStruct((R, T, d), q3.dtype),
-        out_specs=pl.BlockSpec((1, bq, d), by_q),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
-                        pltpu.VMEM((bq, _fa._LANES), jnp.float32),
-                        pltpu.VMEM((bq, _fa._LANES), jnp.float32)],
+        out_specs=pl.BlockSpec((lead, bq, d), by_q),
+        scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32),
+                        pltpu.VMEM((tile, _fa._LANES), jnp.float32),
+                        pltpu.VMEM((tile, _fa._LANES), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
         name="causal_flash_bwd_dq",
-    )(q3, k3, v3, lse, di, do)
+    )(*((q3, k3, v3) + (() if sel is None else (sel,)) + (lse, di, do)))
 
     # dk/dv grid: key blocks outer; inside, the group's heads and under each
     # the query blocks that reach the key block (the reduction axis)
@@ -346,18 +387,27 @@ def _bwd_call(q3, k3, v3, lse, di, do, mask, group, scale, interpret):
                            mask.last_query(j))
 
     by_qt = lambda b, j, t: (head(b, t), block(j, t), 0)
-    row_t = pl.BlockSpec((1, 1, bq), lambda b, j, t: (head(b, t), 0,
-                                                       block(j, t)))
+    row_t = pl.BlockSpec((lead, 1, bq), lambda b, j, t: (head(b, t), 0,
+                                                          block(j, t)))
     by_k = lambda b, j, t: (b, j, 0)
+    ins = (q3, k3, v3, lse, di, do)
+    sel_t = []
+    if sel is not None:
+        # the transposed mask, [keys, queries]: the tile is [bk, bq] here
+        rows_per_seq = k3.shape[0] // sel.shape[0]
+        sel_t = [pl.BlockSpec((1, bk, bq), lambda b, j, t: (
+            jax.lax.div(b, rows_per_seq), j, block(j, t)))]
+        ins = ins[:3] + (jnp.swapaxes(sel, 1, 2),) + ins[3:]
     dk, dv_ = pl.pallas_call(
         functools.partial(_fa._bwd_dkv_masked_kernel, scale=scale, mask=mask,
-                          group=group),
-        grid=(R // group, mask.nk, group * span),
-        in_specs=[pl.BlockSpec((1, bq, d), by_qt),
+                          group=group, stacked=sel is not None),
+        grid=(rows // group, mask.nk, group * span),
+        in_specs=[pl.BlockSpec((lead, bq, d), by_qt),
                   pl.BlockSpec((1, bk, d), by_k),
-                  pl.BlockSpec((1, bk, dv), by_k),
-                  row_t, row_t,
-                  pl.BlockSpec((1, bq, dv), by_qt)],
+                  pl.BlockSpec((1, bk, dv), by_k)]
+        + sel_t
+        + [row_t, row_t,
+           pl.BlockSpec((lead, bq, dv), by_qt)],
         out_shape=(jax.ShapeDtypeStruct(k3.shape, k3.dtype),
                    jax.ShapeDtypeStruct(v3.shape, v3.dtype)),
         out_specs=(pl.BlockSpec((1, bk, d), by_k),
@@ -367,59 +417,70 @@ def _bwd_call(q3, k3, v3, lse, di, do, mask, group, scale, interpret):
         compiler_params=params,
         interpret=interpret,
         name="causal_flash_bwd_dkv",
-    )(q3, k3, v3, lse, di, do)
+    )(*ins)
     return dq, dk, dv_
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q3, k3, v3, mask, group, scale, interpret, keep):
-    return _fwd_call(q3, k3, v3, mask, group, scale, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q3, k3, v3, sel, mask, group, scale, interpret, keep):
+    return _fwd_call(q3, k3, v3, mask, group, scale, interpret, sel)[0]
 
 
-def _flash_fwd(q3, k3, v3, mask, group, scale, interpret, keep):
-    o, lse = _fwd_call(q3, k3, v3, mask, group, scale, interpret)
+def _flash_fwd(q3, k3, v3, sel, mask, group, scale, interpret, keep):
+    o, lse = _fwd_call(q3, k3, v3, mask, group, scale, interpret, sel)
     if keep:
         # the backward kernels read both: the forward kernel leaves a
         # segment's recomputation only if neither has to be rebuilt
         o, lse = _tag(o), _tag(lse)
-    return o, (q3, k3, v3, o, lse)
+    return o, (q3, k3, v3, o, lse, sel)
 
 
 def _flash_bwd(mask, group, scale, interpret, keep, res, do):
-    q3, k3, v3, o, lse = res
+    q3, k3, v3, o, lse, sel = res
     di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    # the mask is data and takes no gradient
     return _bwd_call(q3, k3, v3, lse, di[:, None, :], do, mask, group, scale,
-                     interpret)
+                     interpret, sel) + (None,)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def causal_flash(q, k, v, *, window: Optional[int] = None, blocks=None,
-                 interpret: bool = False, keep: bool = False):
+                 interpret: bool = False, keep: bool = False, select=None):
     """The masked kernels on ``q`` ``[B, H, T, d]``, ``k`` ``[B, KV, T, d]``,
     ``v`` ``[B, KV, T, dv]`` -> ``[B, H, T, dv]``. ``blocks``: (block_q,
     block_k), multiples of 128 that divide ``T`` (default:
     :func:`causal_blocks`). ``keep`` tags the output and the logsumexp
-    ``memory.KEPT`` for a recomputing caller. Raises ValueError where
-    nothing tiles: callers go through :func:`causal_attention` for guarded
+    ``memory.KEPT`` for a recomputing caller. ``select`` ``[B, T, T]``
+    bool: a mask that is data, read by the kernels a tile at a time beside
+    the causal cut (every row must keep a key open); the ``H // KV`` query
+    heads of a KV head then share a tile. Raises ValueError where nothing
+    tiles: callers go through :func:`causal_attention` for guarded
     dispatch."""
     B, H, T, d = q.shape
     KV, dv = k.shape[1], v.shape[-1]
+    stacked = 0 if select is None else H // KV
     blocks = blocks or causal_blocks(T, d, dv, window,
-                                     np.dtype(q.dtype).itemsize)
+                                     np.dtype(q.dtype).itemsize, stacked)
     if blocks is None or T % blocks[0] or T % blocks[1]:
         raise ValueError(f"a sequence of {T} does not tile into {blocks}")
+    if select is not None:
+        if window is not None:
+            raise ValueError("a selection has no window")
+        select = select.astype(jnp.int8)
     o = _flash(q.reshape(B * H, T, d), k.reshape(B * KV, T, d),
-               v.reshape(B * KV, T, dv), _fa.BlockMask(*blocks, T, window),
-               H // KV, 1.0 / math.sqrt(d), bool(interpret), bool(keep))
+               v.reshape(B * KV, T, dv), select,
+               _fa.BlockMask(*blocks, T, window), H // KV,
+               1.0 / math.sqrt(d), bool(interpret), bool(keep))
     return o.reshape(B, H, T, dv)
 
 
 def _xla_reason(q, group: int, T: int, d: int, dv: int,
-                window: Optional[int]):
+                window: Optional[int], stacked: int = 0):
     """Why a sequence that tiles goes to the XLA path all the same, or None
-    where the kernels take it."""
+    where the kernels take it. ``stacked``: the heads a tile stacks under a
+    mask that is data."""
     if _partitioned() is not None:
         return "gspmd"
     if _fa.mode() == "off":
@@ -428,7 +489,8 @@ def _xla_reason(q, group: int, T: int, d: int, dv: int,
         return "platform"
     if q.dtype not in _fa._FUSABLE_DTYPES or T % _BLOCKS[-1]:
         return "shape"
-    if causal_blocks(T, d, dv, window, np.dtype(q.dtype).itemsize) is None:
+    if causal_blocks(T, d, dv, window, np.dtype(q.dtype).itemsize,
+                     stacked) is None:
         return "vmem"
     if group == 1 and _fa.mode() != "force":
         # one query head a KV head: XLA's blocks are [1, block, keys] there
@@ -453,11 +515,13 @@ def causal_attention(q, k, v, *, window: Optional[int] = None,
     (``decision=kernel``; ``flash_attention.set_mode`` is the switch, and
     ``force`` runs them in interpret mode off the chip) or the blocked XLA
     path with the reason (``decision=blocked_rows | blocked_pairs``,
-    ``why=platform | mode | gspmd | shape | vmem | ungrouped | select``).
+    ``why=platform | mode | gspmd | shape | vmem | ungrouped``).
     ``select`` ``[B, T, T]`` bool is a mask that is data (``ops/
     sparse_attention.py``): key ``j`` is open to query ``i`` only where it
-    is set too, for every head alike; the kernels' masks are static, so such
-    a site takes the XLA path (``why=select``) and has no window. ``kind``
+    is set too, for every head alike, and every query keeps a key open. It
+    is dispatched as any other site: on the kernels it is an operand read a
+    tile at a time, with the query heads of a KV head in one tile; on the
+    XLA path every causal block reads its slice. It has no window. ``kind``
     names the site in the ``attention.dispatch`` counter and the
     ``attn.<kind>`` scope (default: ``sparse`` with a selection, else
     ``full`` or ``window`` by the mask).
@@ -484,14 +548,14 @@ def causal_attention(q, k, v, *, window: Optional[int] = None,
         select = _tag(select)
     with jax.named_scope(f"attn.{kind}"):
         tiles = T > block and T % block == 0
-        why = None if not tiles else "select" if select is not None \
-            else _xla_reason(q, G, T, d, dv, window)
+        why = None if not tiles else _xla_reason(
+            q, G, T, d, dv, window, 0 if select is None else G)
         if tiles and why is None:
             _DISPATCH.inc(kind=kind, decision="kernel")
             heads_first = lambda a: a.transpose(0, 2, 1, 3)
             out = causal_flash(heads_first(q), heads_first(k), heads_first(v),
                                window=window, interpret=_fa._interpret(),
-                               keep=keep)
+                               keep=keep, select=select)
             return heads_first(out)
         qg = q.reshape(B, T, KV, G, d).transpose(0, 2, 3, 1, 4)  # [B,KV,G,T,d]
         kg, vg = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)  # [B,KV,T,d]

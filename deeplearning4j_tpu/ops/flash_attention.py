@@ -30,7 +30,10 @@ done by hand. This module is that hand fusion:
     under a static :class:`BlockMask` (causal, or causal with a window) for
     ``ops/causal_attention.py``, with a dk/dv body of their own that sums
     over the query heads of a KV head: a block pair the mask closes is
-    skipped, and the statistics leave as one compact logsumexp row.
+    skipped, and the statistics leave as one compact logsumexp row. With a
+    mask that is data as one more 8-bit operand (``stacked``) all three
+    read its tile beside the key block, and the query heads of a KV head
+    are stacked in one tile so that the tile is fetched once for them.
 - :func:`reference_attention` — the quadratic einsum path, scores upcast to
   f32 before softmax (matching the kernel's f32 accumulators; this is also
   the numerics fix for the layers' bf16 dtype policy).
@@ -228,24 +231,79 @@ def _as_column(row):
     return jnp.transpose(jnp.broadcast_to(row, (_LANES, row.shape[1])))
 
 
-def _scores(q_ref, k_ref, bias_ref, scale):
+def _stacked(ref):
+    """The query side's block of the ``G`` query heads of one KV head,
+    ``[G, bq, w]``, as one ``[G * bq, w]`` tile."""
+    x = ref[...]
+    return x.reshape(x.shape[0] * x.shape[1], x.shape[2])
+
+
+def _stacked_row(ref):
+    """The ``G`` heads' ``[1, bq]`` statistics rows side by side, ``[1, G *
+    bq]``: the transposed tile's columns in :func:`_stacked`'s order."""
+    return jnp.concatenate([ref[g] for g in range(ref.shape[0])], axis=1)
+
+
+def _stacked_column(ref):
+    """The same rows as one lane-replicated ``[G * bq, _LANES]`` column."""
+    return jnp.concatenate([_as_column(ref[g]) for g in range(ref.shape[0])],
+                           axis=0)
+
+
+def _open(mask: BlockMask, i, j, cut, sel_ref, transposed: bool = False):
+    """One head's open pairs in the tile: the causal cut where ``cut``;
+    under a mask that is data (``sel_ref``: its ``[bq, bk]`` tile, or the
+    transposed mask's ``[bk, bq]``, nonzero where the pair is open) that
+    tile, ANDed with the cut where there is one."""
+    if sel_ref is None:
+        return mask.open(i, j, transposed)
+    open_ = sel_ref[0].astype(jnp.int32) != 0
+    if cut:
+        open_ &= mask.open(i, j, transposed)
+    return open_
+
+
+def _closed(s, open_, transposed: bool = False):
+    """``s`` with the pairs ``open_`` leaves shut at ``_NEG``; ``open_`` is
+    one head's and ``s`` may stack several along its query axis."""
+    reps = s.shape[1 if transposed else 0] // open_.shape[1 if transposed
+                                                          else 0]
+    if reps > 1:
+        open_ = jnp.tile(open_, (1, reps) if transposed else (reps, 1))
+    return jnp.where(open_, s, _NEG)
+
+
+def _scores(q_ref, k_ref, bias_ref, scale, stacked: bool = False):
+    q = _stacked(q_ref) if stacked else q_ref[0]
     s = jax.lax.dot_general(
-        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+        q, k_ref[0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     if bias_ref is not None:
         s = s + bias_ref[0].astype(jnp.float32)  # [1, bk] broadcasts rows
     return s
 
 
-def _fwd_kernel(*refs, scale, nk, has_bias, mask: Optional[BlockMask] = None):
+def _fwd_kernel(*refs, scale, nk, has_bias, mask: Optional[BlockMask] = None,
+                stacked: bool = False):
     """Under a ``mask`` the key axis of the grid spans only the blocks a
     query block reaches (position ``j`` is key block ``first_key(i) + j``),
     and the statistics leave as one compact row, the logsumexp ``[1, bq]``:
-    a causal row always has its own key open, so its maximum is a score and
-    cannot absorb ``log(l)`` as a mask bias can."""
-    bias_ref = None
+    every row has a key open (a causal row its own), so its maximum is a
+    score and cannot absorb ``log(l)`` as a mask bias can. A row may meet
+    whole tiles closed before its first open key: their ``exp(0)`` terms
+    are wiped by ``alpha = exp(_NEG - score) = 0`` once a score arrives.
+
+    ``stacked``: a mask that is data too. Its ``[bq, bk]`` tile follows the
+    values as 8-bit integers (nonzero: open), and the query side's blocks
+    hold the ``G`` query heads of one KV head (``[G, bq, w]``, logsumexp
+    ``[G, 1, bq]``), scored as one ``[G * bq, bk]`` tile under the one mask
+    tile, so that the mask is fetched once a KV head."""
+    bias_ref = sel_ref = None
     if has_bias:
         (q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
+         m_scr, l_scr, acc_scr) = refs
+    elif stacked:
+        (q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref,
          m_scr, l_scr, acc_scr) = refs
     elif mask is not None:
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
@@ -262,9 +320,9 @@ def _fwd_kernel(*refs, scale, nk, has_bias, mask: Optional[BlockMask] = None):
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     def step(cut):
-        s = _scores(q_ref, k_ref, bias_ref, scale)         # [bq, bk] f32
-        if cut:
-            s = jnp.where(mask.open(i, kb), s, _NEG)
+        s = _scores(q_ref, k_ref, bias_ref, scale, stacked)  # [bq, bk] f32
+        if cut or stacked:
+            s = _closed(s, _open(mask, i, kb, cut, sel_ref))
         m_prev, l_prev = m_scr[...], l_scr[...]            # [bq, LANES]
         m_curr = jnp.max(s, axis=1, keepdims=True)         # [bq, 1]
         m_next = jnp.maximum(m_prev, m_curr)               # [bq, LANES]
@@ -282,6 +340,14 @@ def _fwd_kernel(*refs, scale, nk, has_bias, mask: Optional[BlockMask] = None):
     def _finish():
         l_fin = l_scr[...]
         safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
+        if stacked:
+            o = (acc_scr[...] / _lanes(safe, d)).astype(o_ref.dtype)
+            o_ref[...] = o.reshape(o_ref.shape)
+            lse = m_scr[...] + jnp.log(safe)
+            bq = lse_ref.shape[-1]
+            for g in range(lse_ref.shape[0]):
+                lse_ref[g] = _as_row(lse[g * bq:(g + 1) * bq])
+            return
         o_ref[0] = (acc_scr[...] / _lanes(safe, d)).astype(o_ref.dtype)
         if mask is not None:
             lse_ref[0] = _as_row(m_scr[...] + jnp.log(safe))
@@ -296,14 +362,17 @@ def _fwd_kernel(*refs, scale, nk, has_bias, mask: Optional[BlockMask] = None):
 
 
 def _bwd_dq_kernel(*refs, scale, nk, has_bias,
-                   mask: Optional[BlockMask] = None):
+                   mask: Optional[BlockMask] = None, stacked: bool = False):
     """Under a ``mask``: the grid of :func:`_fwd_kernel`, and the compact
     ``[1, bq]`` logsumexp and ``di`` rows turned into columns once a query
-    block."""
-    bias_ref = None
+    block; ``stacked`` as there (the mask's tile follows the values)."""
+    bias_ref = sel_ref = None
     if has_bias:
         (q_ref, k_ref, v_ref, bias_ref, m_ref, l_ref, di_ref, do_ref,
          dq_ref, dq_scr) = refs
+    elif stacked:
+        (q_ref, k_ref, v_ref, sel_ref, lse_ref, di_ref, do_ref,
+         dq_ref, dq_scr, lse_scr, di_scr) = refs
     elif mask is not None:
         (q_ref, k_ref, v_ref, lse_ref, di_ref, do_ref,
          dq_ref, dq_scr, lse_scr, di_scr) = refs
@@ -316,24 +385,27 @@ def _bwd_dq_kernel(*refs, scale, nk, has_bias,
     @pl.when(j == 0)
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
-        if mask is not None:
+        if stacked:
+            lse_scr[...] = _stacked_column(lse_ref)
+            di_scr[...] = _stacked_column(di_ref)
+        elif mask is not None:
             lse_scr[...] = _as_column(lse_ref[0])
             di_scr[...] = _as_column(di_ref[0])
 
     def step(cut):
-        s = _scores(q_ref, k_ref, bias_ref, scale)
+        s = _scores(q_ref, k_ref, bias_ref, scale, stacked)
         bk = s.shape[1]
         if mask is None:
             p = jnp.exp(s - _lanes(m_ref[0], bk)) * _lanes(1.0 / l_ref[0], bk)
             di = di_ref[0]
         else:
-            if cut:
-                s = jnp.where(mask.open(i, kb), s, _NEG)
+            if cut or stacked:
+                s = _closed(s, _open(mask, i, kb, cut, sel_ref))
             p = jnp.exp(s - _lanes(lse_scr[...], bk))
             di = di_scr[...]
         dp = jax.lax.dot_general(                           # do @ v^T
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            _stacked(do_ref) if stacked else do_ref[0], v_ref[0],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
         ds = p * (dp - _lanes(di, bk)) * scale              # [bq, bk] f32
         dq_scr[...] += jax.lax.dot(ds.astype(k_ref.dtype), k_ref[0],
                                    preferred_element_type=jnp.float32)
@@ -342,6 +414,10 @@ def _bwd_dq_kernel(*refs, scale, nk, has_bias,
 
     @pl.when(j == nk - 1)
     def _finish():
+        if stacked:
+            dq_ref[...] = dq_scr[...].astype(dq_ref.dtype).reshape(
+                dq_ref.shape)
+            return
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
@@ -381,16 +457,25 @@ def _bwd_dkv_kernel(*refs, scale, nq, has_bias):
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _bwd_dkv_masked_kernel(q_ref, k_ref, v_ref, lse_ref, di_ref, do_ref,
-                           dk_ref, dv_ref, dk_scr, dv_scr, *, scale,
-                           mask: BlockMask, group: int):
+def _bwd_dkv_masked_kernel(*refs, scale, mask: BlockMask, group: int,
+                           stacked: bool = False):
     """dk and dv of one key block under a ``mask``, summed over the query
     blocks that reach it and over the ``group`` query heads that read its KV
     head: grid position ``t`` of the inner axis is head ``t // span`` and
     query block ``first_query(j) + t % span``. The tile is held TRANSPOSED,
     ``[bk, bq]``, as in :func:`_bwd_row_kernel`: the compact ``[1, bq]``
     logsumexp and ``di`` rows broadcast down the keys as they are, and dv =
-    p^T do and dk = ds^T q are plain products."""
+    p^T do and dk = ds^T q are plain products. ``stacked`` as in
+    :func:`_fwd_kernel`, with the TRANSPOSED mask's ``[bk, bq]`` tile after
+    the values and the KV head's query heads in the tile (``group`` 1 in the
+    grid): ``[bk, G * bq]``."""
+    sel_ref = None
+    if stacked:
+        (q_ref, k_ref, v_ref, sel_ref, lse_ref, di_ref, do_ref,
+         dk_ref, dv_ref, dk_scr, dv_scr) = refs
+    else:
+        (q_ref, k_ref, v_ref, lse_ref, di_ref, do_ref,
+         dk_ref, dv_ref, dk_scr, dv_scr) = refs
     j, t = pl.program_id(1), pl.program_id(2)
     span = mask.query_span
     i = mask.first_query(j) + jax.lax.rem(t, span)
@@ -401,19 +486,24 @@ def _bwd_dkv_masked_kernel(q_ref, k_ref, v_ref, lse_ref, di_ref, do_ref,
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
     def step(cut):
-        q, do = q_ref[0], do_ref[0]
+        if stacked:
+            q, do = _stacked(q_ref), _stacked(do_ref)
+        else:
+            q, do = q_ref[0], do_ref[0]
         s = jax.lax.dot_general(                            # k @ q^T
             k_ref[0], q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # [bk, bq] f32
-        if cut:
-            s = jnp.where(mask.open(i, j, transposed=True), s, _NEG)
-        p = jnp.exp(s - lse_ref[0])
+        if cut or stacked:
+            s = _closed(s, _open(mask, i, j, cut, sel_ref, transposed=True),
+                        transposed=True)
+        p = jnp.exp(s - (_stacked_row(lse_ref) if stacked else lse_ref[0]))
         dv_scr[...] += jax.lax.dot(p.astype(do.dtype), do,
                                    preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(                           # v @ do^T
             v_ref[0], do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - di_ref[0]) * scale
+        ds = p * (dp - (_stacked_row(di_ref) if stacked
+                        else di_ref[0])) * scale
         dk_scr[...] += jax.lax.dot(ds.astype(q.dtype), q,
                                    preferred_element_type=jnp.float32)
 
@@ -896,7 +986,7 @@ def heads_per_step(heads: int, bq: int, bk: int, d: int,
 
 
 def vmem_bytes_attention(bq: int, bk: int, d: int, itemsize: int = 4,
-                         hb: int = 1) -> int:
+                         hb: int = 1, mask_rows: int = 0) -> int:
     """VMEM a (bq, bk) tiling holds in the worst of its kernels, the
     backward: the blocks the pipeline fetches and writes back (q, do, k, v
     in; dq, dk, dv out; the m/l/di rows of a blocked grid or the key bias
@@ -904,18 +994,24 @@ def vmem_bytes_attention(bq: int, bk: int, d: int, itemsize: int = 4,
     the f32 dk/dv scratch and two f32 score-sized temporaries. Mosaic
     streams the elementwise chains between the products and keeps about one
     and a half such tiles (the compiler's own count for a 2048 x 2048 bf16
-    whole-row backward is 31.25 MiB, this one's 42.5)."""
-    fetched = (2 * (bq + bk) + bq + 2 * bk) * hb * d * itemsize
+    whole-row backward is 31.25 MiB, this one's 42.5). ``mask_rows``: the
+    rows of a mask that is data, fetched beside the keys as an 8-bit
+    ``[mask_rows, bk]`` tile (``bq`` then counts the query heads it
+    serves)."""
+    fetched = (2 * (bq + bk) + bq + 2 * bk) * hb * d * itemsize \
+        + mask_rows * bk
     rows = max(3 * bq, bk) * _LANES * 4
     scratch = 2 * hb * bk * d * 4
     tiles = 2 * hb * bq * bk * 4
     return 2 * (fetched + rows) + scratch + tiles
 
 
-def fits_vmem_attention(bq: int, bk: int, d: int, itemsize: int = 4) -> bool:
+def fits_vmem_attention(bq: int, bk: int, d: int, itemsize: int = 4,
+                        mask_rows: int = 0) -> bool:
     """The one guard the dispatcher, the default tiling, the autotuner's
     candidates and the kernels' wrappers share."""
-    return vmem_bytes_attention(bq, bk, d, itemsize) <= _VMEM_TILE_BUDGET
+    return vmem_bytes_attention(bq, bk, d, itemsize,
+                                mask_rows=mask_rows) <= _VMEM_TILE_BUDGET
 
 
 def _tilings(tq: int, tk: int, has_bias: bool):

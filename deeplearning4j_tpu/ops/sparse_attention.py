@@ -8,8 +8,9 @@ against ONE index key a position, in float32. Key ``s`` is open to query
 row (all of the row where ``t + 1 <= topk``); exactly ``topk`` whatever the
 ties, the lower key index first, as ``jax.lax.top_k`` breaks them. The mask
 is data, one ``[B, T, T]`` boolean a layer, shared by every head:
-``causal_attention(..., select=mask)`` reads it. No gradient passes through
-the choice.
+``causal_attention(..., select=mask)`` reads it (on the chip the masked flash
+kernels take it as an 8-bit operand, a tile at a time, once for the query
+heads of a KV head). No gradient passes through the choice.
 
 :func:`open_keys` walks the queries in blocks so that no ``[Hi, T, T]`` array
 exists: the rows that lie within the first ``topk`` positions are the causal

@@ -6,7 +6,9 @@ a KV head with values narrower than the scored width); the dispatch counter
 for every decision and fallback reason; a count of the grid steps that
 computed against the blocks the mask leaves open; and (ISSUE 38) the output
 and the logsumexp kept across a recomputed segment, so that its
-recomputation holds no forward kernel."""
+recomputation holds no forward kernel; (ISSUE 42) the same kernels under a
+mask that is data (``select=``), the query heads of a KV head stacked in one
+tile, against the blocked XLA path and a direct masked softmax."""
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +19,7 @@ from deeplearning4j_tpu.nn import memory as memmod
 from deeplearning4j_tpu.ops import causal_attention as ca
 from deeplearning4j_tpu.ops import flash_attention as fa
 from deeplearning4j_tpu.ops import pallas_kernels as pk
+from deeplearning4j_tpu.ops import sparse_attention as sa
 from deeplearning4j_tpu.runtime import telemetry as tel
 
 BLOCK, T = 128, 512                       # a sequence of four blocks
@@ -138,13 +141,17 @@ def test_block_mask_against_the_whole_mask(bq, bk, t, window):
                                   tiles[a, b].T)
 
 
-@pytest.mark.parametrize("window", [None, 72, BLOCK, 200],
-                         ids=["full", "w72", "w128", "w200"])
-def test_closed_blocks_are_skipped_not_masked(monkeypatch, window):
+@pytest.mark.parametrize("window,select", [(None, False), (72, False),
+                                           (BLOCK, False), (200, False),
+                                           (None, True)],
+                         ids=["full", "w72", "w128", "w200", "select"])
+def test_closed_blocks_are_skipped_not_masked(monkeypatch, window, select):
     """Every grid step that computes runs its ``step`` inside
     ``_masked_steps``' guard: count them through a host callback there and
     hold them to the blocks the mask leaves open, in all three kernels. A
-    ``where`` over a full grid would count nq * nk a head."""
+    ``where`` over a full grid would count nq * nk a head. Under a mask that
+    is data a step computes the query heads of a KV head at once: a KV head
+    counts each pair once, so its mask tile is fetched once for them all."""
     ran = []
     real = fa._masked_steps
 
@@ -171,14 +178,19 @@ def test_closed_blocks_are_skipped_not_masked(monkeypatch, window):
         jax.effects_barrier()
         return list(ran)
 
+    sel = jnp.tril(jnp.ones((1, T, T), bool)) if select else None
     flash = lambda *a: ca.causal_flash(*a, window=window,
-                                       blocks=(BLOCK, BLOCK), interpret=True)
+                                       blocks=(BLOCK, BLOCK), interpret=True,
+                                       select=sel)
+    rows = KV if select else H
     fwd = run(flash)
-    assert len(fwd) == H * len(open_pairs) and set(fwd) == open_pairs
+    assert len(fwd) == rows * len(open_pairs) and set(fwd) == open_pairs
     both = run(jax.grad(lambda *a: jnp.sum(flash(*a) ** 2),
                         argnums=(0, 1, 2)))
-    # forward, dq and dk/dv each visit every open pair once a query head
-    assert len(both) == 3 * H * len(open_pairs) and set(both) == open_pairs
+    # forward, dq and dk/dv each visit every open pair once a query head (a
+    # KV head under a selection)
+    assert len(both) == 3 * rows * len(open_pairs) \
+        and set(both) == open_pairs
 
 
 def _dispatch(**labels):
@@ -386,3 +398,207 @@ def test_a_caller_outside_a_segment_keeps_nothing(forced):
     # and under a policy that recomputes nothing
     none = memmod.checkpoint(fn, memmod.resolve_policy("none"))
     assert none is fn and not memmod.recomputing()
+
+
+# ---- a mask that is data (ISSUE 42) ---------------------------------------
+#: (query heads, KV heads): 8 and 2 query heads a KV head
+GROUPS = {"g8": (16, 2), "g2": (4, 2)}
+
+
+def _index(seed, rounded=False, silent_rows=()):
+    """The indexer's inputs for two sequences of ``T``: 3 index heads of 4.
+    ``rounded`` makes many equal scores; the index weights of
+    ``silent_rows`` are nought, so those rows score every key 0."""
+    k0 = jax.random.PRNGKey(seed)
+    qi = jax.random.normal(k0, (2, T, 3, 4))
+    ki = jax.random.normal(jax.random.fold_in(k0, 1), (2, T, 4))
+    wi = jax.random.normal(jax.random.fold_in(k0, 2), (2, T, 3))
+    if rounded:
+        qi, ki, wi = jnp.round(qi), jnp.round(ki), jnp.round(wi)
+    return qi, ki, wi.at[:, list(silent_rows)].set(0.0)
+
+
+def _late_keys():
+    """Every row opens its own key and the three before it and nothing
+    else: from the third block on, whole tiles of a row are closed before
+    its first open key."""
+    i, j = np.arange(T)[:, None], np.arange(T)[None, :]
+    return jnp.asarray(np.broadcast_to((j <= i) & (j > i - 4), (2, T, T)))
+
+
+#: name -> the mask of two sequences of T
+SELECTIONS = {
+    "open_keys": lambda: sa.open_keys(*_index(42), 200)[0],
+    "closed_tiles_first": _late_keys,
+    # rows BLOCK - 1 and BLOCK straddle the topk boundary at a tile's edge
+    "topk_boundary": lambda: sa.open_keys(*_index(43), BLOCK)[0],
+    # many ties, and rows 300 and 301 tie on every key: their topk lowest
+    "ties": lambda: sa.open_keys(*_index(44, True, (300, 301)), 150)[0],
+}
+
+
+def _selected_qkv(group, seed=42):
+    H, KV = GROUPS[group]
+    k0 = jax.random.PRNGKey(seed)
+    return (jax.random.normal(k0, (2, T, H, 16)),
+            jax.random.normal(jax.random.fold_in(k0, 1), (2, T, KV, 16)),
+            jax.random.normal(jax.random.fold_in(k0, 2), (2, T, KV, 16)))
+
+
+def _direct(mask):
+    """A plain softmax over the open keys, every head on its KV head."""
+    def fn(q, k, v):
+        G = q.shape[2] // k.shape[2]
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, G, axis=2),
+                       precision="highest") / 4.0
+        p = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), -1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, jnp.repeat(v, G, axis=2),
+                          precision="highest")
+    return fn
+
+
+def _selected_kernel(mask, blocks):
+    hf = lambda a: a.transpose(0, 2, 1, 3)
+    return lambda q, k, v: hf(ca.causal_flash(
+        hf(q), hf(k), hf(v), blocks=blocks, interpret=True, select=mask))
+
+
+def _blocked_rows(mask):
+    """``_rows_select``, the XLA path's blocks under the selection."""
+    def fn(q, k, v):
+        old = fa.set_mode("off")
+        try:
+            return ca.causal_attention(q, k, v, block=BLOCK, select=mask)
+        finally:
+            fa.set_mode(old)
+    return fn
+
+
+@pytest.mark.parametrize("blocks", [(BLOCK, BLOCK), (BLOCK, 2 * BLOCK)],
+                         ids=["128x128", "128x256"])
+@pytest.mark.parametrize("selection", list(SELECTIONS))
+@pytest.mark.parametrize("group", list(GROUPS))
+def test_selected_kernels_equal_blocked_rows_and_a_softmax(group, selection,
+                                                           blocks):
+    """Output and the q / k / v gradients of the kernels under a mask that
+    is data, over four query blocks and two or four key blocks, against
+    ``_rows_select`` and a direct softmax over the open keys."""
+    mask = SELECTIONS[selection]()
+    assert bool(jnp.all(jnp.any(mask, axis=-1)))     # every row has a key
+    q, k, v = _selected_qkv(group)
+    fns = [_selected_kernel(mask, blocks), _blocked_rows(mask),
+           _direct(mask)]
+    outs = [fn(q, k, v) for fn in fns]
+    assert _worst(outs[0], outs[1]) <= 1e-5
+    assert _worst(outs[0], outs[2]) <= 1e-5
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    got, *wants = [grads(fn) for fn in fns]
+    for want in wants:
+        for name, g, w in zip("qkv", got, want):
+            scale = max(1.0, float(jnp.max(jnp.abs(w))))
+            assert _worst(g, w) <= 1e-5 * scale, name
+
+
+def test_a_selection_is_dispatched_as_any_site(monkeypatch):
+    """``select=`` goes through the same reasons as a static mask: the
+    kernels under ``force``, in a GSPMD-partitioned trace the XLA path,
+    where no stacked tiling fits VMEM ``vmem``, one query head a KV head on
+    a TPU ``ungrouped``; and it still has no window."""
+    mask = SELECTIONS["open_keys"]()
+    q, k, v = _selected_qkv("g8")
+    counter = tel.registry.get("attention.dispatch")
+
+    def counted(run, **labels):
+        before = counter.value(kind="sparse", **labels)
+        out = run()
+        assert counter.value(kind="sparse", **labels) == before + 1, labels
+        return out
+
+    attend = lambda *a: ca.causal_attention(*a, block=BLOCK, select=mask)
+    old = fa.set_mode("force")
+    try:
+        got = counted(lambda: attend(q, k, v), decision="kernel")
+        assert _worst(got, _direct(mask)(q, k, v)) <= 1e-5
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("data",))
+        with pk.gspmd_trace(mesh):
+            counted(lambda: attend(q, k, v), decision="blocked_rows",
+                    why="gspmd")
+        monkeypatch.setattr(fa, "_VMEM_TILE_BUDGET", 1)
+        counted(lambda: attend(q, k, v), decision="blocked_rows", why="vmem")
+        monkeypatch.undo()
+        fa.set_mode("auto")
+        monkeypatch.setattr(ca, "_tpu_available", lambda: True)
+        one = k.shape[2]
+        counted(lambda: attend(q[:, :, :one], k, v), decision="blocked_rows",
+                why="ungrouped")
+    finally:
+        fa.set_mode(old)
+    with pytest.raises(ValueError, match="window"):
+        ca.causal_flash(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)),
+                        window=200, select=mask)
+
+
+@pytest.mark.parametrize("t,group,want", [
+    (8192, 8, (256, 1024)),          # keye: 32 heads on 4 KV heads of 128
+    (8192, 2, (1024, 1024)), (8192, 16, (128, 1024)), (512, 8, (512, 256))])
+def test_stacked_tiling_counts_the_mask_and_the_heads(t, group, want):
+    """Under a selection the tile stacks the group's heads and fetches an
+    8-bit mask tile beside the keys: the rule counts both, and its choice
+    at Keye's shape is the fastest of the four the chip measured (PERF.md,
+    PR 42). Without a selection nothing changes."""
+    got = ca.causal_blocks(t, 128, 128, None, 2, group)
+    assert got == want
+    bq, bk = got
+    assert fa.fits_vmem_attention(group * bq, bk, 128, 2, mask_rows=bq)
+    assert fa.vmem_bytes_attention(group * bq, bk, 128, 2, mask_rows=bq) \
+        == fa.vmem_bytes_attention(group * bq, bk, 128, 2) + 2 * bq * bk
+    assert ca.causal_blocks(t, 128, 128, None, 2) == \
+        ca.causal_blocks(t, 128, 128, None, 2, 0)
+
+
+def test_selected_kernels_keep_output_logsumexp_and_mask(forced):
+    """Inside a recomputed segment the kept set is the output, the
+    logsumexp and the mask: the gradient holds one forward kernel a segment
+    (the forward pass's), and equals the one that recomputes it bit for
+    bit."""
+    mask = SELECTIONS["open_keys"]()[:1]
+    H, KV = GROUPS["g2"]
+    k0 = jax.random.PRNGKey(38)
+    x = jax.random.normal(k0, (1, T, 24), jnp.float32)
+    w = jax.random.normal(jax.random.fold_in(k0, 1),
+                          (24, (H + 2 * KV) * 16), jnp.float32) * 0.2
+    wo = jax.random.normal(jax.random.fold_in(k0, 2), (H * 16, 24),
+                           jnp.float32) * 0.2
+
+    def make(keep):
+        def segment(w, wo, x):
+            y = x @ w
+            q = y[..., :H * 16].reshape(1, T, H, 16)
+            k = y[..., H * 16:(H + KV) * 16].reshape(1, T, KV, 16)
+            v = y[..., (H + KV) * 16:].reshape(1, T, KV, 16)
+            o = ca.causal_attention(q, k, v, block=BLOCK, keep=keep,
+                                    select=mask)
+            return x + o.reshape(1, T, H * 16) @ wo
+
+        def loss(w, wo, x):
+            for _ in range(2):
+                x = memmod.checkpoint(segment, memmod.resolve_policy("full"))(
+                    w, wo, x)
+            return jnp.sum(jnp.sin(x))
+        return jax.grad(loss, argnums=(0, 1))
+
+    kept = jax.make_jaxpr(make(True))(w, wo, x).jaxpr
+    names = _kernel_names(kept)
+    assert names.count("causal_flash_fwd") == 2
+    assert names.count("causal_flash_bwd_dq") == 2
+    tags = {tuple(e.outvars[0].aval.shape) for e in _eqns(kept, "name")
+            if e.params["name"] == memmod.KEPT}
+    assert tags == {(H, T, 16), (H, 1, T), (1, T, T)}
+    assert _kernel_names(jax.make_jaxpr(make(False))(w, wo, x).jaxpr) \
+        .count("causal_flash_fwd") == 4
+    for a, b in zip(make(True)(w, wo, x), make(False)(w, wo, x)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

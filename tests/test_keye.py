@@ -5,12 +5,12 @@ whole stack (logits, loss, every gradient leaf with the indexer's three
 exactly nought, three Adam steps through ``fit_on_device`` with and without
 recomputation), the selection alone against ``jax.lax.top_k`` on rows shorter
 than, as long as and longer than ``topk`` and on rows with ties across the
-threshold, the selected attention through one block and through blocked rows,
-the open-key counter against its closed form, the layer with every key open
-against ``CausalSelfAttentionLayer``, softmax routing against the reference,
-the share test (the 16 shares of 8 experts add up to the uncut layer), what
-must NOT pass (one key more or fewer a row, sigmoid routing, bfloat16), the
-builder's refusals, and the counters after one call."""
+threshold, the selected attention through one block, blocked rows and the
+masked kernels, the open-key counter against its closed form, the layer with
+every key open against ``CausalSelfAttentionLayer``, softmax routing against
+the reference, the share test (the 16 shares of 8 experts add up to the uncut
+layer), what must NOT pass (one key more or fewer a row, sigmoid routing,
+bfloat16), the builder's refusals, and the counters after one call."""
 
 import importlib
 import json
@@ -30,6 +30,7 @@ from deeplearning4j_tpu.nn.layers.decoder import (CausalSelfAttentionLayer,
                                                   SparseExpertLayer,
                                                   SparseSelectAttentionLayer)
 from deeplearning4j_tpu.ops import causal_attention as ca
+from deeplearning4j_tpu.ops import flash_attention as fa
 from deeplearning4j_tpu.ops import moe
 from deeplearning4j_tpu.ops import sparse_attention as sa
 from deeplearning4j_tpu.runtime import telemetry as tel
@@ -274,41 +275,46 @@ def test_the_index_scores_are_the_references():
 
 
 # ---------------------------------------------------------------- attention
-@pytest.mark.parametrize("block,decision", [(32, "one_block"),
-                                            (8, "blocked_rows")])
-def test_selected_attention_through_one_block_and_blocked_rows(block,
-                                                              decision):
+@pytest.mark.parametrize("t,block,mode,labels", [
+    (32, 32, "auto", dict(decision="one_block")),
+    (32, 8, "auto", dict(decision="blocked_rows", why="platform")),
+    (256, 32, "force", dict(decision="kernel"))],
+    ids=["one_block", "blocked_rows", "kernel"])
+def test_selected_attention_through_one_block_and_blocked_rows(t, block, mode,
+                                                              labels):
     """4 query heads on 2 KV heads under a mask that is data: one block,
-    blocked rows and a direct softmax over the open keys agree, gradients
-    too; the site is counted under ``kind=sparse``."""
+    blocked rows (off the chip, ``why=platform``), the masked kernels
+    (``force``: the interpreter) and a direct softmax over the open keys
+    agree, gradients too; the site is counted under ``kind=sparse``."""
     k0 = jax.random.PRNGKey(4)
-    q = jax.random.normal(k0, (B, 32, 4, 8))
-    k = jax.random.normal(jax.random.fold_in(k0, 1), (B, 32, 2, 8))
-    v = jax.random.normal(jax.random.fold_in(k0, 2), (B, 32, 2, 8))
-    mask, _, _ = sa.open_keys(*_index_inputs(9, 32), 6)
+    q = jax.random.normal(k0, (B, t, 4, 8))
+    k = jax.random.normal(jax.random.fold_in(k0, 1), (B, t, 2, 8))
+    v = jax.random.normal(jax.random.fold_in(k0, 2), (B, t, 2, 8))
+    mask, _, _ = sa.open_keys(*_index_inputs(9, t), 6)
     run = lambda q, k, v: ca.causal_attention(q, k, v, block=block,
                                               select=mask)
     counter = tel.registry.get("attention.dispatch")
-    labels = dict(kind="sparse", decision=decision)
-    if decision == "blocked_rows":
-        labels["why"] = "select"
-    before = counter.value(**labels)
-    got = run(q, k, v)
-    assert counter.value(**labels) == before + 1
+    old = fa.set_mode(mode)
+    try:
+        before = counter.value(kind="sparse", **labels)
+        got = run(q, k, v)
+        assert counter.value(kind="sparse", **labels) == before + 1
 
-    def direct(q, k, v):
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, 2, axis=2)) \
-            / np.sqrt(8.0)
-        p = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), -1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, jnp.repeat(v, 2, axis=2))
+        def direct(q, k, v):
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, 2, axis=2)) \
+                / np.sqrt(8.0)
+            p = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), -1)
+            return jnp.einsum("bhqk,bkhd->bqhd", p, jnp.repeat(v, 2, axis=2))
 
-    close(got, direct(q, k, v), tol=1e-5)
-    for arg in range(3):
-        g = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) ** 2),
-                               argnums=arg)(q, k, v)
-        close(g(run), g(direct), tol=1e-5)
-    with pytest.raises(ValueError, match="window"):
-        ca.causal_attention(q, k, v, window=8, select=mask)
+        close(got, direct(q, k, v), tol=1e-5)
+        for arg in range(3):
+            g = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) ** 2),
+                                   argnums=arg)(q, k, v)
+            close(g(run), g(direct), tol=1e-5)
+        with pytest.raises(ValueError, match="window"):
+            ca.causal_attention(q, k, v, window=8, select=mask)
+    finally:
+        fa.set_mode(old)
 
 
 def _select_layer(topk, **kw):

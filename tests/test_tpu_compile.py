@@ -360,25 +360,38 @@ def test_indexer_and_selection_compile_without_a_sort(one_chip):
     assert not [r for r in results if shapes.search(r)]
 
 
-def test_selected_attention_compiles_backward(one_chip):
-    """The blocked XLA path under a mask that is data, at Keye-VL-2.0's head
-    counts (32 query heads on 4 KV heads of 128), forward and backward: the
-    mask is the one ``[T, T]`` array, every block reads its slice of it."""
+def test_selected_attention_compiles_backward(one_chip, monkeypatch):
+    """Keye-VL-2.0's selected attention (32 query heads on 4 KV heads of
+    128, two sequences of 8,192) forward and backward through
+    ``causal_attention`` with a TPU in sight: the masked kernels under
+    their names with the mask as an operand, the 8 query heads of a KV head
+    in one tile that fits VMEM, and no ``[.., 1024, T]`` or ``[T, T]`` block
+    of float scores anywhere in the program."""
     from deeplearning4j_tpu.ops import causal_attention as ca
-    T = 4096
+    from deeplearning4j_tpu.runtime import telemetry as tel
+    B, T, H, KV, d = 2, 8192, 32, 4, 128
+    monkeypatch.setattr(ca, "_tpu_available", lambda: True)
+    monkeypatch.setattr(fa, "_tpu_available", lambda: True)
+    counter = tel.registry.get("attention.dispatch")
 
     def loss(q, k, v, select):
-        return jnp.sum(ca.causal_attention(q, k, v, select=select)
-                       .astype(jnp.float32))
+        return jnp.sum(jnp.sin(ca.causal_attention(q, k, v, select=select)
+                               .astype(jnp.float32)))
 
-    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
-        ((1, T, 32, 128), BF16), ((1, T, 4, 128), BF16),
-        ((1, T, 4, 128), BF16), ((1, T, T), jnp.bool_))]
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args) \
-        .compile().as_text()
+    before = counter.value(kind="sparse", decision="kernel")
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    ((B, T, H, d), BF16), ((B, T, KV, d), BF16),
+                    ((B, T, KV, d), BF16), ((B, T, T), jnp.bool_),
+                    kernels=("causal_flash_fwd", "causal_flash_bwd_dq",
+                             "causal_flash_bwd_dkv"))
+    assert counter.value(kind="sparse", decision="kernel") == before + 1
     assert not re.search(rf"(f32|bf16)\[(\d+,)*{T},{T}\]", text)
-    assert re.search(rf"pred\[(1,)?1024,{T}\]", text)      # a block's slice
-    assert "tpu_custom_call" not in text                   # no kernel yet
+    assert not re.search(rf"(f32|bf16)\[(\d+,)*1024,{T}\]", text)
+    # the mask reaches the kernels as bytes, and transposed for dk/dv
+    assert f"s8[{B},{T},{T}]" in text
+    assert f"f32[{B * H},1,{T}]" in text           # the compact logsumexp
+    bq, bk = ca.causal_blocks(T, d, d, None, 2, H // KV)
+    assert fa.fits_vmem_attention(H // KV * bq, bk, d, 2, mask_rows=bq)
 
 
 def test_held_experts_compile_as_grouped_products(one_chip):
