@@ -18,7 +18,10 @@ One process, one TPU v5e chip, the entry points a user would call:
              as wide as its hidden size, one decoder layer recomputed at a
              time: ``attention.kept`` says the output is kept across the
              segment, and the step's ``memory_analysis()`` is printed with
-             the output kept and with it recomputed.
+             the output kept and with it recomputed;
+6. staging - ``fit_on_device``'s cast and upload of float inputs
+             (``nn/staging.py``) against numpy's ``astype``, bit for bit,
+             on what the device holds: NaN, infinities, subnormals, ties.
 
 ``--chips 4`` runs only the data-parallel path instead: ResNet-50 under
 ``ParallelWrapper(shard_update=True)`` on a 4-device ``data`` mesh, and
@@ -42,6 +45,7 @@ import json
 import sys
 import time
 import urllib.request
+import warnings
 from importlib import metadata
 
 import numpy as np
@@ -88,6 +92,10 @@ class Sizes:
     # values and reduction order alone moves the update by percents
     dp_tol: float = 3e-2
     lr: float = 0.01                           # the rehearsal's size needs less
+    # [batches, batch, ...] staged by fit_on_device: small ones salted with
+    # the cast's edge values, then resnet50.train.resident's own
+    stage_shapes: tuple = ((1, 4, 5, 3), (2, 4, 5, 3), (8, 4, 5, 3),
+                           (13, 4, 5, 3), (8, 128, 224, 224, 3))
 
 
 def say(msg: str) -> None:
@@ -636,6 +644,81 @@ def kept_phase(sz: Sizes):
                                  "outputs")
 
 
+# --------------------------------------------------------------- 6. staging
+def edge_values(src, dt) -> np.ndarray:
+    """Values at the edges of the cast ``src -> dt``: NaN of both signs,
+    infinities, signed zeros, subnormals of both types, overflow, and exact
+    ties that round-to-nearest-even sends both ways."""
+    import ml_dtypes
+    out = [np.nan, -np.nan, np.inf, -np.inf, 0.0, -0.0, 1.0, -1.0,
+           np.finfo(src).tiny / 3, -np.finfo(src).tiny / 7,
+           np.finfo(src).max, -np.finfo(src).max]
+    if dt == np.dtype(ml_dtypes.bfloat16):
+        # 1 + 2^-8 ties to 1.0; 1 + 3 * 2^-8 ties up to 1 + 2^-6
+        tiny = float(ml_dtypes.finfo(dt).tiny)
+        out += [1 + 2.0 ** -8, 1 + 3 * 2.0 ** -8, -(1 + 2.0 ** -8),
+                tiny / 2 ** 3, tiny * (1 + 2.0 ** -8)]
+        if src == np.float64:
+            # a tie in float32 that is above the tie in float64: a cast
+            # through float32 would round it down, a direct one rounds up
+            out += [1 + 2.0 ** -8 + 2.0 ** -30]
+    else:
+        # 1 + 2^-11 ties to 1.0; 2^-25 is half the least subnormal (to 0)
+        out += [1 + 2.0 ** -11, 1 + 3 * 2.0 ** -11, 2.0 ** -25,
+                3 * 2.0 ** -25, 65520.0, 70000.0, 6e-8, -3e-5]
+    return np.array(out, src)
+
+
+def edge_array(src, dt, shape, seed: int) -> np.ndarray:
+    """A normal draw of ``shape`` in ``src`` with ``edge_values`` written
+    at random places, each three times where the array has room."""
+    rng = np.random.default_rng(seed)
+    a = (rng.standard_normal(shape) * 4).astype(src)
+    flat = a.reshape(-1)
+    edges = edge_values(src, dt)
+    at = rng.choice(flat.size, size=min(flat.size, 3 * edges.size),
+                    replace=False)
+    flat[at] = np.resize(edges, at.size)
+    return a
+
+
+def staging_phase(sz: Sizes):
+    """Each ``sz.stage_shapes`` staged from ``src`` to ``dt`` as
+    ``fit_on_device`` stages a feature array, with three rows of ragged
+    tail dropped, and read back: the device must hold ``astype``'s bits.
+    A device that flushed subnormals or dropped a NaN's sign on the way
+    would fail here, where the CPU cannot."""
+    import ml_dtypes
+
+    from deeplearning4j_tpu.nn import staging
+
+    bf16 = np.dtype(ml_dtypes.bfloat16)
+    for src, dt in ((np.float32, bf16), (np.float64, bf16),
+                    (np.float32, np.dtype(np.float16))):
+        for k, shape in enumerate(sz.stage_shapes):
+            nb, b = shape[:2]
+            a = edge_array(src, dt, (nb * b + 3,) + shape[2:],
+                           seed=sz.seed + k)
+            with warnings.catch_warnings():    # the edge values overflow
+                warnings.simplefilter("ignore", RuntimeWarning)
+                t0 = time.perf_counter()
+                got = np.asarray(staging.stack(a, nb, b, dt, True,
+                                               {"model": "chip_smoke"}))
+                took = time.perf_counter() - t0
+                want = a[:nb * b].reshape(shape).astype(dt)
+            bits = np.dtype(f"u{dt.itemsize}")
+            bad = got.view(bits) != want.view(bits)
+            say(f"  staging {np.dtype(src).name} -> {dt.name} {shape}: "
+                f"{int(bad.sum())} of {bad.size} differ; staged and read "
+                f"back in {took:.3f}s")
+            if got.dtype != dt or got.shape != shape or bad.any():
+                pairs = [(hex(int(w)), hex(int(g))) for w, g in zip(
+                    want.view(bits)[bad][:8], got.view(bits)[bad][:8])]
+                raise AssertionError(
+                    f"staged {np.dtype(src).name} -> {dt.name} {shape} is "
+                    f"not astype's bits (astype, device): {pairs}")
+
+
 def _device_bytes(tree) -> dict:
     """Bytes each device holds of ``tree``, by device."""
     import jax
@@ -744,6 +827,7 @@ def main(argv=None) -> int:
         generate_phase(sz)
         kernels_phase(sz)
         kept_phase(sz)
+        staging_phase(sz)
     say(f"compile cache: {cache.hits} hits, {cache.misses} misses; "
         f"total {time.perf_counter() - t0:.0f}s")
     print(json.dumps({"ok": True, "device": info}), flush=True)

@@ -51,6 +51,7 @@ from ..data.dataset import (DataSet, DataSetIterator, MultiDataSet,
 from ..ops import losses as _loss
 from ..runtime import telemetry as _tel
 from . import constraints as _constraints
+from . import staging as _staging
 from . import updaters as _upd
 from .layers.base import Layer
 from .layers.core import LossLayer, OutputLayer
@@ -972,6 +973,12 @@ class ComputationGraph(_caches.CompiledCacheMixin):
         need static shapes; silent data loss was r3's recorded footgun).
         Returns the loss history ``[epochs * n_batches]``. Masked datasets
         must use ``fit()``.
+
+        A floating feature array that needs the network's dtype is cast in
+        runs of rows on a pool of host threads (``nn/staging.py``), bit for
+        bit ``a.astype(dtype)``; ids, labels and arrays already in that
+        dtype are uploaded as they are. The counter ``train.stage{decision=,
+        why=}`` says which each array took.
         """
         span_labels = self._phase_labels()
         with _tel.span("train.phase.call_s", span_labels,
@@ -994,20 +1001,10 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                     "drop_remainder=True to accept that, or use fit() which "
                     "pads and masks the tail")
             dt = _dt.resolve(self.conf.dtype)
-
-            def stack(a, cast):
-                # features get the net-dtype cast fit() applies in _forward;
-                # labels stay in their original precision (the loss computes
-                # in fp32 under the mixed-precision policy — pre-rounding
-                # regression targets to bf16 would diverge from fit())
-                with _tel.span("train.phase.stage_s", span_labels):
-                    a = a[:nb * b].reshape((nb, b) + a.shape[1:])
-                    if cast and np.issubdtype(a.dtype, np.floating) and \
-                            jnp.issubdtype(dt, jnp.floating):
-                        a = a.astype(dt)
-                    return jax.device_put(jnp.asarray(a))
-            xs = tuple(stack(f, True) for f in feats)
-            ys = tuple(stack(l, False) for l in labs)
+            xs = tuple(_staging.stack(f, nb, b, dt, True, span_labels)
+                       for f in feats)
+            ys = tuple(_staging.stack(l, nb, b, dt, False, span_labels)
+                       for l in labs)
             if self._epoch_fn is None:
                 self._epoch_fn = self._build_epoch_fn()
                 self._record_build("train.epoch_fn", cache_attr="_epoch_fn")

@@ -33,6 +33,7 @@ from . import caches as _caches
 from ..data.dataset import DataSet, DataSetIterator, NumpyDataSetIterator
 from ..ops import losses as _loss
 from ..runtime import telemetry as _tel
+from . import staging as _staging
 from .config import MultiLayerConfiguration
 from .layers.core import LossLayer, OutputLayer
 
@@ -458,7 +459,11 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
         scanned per epoch; returns the loss history. A non-divisible
         dataset RAISES unless ``drop_remainder=True`` explicitly discards
         the tail (silent data loss was r3's recorded footgun — VERDICT
-        weak #5). Masked datasets must use fit()."""
+        weak #5). Masked datasets must use fit(). A floating feature array
+        that needs the network's dtype is cast in runs of rows on a pool of
+        host threads (``nn/staging.py``), bit for bit ``a.astype(dtype)``; the
+        counter ``train.stage{decision=, why=}`` says how each array was
+        staged."""
         span_labels = self._phase_labels()
         with _tel.span("train.phase.call_s", span_labels,
                        entry="MultiLayerNetwork.fit_on_device"):
@@ -478,16 +483,8 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
                     "drop_remainder=True to accept that, or use fit() which "
                     "pads and masks the tail")
             dt = _dt.resolve(self.conf.dtype)
-
-            def stack(a, cast):
-                with _tel.span("train.phase.stage_s", span_labels):
-                    a = a[:nb * b].reshape((nb, b) + a.shape[1:])
-                    if cast and np.issubdtype(a.dtype, np.floating) and \
-                            jnp.issubdtype(dt, jnp.floating):
-                        a = a.astype(dt)
-                    return jax.device_put(jnp.asarray(a))
-            xs = stack(x, True)
-            ys = stack(y, False)
+            xs = _staging.stack(x, nb, b, dt, True, span_labels)
+            ys = _staging.stack(y, nb, b, dt, False, span_labels)
             if getattr(self, "_epoch_fn", None) is None:
                 self._epoch_fn = self._build_epoch_fn()
                 self._record_build("train.epoch_fn", cache_attr="_epoch_fn")

@@ -14,7 +14,9 @@ flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
 
+import importlib.util  # noqa: E402
 import json  # noqa: E402
+import sys  # noqa: E402
 import time  # noqa: E402
 
 import jax  # noqa: E402
@@ -28,6 +30,19 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(1234)
+
+
+@pytest.fixture(scope="session")
+def smoke():
+    """``chip_smoke.py`` as a module: its phases rehearse here at a tiny
+    size, and its edge values of a cast serve the staging tests."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules["chip_smoke"] = mod     # dataclasses resolves the module
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def pytest_configure(config):

@@ -3,25 +3,16 @@ cache rule, and a CPU rehearsal of its phases at a tiny size (Pallas
 kernels in interpret mode, by explicit ``force``). A rehearsal that passes
 here finds wrong paths and arguments; it says nothing about the chip."""
 
-import importlib.util
 import os
 import subprocess
 import sys
 
 import jax
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOKE = os.path.join(ROOT, "chip_smoke.py")
-
-
-@pytest.fixture(scope="module")
-def smoke():
-    spec = importlib.util.spec_from_file_location("chip_smoke", SMOKE)
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules["chip_smoke"] = mod     # dataclasses resolves the module
-    spec.loader.exec_module(mod)
-    return mod
 
 
 @pytest.fixture(scope="module")
@@ -36,7 +27,7 @@ def tiny(smoke):
         page=8, verify_window=4, ln_shape=(32, 128),
         affine_shape=(64, 128), lstm_shape=(8, 16),
         kept_shape=(2, 32, 32, 4, 8, 48, 40, 2), dp_batch=8, dp_steps=2,
-        dp_tol=0.5, lr=1e-3)
+        dp_tol=0.5, lr=1e-3, stage_shapes=((1, 4, 5, 3), (3, 4, 5, 3)))
 
 
 @pytest.mark.parametrize("args", [[], ["--chips", "4"]],
@@ -105,3 +96,25 @@ def test_rehearse_kept(smoke, tiny):
 
 def test_rehearse_data_parallel(smoke, tiny):
     smoke.data_parallel_phase(tiny, jax.devices()[:4])
+
+
+def test_rehearse_staging(smoke, tiny):
+    smoke.staging_phase(tiny)
+
+
+def test_staging_phase_catches_a_device_that_flushes(smoke, tiny,
+                                                     monkeypatch):
+    """The fault the phase is there for: a staged array whose subnormals
+    came back as zeros is refused."""
+    from deeplearning4j_tpu.nn import staging
+    stack = staging.stack
+
+    def flushing(*args):
+        got = np.array(stack(*args))
+        normal = np.finfo(np.float32).tiny      # bfloat16's too
+        got[np.abs(got.astype(np.float32)) < normal] = 0
+        return got
+
+    monkeypatch.setattr(staging, "stack", flushing)
+    with pytest.raises(AssertionError, match="not astype's bits"):
+        smoke.staging_phase(tiny)
