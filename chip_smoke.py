@@ -82,6 +82,8 @@ class Sizes:
     ln_shape: tuple = (4096, 768)
     affine_shape: tuple = (128 * 56 * 56, 256)
     lstm_shape: tuple = (64, 256)
+    # tokens, hidden, expert width, experts held, experts, chosen a token
+    moe_shape: tuple = (4096, 2048, 768, 8, 128, 8)
     # batch, positions, hidden, heads x head size (1x the hidden size),
     # feed-forward, vocabulary, layers
     kept_shape: tuple = (2, 4096, 1024, 8, 128, 2816, 4096, 2)
@@ -495,6 +497,45 @@ def kernels_phase(sz: Sizes, interpret: bool = False):
         name = f"causal {kind} {[B, T, H, d]} over {KV} KV heads"
         for g, w, n in zip(got, want, ("fwd", "dq", "dk", "dv")):
             close(f"{name} {n}", g, w, 2e-2 if n == "fwd" else 4e-2)
+
+    # the expert layer's held rows back to their tokens: the segment-sum
+    # kernel the dispatcher takes on the chip against its jnp form
+    from deeplearning4j_tpu.nn.layers.decoder import SparseExpertLayer
+    from deeplearning4j_tpu.ops import moe
+    N, d, f, held, E, k = sz.moe_shape
+    x, wr = arr((N, d)), arr((d, E))
+    w1, w3, w2 = (arr(s_, scale=0.05) for s_ in
+                  ((held, d, f), (held, d, f), (held, f, d)))
+    rows = SparseExpertLayer(num_experts=E, top_k=k,
+                             held=(0, held)).chunk_rows(N)
+
+    def experts(mode):
+        def layer(x, w1, w3, w2):
+            top_e, w = moe.route(x, wr, k, 1.0, scoring="softmax")
+            order, ends, _ = moe.plan(top_e, 0, held)
+            return moe.held_experts(x, w, w1, w3, w2, order, ends, rows, k,
+                                    "relu")[0]
+
+        old = fa.set_mode(mode)
+        try:
+            return (jax.jit(layer)(x, w1, w3, w2),) + jax.jit(jax.grad(
+                lambda *a: total(layer(*a)), argnums=(0, 1, 2, 3)))(
+                    x, w1, w3, w2)
+        finally:
+            fa.set_mode(old)
+
+    counter = tel.registry.get("moe.combine")
+    before = [counter.value(**{"pass": p}, decision="kernel")
+              for p in ("forward", "backward")]
+    got, want = experts("force" if interpret else "auto"), experts("off")
+    took = [counter.value(**{"pass": p}, decision="kernel") - b
+            for p, b in zip(("forward", "backward"), before)]
+    say(f"  moe.combine{{decision=kernel}} forward +{took[0]} "
+        f"backward +{took[1]}")
+    if min(took) < 1:
+        raise AssertionError("the expert layer did not take the kernel")
+    for g, w, n in zip(got, want, ("out", "dx", "dW1", "dW3", "dW2")):
+        close(f"experts {[N, d]} {held} of {E} held {n}", g, w, 1e-4)
 
     B, H, C, d = sz.decode_shape
     q1, kc, vc = arr((B, H, 1, d)), arr((B, H, C, d)), arr((B, H, C, d))

@@ -26,6 +26,7 @@ def tiny(smoke):
         causal_shape=(1, 256, 4, 2, 16), causal_window=100, causal_block=128,
         page=8, verify_window=4, ln_shape=(32, 128),
         affine_shape=(64, 128), lstm_shape=(8, 16),
+        moe_shape=(256, 128, 64, 8, 32, 4),
         kept_shape=(2, 32, 32, 4, 8, 48, 40, 2), dp_batch=8, dp_steps=2,
         dp_tol=0.5, lr=1e-3, stage_shapes=((1, 4, 5, 3), (3, 4, 5, 3)))
 

@@ -240,24 +240,43 @@ def test_no_token_is_dropped_when_all_pick_the_same_experts():
     assert int(state["dropped"]) == 0
 
 
-def test_chunks_do_not_change_the_result():
+@pytest.mark.parametrize("routing,rows,mode", [
+    ("router", 8, "auto"), ("router", 16, "force"),
+    ("one_expert", 8, "auto"), ("one_expert", 16, "force")],
+    ids=["router", "router_kernel", "one_expert", "one_expert_kernel"])
+def test_chunks_do_not_change_the_result(monkeypatch, routing, rows, mode):
+    """One chunk against chunks of ``rows``, the router's gradient
+    included; with every token on one held expert too (two chunks or more).
+    ``force`` takes the segment-sum kernel (interpret mode off the chip) in
+    both, and both paths give the same sums."""
+    from deeplearning4j_tpu.ops import flash_attention as fa
     rng = jax.random.PRNGKey(2)
     x = jax.random.normal(rng, (32, 8))
     w1, w3 = jax.random.normal(rng, (2, 4, 8, 6)) * 0.3
     w2 = jax.random.normal(rng, (4, 6, 8)) * 0.3
     wr = jax.random.normal(rng, (8, 8))
+    if routing == "one_expert":
+        x = x.at[:, 0].set(1.0)
+        wr = wr.at[0, 2].set(20.0)
 
     def run(x, rows):
         top_e, w = moe.route(x, wr, 2, 2.5)
-        order, ends, _ = moe.plan(top_e, 2, 4)
+        order, ends, tokens = moe.plan(top_e, 2, 4)
         out, n = moe.held_experts(x, w, w1, w3, w2, order, ends, rows, 2)
-        return out, (n, ends[-1])
+        return out, (n, ends[-1], tokens)
 
-    (one, (n1, here)), (many, (n2, _)) = run(x, 64), run(x, 8)
+    monkeypatch.setattr(fa, "_state", {"mode": mode})
+    (one, (n1, here, tokens)), (many, (n2, _, _)) = run(x, 64), run(x, rows)
     assert int(n1) == int(n2) == int(here)
+    if routing == "one_expert":
+        assert int(tokens[0]) == 32 and int(here) > 2 * rows
     close(one, many, tol=1e-5)
     g = lambda rows: jax.grad(lambda x: jnp.sum(run(x, rows)[0] ** 2))(x)
-    close(g(64), g(8), tol=1e-5)
+    g_many = g(rows)
+    close(g(64), g_many, tol=1e-5)
+    monkeypatch.setattr(fa, "_state", {"mode": "auto"})
+    close(many, run(x, rows)[0], tol=1e-5)
+    close(g_many, g(rows), tol=1e-5)
 
 
 @pytest.mark.parametrize("t,same", [(8, True), (16, False)],

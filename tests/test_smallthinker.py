@@ -7,8 +7,9 @@ loss, every gradient leaf, three Adam steps through ``fit_on_device`` with
 and without recomputation); the share test (the 8 shares of 8 of 64 experts
 add up to the uncut layer); the stand-in for the experts held elsewhere
 (their router columns get no gradient); the early router (perturb the
-experts' input alone and the choice stays); ReGLU through ``held_experts``
-against plain ``jnp`` forward and backward with rows at relu's zero; no
+experts' input alone and the choice stays); ``held_experts`` against plain
+``jnp`` and the scatter-add it replaced, forward and backward, through the
+segment-sum kernel and its ``jnp`` form, with rows at relu's zero; no
 rotary tables in a NoPE layer; the layouts read layer by layer; what must
 NOT pass (bfloat16, the router on its own input, SwiGLU); the builder's
 refusals; the new counter labels; and the lowered text of every other
@@ -428,53 +429,144 @@ def test_swiglu_is_not_the_reference(world):
     assert gap(swish, want) > 50 * 2e-4
 
 
-def _plain_reglu(x, w, w1, w3, w2, top_e):
+def _plain_experts(x, w, w1, w3, w2, top_e, activation="relu"):
     """Every token through the experts it chose, in a Python loop over the
-    held experts: ``sum_e w_e (relu(x W1_e) * (x W3_e)) W2_e``."""
+    held experts: ``sum_e w_e (act(x W1_e) * (x W3_e)) W2_e``."""
+    act = jax.nn.relu if activation == "relu" else jax.nn.silu
     out = jnp.zeros(x.shape, jnp.float32)
     for e in range(w1.shape[0]):
         weight = jnp.sum(jnp.where(top_e == e, w, 0.0), axis=1)[:, None]
-        h = jax.nn.relu(x @ w1[e]) * (x @ w3[e])
+        h = act(x @ w1[e]) * (x @ w3[e])
         out = out + weight * (h @ w2[e])
     return out
 
 
-@pytest.mark.parametrize("chunk_rows", [64, 24], ids=["one_chunk", "chunks"])
-def test_reglu_through_held_experts_forward_and_backward(chunk_rows):
-    """``held_experts(..., activation="relu")`` against plain ``jnp``: the
-    output and the gradients of every operand, in one chunk and in several,
-    with a quarter of the rows at relu's zero (rows of nought: ``x W1`` is
-    exactly 0 there, where both sides' relu passes no gradient)."""
+def _scatter_add_experts(x, w, w1, w3, w2, order, ends, chunk_rows, top_k,
+                         activation):
+    """The held experts' sum as the expert layer once formed it: each chunk
+    of the sorted rows scatter-added into its tokens (``out.at[tok].add``),
+    differentiated by JAX through the gather and the scatter."""
+    out = jnp.zeros(x.shape, jnp.float32)
+    for c in range(-(-int(ends[-1]) // chunk_rows)):
+        rows = c * chunk_rows + jnp.arange(chunk_rows)
+        live = rows < ends[-1]
+        a = jnp.take(order, jnp.minimum(rows, order.shape[0] - 1))
+        keep = live[:, None]
+        y = moe._gated(jnp.where(keep, jnp.take(x, a // top_k, axis=0), 0),
+                       w1, w3, w2, moe._sizes(c, ends, chunk_rows), keep,
+                       activation)
+        wt = jnp.where(live, jnp.take(w.reshape(-1), a), 0.0)[:, None]
+        out = out.at[a // top_k].add(y.astype(jnp.float32) * wt)
+    return out
+
+
+def _routing(kind, n, k, held, key):
+    """``[n, k]`` distinct experts a token, some held (``0`` to ``held``) and
+    some not. ``mixed``: a quarter of the tokens chose no held expert, a
+    quarter one, half all ``k``; ``one_expert``: every token chose expert 0
+    (and another held one); ``empty_expert``: no token chose the last held
+    expert; ``all_held``: every choice is held, as with ``held=None``."""
+    t = np.arange(n)[:, None]
+    j = np.arange(k)[None, :]
+    shift = np.asarray(jax.random.randint(key, (n, 1), 0, held))
+    if kind == "mixed":
+        top_e = np.where(t % 4 == 0, held + j,
+                         np.where((t % 4 == 1) & (j > 0), held + j,
+                                  (shift + j) % held))
+    elif kind == "one_expert":
+        top_e = np.where(j == 0, 0, 1 + (shift + j) % (held - 1))
+    elif kind == "empty_expert":
+        top_e = np.where(t % 3 == 0, held + j, (shift + j) % (held - 1))
+    else:
+        top_e = (shift + j) % held
+    return jnp.asarray(top_e, jnp.int32)
+
+
+#: (chunk rows, routing, activation, path) of 32 tokens, 2 choices each,
+#: over 4 held experts; the kernel runs in interpret mode off the chip, and
+#: a chunk of 24 rows does not tile it (its ``jnp`` form, ``why=shape``)
+HELD_CASES = {
+    "one_chunk": (64, "mixed", "relu", "jnp"),
+    "chunks": (24, "mixed", "relu", "jnp"),
+    "swish": (64, "mixed", "swish", "jnp"),
+    "kernel": (64, "mixed", "relu", "kernel"),
+    "untiled_chunks": (24, "mixed", "swish", "kernel"),
+    "kernel_chunks": (16, "mixed", "swish", "kernel"),
+    "one_expert_chunks": (16, "one_expert", "relu", "kernel"),
+    "empty_expert": (32, "empty_expert", "swish", "kernel"),
+    "all_held": (64, "all_held", "relu", "kernel"),
+    "all_held_jnp": (24, "all_held", "swish", "jnp"),
+}
+
+
+@pytest.mark.parametrize("case", list(HELD_CASES))
+def test_reglu_through_held_experts_forward_and_backward(case,
+                                                         monkeypatch):
+    """``held_experts(..., activation=)`` against plain ``jnp`` and against
+    the scatter-add it replaced: the output and the gradients of every
+    operand, in one chunk and in several, through the segment-sum kernel and
+    its ``jnp`` form, for tokens with no, one and every choice held, a held
+    expert no token chose, and every token on one expert; with a quarter of
+    the rows at nought (``x W1`` is exactly 0 there, where relu passes no
+    gradient). ``moe.combine`` counts the path once a pass."""
+    from deeplearning4j_tpu.ops import flash_attention as fa
+    chunk_rows, kind, activation, path = HELD_CASES[case]
     n, d, f, held, k = 32, 16, 8, 4, 2
     keys = jax.random.split(jax.random.PRNGKey(21), 6)
     x = jax.random.normal(keys[0], (n, d)).at[::4].set(0.0)
     w = jax.nn.softmax(jax.random.normal(keys[1], (n, k)), -1)
     w1, w3 = (0.3 * jax.random.normal(kk, (held, d, f)) for kk in keys[2:4])
     w2 = 0.3 * jax.random.normal(keys[4], (held, f, d))
-    top_e = jax.random.randint(keys[5], (n, k), 0, held + 2)  # some elsewhere
-    order, ends, _ = moe.plan(top_e, 0, held)
+    top_e = _routing(kind, n, k, held, keys[5])
+    order, ends, tokens = moe.plan(top_e, 0, held)
+    if kind == "one_expert":
+        assert int(tokens[0]) == n and int(ends[-1]) > 2 * chunk_rows
+    if kind == "empty_expert":
+        assert int(tokens[-1]) == 0
+    monkeypatch.setattr(fa, "_state", {"mode": "force" if path == "kernel"
+                                       else "auto"})
 
     def ours(x, w, w1, w3, w2):
         return moe.held_experts(x, w, w1, w3, w2, order, ends, chunk_rows, k,
-                                "relu")[0]
+                                activation)[0]
 
     def plain(x, w, w1, w3, w2):
-        return _plain_reglu(x, w, w1, w3, w2, top_e)
+        return _plain_experts(x, w, w1, w3, w2, top_e, activation)
+
+    def scattered(x, w, w1, w3, w2):
+        return _scatter_add_experts(x, w, w1, w3, w2, order, ends,
+                                    chunk_rows, k, activation)
 
     args = (x, w, w1, w3, w2)
+    ct = jax.random.normal(jax.random.PRNGKey(5), (n, d))
+    grad = lambda fn: jax.grad(lambda *a: jnp.sum(fn(*a) * ct),
+                               argnums=range(5))(*args)
+    tiling = moe.segment_tiling(n, chunk_rows, d, 4, min(k, held))
+    labels = ({"decision": "jnp", "why": "platform"} if path == "jnp" else
+              {"decision": "jnp", "why": tiling} if isinstance(tiling, str)
+              else {"decision": "kernel"})
+    before = [_total("moe.combine", **{"pass": p}, **labels)
+              for p in ("forward", "backward")]
+    jax.eval_shape(lambda: grad(ours))
+    assert [_total("moe.combine", **{"pass": p}, **labels)
+            for p in ("forward", "backward")] == [b + 1 for b in before]
     with jax.default_matmul_precision("highest"):
-        close(ours(*args), plain(*args), tol=1e-5)
-        ct = jax.random.normal(jax.random.PRNGKey(5), (n, d))
-        g_ours = jax.grad(lambda *a: jnp.sum(ours(*a) * ct),
-                          argnums=range(5))(*args)
-        g_plain = jax.grad(lambda *a: jnp.sum(plain(*a) * ct),
-                           argnums=range(5))(*args)
-    for got, want in zip(g_ours, g_plain):
-        close(got, want, tol=1e-5)
-    # a zero row's hidden units sit at relu's zero: its x-gradient comes from
-    # W3's side alone, times relu(0) = 0, so it is nought on both sides
-    assert not np.asarray(g_plain[0])[::4].any()
-    assert not np.asarray(g_ours[0])[::4].any()
+        got = ours(*args)
+        close(got, plain(*args), tol=1e-5)
+        close(got, scattered(*args), tol=1e-5)
+        g_ours, g_plain, g_scattered = grad(ours), grad(plain), grad(scattered)
+    for mine, want, old in zip(g_ours, g_plain, g_scattered):
+        close(mine, want, tol=1e-5)
+        close(mine, old, tol=1e-5)
+    if kind == "mixed":
+        # the tokens that chose no held expert get an exact nought
+        assert not np.asarray(got)[::4].any()
+    if activation == "relu":
+        # a zero row's hidden units sit at relu's zero: its x-gradient comes
+        # from W3's side alone, times relu(0) = 0, so it is nought on both
+        # sides
+        assert not np.asarray(g_plain[0])[::4].any()
+        assert not np.asarray(g_ours[0])[::4].any()
 
 
 # ------------------------------------------------------ builder and counters
@@ -504,11 +596,16 @@ def test_counters_after_one_call(world):
     """``attention.dispatch`` names the rotation (``rope=none`` on the full
     layer, ``default`` on the window layers); ``moe.route`` names the
     tensor the router reads (``input=early``) and the experts' activation
-    (``act=relu``); the expert counters count as in every cell."""
+    (``act=relu``); the expert counters count as in every cell, and
+    ``moe.combine`` names the segment sum's path in both passes of every
+    expert layer (its ``jnp`` form off the chip)."""
     cfg = world["cfg"]
     net = build(cfg, jax.tree.map(jnp.copy, world["weights"]))
     rows = np.random.default_rng(9).integers(0, cfg["vocab_size"],
                                              (2 * B, T), dtype=np.int32)
+    combine0 = [_total("moe.combine", **{"pass": p}, decision="jnp",
+                       why="platform") for p in ("forward", "backward")]
+    kernel0 = _total("moe.combine", decision="kernel")
     nope0 = _total("attention.dispatch", kind="full", rope="none")
     win0 = _total("attention.dispatch", kind="window", rope="default")
     early0 = _total("moe.route", select="plain", scoring="softmax",
@@ -523,6 +620,10 @@ def test_counters_after_one_call(world):
                   input="early", act="relu") - early0 >= LAYERS
     assert _total("moe.assignments") - assign0 == LAYERS * 2 * B * T * 2
     assert _total("moe.dropped") == 0
+    assert all(_total("moe.combine", **{"pass": p}, decision="jnp",
+                      why="platform") - c0 >= LAYERS
+               for p, c0 in zip(("forward", "backward"), combine0))
+    assert _total("moe.combine", decision="kernel") == kernel0
 
 
 def test_the_other_cells_count_under_the_defaults():
@@ -554,14 +655,16 @@ def test_the_other_cells_count_under_the_defaults():
 #: builder's tiny stack, as the parent of the PR that brought
 #: ``rope_type="none"``, ``activation`` and ``early_router`` (PR 42) lowered
 #: it: the new options' defaults are that program, to the byte. A PR that
-#: moves one of these programs on purpose writes its own digest here.
+#: moves one of these programs on purpose writes its own digest here: the
+#: three expert stacks' are those of the held rows going back to their
+#: tokens by a segment sum.
 PARENT_DIGESTS = {
     "laguna_xs2":
-        "33d283f736cd1d259e8227de0782baf3bf0f73a11034f505fe763dadf74d53af",
+        "e5a0c180f4c81ebc078885925a620b90cc014199aefb94d427641be2069a6702",
     "kanana2_30b_a3b":
-        "cbdcb559359e252092bb6c0eeb4e3b2aa9b11c6042d5edd7e58dda0086c9ae92",
+        "c1f098c23d6291a9056f2221bad9efe8fdfb755ece6e2be2a1fc0cbb6871a464",
     "keye_vl2_30b_a3b":
-        "59a4c9c3b1682be214c19fd3f680dafc9707a40caca361361e2174d2a859c299",
+        "3ac040a47879c74fa0e90ec2aef68e87e55a6e3c02260a60af90ea4bd4243551",
     "ouro_2_6b":
         "020ddfdccf8ca29a2fc5619f0d0cc5e45525f18ba320e8e436529329c0e24d1f",
 }

@@ -397,10 +397,22 @@ def test_selected_attention_compiles_backward(one_chip, monkeypatch):
     assert fa.fits_vmem_attention(H // KV * bq, bk, d, 2, mask_rows=bq)
 
 
-def test_held_experts_compile_as_grouped_products(one_chip):
+def _scatters(text, rows, width):
+    """The scatters of a compiled text whose result is ``[rows, width]``."""
+    return re.findall(rf"= [a-z0-9]+\[{rows},{width}\][^\n]* scatter\(", text)
+
+
+def test_held_experts_compile_as_grouped_products(one_chip, monkeypatch):
     """16 experts of 2048 x 512 held, 8 of 256 chosen a token: the chunk
-    loop with the compiler's ragged-dot kernels, forward and backward."""
+    loop with the compiler's ragged-dot kernels, forward and backward; the
+    held rows go back to their tokens through the ``moe_combine`` kernel,
+    with no scatter of a ``[tokens, hidden]`` result left in the program."""
     from deeplearning4j_tpu.ops import moe
+    from deeplearning4j_tpu.runtime import telemetry as tel
+    monkeypatch.setattr(moe, "_tpu_available", lambda: True)
+    counter = tel.registry.get("moe.combine")
+    before = [counter.value(**{"pass": p}, decision="kernel")
+              for p in ("forward", "backward")]
     N, d, f, held, k = 4096, 2048, 512, 16, 8
 
     def loss(x, wr, w1, w3, w2):
@@ -415,6 +427,10 @@ def test_held_experts_compile_as_grouped_products(one_chip):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*args) \
         .compile().as_text()
     assert "ragged-dot" in text
+    assert any("moe_combine" in c for c in _custom_call_names(text))
+    assert not _scatters(text, N, d)
+    assert [counter.value(**{"pass": p}, decision="kernel")
+            for p in ("forward", "backward")] == [b + 1 for b in before]
 
 
 def test_smallthinker_epoch_compiles_for_the_chip(one_chip, monkeypatch):
@@ -423,7 +439,9 @@ def test_smallthinker_epoch_compiles_for_the_chip(one_chip, monkeypatch):
     on 2 KV heads (7 a KV head, as published), a positionless full layer
     and a rotated 512-window layer on rows of 2,048, ReGLU experts behind
     the early router. Both kinds take the masked kernels, counted with
-    their rotation, and the experts the ragged-dot kernels."""
+    their rotation, and the experts the ragged-dot kernels; the held rows go
+    back to their tokens through ``moe_combine``, forward and backward, and
+    no scatter of a ``[tokens, hidden]`` result is left."""
     import importlib
     import json
     from deeplearning4j_tpu.nn import graph
@@ -445,8 +463,10 @@ def test_smallthinker_epoch_compiles_for_the_chip(one_chip, monkeypatch):
         "benchmarks.configs.smallthinker_21b_a3b.program")
     ref = importlib.import_module(
         "benchmarks.reference.smallthinker_21b_a3b")
+    from deeplearning4j_tpu.ops import moe
     monkeypatch.setattr(ca, "_tpu_available", lambda: True)
     monkeypatch.setattr(fa, "_tpu_available", lambda: True)
+    monkeypatch.setattr(moe, "_tpu_available", lambda: True)
     real_init = graph.ComputationGraph.init
 
     def abstract_init(self):
@@ -481,6 +501,8 @@ def test_smallthinker_epoch_compiles_for_the_chip(one_chip, monkeypatch):
         assert any(kernel in c for c in calls), (kernel, calls)
     assert "ragged-dot" in text
     assert not re.search(rf"\[(\d+,)*{T},{T}\]", text)
+    assert any("moe_combine" in c for c in calls)
+    assert not _scatters(text, B * T, cfg["hidden_size"])
 
 
 def test_lstm_cell_compiles(one_chip):
