@@ -9,6 +9,9 @@ site or mutation point gets fixed in exactly one place.
 
 from __future__ import annotations
 
+import copy
+import dataclasses
+
 from .. import dtypes as _dt
 from ..runtime import telemetry as _tel
 from ..runtime.sentinel import SentinelCounterMixin
@@ -64,12 +67,19 @@ class CompiledCacheMixin(SentinelCounterMixin):
     # telemetry_label (model=<id> registry label) is inherited from
     # SentinelCounterMixin so SameDiff shares the same contract
 
-    def _replace_conf_dtype(self, dtype: str):
-        """Return a conf carrying ``dtype`` WITHOUT mutating the current
+    def _replace_conf(self, **fields):
+        """Return a conf carrying ``fields`` WITHOUT mutating the current
         one in place — confs may be shared across nets, and a sibling's
         live traces must not see the new policy without their own
-        invalidation."""
-        raise NotImplementedError
+        invalidation. A dataclass conf is replaced, any other shallow-
+        copied."""
+        conf = self.conf
+        if dataclasses.is_dataclass(conf):
+            return dataclasses.replace(conf, **fields)
+        conf = copy.copy(conf)
+        for k, v in fields.items():
+            setattr(conf, k, v)
+        return conf
 
     def _invalidate_compiled(self, cause: str = "invalidate"):
         """Drop every cached compiled function. MUST be called at any
@@ -128,7 +138,7 @@ class CompiledCacheMixin(SentinelCounterMixin):
         and every compiled trace is invalidated — the old traces baked
         the previous policy in and would silently serve it."""
         _dt.resolve(dtype)  # validate the name before mutating anything
-        self.conf = self._replace_conf_dtype(dtype)
+        self.conf = self._replace_conf(dtype=dtype)
         pdt = _dt.param_dtype(dtype)
         self.params = _dt.cast_floating(self.params, pdt)
         self.state = _dt.cast_floating(self.state, pdt)
@@ -148,21 +158,9 @@ class CompiledCacheMixin(SentinelCounterMixin):
         rebuild it the same way as after ``set_dtype``.)"""
         from . import memory as _memory
         policy = _memory.resolve_policy(mode)  # validate before mutating
-        self.conf = self._replace_conf_workspace_mode(policy.name)
+        self.conf = self._replace_conf(workspace_mode=policy.name)
         self._invalidate_compiled(cause="workspace_mode")
         return self
-
-    def _replace_conf_workspace_mode(self, mode: str):
-        # same copy-on-write contract as _replace_conf_dtype; both engines'
-        # confs carry a plain `workspace_mode` str field
-        import copy
-        import dataclasses
-        conf = self.conf
-        if dataclasses.is_dataclass(conf):
-            return dataclasses.replace(conf, workspace_mode=mode)
-        conf = copy.copy(conf)
-        conf.workspace_mode = mode
-        return conf
 
     def memory_report(self, batch_size: int, accum_steps: int = 1,
                       seq_len=None) -> dict:

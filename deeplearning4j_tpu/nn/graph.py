@@ -29,8 +29,9 @@ Usage mirrors DL4J::
     net.fit(multi_dataset_iterator, epochs=2)
 
 Param/state layout: pytree keyed by VERTEX NAME (stable across JSON);
-flat-param adapter orders by topological order then DL4J param-name order —
-same contract as MultiLayerNetwork.
+flat-param adapter orders by topological order then DL4J param-name order.
+``fit``, ``fit_on_device`` and the rest of the host side are the two
+engines' shared ``nn/engine.py``.
 """
 
 from __future__ import annotations
@@ -44,18 +45,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import dtypes as _dt
-from .. import environment as _env
-from . import caches as _caches
 from ..data.dataset import (DataSet, DataSetIterator, MultiDataSet,
                             MultiDataSetIterator, NumpyMultiDataSetIterator)
 from ..ops import losses as _loss
 from ..runtime import telemetry as _tel
 from . import constraints as _constraints
-from . import staging as _staging
+from . import engine as _engine
+from . import microbatch as _micro
 from . import updaters as _upd
 from .layers.base import Layer
-from .layers.core import LossLayer, OutputLayer
-from .model import _get_path, _param_paths, _set_path
 from .vertices import GraphVertex, LayerVertex
 
 
@@ -433,19 +431,11 @@ class GraphBuilder:
             repeats=self._repeats)
 
 
-class ComputationGraph(_caches.CompiledCacheMixin):
+class ComputationGraph(_engine.NetworkEngine):
     """DAG network engine (DL4J ``ComputationGraph``)."""
 
-    def _replace_conf_dtype(self, dtype: str):
-        # shallow copy: the conf may be shared by other graphs ("the thing
-        # that serializes"); only this net's dtype policy changes
-        import copy
-        conf = copy.copy(self.conf)
-        conf.dtype = dtype
-        return conf
-
     def __init__(self, conf: ComputationGraphConfiguration):
-        self.conf = conf
+        super().__init__(conf)
         self._vertex_map: Dict[str, Tuple[GraphVertex, List[str]]] = {
             n: (v, ins) for n, v, ins in conf.vertices}
         self._topo = conf.topo_order()
@@ -457,27 +447,14 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             import weakref
             weakref.finalize(self, _tel.registry.discard_cells,
                              graph=self.telemetry_label)
-        self.params: Dict[str, Dict[str, jax.Array]] = {}
-        self.state: Dict[str, Dict[str, jax.Array]] = {}
-        self.updater_state: Any = None
-        self.iteration = 0
-        self.epoch = 0
-        self._score = float("nan")
-        self._listeners: List[Any] = []
-        self._train_step = None
-        self._train_output_fn = None
-        self._epoch_fn = None
-        self._inference_engine = None
         self._layer_counts_seen: Dict[str, Any] = {}
-        self._key = jax.random.PRNGKey(conf.seed)
         self._out_layers: Dict[str, Any] = {}
         for o in conf.outputs:
             v = self._vertex_map[o][0]
             lyr = v.layer if isinstance(v, LayerVertex) else None
             # duck-typed loss heads (OutputLayer, LossLayer, CenterLoss,
             # Yolo2Output, custom) — same probe as the sequential engine
-            from .model import _is_loss_head
-            if lyr is not None and _is_loss_head(lyr):
+            if lyr is not None and _engine._is_loss_head(lyr):
                 self._out_layers[o] = lyr
 
     # ------------------------------------------------------------------ init
@@ -513,9 +490,6 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             if self.conf.updater else {}
         self._invalidate_compiled(cause="init")
         return self
-
-    def num_params(self) -> int:
-        return sum(int(np.prod(l.shape)) for l in jax.tree.leaves(self.params))
 
     def summary(self) -> str:
         lines = [f"{'vertex':<24}{'type':<22}{'inputs':<30}{'out shape':<18}params"]
@@ -792,47 +766,6 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             new_state.update(ns)
         return acts, mks, new_state, rng
 
-    def _regularization(self, params):
-        total = 0.0
-        for name in self._topo:
-            v, _ = self._vertex_map[name]
-            lyr = v.layer if isinstance(v, LayerVertex) else None
-            if getattr(lyr, "frozen", False):
-                continue  # FrozenLayer: no updates of any kind (DL4J)
-            l1 = (getattr(lyr, "l1", 0.0) or self.conf.l1) if lyr else self.conf.l1
-            l2 = (getattr(lyr, "l2", 0.0) or self.conf.l2) if lyr else self.conf.l2
-            if not (l1 or l2):
-                continue
-            w = params.get(name, {}).get("W")
-            if w is None:
-                continue
-            if l1:
-                total = total + l1 * jnp.sum(jnp.abs(w))
-            if l2:
-                total = total + 0.5 * l2 * jnp.sum(jnp.square(w))
-        return total
-
-    def _uses_regularization(self) -> bool:
-        """Any l1/l2 penalty configured? Gates the mixed-precision cast
-        hoist in ``_build_train_step`` (see MultiLayerNetwork's twin)."""
-        if self.conf.l1 or self.conf.l2:
-            return True
-        return any((getattr(v.layer, "l1", 0.0) or
-                    getattr(v.layer, "l2", 0.0))
-                   for _, v, _ in self.conf.vertices
-                   if isinstance(v, LayerVertex))
-
-    def _clip(self, grads):
-        """Gradient normalization/clipping; returns ``(grads, clip_events)``
-        — the shared ``gradnorm.clip_with_events`` pipeline (the sentinel
-        accumulates the events as telemetry)."""
-        from . import gradnorm as _gn
-        return _gn.clip_with_events(
-            self.conf.gradient_normalization,
-            self.conf.gradient_normalization_threshold,
-            self.conf.gradient_clip_value, self.conf.gradient_clip_l2, grads)
-
-    # ------------------------------------------------------------ train step
     def _build_loss_fn(self):
         """The pure training loss ``(params, bn_state, key, xs, ys, fms,
         lms) -> (loss, new_bn_state)`` the train step differentiates —
@@ -889,152 +822,32 @@ class ComputationGraph(_caches.CompiledCacheMixin):
 
         return loss_fn
 
-    def fused_updater_active(self) -> bool:
-        """Fused master-cast updater gate (ISSUE 16) — see
-        ``MultiLayerNetwork.fused_updater_active``."""
-        from ..ops import fused_epilogues as _fe
-        return _fe.route_updater(
-            self.conf.dtype,
-            has_penalty=self._uses_regularization()) is None
+    # ---------------------------------------- the shared host side's hooks
+    def _keyed_layers(self):
+        for name in self._topo:
+            v = self._vertex_map[name][0]
+            yield name, (v.layer if isinstance(v, LayerVertex) else None)
 
-    def _build_train_step(self, accum_steps: int = 1, grad_transform=None,
-                          fused_cast: bool = False):
-        """Fused pure train step; ``accum_steps=k`` scans the gradient over
-        k microbatches before the single updater application (same contract
-        as ``MultiLayerNetwork._build_train_step`` and the same body,
-        ``nn/trainstep.py``; see ``nn/microbatch.py``). The conf's
-        ``workspace_mode`` remat policy (``nn/memory.py``) composes with
-        both. ``grad_transform`` and the r12 mixed-precision cast hoist
-        follow the MultiLayerNetwork twin's contract (see its docstring):
-        the transform is value-identity scheduling structure applied BEFORE
-        clip/sentinel; the hoist casts fp32 masters to the compute dtype
-        once per step instead of once per microbatch (bit-equivalent, gated
-        on no l1/l2). ``fused_cast=True`` (ISSUE 16, gated on
-        :meth:`fused_updater_active`) compiles the fused master-cast variant
-        — ``params_c`` compute copy in the signature, cast folded into the
-        updater write; see ``MultiLayerNetwork._build_train_step`` for the
-        exactness argument."""
-        from .layers.wrappers import FrozenLayer
-        from . import microbatch as _micro
-        from . import trainstep as _ts
-        frozen_keys = frozenset(
-            n for n, v, _ in self.conf.vertices
-            if isinstance(v, LayerVertex) and isinstance(v.layer, FrozenLayer))
-        step = _ts.engine_step(
-            self, self._build_loss_fn(), frozen_keys,
-            _micro.multi_output_weight, accum_steps, grad_transform,
-            fused_cast)
-        return jax.jit(step,
-                       donate_argnums=(0, 1, 2, 3) if fused_cast
-                       else (0, 1, 2),
-                       compiler_options=_env.engine_compiler_options())
+    def _iterator(self, data, labels):
+        return _as_multi_iterator(data, labels)
 
-    # ------------------------------------------------- on-device epoch loop
-    def _build_epoch_fn(self):
-        """Compiled multi-batch trainer: ``lax.scan`` of the fused train step
-        over a device-resident stack of batches — the whole epoch is ONE XLA
-        program launch.
+    def _stage_batch(self, mds):
+        return tuple(tuple(None if a is None else jnp.asarray(a)
+                           for a in part)
+                     for part in (mds.features, mds.labels,
+                                  mds.features_masks, mds.labels_masks))
 
-        Why this exists (TPU-first divergence from DL4J's per-batch fit
-        loop): each host->device dispatch costs fixed latency (PJRT call
-        overhead). Scanning on device removes it entirely and is how XLA-era
-        trainers are meant to run epochs whose data fits in HBM.
+    def _host_arrays(self, features, labels):
+        return _host_list(features), _host_list(labels)
 
-        Under the fused master-cast updater (ISSUE 16) the scan carries
-        the ``params_c`` compute copy — one cast per epoch launch, the
-        rest emitted by the fused updater write; external signature
-        unchanged (masters in, masters out). ``xs``/``ys`` are tuples of
-        stacked arrays ``[n_batches, B, ...]`` aligned with
-        ``conf.inputs``/``conf.outputs``; masks are unsupported on this path.
-        """
-        # one dispatch decision per compiled program, as ``fit`` counts it
-        from ..ops import fused_epilogues as _fe
-        from . import trainstep as _ts
-        _fe.dispatch_updater(self.conf.dtype,
-                             has_penalty=self._uses_regularization())
-        fused = self.fused_updater_active()
-        step = self._build_train_step(fused_cast=fused).__wrapped__
-        masks = ((None,) * len(self.conf.inputs),
-                 (None,) * len(self.conf.outputs))
-        return jax.jit(_ts.build_epoch(step, fused,
-                                       _dt.resolve(self.conf.dtype), masks),
-                       donate_argnums=(0, 1, 2, 3),
-                       compiler_options=_env.engine_compiler_options())
+    def _pack(self, arrays):
+        return tuple(arrays)
 
-    def fit_on_device(self, features, labels, epochs: int = 1,
-                      batch_size: Optional[int] = None,
-                      drop_remainder: bool = False) -> np.ndarray:
-        """Train with the compiled on-device epoch loop (see
-        ``_build_epoch_fn``). ``features``/``labels`` are arrays (or lists of
-        arrays for multi-input/output graphs); they are reshaped to
-        ``[n_batches, batch_size, ...]``, uploaded ONCE, and scanned over
-        ``epochs`` times. A non-divisible dataset RAISES unless
-        ``drop_remainder=True`` explicitly discards the tail (device loops
-        need static shapes; silent data loss was r3's recorded footgun).
-        Returns the loss history ``[epochs * n_batches]``. Masked datasets
-        must use ``fit()``.
+    def _epoch_masks(self):
+        return ((None,) * len(self.conf.inputs),
+                (None,) * len(self.conf.outputs))
 
-        A floating feature array that needs the network's dtype is cast in
-        runs of rows on a pool of host threads (``nn/staging.py``), bit for
-        bit ``a.astype(dtype)``; ids, labels and arrays already in that
-        dtype are uploaded as they are. The counter ``train.stage{decision=,
-        why=}`` says which each array took.
-        """
-        span_labels = self._phase_labels()
-        with _tel.span("train.phase.call_s", span_labels,
-                       entry="ComputationGraph.fit_on_device"):
-            if not self.params and not self.state:
-                self.init()
-            feats = [np.asarray(f) for f in
-                     (features if isinstance(features, (list, tuple)) else [features])]
-            labs = [np.asarray(l) for l in
-                    (labels if isinstance(labels, (list, tuple)) else [labels])]
-            n = feats[0].shape[0]
-            b = batch_size or n
-            nb = n // b
-            if nb == 0:
-                raise ValueError(f"batch_size {b} exceeds dataset size {n}")
-            if n % b and not drop_remainder:
-                raise ValueError(
-                    f"dataset size {n} is not divisible by batch_size {b}: "
-                    f"the on-device scan would drop {n % b} examples. Pass "
-                    "drop_remainder=True to accept that, or use fit() which "
-                    "pads and masks the tail")
-            dt = _dt.resolve(self.conf.dtype)
-            xs = tuple(_staging.stack(f, nb, b, dt, True, span_labels)
-                       for f in feats)
-            ys = tuple(_staging.stack(l, nb, b, dt, False, span_labels)
-                       for l in labs)
-            if self._epoch_fn is None:
-                self._epoch_fn = self._build_epoch_fn()
-                self._record_build("train.epoch_fn", cache_attr="_epoch_fn")
-            history = []
-            for _ in range(epochs):
-                with _tel.span("train.phase.prepare_s", span_labels):
-                    self._key, sub = jax.random.split(self._key)
-                    sentinel = self._ensure_sentinel()
-                    start = jnp.int32(self.iteration)
-                    args = (self.params, self.updater_state, self.state,
-                            sentinel, start, sub, xs, ys)
-                with self._timed_dispatch(span_labels):
-                    (self.params, self.updater_state, self.state,
-                     self._sentinel, losses) = self._epoch_fn(*args)
-                _tel.record_dispatch("train.epoch_fn", self._epoch_fn, args,
-                                     self._program_labels)
-                del args
-                self.iteration += nb
-                self.epoch += 1
-                self._count_passes(nb)
-                # lazy device scalar — listeners calling score() get this
-                # epoch's final loss without forcing a mid-chain host sync
-                self._score = losses[-1]
-                history.append(losses)
-                self._notify_listeners(span_labels, "on_epoch_end")
-            with _tel.span("train.phase.readback_s", span_labels):
-                out = np.concatenate([np.asarray(h) for h in history])
-                self._publish_layer_counters()
-            self._score = float(out[-1])
-            return out
+    _label_weight = staticmethod(_micro.multi_output_weight)
 
     def _scope_names(self):
         """The names under which the walks scope each vertex's forward."""
@@ -1060,91 +873,6 @@ class ComputationGraph(_caches.CompiledCacheMixin):
                 now = jax.device_get(self.state[name])
                 publish(name, now, seen.get(name))
                 seen[name] = now
-
-    def fit(self, data, labels=None, epochs: int = 1,
-            resilience=None) -> "ComputationGraph":
-        """Accepts MultiDataSetIterator, MultiDataSet, DataSetIterator,
-        DataSet, or (features, labels) arrays.
-
-        ``resilience`` (a ``parallel.resilience.ResiliencePolicy``) wraps
-        the epoch loop in the auto-resume driver — same contract as
-        ``MultiLayerNetwork.fit``."""
-        if resilience is not None:
-            from ..parallel.resilience import run_resilient_fit
-            return run_resilient_fit(self, data, labels=labels,
-                                     epochs=epochs, policy=resilience)
-        if not self.params and not self.state:
-            self.init()
-        if self._train_step is None:
-            self._train_step_fused = self.fused_updater_active()
-            self._train_step = self._build_train_step(
-                fused_cast=self._train_step_fused)
-            from ..ops import fused_epilogues as _fe
-            _fe.dispatch_updater(self.conf.dtype,
-                                 has_penalty=self._uses_regularization())
-            self._record_build("train.step", cache_attr="_train_step")
-        fused = getattr(self, "_train_step_fused", False)
-        # fused master-cast carry (ISSUE 16): one host-side cast per fit()
-        # call — see MultiLayerNetwork.fit
-        params_c = _dt.cast_floating(
-            self.params, _dt.resolve(self.conf.dtype)) if fused else None
-        from ..runtime import faults as _faults
-        it = _as_multi_iterator(data, labels)
-        # the train.phase.* spans: shared scaffold on CompiledCacheMixin
-        # (see caches.py, "phase tracing")
-        span_labels = self._phase_labels()
-        with _tel.span("train.phase.call_s", span_labels,
-                       entry="ComputationGraph.fit"):
-            for _ in range(epochs):
-                for mds in self._timed_batches(it, span_labels):
-                    with _tel.span("train.phase.stage_s", span_labels):
-                        xs = tuple(jnp.asarray(f) for f in mds.features)
-                        ys = tuple(jnp.asarray(l) for l in mds.labels)
-                        fms = tuple(None if m is None else jnp.asarray(m)
-                                    for m in mds.features_masks)
-                        lms = tuple(None if m is None else jnp.asarray(m)
-                                    for m in mds.labels_masks)
-                    with _tel.span("train.phase.prepare_s", span_labels):
-                        self._key, sub = jax.random.split(self._key)
-                        if _faults.enabled():
-                            _faults.trip("train.step")  # crash/preemption site
-                            # float check FIRST: all-int inputs must not
-                            # consume the injection's fire budget without
-                            # poisoning anything
-                            if any(jnp.issubdtype(x.dtype, jnp.floating)
-                                   for x in xs) and \
-                                    _faults.trip("train.nonfinite") \
-                                    is not None:
-                                xs = tuple(
-                                    jnp.full_like(x, jnp.nan)
-                                    if jnp.issubdtype(x.dtype, jnp.floating)
-                                    else x for x in xs)  # sentinel site
-                        step = jnp.asarray(self.iteration, dtype=jnp.int32)
-                        sentinel = self._ensure_sentinel()
-                        args = (self.params,) + ((params_c,) if fused else ()) \
-                            + (self.updater_state, self.state, step, sub, xs,
-                               ys, fms, lms, sentinel)
-                    self._last_batch = xs  # StatsListener activation sampling
-                    with self._timed_dispatch(span_labels):
-                        if fused:
-                            (self.params, params_c, self.updater_state,
-                             self.state, self._sentinel, loss) = \
-                                self._train_step(*args)
-                        else:
-                            (self.params, self.updater_state, self.state,
-                             self._sentinel, loss) = self._train_step(*args)
-                    _tel.record_dispatch("train.step", self._train_step,
-                                         args, self._program_labels)
-                    del args
-                    self._score = loss
-                    self.iteration += 1
-                    self._count_passes(1)
-                    self._notify_listeners(span_labels, "iteration_done",
-                                           self.iteration, self.epoch)
-                self.epoch += 1
-                self._notify_listeners(span_labels, "on_epoch_end")
-                it = _as_multi_iterator(data, labels)
-        return self
 
     # ------------------------------------------------------------- inference
     def feed_forward(self, *inputs, train: bool = False, rng=None):
@@ -1200,19 +928,6 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             return [np.argmax(o, axis=-1) for o in out]
         return np.argmax(out, axis=-1)
 
-    def quantize_params(self, mode: str = "int8") -> dict:
-        """Post-training per-channel int8 quantization of the opted-in
-        layer-vertex weights (ISSUE 9): the vertex-walk twin of
-        ``MultiLayerNetwork.quantize_params`` — returns a NEW params
-        tree with every ``quantize_spec``-marked weight replaced by a
-        ``QuantizedTensor``; merge/norm/embedding vertices stay f32 and
-        the model's own params are untouched."""
-        if mode != "int8":
-            raise ValueError(f"unknown quantization mode {mode!r} "
-                             "(expected 'int8')")
-        from ..ops import quantize as _q
-        return _q.quantize_model_params(self)[0]
-
     def score(self, data=None) -> float:
         """Loss of the last fit batch, or of the given (Multi)DataSet;
         includes the regularization term on both paths."""
@@ -1256,60 +971,10 @@ class ComputationGraph(_caches.CompiledCacheMixin):
             ev.eval(mds.labels[output], out, mask=mds.labels_masks[output])
         return ev
 
-    # -------------------------------------------------------------- listeners
-    def set_listeners(self, *listeners):
-        self._listeners = list(listeners)
-        return self
 
-    def add_listener(self, l):
-        self._listeners.append(l)
-        return self
-
-    # ---------------------------------------------------- flat-param adapter
-    def _flat_entries(self) -> List[Tuple[str, Tuple[str, ...]]]:
-        out = []
-        for name in self._topo:
-            if name in self.params:
-                out.extend((name, path)
-                           for path in _param_paths(self.params[name]))
-        return out
-
-    def params_flat(self) -> np.ndarray:
-        parts = [np.asarray(_get_path(self.params[vn], path)).ravel()
-                 for vn, path in self._flat_entries()]
-        return np.concatenate(parts) if parts else np.zeros((0,), np.float32)
-
-    def set_params_flat(self, vec) -> "ComputationGraph":
-        vec = np.asarray(vec)
-        total = self.num_params()
-        if vec.size != total:
-            raise ValueError(f"param vector length {vec.size} != model {total}")
-        off = 0
-        new = dict(self.params)
-        for vn, path in self._flat_entries():
-            a = _get_path(self.params[vn], path)
-            size = int(np.prod(a.shape))
-            new[vn] = _set_path(new[vn], path, jnp.asarray(
-                vec[off:off + size].reshape(a.shape), dtype=a.dtype))
-            off += size
-        self.params = new
-        return self
-
-    # ------------------------------------------------------------------ serde
-    def save(self, path, save_updater: bool = True, normalizer=None,
-             iterator=None):
-        from ..utils.serializer import save_model
-        save_model(self, path, save_updater=save_updater,
-                   normalizer=normalizer, iterator=iterator)
-
-    @staticmethod
-    def load(path, load_updater: bool = True):
-        from ..utils.serializer import load_model
-        model = load_model(path, load_updater=load_updater)
-        if not isinstance(model, ComputationGraph):
-            raise TypeError(f"{path} holds a {type(model).__name__}, "
-                            "not a ComputationGraph")
-        return model
+def _host_list(a) -> List[np.ndarray]:
+    """An array, or a list or tuple of them, as a list of host arrays."""
+    return [np.asarray(x) for x in (a if isinstance(a, (list, tuple)) else [a])]
 
 
 def _as_multi_iterator(data, labels=None) -> MultiDataSetIterator:
@@ -1322,9 +987,9 @@ def _as_multi_iterator(data, labels=None) -> MultiDataSetIterator:
     if isinstance(data, DataSetIterator):
         return _DataSetIteratorAdapter(data)
     if labels is not None:
-        f = [np.asarray(a) for a in (data if isinstance(data, (list, tuple)) else [data])]
-        l = [np.asarray(a) for a in (labels if isinstance(labels, (list, tuple)) else [labels])]
-        return NumpyMultiDataSetIterator(f, l, batch_size=f[0].shape[0])
+        f = _host_list(data)
+        return NumpyMultiDataSetIterator(f, _host_list(labels),
+                                         batch_size=f[0].shape[0])
     raise TypeError(f"cannot make a MultiDataSetIterator from {type(data)}")
 
 

@@ -1,7 +1,7 @@
 """Gradient micro-accumulation: one optimizer step over k microbatches.
 
-Shared by both engines' pure-step factories (``MultiLayerNetwork.
-_build_train_step`` / ``ComputationGraph._build_train_step``): the incoming
+Shared by both engines' pure-step factory (``nn/engine.py``
+``NetworkEngine._build_train_step``): the incoming
 batch [B, ...] is reshaped to [k, B/k, ...] and a ``lax.scan`` accumulates
 the gradient before the SINGLE updater application — global batch can
 grow past HBM (only one microbatch of activations is live at a time) without

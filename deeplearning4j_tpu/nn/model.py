@@ -19,87 +19,33 @@ DL4J's param-name order (W, b, gamma, beta).
 
 from __future__ import annotations
 
-import dataclasses
-import functools
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .. import dtypes as _dt
-from .. import environment as _env
-from . import caches as _caches
 from ..data.dataset import DataSet, DataSetIterator, NumpyDataSetIterator
 from ..ops import losses as _loss
-from ..runtime import telemetry as _tel
-from . import staging as _staging
+from . import engine as _engine
+from . import microbatch as _micro
 from .config import MultiLayerConfiguration
-from .layers.core import LossLayer, OutputLayer
-
-# DL4J param-name ordering inside a layer, for the flat view
-# (LSTMParamInitializer order W, RW, b; PW is our peephole tensor;
-# fw/bw are Bidirectional sub-trees)
-_PARAM_ORDER = {"W": 0, "RW": 1, "PW": 2, "b": 3, "gamma": 4, "beta": 5,
-                "fw": 6, "bw": 7}
+from .engine import _get_path, _is_loss_head  # noqa: F401 (_get_path: tests)
 
 
-def _param_paths(node, prefix=()):
-    """Depth-first (name, ...) paths to array leaves inside one layer/vertex
-    param dict, DL4J name order at each level (handles nested sub-trees like
-    Bidirectional's fw/bw)."""
-    if not isinstance(node, dict):
-        return [prefix]
-    out = []
-    for k in sorted(node, key=lambda n: (_PARAM_ORDER.get(n, 99), n)):
-        out.extend(_param_paths(node[k], prefix + (k,)))
-    return out
-
-
-def _get_path(tree, path):
-    for p in path:
-        tree = tree[p]
-    return tree
-
-
-def _set_path(tree, path, value):
-    """Set a leaf in a nested dict, copying the dicts along the path."""
-    if len(path) == 1:
-        new = dict(tree)
-        new[path[0]] = value
-        return new
-    new = dict(tree)
-    new[path[0]] = _set_path(tree[path[0]], path[1:], value)
-    return new
-
-
-class MultiLayerNetwork(_caches.CompiledCacheMixin):
+class MultiLayerNetwork(_engine.NetworkEngine):
     # invalidation also drops the rnn streaming pair: a carry captured
     # under the old dtype policy must not feed a retraced step
     _cache_attrs = ("_train_step", "_train_output_fn", "_epoch_fn",
                     "_rnn_step_fn", "_rnn_stream")
 
-    def _replace_conf_dtype(self, dtype: str):
-        return dataclasses.replace(self.conf, dtype=dtype)
-
     def __init__(self, conf: MultiLayerConfiguration):
-        self.conf = conf
+        super().__init__(conf)
         self.layers = conf.layers
-        self.params: Dict[str, Dict[str, jax.Array]] = {}
-        self.state: Dict[str, Dict[str, jax.Array]] = {}
-        self.updater_state: Any = None
-        self.iteration = 0
-        self.epoch = 0
-        self._score = float("nan")
-        self._listeners: List[Any] = []
-        self._train_step = None
-        self._train_output_fn = None
         self._rnn_step_fn = None
         self._rnn_stream = None
-        self._epoch_fn = None
         self._solver = None
-        self._inference_engine = None
-        self._key = jax.random.PRNGKey(conf.seed)
         self._out_layer = self.layers[-1] if self.layers else None
         if self.layers and not _is_loss_head(self._out_layer):
             # duck-typed: any layer exposing loss_value is a loss head
@@ -131,9 +77,6 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
         self._solver = None
         self._invalidate_compiled(cause="init")
         return self
-
-    def num_params(self) -> int:
-        return sum(int(np.prod(l.shape)) for l in jax.tree.leaves(self.params))
 
     # --------------------------------------------------------------- forward
     def _forward(self, params, x, state, *, train, rng, mask=None,
@@ -268,36 +211,6 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
             new_state.update(ns)
         return x, new_state, mask
 
-    def _regularization(self, params):
-        """Per-layer l1/l2 on weights (DL4J regularizes W, not b, by default)."""
-        total = 0.0
-        for i, layer in enumerate(self.layers):
-            if getattr(layer, "frozen", False):
-                continue  # FrozenLayer: no updates of any kind (DL4J)
-            l1 = getattr(layer, "l1", 0.0) or self.conf.l1
-            l2 = getattr(layer, "l2", 0.0) or self.conf.l2
-            if not (l1 or l2):
-                continue
-            p = params.get(str(i), {})
-            w = p.get("W")
-            if w is None:
-                continue
-            if l1:
-                total = total + l1 * jnp.sum(jnp.abs(w))
-            if l2:
-                total = total + 0.5 * l2 * jnp.sum(jnp.square(w))
-        return total
-
-    def _clip(self, grads):
-        """Gradient normalization/clipping; returns ``(grads, clip_events)``
-        — the shared ``gradnorm.clip_with_events`` pipeline (the sentinel
-        accumulates the events as telemetry)."""
-        from . import gradnorm as _gn
-        return _gn.clip_with_events(
-            self.conf.gradient_normalization,
-            self.conf.gradient_normalization_threshold,
-            self.conf.gradient_clip_value, self.conf.gradient_clip_l2, grads)
-
     # ------------------------------------------------------------- train step
     def _build_loss_fn(self):
         """The pure training loss ``(params, bn_state, key, x, y, fmask,
@@ -347,272 +260,38 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
 
         return loss_fn
 
-    def _uses_regularization(self) -> bool:
-        """Any l1/l2 penalty configured (conf-level or per-layer)? Gates
-        the mixed-precision cast hoist in ``_build_train_step`` — the
-        regularization term reads the params the loss fn is handed, so the
-        hoist (which hands it compute-dtype copies) only applies when the
-        term is identically zero."""
-        if self.conf.l1 or self.conf.l2:
-            return True
-        return any((getattr(l, "l1", 0.0) or getattr(l, "l2", 0.0))
-                   for l in self.layers)
+    # ---------------------------------------- the shared host side's hooks
+    def _keyed_layers(self):
+        return ((str(i), l) for i, l in enumerate(self.layers))
 
-    def fused_updater_active(self) -> bool:
-        """Does the train step fold the per-step f32->compute master cast
-        into the updater write (ISSUE 16)? True under a 16-bit policy with
-        no l1/l2 term (the regularization reads the params the loss fn is
-        handed, so it must see f32 masters) and the fused-epilogue library
-        enabled. When True the step carries a ``params_c`` compute copy
-        alongside the masters and the standalone per-step cast sweep
-        disappears from the compiled program."""
-        from ..ops import fused_epilogues as _fe
-        return _fe.route_updater(
-            self.conf.dtype,
-            has_penalty=self._uses_regularization()) is None
+    def _iterator(self, data, labels):
+        return _as_iterator(data, labels)
 
-    def _build_train_step(self, accum_steps: int = 1, grad_transform=None,
-                          fused_cast: bool = False):
-        """Fused pure train step (the body is ``nn/trainstep.py``'s, shared
-        with ``ComputationGraph`` and, from the gradient on, ``SameDiff``).
-        ``accum_steps=k`` splits the batch into k microbatches and
-        accumulates the mean gradient via ``lax.scan`` before the SINGLE
-        updater application (see ``nn/microbatch.py`` for the exactness
-        contract) — peak activation memory drops to one microbatch, so
-        global batch can grow past HBM. The conf's
-        ``workspace_mode`` remat policy (``nn/memory.py``) composes: inside
-        each microbatch, intra-segment activations are recomputed in the
-        backward pass instead of cached.
+    def _stage_batch(self, ds):
+        return tuple(None if a is None else jnp.asarray(a)
+                     for a in (ds.features, ds.labels, ds.features_mask,
+                               ds.labels_mask))
 
-        ``grad_transform`` (value-identity, e.g. the collective-overlap
-        sharding pins from ``parallel/overlap.py``) is applied to the raw
-        gradients BEFORE clipping/sentinel — the earliest point the full
-        tree exists, so a sharding constraint there moves the gradient
-        collectives ahead of the global-norm joins.
+    def _host_arrays(self, features, labels):
+        return [np.asarray(features)], [np.asarray(labels)]
 
-        bf16 audit fix (r12): under a 16-bit dtype policy with
-        ``accum_steps>1`` and no l1/l2 term, the fp32-master -> compute-
-        dtype cast is HOISTED out of the microbatch scan — the masters are
-        cast once per step and the scan body's ``cast_floating`` becomes an
-        identity, instead of re-materializing a compute-dtype copy of every
-        parameter k times per step. Gradients come back in the compute
-        dtype and promote exactly into the f32 scan accumulator (the same
-        values the per-microbatch cast-backward produced), then cast to the
-        master dtype before clipping — bit-equivalent (tested).
+    def _pack(self, arrays):
+        return arrays[0]
 
-        ``fused_cast=True`` (ISSUE 16, caller gates on
-        :meth:`fused_updater_active`) compiles the FUSED MASTER-CAST
-        variant: the signature gains a ``params_c`` compute-dtype copy
-        after ``params``, the forward differentiates the copy
-        (``_forward``'s ``cast_floating`` is identity on pre-cast leaves
-        -> bit-equal forward), cotangents upcast exactly like the unfused
-        cast's transpose, and ``apply_leafwise_cast`` emits next step's
-        compute copy inside the same fusion that writes the f32 master —
-        the standalone per-step cast sweep is gone from the program.
-        Bit-parity of params AND updater state vs the unfused step is
-        asserted in tests."""
-        from .layers.wrappers import FrozenLayer
-        from . import microbatch as _micro
-        from . import trainstep as _ts
-        frozen_keys = frozenset(str(i) for i, l in enumerate(self.layers)
-                                if isinstance(l, FrozenLayer))
-        step = _ts.engine_step(
-            self, self._build_loss_fn(), frozen_keys,
-            lambda x, y, fm, lm: _micro.label_count_weight(lm),
-            accum_steps, grad_transform, fused_cast)
-        # donate params/opt/bn buffers: in-place update on device (workspace
-        # arenas' moral equivalent, handled by XLA)
-        return jax.jit(step,
-                       donate_argnums=(0, 1, 2, 3) if fused_cast
-                       else (0, 1, 2),
-                       compiler_options=_env.engine_compiler_options())
+    def _epoch_masks(self):
+        return None, None
 
-    # ------------------------------------------------- on-device epoch loop
-    def _build_epoch_fn(self):
-        """lax.scan of the fused train step over a device-resident batch
-        stack — one XLA launch per epoch (see ComputationGraph.
-        _build_epoch_fn for the rationale; same contract, singular
-        batch arity). When the fused master-cast updater is active
-        (ISSUE 16) the scan body carries the compute-dtype ``params_c``
-        copy: the masters are cast ONCE per epoch launch and every
-        subsequent copy is emitted by the fused updater write — the
-        per-scan-step cast sweep is gone. External signature unchanged
-        (masters in, masters out)."""
-        # one dispatch decision per compiled program, as ``fit`` counts it
-        from ..ops import fused_epilogues as _fe
-        from . import trainstep as _ts
-        _fe.dispatch_updater(self.conf.dtype,
-                             has_penalty=self._uses_regularization())
-        fused = self.fused_updater_active()
-        step = self._build_train_step(fused_cast=fused).__wrapped__
-        return jax.jit(_ts.build_epoch(step, fused,
-                                       _dt.resolve(self.conf.dtype),
-                                       (None, None)),
-                       donate_argnums=(0, 1, 2, 3),
-                       compiler_options=_env.engine_compiler_options())
+    def _label_weight(self, x, y, fmask, lmask):
+        return _micro.label_count_weight(lmask)
 
-    def fit_on_device(self, features, labels, epochs: int = 1,
-                      batch_size: Optional[int] = None,
-                      drop_remainder: bool = False) -> np.ndarray:
-        """Compiled on-device training (ComputationGraph.fit_on_device
-        contract): data reshaped to [n_batches, B, ...], uploaded once,
-        scanned per epoch; returns the loss history. A non-divisible
-        dataset RAISES unless ``drop_remainder=True`` explicitly discards
-        the tail (silent data loss was r3's recorded footgun — VERDICT
-        weak #5). Masked datasets must use fit(). A floating feature array
-        that needs the network's dtype is cast in runs of rows on a pool of
-        host threads (``nn/staging.py``), bit for bit ``a.astype(dtype)``; the
-        counter ``train.stage{decision=, why=}`` says how each array was
-        staged."""
-        span_labels = self._phase_labels()
-        with _tel.span("train.phase.call_s", span_labels,
-                       entry="MultiLayerNetwork.fit_on_device"):
-            if not self.params and not self.state:
-                self.init()
-            x = np.asarray(features)
-            y = np.asarray(labels)
-            n = x.shape[0]
-            b = batch_size or n
-            nb = n // b
-            if nb == 0:
-                raise ValueError(f"batch_size {b} exceeds dataset size {n}")
-            if n % b and not drop_remainder:
-                raise ValueError(
-                    f"dataset size {n} is not divisible by batch_size {b}: "
-                    f"the on-device scan would drop {n % b} examples. Pass "
-                    "drop_remainder=True to accept that, or use fit() which "
-                    "pads and masks the tail")
-            dt = _dt.resolve(self.conf.dtype)
-            xs = _staging.stack(x, nb, b, dt, True, span_labels)
-            ys = _staging.stack(y, nb, b, dt, False, span_labels)
-            if getattr(self, "_epoch_fn", None) is None:
-                self._epoch_fn = self._build_epoch_fn()
-                self._record_build("train.epoch_fn", cache_attr="_epoch_fn")
-            history = []
-            for _ in range(epochs):
-                with _tel.span("train.phase.prepare_s", span_labels):
-                    self._key, sub = jax.random.split(self._key)
-                    sentinel = self._ensure_sentinel()
-                    start = jnp.int32(self.iteration)
-                    args = (self.params, self.updater_state, self.state,
-                            sentinel, start, sub, xs, ys)
-                with self._timed_dispatch(span_labels):
-                    (self.params, self.updater_state, self.state,
-                     self._sentinel, losses) = self._epoch_fn(*args)
-                _tel.record_dispatch("train.epoch_fn", self._epoch_fn, args,
-                                     self._program_labels)
-                del args
-                self.iteration += nb
-                self.epoch += 1
-                self._score = losses[-1]  # lazy device scalar for listeners
-                history.append(losses)
-                self._notify_listeners(span_labels, "on_epoch_end")
-            with _tel.span("train.phase.readback_s", span_labels):
-                out = np.concatenate([np.asarray(h) for h in history])
-            self._score = float(out[-1])
-            return out
-
-    def fit(self, data, labels=None, epochs: int = 1,
-            resilience=None) -> "MultiLayerNetwork":
-        """DL4J fit(): accepts DataSetIterator, DataSet, or (features, labels).
-
-        ``resilience`` (a ``parallel.resilience.ResiliencePolicy``) wraps
-        the epoch loop in the auto-resume driver: bounded retry-with-backoff
-        on transient runtime failures (device loss / preemption-shaped
-        ``XlaRuntimeError`` / iterator I/O errors) restoring model + updater
-        + iterator state from the policy's crash-safe checkpointer, plus
-        divergence escalation (rollback + LR backoff) after K consecutive
-        sentinel-skipped steps."""
-        if resilience is not None:
-            from ..parallel.resilience import run_resilient_fit
-            return run_resilient_fit(self, data, labels=labels,
-                                     epochs=epochs, policy=resilience)
-        if not self.params and not self.state:
-            self.init()
+    def _fit_by_solver(self, data, labels, epochs) -> bool:
+        """DL4J Solver.optimize path (§3.1): LBFGS/CG/line-search per batch
+        instead of the fused SGD step, where the conf names one."""
         if self._out_layer is None:
             raise ValueError("last layer must be an OutputLayer/LossLayer to fit()")
         algo = getattr(self.conf, "optimization_algo", "SGD") or "SGD"
-        if algo.upper() not in ("SGD", "STOCHASTIC_GRADIENT_DESCENT"):
-            return self._fit_with_solver(data, labels, epochs)
-        from ..runtime import faults as _faults
-        it = _as_iterator(data, labels)
-        if self._train_step is None:
-            self._train_step_fused = self.fused_updater_active()
-            self._train_step = self._build_train_step(
-                fused_cast=self._train_step_fused)
-            # one dispatch decision per compiled step (zero silent
-            # fallbacks — fused_epilogues.dispatch{decision=} discipline)
-            from ..ops import fused_epilogues as _fe
-            _fe.dispatch_updater(self.conf.dtype,
-                                 has_penalty=self._uses_regularization())
-            self._record_build("train.step", cache_attr="_train_step")
-        fused = getattr(self, "_train_step_fused", False)
-        # fused master-cast carry (ISSUE 16): ONE host-side cast per fit()
-        # call; every later compute copy is emitted by the fused updater
-        # write on-device (listener-side mutation of self.params mid-fit
-        # is not supported under the fused step — same contract as
-        # fit_on_device where the whole epoch is device-resident)
-        params_c = _dt.cast_floating(
-            self.params, _dt.resolve(self.conf.dtype)) if fused else None
-        # the train.phase.* spans: shared scaffold on CompiledCacheMixin
-        # (see caches.py, "phase tracing")
-        span_labels = self._phase_labels()
-        with _tel.span("train.phase.call_s", span_labels,
-                       entry="MultiLayerNetwork.fit"):
-            for _ in range(epochs):
-                for ds in self._timed_batches(it, span_labels):
-                    with _tel.span("train.phase.stage_s", span_labels):
-                        x = jnp.asarray(ds.features)
-                        y = jnp.asarray(ds.labels)
-                        fm = None if ds.features_mask is None \
-                            else jnp.asarray(ds.features_mask)
-                        lm = None if ds.labels_mask is None \
-                            else jnp.asarray(ds.labels_mask)
-                    with _tel.span("train.phase.prepare_s", span_labels):
-                        self._key, sub = jax.random.split(self._key)
-                        if _faults.enabled():
-                            _faults.trip("train.step")  # crash/preemption site
-                            # float check FIRST: a non-float input must not
-                            # consume the injection's fire budget without
-                            # poisoning anything
-                            if jnp.issubdtype(x.dtype, jnp.floating) and \
-                                    _faults.trip("train.nonfinite") \
-                                    is not None:
-                                x = jnp.full_like(x, jnp.nan)  # sentinel site
-                        # traced, no retrace per step
-                        step = jnp.asarray(self.iteration, dtype=jnp.int32)
-                        sentinel = self._ensure_sentinel()
-                        args = (self.params,) + ((params_c,) if fused else ()) \
-                            + (self.updater_state, self.state, step, sub, x,
-                               y, fm, lm, sentinel)
-                    self._last_batch = x  # StatsListener activation sampling
-                    with self._timed_dispatch(span_labels):
-                        if fused:
-                            (self.params, params_c, self.updater_state,
-                             self.state, self._sentinel, loss) = \
-                                self._train_step(*args)
-                        else:
-                            (self.params, self.updater_state, self.state,
-                             self._sentinel, loss) = self._train_step(*args)
-                    _tel.record_dispatch("train.step", self._train_step,
-                                         args, self._program_labels)
-                    del args
-                    # keep the loss on device: score() syncs lazily, so the
-                    # train loop never blocks on the host (async dispatch
-                    # back-to-back)
-                    self._score = loss
-                    self.iteration += 1
-                    self._notify_listeners(span_labels, "iteration_done",
-                                           self.iteration, self.epoch)
-                self.epoch += 1
-                self._notify_listeners(span_labels, "on_epoch_end")
-                it = _as_iterator(data, labels)  # fresh pass
-        return self
-
-    def _fit_with_solver(self, data, labels, epochs: int
-                         ) -> "MultiLayerNetwork":
-        """DL4J Solver.optimize path (§3.1): LBFGS/CG/line-search per batch
-        instead of the fused SGD step."""
+        if algo.upper() in ("SGD", "STOCHASTIC_GRADIENT_DESCENT"):
+            return False
         from ..optimize.solvers import Solver
         if self._solver is None:
             self._solver = Solver(
@@ -620,15 +299,9 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
                 iterations=getattr(self.conf, "solver_iterations", 5),
                 max_line_search_iterations=getattr(
                     self.conf, "max_line_search_iterations", 5))
-        it = _as_iterator(data, labels)
         for _ in range(epochs):
-            for ds in it:
-                x = jnp.asarray(ds.features)
-                y = jnp.asarray(ds.labels)
-                fm = None if ds.features_mask is None else \
-                    jnp.asarray(ds.features_mask)
-                lm = None if ds.labels_mask is None else \
-                    jnp.asarray(ds.labels_mask)
+            for ds in _as_iterator(data, labels):
+                x, y, fm, lm = self._stage_batch(ds)
                 self._last_batch = x  # StatsListener activation sampling
                 self._key, sub = jax.random.split(self._key)
                 self._score = self._solver.optimize(x, y, fm, lm, key=sub)
@@ -638,8 +311,7 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
             self.epoch += 1
             for cb in self._listeners:
                 cb.on_epoch_end(self)
-            it = _as_iterator(data, labels)
-        return self
+        return True
 
     def feed_forward(self, x, train: bool = False, rng=None):
         """Per-layer activations for input ``x`` (DL4J ``feedForward()``:
@@ -674,22 +346,6 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
     def predict(self, x) -> np.ndarray:
         """Class indices (DL4J ``predict()``)."""
         return np.argmax(self.output(x), axis=-1)
-
-    def quantize_params(self, mode: str = "int8") -> dict:
-        """Post-training per-channel int8 quantization of the opted-in
-        matmul/conv weights (ISSUE 9): a layer walk mirroring the
-        decode/remat pattern — every layer whose ``quantize_spec`` names
-        weights gets them replaced by ``ops.quantize.QuantizedTensor``;
-        norms, biases and embeddings stay f32. Returns a NEW params tree
-        (the model's own f32 params are untouched — training and f32
-        serving keep working); the serving engines call this at warmup
-        (``InferenceEngine(quantize="int8")``) so every AOT bucket
-        executable compiles the quantized graph."""
-        if mode != "int8":
-            raise ValueError(f"unknown quantization mode {mode!r} "
-                             "(expected 'int8')")
-        from ..ops import quantize as _q
-        return _q.quantize_model_params(self)[0]
 
     # ----------------------------------------------------- rnnTimeStep state
     def rnn_time_step(self, x):
@@ -968,72 +624,6 @@ class MultiLayerNetwork(_caches.CompiledCacheMixin):
             out = self.output(ds.features)
             ev.eval(ds.labels, out, mask=ds.labels_mask)
         return ev
-
-    # -------------------------------------------------------------- listeners
-    def set_listeners(self, *listeners):
-        self._listeners = list(listeners)
-        return self
-
-    def add_listener(self, l):
-        self._listeners.append(l)
-        return self
-
-    # ---------------------------------------------------- flat-param adapter
-    def _flat_entries(self) -> List[Tuple[str, Tuple[str, ...]]]:
-        out = []
-        for i in range(len(self.layers)):
-            si = str(i)
-            if si in self.params:
-                out.extend((si, path) for path in _param_paths(self.params[si]))
-        return out
-
-    def params_flat(self) -> np.ndarray:
-        """One contiguous fp vector, DL4J layer/param ordering."""
-        parts = [np.asarray(_get_path(self.params[si], path)).ravel()
-                 for si, path in self._flat_entries()]
-        return np.concatenate(parts) if parts else np.zeros((0,), np.float32)
-
-    def set_params_flat(self, vec) -> "MultiLayerNetwork":
-        vec = np.asarray(vec)
-        total = self.num_params()
-        if vec.size != total:
-            raise ValueError(f"param vector length {vec.size} != model {total}")
-        off = 0
-        new = dict(self.params)
-        for si, path in self._flat_entries():
-            a = _get_path(self.params[si], path)
-            size = int(np.prod(a.shape))
-            new[si] = _set_path(new[si], path, jnp.asarray(
-                vec[off:off + size].reshape(a.shape), dtype=a.dtype))
-            off += size
-        self.params = new
-        return self
-
-    # ------------------------------------------------------------------ serde
-    def save(self, path, save_updater: bool = True, normalizer=None,
-             iterator=None):
-        from ..utils.serializer import save_model
-        save_model(self, path, save_updater=save_updater,
-                   normalizer=normalizer, iterator=iterator)
-
-    @staticmethod
-    def load(path, load_updater: bool = True):
-        from ..utils.serializer import load_model
-        model = load_model(path, load_updater=load_updater)
-        if not isinstance(model, MultiLayerNetwork):
-            raise TypeError(f"{path} holds a {type(model).__name__}, "
-                            "not a MultiLayerNetwork")
-        return model
-
-
-def _is_loss_head(l) -> bool:
-    """True when the (FrozenLayer-unwrapped) layer really implements
-    loss_value — FrozenLayer delegates it unconditionally, so probe the
-    wrapped layer, not the wrapper."""
-    inner = getattr(l, "layer", None)
-    while inner is not None and hasattr(l, "frozen"):
-        l, inner = inner, getattr(inner, "layer", None)
-    return hasattr(l, "loss_value")
 
 
 def _as_iterator(data, labels=None) -> DataSetIterator:

@@ -1,7 +1,6 @@
-"""How ``fit_on_device`` stages a host array: reshaped to ``[n_batches,
-batch, ...]`` and placed on the device once a call, features cast to the
-network's dtype on the way (``MultiLayerNetwork`` and ``ComputationGraph``
-share this one function).
+"""How ``fit_on_device`` (``nn/engine.py``, both engines') stages a host
+array: reshaped to ``[n_batches, batch, ...]`` and placed on the device
+once a call, features cast to the network's dtype on the way.
 
 A cast runs on a pool of up to 8 host threads, each ``np.copyto`` writing
 one contiguous run of rows into its slice of one preallocated output:

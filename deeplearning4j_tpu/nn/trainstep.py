@@ -115,7 +115,8 @@ def engine_step(net, loss_fn, frozen_keys, weight_fn, accum_steps=1,
     microbatch weight (``nn/microbatch.py``); the updater, dtype policy,
     clipping, constraints and ``workspace_mode`` are read from ``net.conf``.
     The contracts (microbatch exactness, the cast hoist, the fused variant's
-    bit parity) are in ``MultiLayerNetwork._build_train_step``'s docstring.
+    bit parity) are in ``NetworkEngine._build_train_step``'s docstring
+    (``nn/engine.py``).
     """
     conf = net.conf
     vg_fn = jax.value_and_grad(loss_fn, has_aux=True)

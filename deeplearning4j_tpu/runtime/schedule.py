@@ -318,8 +318,8 @@ def _with_schedule(target, cfg: dict):
     happens here."""
     m = _model_of(target)
     conf0 = m.conf
-    m.conf = m._replace_conf_workspace_mode(
-        _memory_policy_name(cfg["workspace_mode"]))
+    m.conf = m._replace_conf(
+        workspace_mode=_memory_policy_name(cfg["workspace_mode"]))
     saved = None
     if _is_wrapper(target):
         saved = (target.accum_steps, target.overlap_grads,

@@ -764,8 +764,10 @@ def _check_lock_discipline(idx: ModuleIndex):
 #: latency-critical loops. Step OUTPUTS synced here stall the async
 #: dispatch pipeline; inputs (np->device conversion) are fine.
 HOT_PATHS = (
-    ("nn/model.py", "fit"),
-    ("nn/graph.py", "fit"),
+    # both engines' loops (nn/engine.py): a sync there stalls every step,
+    # or in fit_on_device every epoch launch
+    ("nn/engine.py", "fit"),
+    ("nn/engine.py", "fit_on_device"),
     ("parallel/data_parallel.py", "fit"),
     ("serving/batcher.py", "_dispatcher"),
     ("serving/batcher.py", "_run"),

@@ -125,24 +125,33 @@ def test_residual_graph_trains():
     assert net.score() < s0
 
 
-def test_graph_matches_sequential_when_linear():
+@pytest.mark.parametrize("case", ["fit", "fit_on_device", "l2", "bf16",
+                                  "save_load"])
+def test_graph_matches_sequential_when_linear(case, tmp_path):
     """A linear chain graph must produce identical training to the same
-    MultiLayerNetwork (same seed => same init => same fused step math)."""
+    MultiLayerNetwork (same seed => same init => same fused step math): the
+    two engines share one host side, so ``fit`` and ``fit_on_device``, an
+    l1/l2 term, the bf16 fused master-cast updater and save/load agree."""
     from deeplearning4j_tpu.nn.model import MultiLayerNetwork
 
     x = np.random.default_rng(3).normal(size=(16, 4)).astype(np.float32)
     y = np.eye(2, dtype=np.float32)[(x.sum(1) > 0).astype(int)]
 
-    mln_conf = (NeuralNetConfiguration.builder().seed(7)
-                .updater(Sgd(learning_rate=0.1))
-                .input_type(InputType.feed_forward(4))
+    def base():
+        b = (NeuralNetConfiguration.builder().seed(7)
+             .updater(Sgd(learning_rate=0.1)))
+        if case == "l2":
+            b = b.l2(1e-2)
+        if case == "bf16":
+            b = b.data_type("BFLOAT16")
+        return b
+
+    mln_conf = (base().input_type(InputType.feed_forward(4))
                 .list(DenseLayer(n_out=8, activation="tanh"),
                       OutputLayer(n_out=2)).build())
     mln = MultiLayerNetwork(mln_conf).init()
 
-    cg_conf = (NeuralNetConfiguration.builder().seed(7)
-               .updater(Sgd(learning_rate=0.1))
-               .graph_builder()
+    cg_conf = (base().graph_builder()
                .add_inputs("in")
                .set_input_types(InputType.feed_forward(4))
                .add_layer("dense", DenseLayer(n_out=8, activation="tanh"), "in")
@@ -151,11 +160,35 @@ def test_graph_matches_sequential_when_linear():
                .build())
     cg = ComputationGraph(cg_conf).init()
 
-    mln.fit(DataSet(x, y), epochs=5)
-    cg.fit(DataSet(x, y), epochs=5)
+    if case == "fit_on_device":
+        np.testing.assert_allclose(
+            mln.fit_on_device(x, y, epochs=5, batch_size=8),
+            cg.fit_on_device(x, y, epochs=5, batch_size=8),
+            rtol=1e-5, atol=1e-6)
+    else:
+        mln.fit(DataSet(x, y), epochs=5)
+        cg.fit(DataSet(x, y), epochs=5)
     # same layer kinds in same order with same seed stream => same params
     np.testing.assert_allclose(mln.params_flat(), cg.params_flat(),
                                rtol=1e-5, atol=1e-6)
+    if case == "l2":
+        assert mln._uses_regularization() and cg._uses_regularization()
+        np.testing.assert_allclose(mln.score(), cg.score(), rtol=1e-5)
+        np.testing.assert_allclose(mln.score(DataSet(x, y)),
+                                   cg.score(DataSet(x, y)), rtol=1e-5)
+    if case == "bf16":
+        assert mln.fused_updater_active() and cg.fused_updater_active()
+    if case == "save_load":
+        nets = {MultiLayerNetwork: mln, ComputationGraph: cg}
+        paths = {cls: os.path.join(tmp_path, cls.__name__) for cls in nets}
+        for cls, net in nets.items():
+            net.save(paths[cls])
+        for cls, other in ((MultiLayerNetwork, ComputationGraph),
+                           (ComputationGraph, MultiLayerNetwork)):
+            np.testing.assert_array_equal(cls.load(paths[cls]).params_flat(),
+                                          nets[cls].params_flat())
+            with pytest.raises(TypeError, match=f"not a {cls.__name__}"):
+                cls.load(paths[other])
 
 
 def test_multi_input_multi_output():

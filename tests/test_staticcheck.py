@@ -194,16 +194,39 @@ def test_host_sync_in_hot_path_positive_negative():
             "            x = np.asarray(ds.features)\n"
             "            out = self._train_step(x)\n"
             "            self._score = out\n")
-    assert rules_of(sc.check_source(bad, rel="fix/nn/model.py",
+    assert rules_of(sc.check_source(bad, rel="fix/nn/engine.py",
                                     rules=["host-sync-in-hot-path"])) \
         == ["host-sync-in-hot-path"]
-    assert rules_of(sc.check_source(item, rel="fix/nn/model.py",
+    assert rules_of(sc.check_source(item, rel="fix/nn/engine.py",
                                     rules=["host-sync-in-hot-path"])) \
         == ["host-sync-in-hot-path"]
-    assert sc.check_source(good, rel="fix/nn/model.py",
+    assert sc.check_source(good, rel="fix/nn/engine.py",
                            rules=["host-sync-in-hot-path"]) == []
     # unmapped function/file: no findings even for the bad snippet
     assert sc.check_source(bad, rel="fix/nn/other.py",
+                           rules=["host-sync-in-hot-path"]) == []
+
+
+def test_host_sync_rule_guards_the_epoch_loop():
+    """The engines' shared ``fit_on_device`` is a hot path: a sync on an
+    epoch launch's losses inside the loop is a finding, the readback after
+    it is not."""
+    bad = ("class Net:\n"
+           "    def fit_on_device(self, xs, epochs):\n"
+           "        for _ in range(epochs):\n"
+           "            losses = self._epoch_fn(xs)\n"
+           "            self._score = float(losses[-1])\n")
+    good = ("class Net:\n"
+            "    def fit_on_device(self, xs, epochs):\n"
+            "        history = []\n"
+            "        for _ in range(epochs):\n"
+            "            losses = self._epoch_fn(xs)\n"
+            "            history.append(losses)\n"
+            "        return np.concatenate([np.asarray(h) for h in history])\n")
+    assert rules_of(sc.check_source(bad, rel="fix/nn/engine.py",
+                                    rules=["host-sync-in-hot-path"])) \
+        == ["host-sync-in-hot-path"]
+    assert sc.check_source(good, rel="fix/nn/engine.py",
                            rules=["host-sync-in-hot-path"]) == []
 
 
