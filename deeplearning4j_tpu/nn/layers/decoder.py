@@ -24,6 +24,7 @@ import numpy as np
 
 from ...ops import activations as _act
 from ...ops import causal_attention as _ca
+from ...ops import delta_rule as _dr
 from ...ops import lm_loss as _lm
 from ...ops import moe as _moe
 from ...ops import sparse_attention as _sa
@@ -325,6 +326,195 @@ class LatentAttentionLayer(Layer):
                 state, mask)
 
 
+_LINEAR_CHUNKS = _tel.counter(
+    "linear_attn.chunks", "chunks of the gated delta rule scanned, one a "
+    "value head and chunk of a sequence, summed over the steps run, by layer")
+
+
+#: key heads whose part of a Gated DeltaNet layer is computed, and
+#: recomputed in the backward pass, together: at 32,768 positions a whole
+#: layer's backward took 5.1 GB of temporaries on a v5e, four key heads' 2.6
+_KEY_HEADS_A_GROUP = 4
+
+
+def _l2_normed(x):
+    """``x`` over the root of its squares' sum and 1e-6 (the published
+    ``l2norm``), in float32."""
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + 1e-6)
+
+
+@layer("gated_delta_net")
+class GatedDeltaNetLayer(Layer):
+    """Gated DeltaNet linear attention (arXiv:2412.06464) as Qwen3-Next lays
+    it out. One projection ``in_proj_qkvz`` gives, for each of
+    ``n_key_heads`` key heads in turn, its query and key (``key_size`` each)
+    and the values and output gates ``z`` of the ``n_value_heads /
+    n_key_heads`` value heads it serves (``value_size`` each); ``in_proj_ba``
+    gives each value head's ``b`` and ``a`` in the same grouping. The
+    queries, keys and values (``[q, k, v]`` over all heads) go through a
+    causal depthwise convolution of ``conv_size`` positions (``conv1d``
+    ``[channels, conv_size]``, no bias) and SiLU; queries and keys are
+    L2-normed (eps 1e-6), queries scaled by ``key_size^-1/2``, and both
+    repeated to the value heads (key head ``j`` serves value heads ``j r`` to
+    ``j r + r - 1``). ``beta = sigmoid(b)`` and ``g = -exp(A_log)
+    softplus(a + dt_bias)``, float32, drive the gated delta rule of
+    ``ops/delta_rule.py`` (a float32 state of ``[key_size, value_size]`` a
+    value head, zero at a sequence's start); its output is RMS-normed over
+    each head's ``value_size`` (statistics in float32, gain ``norm``, shared
+    by the heads, ``rms_eps``), times ``silu(z)``, and projected back by
+    ``out_proj``. No biases. The layer runs four key heads (and the value
+    heads they serve) at a time, each group's part from its slice of the
+    projections to its rows of ``out_proj``, summed in float32; the backward
+    pass recomputes a group at a time, so it holds one group's intermediates
+    (``_KEY_HEADS_A_GROUP``). Inside a recomputed segment the layer's
+    output is kept (``attention.kept{kind=linear}``), so the segment's
+    recomputation runs none of it. Scopes: ``attn.linear.project`` (the three projections) and
+    ``attn.linear`` (the rest). The state counts the chunks scanned since
+    ``init``; ``fit_on_device`` publishes their growth as
+    ``linear_attn.chunks``."""
+    n_key_heads: int = 1
+    n_value_heads: int = 1
+    key_size: int = 128
+    value_size: int = 128
+    conv_size: int = 4
+    rms_eps: float = 1e-6
+    weight_init: str = "xavier"
+    name: Optional[str] = None
+
+    def _sizes(self):
+        hk, hv = self.n_key_heads, self.n_value_heads
+        if hv % hk:
+            raise ValueError(f"{hv} value heads do not divide over {hk} key "
+                             "heads")
+        return hk, hv, hv // hk, self.key_size, self.value_size
+
+    def initialize(self, key, input_shape, dtype):
+        f = int(input_shape[-1])
+        hk, hv, _, dk, dv = self._sizes()
+        ks = jax.random.split(key, 5)
+        wi = self.weight_init
+        channels = 2 * hk * dk + hv * dv
+        return ({"in_proj_qkvz": _w(wi, ks[0], (f, 2 * hk * dk + 2 * hv * dv),
+                                    dtype),
+                 "in_proj_ba": _w(wi, ks[1], (f, 2 * hv), dtype),
+                 "conv1d": _w(wi, ks[2], (channels, self.conv_size), dtype),
+                 "A_log": jnp.log(jax.random.uniform(
+                     ks[3], (hv,), jnp.float32, 1e-3, 16.0)).astype(dtype),
+                 "dt_bias": jnp.ones((hv,), dtype),
+                 "norm": jnp.ones((dv,), dtype),
+                 "out_proj": _w(wi, ks[4], (hv * dv, f), dtype)},
+                {"chunks": jnp.zeros((), jnp.uint32)}, tuple(input_shape))
+
+    def groups(self, params):
+        """The layer's weights cut into its groups of
+        ``_KEY_HEADS_A_GROUP`` key heads (each with the value heads it
+        serves), stacked on a leading axis: the published grouped order makes
+        each group's columns of ``in_proj_qkvz`` and ``in_proj_ba``, its
+        channels of each of the convolution's ``q``, ``k`` and ``v`` parts and
+        its rows of ``out_proj`` contiguous."""
+        hk, hv, r, dk, dv = self._sizes()
+        per = min(_KEY_HEADS_A_GROUP, hk)
+        if hk % per:
+            raise ValueError(f"{hk} key heads do not divide into groups of "
+                             f"{per}")
+        G = hk // per
+        f = params["in_proj_qkvz"].shape[0]
+
+        def cols(w):
+            return w.reshape(f, G, -1).transpose(1, 0, 2)
+
+        conv = params["conv1d"]
+        q, k, v = (conv[:hk * dk], conv[hk * dk:2 * hk * dk],
+                   conv[2 * hk * dk:])
+        return {"in_proj_qkvz": cols(params["in_proj_qkvz"]),
+                "in_proj_ba": cols(params["in_proj_ba"]),
+                "conv1d": jnp.concatenate(
+                    [a.reshape((G, -1) + a.shape[1:]) for a in (q, k, v)],
+                    axis=1),
+                "A_log": params["A_log"].reshape(G, -1),
+                "dt_bias": params["dt_bias"].reshape(G, -1),
+                "out_proj": params["out_proj"].reshape(G, -1, f)}
+
+    def mix(self, params, x, hk):
+        """The gated delta rule's inputs for ``hk`` key heads (``params``:
+        one group's weights, :meth:`groups`): ``q``, ``k`` ``[B, T, Hv, dk]``
+        (normed, scaled, repeated), ``v`` ``[B, T, Hv, dv]``, ``g``,
+        ``beta`` ``[B, T, Hv]`` float32, and the output gates ``z`` ``[B, T,
+        Hv, dv]``."""
+        B, T, _ = x.shape
+        _, _, r, dk, dv = self._sizes()
+        hv = hk * r
+        with jax.named_scope("attn.linear.project"):
+            qkvz = jnp.dot(x, params["in_proj_qkvz"]).reshape(
+                B, T, hk, 2 * dk + 2 * r * dv)
+            ba = jnp.dot(x, params["in_proj_ba"]).reshape(B, T, hk, 2 * r)
+        with jax.named_scope("attn.linear"):
+            z = qkvz[..., 2 * dk + r * dv:].reshape(B, T, hv, dv)
+            mixed = jnp.concatenate(
+                [qkvz[..., :dk].reshape(B, T, hk * dk),
+                 qkvz[..., dk:2 * dk].reshape(B, T, hk * dk),
+                 qkvz[..., 2 * dk:2 * dk + r * dv].reshape(B, T, hv * dv)],
+                axis=-1)
+            K = self.conv_size
+            padded = jnp.pad(mixed, ((0, 0), (K - 1, 0), (0, 0)))
+            w = params["conv1d"].astype(jnp.float32)
+            conv = sum(padded[:, j:j + T].astype(jnp.float32) * w[:, j]
+                       for j in range(K))
+            mixed = jax.nn.silu(conv.astype(x.dtype))
+            q = mixed[..., :hk * dk].reshape(B, T, hk, dk)
+            k = mixed[..., hk * dk:2 * hk * dk].reshape(B, T, hk, dk)
+            v = mixed[..., 2 * hk * dk:].reshape(B, T, hv, dv)
+            q = (_l2_normed(q) * dk ** -0.5).astype(x.dtype)
+            k = _l2_normed(k).astype(x.dtype)
+            q, k = jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2)
+            ba = ba.astype(jnp.float32)
+            beta = jax.nn.sigmoid(ba[..., :r].reshape(B, T, hv))
+            g = -jnp.exp(params["A_log"].astype(jnp.float32)) \
+                * jax.nn.softplus(ba[..., r:].reshape(B, T, hv)
+                                  + params["dt_bias"].astype(jnp.float32))
+            return q, k, v, g, beta, z
+
+    def group(self, params, norm, x, hk):
+        """One group's share of the layer's output, ``[B, T, features]``
+        float32: its heads' mix, delta rule and gated norm through its rows
+        of ``out_proj``."""
+        B, T, _ = x.shape
+        q, k, v, g, beta, z = self.mix(params, x, hk)
+        with jax.named_scope("attn.linear"):
+            o = _dr.gated_delta_rule(q, k, v, g, beta)
+            o = _rms_norm(o, norm, self.rms_eps) \
+                * jax.nn.silu(z.astype(jnp.float32)).astype(o.dtype)
+        with jax.named_scope("attn.linear.project"):
+            return jnp.dot(o.reshape(B, T, -1), params["out_proj"],
+                           preferred_element_type=jnp.float32)
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        B, T, f = x.shape
+        hk, hv, _, _, _ = self._sizes()
+        per = min(_KEY_HEADS_A_GROUP, hk)
+        one = jax.checkpoint(
+            lambda w, x: self.group(w, params["norm"], x, per))
+
+        def step(acc, w):
+            return acc + one(w, x), None
+
+        y, _ = jax.lax.scan(step, jnp.zeros((B, T, f), jnp.float32),
+                            self.groups(params))
+        y = y.astype(x.dtype)
+        if _ca._keeps("linear", _keeps_output(f, f)):
+            y = _ca._tag(y)
+        if train and "chunks" in state:
+            state = {**state, "chunks": state["chunks"] + jnp.uint32(
+                B * hv * -(-T // _dr.CHUNK))}
+        return y, state, mask
+
+    def publish_counters(self, vertex: str, now: dict, before) -> None:
+        """Add what the state's count grew by since ``before`` (None: since
+        zero) to ``linear_attn.chunks``; counts wrap at 2**32."""
+        _LINEAR_CHUNKS.inc(int(_grew(now, before, "chunks")), layer=vertex)
+
+
 def _gated_ffn(x, w1, w3, w2, activation):
     return jnp.dot(_act.get(activation)(jnp.dot(x, w1)) * jnp.dot(x, w3), w2)
 
@@ -389,7 +579,9 @@ class SparseExpertLayer(Layer):
     tokens routed to them with no token dropped (``ops/moe.py``), and adds a
     shared expert of width ``shared_hidden`` (0: none) that every token goes
     through; routed and shared experts take the one ``activation`` (``swish``:
-    SwiGLU, ``relu``: ReGLU). ``held=None`` holds every expert. What experts
+    SwiGLU, ``relu``: ReGLU). With ``shared_gate`` the shared expert's output
+    is scaled by ``sigmoid(x Wsg)``, ``Wsg`` ``[hidden, 1]``, a token at a
+    time. ``held=None`` holds every expert. What experts
     held elsewhere would add is left out (``absent="left_out"``); with
     ``absent="stand_in"`` the held experts a token chose stand in for its
     chosen experts held elsewhere, their weights renormalised over
@@ -409,6 +601,7 @@ class SparseExpertLayer(Layer):
     activation: str = "swish"
     early_router: bool = False
     absent: str = "left_out"
+    shared_gate: bool = False
     weight_init: str = "xavier"
     name: Optional[str] = None
 
@@ -445,6 +638,9 @@ class SparseExpertLayer(Layer):
             params.update(S1=_w(wi, ks[4], (f, s), dtype),
                           S3=_w(wi, ks[5], (f, s), dtype),
                           S2=_w(wi, ks[6], (s, f), dtype))
+            if self.shared_gate:
+                params["Wsg"] = _w(wi, jax.random.fold_in(key, 7), (f, 1),
+                                   dtype)
         state = {"tokens": jnp.zeros((count,), jnp.uint32),
                  "here": jnp.zeros((), jnp.uint32),
                  "elsewhere": jnp.zeros((), jnp.uint32),
@@ -483,8 +679,12 @@ class SparseExpertLayer(Layer):
         with jax.named_scope("moe.combine"):
             y = routed
             if self.shared_hidden:
-                y = y + _gated_ffn(xt, params["S1"], params["S3"],
-                                   params["S2"], self.activation)
+                shared = _gated_ffn(xt, params["S1"], params["S3"],
+                                    params["S2"], self.activation)
+                if self.shared_gate:
+                    shared = shared * jax.nn.sigmoid(
+                        jnp.dot(xt, params["Wsg"]))
+                y = y + shared
             y = y.astype(x.dtype).reshape(lead + (f,))
         if train and "tokens" in state:
             here = ends[-1].astype(jnp.uint32)
@@ -528,10 +728,15 @@ class CausalLMOutputLayer(Layer):
     nothing of the head. In training ``apply`` returns the loss itself, a
     float32 scalar that ``loss_value`` hands on (only a scalar can be
     differentiated through this head, and not in forward mode), otherwise
-    the probabilities ``[B, T, n_out]``."""
+    the probabilities ``[B, T, n_out]``. With ``block`` the logits are made
+    ``block`` positions at a time instead (a sequence length it does not
+    divide is taken a sequence at a time), each row's last position weighted
+    nought: at 32,768 positions and a vocabulary of 18,992 a sequence's
+    float32 logits are 2.5 GB."""
     n_inputs = 2
     quantizable = True
     n_out: int = 0
+    block: Optional[int] = None
     weight_init: str = "xavier"
     name: Optional[str] = None
 
@@ -551,9 +756,19 @@ class CausalLMOutputLayer(Layer):
                              preferred_element_type=jnp.float32)
             return jax.nn.softmax(logits, axis=-1), state, mask
 
-        B, T = h.shape[:2]
+        B, T, d = h.shape
+        tokens = jnp.asarray(tokens, jnp.int32)
+        if self.block and T % self.block == 0:
+            C = self.block
+            w = jnp.where(jnp.arange(T) < T - 1, 1.0 / (B * (T - 1)), 0.0)
+            loss, _ = _lm.weighted_cross_entropy(
+                h.reshape(B * T // C, C, d), params["W"],
+                jnp.roll(tokens, -1, axis=1).reshape(B * T // C, C),
+                jnp.broadcast_to(w, (B, T)).reshape(B * T // C, C),
+                layer="causal")
+            return loss, state, None
         loss, _ = _lm.weighted_cross_entropy(
-            h[:, :-1], params["W"], jnp.asarray(tokens, jnp.int32)[:, 1:],
+            h[:, :-1], params["W"], tokens[:, 1:],
             jnp.full((B, T - 1), 1.0 / (B * (T - 1)), jnp.float32),
             layer="causal")
         return loss, state, None

@@ -505,6 +505,58 @@ def test_smallthinker_epoch_compiles_for_the_chip(one_chip, monkeypatch):
     assert not _scatters(text, B * T, cfg["hidden_size"])
 
 
+def test_delta_rule_compiles(one_chip, monkeypatch):
+    """The gated delta rule's three kernels at Qwen3-Next's widths (heads of
+    128, chunks of 64, bfloat16 operands), forward and backward through
+    ``gated_delta_rule`` with a TPU in sight: ``delta_rule_fwd``,
+    ``delta_rule_states`` and ``delta_rule_bwd`` under their names, each
+    chunk's decay read from scalar memory, and the dispatch counted as the
+    kernels'."""
+    from deeplearning4j_tpu.ops import delta_rule as dr
+    from deeplearning4j_tpu.runtime import telemetry as tel
+    monkeypatch.setattr(dr, "_tpu_available", lambda: True)
+    counter = tel.registry.get("linear_attn.dispatch")
+    B, T, H, d = 1, 4096, 8, 128
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(jnp.sin(dr.gated_delta_rule(q, k, v, g, beta)
+                               .astype(jnp.float32)))
+
+    before = counter.value(decision="kernel")
+    _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip,
+             ((B, T, H, d), BF16), ((B, T, H, d), BF16), ((B, T, H, d), BF16),
+             ((B, T, H), jnp.float32), ((B, T, H), jnp.float32),
+             kernels=("delta_rule_fwd", "delta_rule_states",
+                      "delta_rule_bwd"))
+    assert counter.value(decision="kernel") == before + 1
+
+
+def test_the_full_layer_at_a_head_of_256_compiles(one_chip, monkeypatch):
+    """Qwen3-Next's gated full layer as the masked kernels see it: 16 query
+    heads of 256 on 2 KV heads, 32,768 positions, forward and backward, on
+    the kernels (``causal_blocks`` tiles it 1,024 x 1,024)."""
+    from deeplearning4j_tpu.ops import causal_attention as ca
+    from deeplearning4j_tpu.runtime import telemetry as tel
+    monkeypatch.setattr(ca, "_tpu_available", lambda: True)
+    monkeypatch.setattr(fa, "_tpu_available", lambda: True)
+    counter = tel.registry.get("attention.dispatch")
+    B, T, H, KV, d = 1, 32768, 16, 2, 256
+    assert ca.causal_blocks(T, d, d, None, 2) == (1024, 1024)
+
+    def loss(q, k, v):
+        return jnp.sum(jnp.sin(ca.causal_attention(q, k, v).astype(
+            jnp.float32)))
+
+    before = counter.value(kind="full", rope="default", decision="kernel")
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+             ((B, T, H, d), BF16), ((B, T, KV, d), BF16),
+             ((B, T, KV, d), BF16),
+             kernels=("causal_flash_fwd", "causal_flash_bwd_dq",
+                      "causal_flash_bwd_dkv"))
+    assert counter.value(kind="full", rope="default",
+                         decision="kernel") == before + 1
+
+
 def test_lstm_cell_compiles(one_chip):
     B, U = 64, 256
     assert pk.fits_vmem(B, U, U)
